@@ -128,3 +128,20 @@ def test_lake_npz_works_without_pyarrow(tmp_path):
         assert kept == [] and pruned == 1
     finally:
         F.HAVE_PYARROW = real
+
+
+def test_imports_initialise_no_backend():
+    """A chip belongs to one process: importing the package — every
+    module of it, as the fleet parent, its workers and every tool do —
+    must not touch a device. A module-level `jnp` constant is enough to
+    initialise the backend and take the chip from the engine."""
+    import subprocess
+    code = (
+        "import importlib, sys\n"
+        "from jax._src import xla_bridge\n"
+        f"for m in {MODULES!r}:\n"
+        "    importlib.import_module(m)\n"
+        "assert not xla_bridge._backends, list(xla_bridge._backends)\n")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, cwd=str(_ROOT.parent), timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]

@@ -159,16 +159,34 @@ def test_jit_cache_metrics_exported(runner):
 
 
 def test_compilation_cache_env_var(monkeypatch, tmp_path):
+    """One rule, no knob: with $JAX_COMPILATION_CACHE_DIR set the engine
+    leaves the directory to JAX (no config.update of it in code); unset,
+    the cache goes to the fixed <checkout>/.jax_cache."""
+    import os
     import jax
     import trino_tpu
     before = jax.config.jax_compilation_cache_dir
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, val: (updates.append(name), real_update(name, val)))
     try:
-        monkeypatch.setenv("TRINO_TPU_COMPILATION_CACHE_DIR",
-                           str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         trino_tpu.enable_persistent_cache()
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        trino_tpu.enable_persistent_cache()
+        assert "jax_compilation_cache_dir" in updates
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(trino_tpu.__file__)))
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            checkout, ".jax_cache")
     finally:
-        jax.config.update("jax_compilation_cache_dir", before)
+        real_update("jax_compilation_cache_dir", before)
+        real_update("jax_persistent_cache_min_compile_time_secs", min_secs)
 
 
 # ------------------------------------------------- TPC-H literal variants

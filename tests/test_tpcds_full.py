@@ -25,7 +25,7 @@ pytestmark = pytest.mark.skipif(
     not tpcds_queries.available(),
     reason="reference TPC-DS query resources not present")
 
-# engine == oracle at SF0.01 (generated list; see NOTES_r05.md)
+# engine == oracle at SF0.01 (generated list)
 VERIFIED = [
     "q01", "q03", "q04", "q06", "q07", "q09", "q10", "q11", "q12", "q13",
     "q15", "q16", "q17", "q19", "q20", "q21", "q23", "q24", "q25", "q26",
@@ -57,7 +57,7 @@ KNOWN_FAILING = {}
 
 # the full 99-query sweep takes ~15 min on the 1-core host; default CI
 # runs a representative sample across the join/agg/window/set-op shapes,
-# TRINO_TPU_TPCDS_FULL=1 runs everything (what NOTES_r05 reports)
+# TRINO_TPU_TPCDS_FULL=1 runs everything
 import os
 
 _FULL = os.environ.get("TRINO_TPU_TPCDS_FULL", "0") == "1"

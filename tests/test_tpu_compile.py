@@ -1,0 +1,113 @@
+"""The main path's kernels, compiled for a v5e that is described and not
+attached (the TPU compiler is installed here; nothing runs). These guard
+what the tests on the CPU backend cannot see: what the chip's compiler
+refuses, and programs that take it minutes. A compile that passes is not a
+chip run — `python chip_smoke.py` on the chip is.
+
+One file on purpose: the process that describes the topology holds the TPU
+library until it exits, so every test that needs it lives here, behind one
+module-scoped fixture.
+"""
+
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from trino_tpu import types as T
+from trino_tpu.page import Column, Page
+
+SCAN_WIDTH = 1 << 20        # the table cache's page capacity at SF1
+BUILD_WIDTH = 1 << 18       # q3's orders build after its filters at SF1
+D12_2 = T.DecimalType(12, 2)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _page(sharding, capacity, column_types, lead=()):
+    """A Page of shapes (no arrays: a described device holds none)."""
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(lead + shape, dtype, sharding=sharding)
+    cols = tuple(Column(spec((capacity,), T.to_numpy_dtype(t)), None, t,
+                        None) for t in column_types)
+    return Page(cols, spec((), jnp.int32))
+
+
+def _compile(fn, *args, limit_s):
+    t0 = time.perf_counter()
+    compiled = jax.jit(fn).lower(*args).compile()
+    took = time.perf_counter() - t0
+    assert took < limit_s, \
+        f"{took:.0f}s to compile for the v5e (limit {limit_s}s)"
+    return compiled
+
+
+def test_q6_chain_at_scan_width(one_chip):
+    """The fused scan-filter-project-aggregate chain (Page.filter inside):
+    as a multi-operand sort this took the TPU compiler minutes."""
+    import __graft_entry__
+    page = _page(one_chip, SCAN_WIDTH, (T.DATE, D12_2, D12_2, D12_2))
+    compiled = _compile(__graft_entry__._q6_pipeline(), page, limit_s=60)
+    assert " sort(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
+def test_q3_join_build_sort(one_chip):
+    """The sort-bearing build kernel of q3's joins: 64-bit keys ordered by
+    passes of one 32-bit sort (ops/radix.py)."""
+    from trino_tpu.ops.join import prepare_build
+    build = _page(one_chip, BUILD_WIDTH, (T.BIGINT, T.DATE, T.INTEGER))
+    compiled = _compile(prepare_build([0]), build, limit_s=120)
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
+def test_mxu_lookup_matmul(one_chip):
+    """The indicator-matmul probe at the default slot bound against a scan
+    page: a loop over key-range blocks, one dot in the program."""
+    from trino_tpu.ops.join_mxu import matmul_lookup
+    slots = 4096                                # mxu_join_max_slots
+    table = jax.ShapeDtypeStruct((slots, 2), jnp.float32, sharding=one_chip)
+    kmin = jax.ShapeDtypeStruct((), jnp.uint64, sharding=one_chip)
+    pkey = jax.ShapeDtypeStruct((SCAN_WIDTH,), jnp.uint64,
+                                sharding=one_chip)
+    compiled = _compile(matmul_lookup, table, kmin, pkey, limit_s=60)
+    assert compiled.memory_analysis().temp_size_in_bytes < (4 << 30)
+
+
+def test_mesh_all_to_all_on_four_chips(topo):
+    """One mesh program for the four described chips: the hash
+    repartition exchange must stay a collective inside the program."""
+    from trino_tpu.parallel.exchange import all_to_all_by_key
+    from trino_tpu.parallel.mesh import QueryMesh
+    mesh = QueryMesh(topo.devices[:4])
+    sharded = NamedSharding(mesh.mesh, P(QueryMesh.AXIS))
+    page = _page(sharded, 1 << 15, (T.BIGINT, D12_2), lead=(4,))
+    compiled = _compile(
+        mesh.shard_map(lambda p: all_to_all_by_key(p, [0], 1 << 13)),
+        page, limit_s=150)
+    assert "all-to-all" in compiled.as_text()

@@ -373,21 +373,55 @@ def test_planned_engine_restart_zero_drop_misses(fo):
 def test_failover_metrics_surface(fo):
     """The observability satellite wiring: supervisor counters, breaker
     state, deferred-miss counters, and bus drop counts all land in ONE
-    shared-port scrape."""
-    text = urllib.request.urlopen(f"{fo.base_uri}/v1/metrics",
-                                  timeout=30).read().decode()
+    shared-port scrape. Causes what it asserts — one crash restart (whose
+    outage drops the workers' hit batches on the dead engine socket) and
+    one planned restart — so it holds on whichever xdist worker runs it,
+    with or without the crash tests above in the same process."""
+    from trino_tpu.fleet.supervisor import read_supervisor_record
+
+    def restarts():
+        sup = read_supervisor_record(fo.fleet_dir) or {}
+        counts = sup.get("engine_restarts") or {}
+        return counts.get("crash", 0), counts.get("planned", 0)
+
+    def scrape():
+        return urllib.request.urlopen(f"{fo.base_uri}/v1/metrics",
+                                      timeout=30).read().decode()
+
+    crash_before, planned_before = restarts()
+    hit_sql = "EXECUTE fo_probe USING 5"
+    _prime_hit(fo, hit_sql)
+    epoch_before = fo.engine_epoch
+    os.kill(fo.engine_proc.pid, signal.SIGKILL)
+    # hits keep serving through the outage; their batches to the dead
+    # engine socket are the drops the scrape must show
+    deadline = time.monotonic() + 60
+    while time.monotonic() < deadline:
+        _http(fo.base_uri, hit_sql)
+        if "trino_tpu_fleet_bus_drops_total" in scrape():
+            break
+        time.sleep(0.2)
+    _wait_engine_state(fo, epoch=epoch_before + 1)
+    # the supervisor adopts the respawned generation (and bumps its own
+    # epoch) a moment after the engine's record says active
+    deadline = time.monotonic() + 60
+    while time.monotonic() < deadline and (
+            restarts()[0] <= crash_before
+            or fo.engine_epoch <= epoch_before):
+        time.sleep(0.2)
+    assert fo.engine_restart() == epoch_before + 2
+
+    text = scrape()
     assert 'trino_tpu_engine_restarts_total{kind="crash"}' in text
     assert "trino_tpu_engine_outage_seconds" in text
     assert "trino_tpu_fleet_breaker_state" in text
     assert "trino_tpu_fleet_worker_deferred_misses" in text
     assert "trino_tpu_engine_epoch" in text
-    # the crash tests above dropped hit batches on a dead engine socket
     assert "trino_tpu_fleet_bus_drops_total" in text
     # counts match the supervisor's own record
-    from trino_tpu.fleet.supervisor import read_supervisor_record
-    sup = read_supervisor_record(fo.fleet_dir)
-    assert sup["engine_restarts"]["planned"] >= 1
-    assert sup["engine_restarts"]["crash"] >= 2
+    crash, planned = restarts()
+    assert crash >= crash_before + 1
+    assert planned >= planned_before + 1
 
 
 def test_zz_poison_statement_stops_crash_loop(fo):

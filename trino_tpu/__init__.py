@@ -16,25 +16,26 @@ import jax as _jax
 # proves it safe (e.g. dictionary codes, date arithmetic).
 _jax.config.update("jax_enable_x64", True)
 
-def enable_persistent_cache(directory: str = None) -> None:
-    """Point XLA's persistent compilation cache at `directory` (default:
-    $TRINO_TPU_COMPILATION_CACHE_DIR, else `.jax_cache` beside the
-    package). Query kernels are expensive to compile and keyed purely by
-    program; caching them on disk makes repeat runs — test suites, bench
-    rounds, restarted sessions — skip recompilation. With literal hoisting
-    (expr/hoist.py) kernels are literal-free, so one disk entry serves
-    every literal variant of a query shape across processes; the
+def enable_persistent_cache() -> None:
+    """Turn on XLA's persistent compilation cache, by one rule: where
+    $JAX_COMPILATION_CACHE_DIR is set the directory is JAX's own business
+    (it reads that variable itself; nothing is set in code), otherwise the
+    cache lives at the fixed `<checkout>/.jax_cache` beside the package —
+    fixed because the path is part of the cache key, so a directory that
+    moves never hits. Query kernels are expensive to compile (a sort-bearing
+    program costs the TPU compiler minutes) and keyed purely by program;
+    caching them on disk makes repeat runs — test suites, restarted
+    servers, a second chip_smoke.py — skip recompilation. With literal
+    hoisting (expr/hoist.py) kernels are literal-free, so one disk entry
+    serves every literal variant of a query shape across processes; the
     in-process jit-cache LRU sits above this, holding loaded executables
     (an LRU eviction costs a re-trace + disk load, not a recompile)."""
     import os as _os
-    if directory is None:
-        directory = _os.environ.get("TRINO_TPU_COMPILATION_CACHE_DIR")
-    if not directory:
-        directory = _os.path.join(
+    if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        _jax.config.update("jax_compilation_cache_dir", _os.path.join(
             _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
-            ".jax_cache")
-    _jax.config.update("jax_compilation_cache_dir", directory)
-    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+            ".jax_cache"))
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
 
 
 from trino_tpu import types

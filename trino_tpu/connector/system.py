@@ -143,10 +143,7 @@ def _rows_for(table: str) -> List[tuple]:
         import jax
 
         from trino_tpu.exec.memory import NODE_POOL
-        try:
-            devices = jax.devices()
-        except Exception:
-            devices = []
+        devices = jax.devices()
         # the pool columns repeat per device row (the node pool is the
         # single-controller process's per-chip budget + source); the
         # device_* columns are THAT chip's attributed reservations, fed

@@ -33,7 +33,7 @@ the plain jitted callable rather than failing the query, counted as
 `aot_fallbacks`.
 
 Interaction with the on-disk persistent XLA cache
-(trino_tpu.enable_persistent_cache / TRINO_TPU_COMPILATION_CACHE_DIR): this
+(trino_tpu.enable_persistent_cache): this
 LRU caches *loaded executables + traces in-process*; the persistent cache
 stores *compiled XLA binaries on disk*, keyed by the traced program. An LRU
 eviction (or a process restart) therefore costs a re-trace plus a disk

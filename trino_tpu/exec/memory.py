@@ -361,18 +361,20 @@ NODE_POOL = NodeMemoryPool()
 
 def measured_device_memory_bytes() -> Optional[int]:
     """The backend's reported per-device memory capacity (TPU HBM via
-    device.memory_stats()['bytes_limit']); None when the backend doesn't
-    report (the CPU backend, including the forced 8-device dev mesh)."""
-    try:
-        import jax
-        dev = jax.local_devices()[0]
-        stats = dev.memory_stats()
-        if stats:
-            limit = int(stats.get("bytes_limit") or 0)
-            return limit or None
-    except Exception:
+    device.memory_stats()['bytes_limit']); None on the CPU backend only
+    (including the forced 8-device dev mesh), which reports none. An
+    accelerator that fails to report its limit is an error, never a
+    silent drop to the static CPU default."""
+    import jax
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
         return None
-    return None
+    limit = int((dev.memory_stats() or {}).get("bytes_limit") or 0)
+    if not limit:
+        raise RuntimeError(
+            f"{dev.platform} device {dev.device_kind!r} reports no "
+            "bytes_limit in memory_stats(); cannot size the node pool")
+    return limit
 
 
 def autosize_node_pool(scan_cache_budget: Optional[int] = None,

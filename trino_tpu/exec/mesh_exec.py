@@ -487,7 +487,11 @@ class MeshLowerer:
             out, total = op(probe, build)
             if post_filter is not None:
                 out = out.filter(post_filter(out, ()))
-            aux = {"total": jax.lax.pmax(total.astype(jnp.int64), AXIS),
+            # per-shard: the host takes the max over shards
+            # (_ladder_bumps); an in-program pmax over int64 is a
+            # collective the TPU compiler does not lower (only a 64-bit
+            # SUM all-reduce is)
+            aux = {"total": total.astype(jnp.int64),
                    "cap": jnp.int32(cap)}
             if mxu is not None:
                 aux.update(_mxu_aux(probe, build, build_keys[0], mxu))
@@ -584,7 +588,8 @@ class MeshLowerer:
                            prepared=False, mxu_slots=mxu,
                            null_aware=node.null_aware)
             out, total = op(probe, build)
-            aux = {"total": jax.lax.pmax(total.astype(jnp.int64), AXIS),
+            # per-shard total, as in the join lowering above
+            aux = {"total": total.astype(jnp.int64),
                    "cap": jnp.int32(cap)}
             if mxu is not None:
                 aux.update(_mxu_aux(probe, build, build_keys[0], mxu))

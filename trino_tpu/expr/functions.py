@@ -425,22 +425,31 @@ def _least(out_type, arg_types, *args):
 # ops (vectorizes onto VPU; reference: scalar/DateTimeFunctions.java).
 
 def _civil_from_days(days):
-    """days since 1970-01-01 -> (year, month, day), proleptic Gregorian."""
-    z = days.astype(jnp.int64) + 719468
-    era = jax.lax.div(jnp.where(z >= 0, z, z - 146096), jnp.int64(146097))
-    doe = z - era * 146097
+    """days since 1970-01-01 -> (year, month, day), proleptic Gregorian.
+
+    The arithmetic runs in int32 — every intermediate of a representable
+    date fits — and the results widen to int64 for the callers. The TPU
+    emulates 64-bit integer division op by op: as int64, the fourteen
+    scalar divides of one `date + interval year` made q6's fused chain an
+    8 MB program that took the v5e compiler 22 s and 8 s more to load
+    back from the compile cache (PR 23)."""
+    i32 = jnp.int32
+    z = days.astype(i32) + i32(719468)
+    era = jax.lax.div(jnp.where(z >= 0, z, z - i32(146096)), i32(146097))
+    doe = z - era * i32(146097)
     yoe = jax.lax.div(
-        doe - jax.lax.div(doe, jnp.int64(1460))
-        + jax.lax.div(doe, jnp.int64(36524))
-        - jax.lax.div(doe, jnp.int64(146096)), jnp.int64(365))
-    y = yoe + era * 400
-    doy = doe - (365 * yoe + jax.lax.div(yoe, jnp.int64(4))
-                 - jax.lax.div(yoe, jnp.int64(100)))
-    mp = jax.lax.div(5 * doy + 2, jnp.int64(153))
-    d = doy - jax.lax.div(153 * mp + 2, jnp.int64(5)) + 1
-    m = mp + jnp.where(mp < 10, 3, -9)
-    y = y + (m <= 2)
-    return y, m, d
+        doe - jax.lax.div(doe, i32(1460))
+        + jax.lax.div(doe, i32(36524))
+        - jax.lax.div(doe, i32(146096)), i32(365))
+    y = yoe + era * i32(400)
+    doy = doe - (i32(365) * yoe + jax.lax.div(yoe, i32(4))
+                 - jax.lax.div(yoe, i32(100)))
+    mp = jax.lax.div(i32(5) * doy + i32(2), i32(153))
+    d = doy - jax.lax.div(i32(153) * mp + i32(2), i32(5)) + i32(1)
+    m = mp + jnp.where(mp < 10, i32(3), i32(-9))
+    y = y + (m <= 2).astype(i32)
+    return (y.astype(jnp.int64), m.astype(jnp.int64),
+            d.astype(jnp.int64))
 
 
 def days_from_civil(y: int, m: int, d: int) -> int:
@@ -488,27 +497,22 @@ def _days_of(typ, a):
 
 
 def _add_months_device(days, months):
-    """date + interval year-month with end-of-month clamping."""
-    y, m, d = _civil_from_days(days)
-    total = y * 12 + (m - 1) + months
-    ny = jax.lax.div(jnp.where(total >= 0, total, total - 11), jnp.int64(12))
-    nm = total - ny * 12 + 1
+    """date + interval year-month with end-of-month clamping (int32
+    arithmetic, see _civil_from_days)."""
+    i32 = jnp.int32
+    y, m, d = (x.astype(i32) for x in _civil_from_days(days))
+    total = y * i32(12) + (m - i32(1)) + jnp.asarray(months).astype(i32)
+    ny = jax.lax.div(jnp.where(total >= 0, total, total - i32(11)), i32(12))
+    nm = total - ny * i32(12) + i32(1)
     # clamp day to target month length
-    leap = ((jax.lax.rem(ny, jnp.int64(4)) == 0)
-            & (jax.lax.rem(ny, jnp.int64(100)) != 0)
-            | (jax.lax.rem(ny, jnp.int64(400)) == 0))
-    mlen = jnp.array([31, 28, 31, 30, 31, 30, 31, 31, 30, 31, 30, 31])
-    length = mlen[nm - 1] + ((nm == 2) & leap)
+    leap = ((jax.lax.rem(ny, i32(4)) == 0)
+            & (jax.lax.rem(ny, i32(100)) != 0)
+            | (jax.lax.rem(ny, i32(400)) == 0))
+    mlen = jnp.array([31, 28, 31, 30, 31, 30, 31, 31, 30, 31, 30, 31],
+                     dtype=i32)
+    length = mlen[nm - i32(1)] + ((nm == 2) & leap).astype(i32)
     nd = jnp.minimum(d, length)
-    # days_from_civil, device version
-    yy = ny - (nm <= 2)
-    era = jax.lax.div(jnp.where(yy >= 0, yy, yy - 399), jnp.int64(400))
-    yoe = yy - era * 400
-    doy = jax.lax.div(153 * (nm + jnp.where(nm > 2, -3, 9)) + 2,
-                      jnp.int64(5)) + nd - 1
-    doe = yoe * 365 + jax.lax.div(yoe, jnp.int64(4)) - jax.lax.div(
-        yoe, jnp.int64(100)) + doy
-    return (era * 146097 + doe - 719468).astype(jnp.int32)
+    return _days_from_civil_device(ny, nm, nd).astype(i32)
 
 
 @scalar("day_of_week")
@@ -525,14 +529,19 @@ def _trunc_year_days(days):
 
 
 def _days_from_civil_device(y, m, d):
-    yy = y - (m <= 2)
-    era = jax.lax.div(jnp.where(yy >= 0, yy, yy - 399), jnp.int64(400))
-    yoe = yy - era * 400
-    doy = jax.lax.div(153 * (m + jnp.where(m > 2, -3, 9)) + 2,
-                      jnp.int64(5)) + d - 1
-    doe = yoe * 365 + jax.lax.div(yoe, jnp.int64(4)) - jax.lax.div(
-        yoe, jnp.int64(100)) + doy
-    return era * 146097 + doe - 719468
+    """(year, month, day) -> int64 days since epoch (int32 arithmetic
+    inside, see _civil_from_days)."""
+    i32 = jnp.int32
+    y, m, d = (jnp.asarray(x).astype(i32) for x in (y, m, d))
+    yy = y - (m <= 2).astype(i32)
+    era = jax.lax.div(jnp.where(yy >= 0, yy, yy - i32(399)), i32(400))
+    yoe = yy - era * i32(400)
+    doy = jax.lax.div(
+        i32(153) * (m + jnp.where(m > 2, i32(-3), i32(9))) + i32(2),
+        i32(5)) + d - i32(1)
+    doe = yoe * i32(365) + jax.lax.div(yoe, i32(4)) - jax.lax.div(
+        yoe, i32(100)) + doy
+    return (era * i32(146097) + doe - i32(719468)).astype(jnp.int64)
 
 
 @scalar("day_of_year")

@@ -501,8 +501,9 @@ class FleetServer:
         else:
             env = dict(os.environ)
             # workers never execute queries: pin them to the CPU backend
-            # so a TPU engine's workers don't fight over the device
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            # whatever the fleet was started with — a chip belongs to one
+            # process, the engine's
+            env["JAX_PLATFORMS"] = "cpu"
             env.update(self.worker_env)
             log_path = os.path.join(self.fleet_dir, "workers",
                                     f"{worker_id}.log")

@@ -622,9 +622,6 @@ SERVER_PROPERTY_DOCS: Dict[str, str] = {
     "trace_dir":
         "TrinoServer: directory for per-query JSON trace files "
         "(default off).",
-    "compilation_cache_dir":
-        "TrinoServer: persistent XLA compilation cache directory — "
-        "restarts skip recompilation of warmed query shapes.",
 }
 
 

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from trino_tpu import types as T
+from trino_tpu.ops.radix import sort_by_keys
 from trino_tpu.page import Column, Page
 
 
@@ -572,14 +573,11 @@ def hash_aggregate(
         if sizes is not None:
             return _direct_aggregate(page, key_channels, aggs, resolved,
                                      step, partial_state_channels, sizes)
-        operands = _sort_key_arrays(page, key_channels)
-        perm = jnp.arange(n, dtype=jnp.int32)
-        sorted_ops = jax.lax.sort(operands + [perm],
-                                  num_keys=len(operands))
-        perm_sorted = sorted_ops[-1]
+        sorted_keys, perm_sorted = sort_by_keys(
+            _sort_key_arrays(page, key_channels))
         # boundary detection on the *sorted* key operands (incl. null flags)
-        live_sorted = ~sorted_ops[0]
-        boundary = _boundary_scan(sorted_ops[1:-1], n) & live_sorted
+        live_sorted = ~sorted_keys[0]
+        boundary = _boundary_scan(sorted_keys[1:], n) & live_sorted
         group_of_sorted = jnp.cumsum(boundary.astype(jnp.int32)) - 1
         num_groups = jnp.sum(boundary).astype(jnp.int32)
         # route dead rows to an out-of-range segment id so they drop out

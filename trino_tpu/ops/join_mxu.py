@@ -71,11 +71,7 @@ def exact_int_sum_bound(dtype) -> float:
 def accum_dtype():
     """Aggregate-table accumulation dtype per platform (see module
     docstring): f64 on CPU, f32 on TPU/GPU."""
-    try:
-        backend = jax.default_backend()
-    except Exception:        # pragma: no cover - backend probe failure
-        backend = "cpu"
-    return jnp.float64 if backend == "cpu" else jnp.float32
+    return jnp.float64 if jax.default_backend() == "cpu" else jnp.float32
 
 
 def distinct_live_keys(bkey_s: jnp.ndarray,
@@ -123,15 +119,25 @@ def blocked_lookup(table: jnp.ndarray, kmin, pkey: jnp.ndarray,
     inb = (raw >= 0) & (raw < slots)
     off = jnp.where(inb, raw, -1).astype(jnp.int32)
     n = pkey.shape[0]
-    acc = jnp.zeros((n, ncols), dtype=dtype)
     step = min(block, slots)
-    for start in range(0, slots, step):
-        stop = min(start + step, slots)   # ragged last block is fine
-        cols = jnp.arange(start, stop, dtype=jnp.int32)
-        onehot = (off[:, None] == cols[None, :]).astype(dtype)
-        acc = acc + jnp.dot(onehot, table[start:stop],
-                            preferred_element_type=dtype)
-    return acc
+    nblocks = -(-slots // step)
+    # ragged last block: zero rows no in-span key can select
+    table = jnp.pad(table, ((0, nblocks * step - slots), (0, 0)))
+    lane = jnp.arange(step, dtype=jnp.int32)
+
+    # a LOOP over the key-range blocks, not an unrolled chain: unrolled,
+    # the blocks are independent and a scheduler may keep every
+    # (rows x block) indicator alive at once — at 65 536 slots that is
+    # 128 of them per shard, which is what killed the 8-device mesh
+    # program under JAX 0.9 (tens of GB on the CPU backend)
+    def one_block(i, acc):
+        start = i * step
+        onehot = (off[:, None] == (start + lane)[None, :]).astype(dtype)
+        rows = jax.lax.dynamic_slice_in_dim(table, start, step, axis=0)
+        return acc + jnp.dot(onehot, rows, preferred_element_type=dtype)
+
+    return jax.lax.fori_loop(0, nblocks, one_block,
+                             jnp.zeros((n, ncols), dtype=dtype))
 
 
 def matmul_lookup(table: jnp.ndarray, kmin, pkey: jnp.ndarray,

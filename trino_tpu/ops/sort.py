@@ -2,8 +2,10 @@
 
 Reference parity: operator/OrderByOperator.java (389) + PagesIndex.java with
 codegen'd PagesIndexComparator (sql/gen/OrderingCompiler.java), TopNOperator
-.java, LimitOperator. On TPU: multi-operand `lax.sort` (bitonic, fully on the
-VPU) with null-ordering flags as leading sub-keys replaces comparator codegen.
+.java, LimitOperator. On TPU: a stable argsort over the key columns
+(ops/radix.py — passes of one small `lax.sort`, cheap to compile) with
+null-ordering flags as leading sub-keys replaces comparator codegen; the
+page is gathered through the resulting order.
 
 Ordering semantics (Trino): ASC defaults to NULLS LAST, DESC to NULLS FIRST;
 ORDER BY is stable w.r.t. input order via a trailing row-index key.
@@ -14,10 +16,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 
 from trino_tpu import types as T
+from trino_tpu.ops.radix import stable_argsort
 from trino_tpu.page import Page
 
 
@@ -73,11 +75,7 @@ def order_by(keys: Sequence[SortKey]) -> Callable[[Page], Page]:
     keys = tuple(keys)
 
     def op(page: Page) -> Page:
-        n = page.capacity
-        operands = _sort_operands(page, keys)
-        perm = jnp.arange(n, dtype=jnp.int32)
-        out = jax.lax.sort(operands + [perm], num_keys=len(operands) + 1)
-        order = out[-1]
+        order = stable_argsort(_sort_operands(page, keys))
         return page.gather(order, page.num_rows)
 
     return op

@@ -34,13 +34,15 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from trino_tpu.ops.join import _key_u64, _mix64
+from trino_tpu.ops.radix import stable_argsort
 from trino_tpu.page import Column, Page
 
 AXIS = "workers"
 
-_U64MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
+_U64MAX = np.uint64(0xFFFFFFFFFFFFFFFF)
 
 
 def _is_heavy(key: jnp.ndarray, heavy: jnp.ndarray) -> jnp.ndarray:
@@ -129,7 +131,7 @@ def _exchange_compact(cols, occ, n: int, bucket_capacity: int,
         out_cols.append(Column(vals, valid if c.valid is not None else None,
                                c.type, c.dictionary))
 
-    perm = jnp.argsort(~occ_recv, stable=True)
+    perm = stable_argsort([~occ_recv])
     num = jnp.sum(occ_recv).astype(jnp.int32)
     out_cols = [Column(jnp.take(c.values, perm),
                        None if c.valid is None else jnp.take(c.valid, perm),
@@ -158,7 +160,7 @@ def all_to_all_by_key(page: Page, key_channels: Sequence[int],
 
     # stable sort rows by destination, then slot rows into per-destination
     # fixed-capacity buckets: position within bucket = rank within partition
-    order = jnp.argsort(part, stable=True)
+    order = stable_argsort([part])
     part_sorted = jnp.take(part, order)
     idx = jnp.arange(page.capacity, dtype=jnp.int32)
     # rank within run of equal destinations
@@ -274,7 +276,7 @@ def broadcast_page(page: Page, axis: str = AXIS) -> Page:
             valid = gather(c.valid) & live
         cols.append(Column(vals, valid, c.type, c.dictionary))
     # compact live rows to the front
-    perm = jnp.argsort(~live, stable=True)
+    perm = stable_argsort([~live])
     cols = [Column(jnp.take(c.values, perm),
                    None if c.valid is None else jnp.take(c.valid, perm),
                    c.type, c.dictionary) for c in cols]

@@ -16,10 +16,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from trino_tpu.page import Column, Page
@@ -86,12 +83,8 @@ class QueryMesh:
             return jax.tree_util.tree_map(
                 lambda x: jnp.expand_dims(x, axis=0), out)
 
-        try:
-            return shard_map(wrapped, mesh=self.mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep)
-        except TypeError:  # pre-0.8 jax spells it check_rep
-            return shard_map(wrapped, mesh=self.mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_rep)
+        return shard_map(wrapped, mesh=self.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
     def unshard(self, tree):
         """Fetch a sharded tree to host as per-shard list (axis 0)."""

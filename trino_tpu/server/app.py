@@ -169,7 +169,6 @@ class TrinoServer:
                  max_running: int = 4,
                  resource_groups: Optional[ResourceGroupManager] = None,
                  resource_groups_path: Optional[str] = None,
-                 compilation_cache_dir: Optional[str] = None,
                  plan_cache_max_entries: Optional[int] = None,
                  streaming: bool = True,
                  result_cache: bool = True,
@@ -253,19 +252,14 @@ class TrinoServer:
             # resize (under the cache lock), not a bare attribute write:
             # a shrink over an already-warm runner must evict now
             runner._plan_cache.resize(int(plan_cache_max_entries))
-        # cross-process compile reuse: point XLA's on-disk cache at the
-        # given directory (or $TRINO_TPU_COMPILATION_CACHE_DIR) so a cold
+        # cross-process compile reuse: XLA's on-disk cache, placed by the
+        # one rule of trino_tpu.enable_persistent_cache(), so a cold
         # server start reloads compiled executables instead of recompiling
         # — with literal hoisting the cached programs are literal-free, so
         # the disk entries cover every parameter variant of a shape. The
         # in-process jit-cache LRU (exec/jit_cache.py) layers above this.
-        import os as _os
-        if compilation_cache_dir is None:
-            compilation_cache_dir = _os.environ.get(
-                "TRINO_TPU_COMPILATION_CACHE_DIR")
-        if compilation_cache_dir:
-            import trino_tpu
-            trino_tpu.enable_persistent_cache(compilation_cache_dir)
+        import trino_tpu
+        trino_tpu.enable_persistent_cache()
         # size the node pool from the backend's measured per-device
         # memory at server startup (HBM minus scan-cache budget); CPU
         # backends keep the static default (exec/memory.autosize_node_pool)
