@@ -1,0 +1,180 @@
+"""Program and scope names (PR 25): every jitted program is named from its
+cache key, `<family>__<tag>[_<tag>...]`, and every operator phase inside a
+program carries a `jax.named_scope` of the same grammar — what a device
+trace needs to say whose time a kernel's is (`benchmark/trace_programs.py`
+reads both back)."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from trino_tpu import types as T
+from trino_tpu.exec import LocalQueryRunner, jit_cache
+from trino_tpu.page import Page
+
+import chip_smoke
+
+Q6 = chip_smoke.Q6.format(date="1994-01-01", disc="0.06", qty=24)
+
+# every tag a cache key (or a chain step) leads with today, and the family
+# it must land in: a new tag nobody listed comes out as `misc__<tag>`
+KNOWN_TAGS = {
+    "scan_filter": ["filter", "project", "select", "dconcat", "unnest",
+                    "unnest-count", "assign-unique-id", "tpch-generate",
+                    "tpch-generate-pooled", "tpch-generate-oidx"],
+    "aggregate": ["agg-partial", "agg-bypass", "agg-final",
+                  "agg-intermediate", "agg-single", "agg-groupmax",
+                  "agg-spill-part", "mxu-agg-lookup", "mxu-agg-post",
+                  "mxu-agg-single", "mxu-agg-table"],
+    "join": ["join", "join-prep", "join-spill-part", "uprobe", "uattach",
+             "semijoin", "markjoin", "fulljoin", "cross-attach",
+             "dense-table", "dense-table-rows", "dfbounds", "dfrange",
+             "probe-compact", "spill-prep", "spill-probe",
+             "spill-probe-dense", "mxu-table", "mxu-ndistinct",
+             "mxu-key-bounds"],
+    "sort": ["sort", "sort-spill-bounds", "sort-spill-part",
+             "sort-spill-rank", "topn-masked", "topn", "merge-sort"],
+    "window": ["window"],
+    "exchange": ["exchange-a2a", "exchange-gather", "mesh-prog",
+                 "mesh-sconcat"],
+}
+# tags left to `misc` on purpose: none today
+KNOWN_MISC = set()
+
+
+@pytest.fixture(scope="module")
+def runner():
+    r = LocalQueryRunner.tpch("tiny")
+    for sql in (Q6, chip_smoke.Q1, chip_smoke.Q3):
+        r.execute(sql)
+    return r
+
+
+def test_every_cached_program_has_a_grammar_name(runner):
+    """After q6, q1 and q3 every jit-cache entry — this worker's earlier
+    tests' too — is named by the grammar, and the function jax.jit was
+    given carries that name (it becomes the module's, `jit_<name>`)."""
+    with jit_cache._LOCK:
+        entries = list(jit_cache._CACHE.items())
+    assert len(entries) >= 20
+    for key, entry in entries:
+        name = jit_cache.program_name(key)
+        assert jit_cache.NAME_GRAMMAR.match(name), (key[:1], name)
+        assert entry[0].__name__ == name, (entry[0].__name__, name)
+        assert entry[0].__name__ not in ("run", "op", "prep", "<lambda>")
+    names = {jit_cache.program_name(k) for k, _ in entries}
+    assert {"aggregate__chain_filter_project_agg_partial",
+            "aggregate__agg_final", "join__join_prep", "join__uprobe",
+            "join__uattach", "sort__topn_masked"} <= names, sorted(names)
+
+
+def test_q6_q1_q3_leave_no_misc_program(monkeypatch):
+    """Every kernel the three queries look up, hit or miss, has a family."""
+    from trino_tpu.obs.stats import QueryStatsCollector
+    seen = set()
+    monkeypatch.setattr(QueryStatsCollector, "jit_hit",
+                        lambda self, key=None: seen.add(key))
+    monkeypatch.setattr(QueryStatsCollector, "jit_miss",
+                        lambda self, key=None: seen.add(key))
+    tpch = LocalQueryRunner.tpch("tiny")
+    for sql in (Q6, chip_smoke.Q1, chip_smoke.Q3):
+        tpch.execute(sql)
+    names = sorted({jit_cache.program_name(k) for k in seen})
+    assert len(names) >= 15, names
+    assert [n for n in names if n.startswith("misc__")] == [], names
+
+
+@pytest.mark.parametrize("family", sorted(KNOWN_TAGS))
+def test_known_tags_have_their_family(family):
+    for tag in KNOWN_TAGS[family]:
+        assert jit_cache.family_of(tag) == family, tag
+        name = jit_cache.program_name((tag, ("payload", 1)))
+        assert name == f"{family}__{tag.replace('-', '_')}"
+
+
+def test_unknown_tags_are_misc_and_listed():
+    assert jit_cache.program_name(("brand-new-kernel", 3)) \
+        == "misc__brand_new_kernel"
+    assert jit_cache.program_name((("nested", 1), 2)) == "misc__nested"
+    assert jit_cache.program_name(()) == "misc__untagged"
+    assert KNOWN_MISC == set()
+
+
+def test_names_carry_no_literals_and_stay_bounded():
+    """Tags only: two chains that differ in expression text, literals or
+    column numbers share one name."""
+    a = ("chain", ("filter", "l_quantity < 24", 7), ("project", (1, 2)),
+         ("agg-partial", (0,), ("sum", 3)))
+    b = ("chain", ("filter", "l_discount > 0.05", 9), ("project", (4,)),
+         ("agg-partial", (1, 2), ("avg", 5)))
+    assert jit_cache.program_name(a) == jit_cache.program_name(b) \
+        == "aggregate__chain_filter_project_agg_partial"
+    # a chain takes the family of its blocking tail, else scan_filter
+    assert jit_cache.program_name(("chain", ("filter", 1), ("project", 2))) \
+        == "scan_filter__chain_filter_project"
+    assert jit_cache.program_name(
+        ("chain", ("project", 1), ("topn-masked", 2))) \
+        == "sort__chain_project_topn_masked"
+
+
+def _chain_text():
+    """Lowered text of a filter -> project -> partial-aggregate chain
+    built the way the planner builds it (compose_chain)."""
+    from trino_tpu.exec.local_planner import compose_chain
+    from trino_tpu.ops import AggSpec, Step, hash_aggregate
+    from trino_tpu.page import Column
+
+    def filt():
+        return lambda page, params: page.filter(
+            page.column(0).values < params[0])
+
+    def proj():
+        return lambda page, params: Page(
+            (page.column(0), Column(page.column(1).values * 2, None,
+                                    T.BIGINT, None)), page.num_rows)
+    specs = [AggSpec("sum", 1, T.BIGINT)]
+    pending = ((("filter", "x"), filt, (jnp.int64(5),)),
+               (("project", "y"), proj, ()))
+    key = ("chain", ("filter", "x"), ("project", "y"),
+           ("agg-partial", (0,), "sum"))
+    compose_chain(pending, ("agg-partial", (0,), "sum"),
+                  lambda: hash_aggregate([0], specs, Step.PARTIAL))
+    fn = jit_cache._CACHE[key][0]
+    page = Page.from_numpy(
+        [jnp.arange(64) % 7, jnp.arange(64)], [T.BIGINT, T.BIGINT])
+    lowered = fn.lower(page, ((jnp.int64(5),), ()))
+    return lowered.as_text(debug_info=True)
+
+
+def test_chain_steps_and_tail_each_have_a_scope():
+    text = _chain_text()
+    assert "jit(aggregate__chain_filter_project_agg_partial)/" in text
+    for scope in ("scan_filter__filter", "scan_filter__project",
+                  "aggregate__agg_partial"):
+        assert f"/{scope}/" in text, scope
+    # shared kernels take the family of the operator that called them
+    assert "scan_filter__filter/scan_filter__compact_gather" in text
+    assert "aggregate__agg_partial/aggregate__group_sort/" \
+           "aggregate__radix_pass" in text
+    assert "sort__radix_pass" not in text
+    for scope in set(re.findall(r"(?<=/)[a-z_]+__[a-z0-9_]+(?=/)", text)):
+        assert jit_cache.NAME_GRAMMAR.match(scope), scope
+
+
+def test_scopes_do_not_change_the_program():
+    """Scopes are metadata: the HLO a scoped function lowers to is the
+    unscoped function's, name for name, once locations are left out."""
+    from trino_tpu.page import op_scope
+
+    def plain(x):
+        return jnp.cumsum(x * 2).sum()
+
+    def scoped(x):
+        with op_scope("aggregate__segment_reduce"):
+            return jnp.cumsum(x * 2).sum()
+    x = jnp.arange(128)
+    a = jax.jit(plain).lower(x).as_text()
+    b = jax.jit(scoped).lower(x).as_text()
+    assert a.replace("jit_plain", "F") == b.replace("jit_scoped", "F")

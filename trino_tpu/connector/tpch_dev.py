@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from trino_tpu.connector import tpch_gen as G
+from trino_tpu.exec.jit_cache import named
 
 _DEV_TABLES = {"supplier", "customer", "part", "partsupp", "orders",
                "lineitem"}
@@ -71,10 +72,11 @@ def _chunk_fn(table: str, column: str, sf: float, cap: int,
             return jnp.take(lut, raw, mode="clip").astype(jnp.int32)
         return G.column_stream(table, sf, column, idx, oidx)
 
+    tag = ("tpch-generate-pooled" if pooled else "tpch-generate",)
     if needs_oidx:
-        fn = jax.jit(lambda start, oidx: body(start, oidx))
+        fn = jax.jit(named(body, tag))
     else:
-        f0 = jax.jit(lambda start: body(start, None))
+        f0 = jax.jit(named(lambda start: body(start, None), tag))
         fn = lambda start, oidx: f0(start)   # noqa: E731
     _JIT_CACHE[key] = fn
     return fn
@@ -113,7 +115,7 @@ def _oidx_fn(sf: float, cap: int):
         ind = jnp.zeros(cap, jnp.int32).at[rel].add(1, mode="drop")
         return o_first + jnp.cumsum(ind).astype(jnp.int64)
 
-    fn = jax.jit(f)
+    fn = jax.jit(named(f, ("tpch-generate-oidx",)))
     _JIT_CACHE[key] = fn
     return fn
 

@@ -44,7 +44,8 @@ from trino_tpu.exec.runner import LocalQueryRunner, MaterializedResult
 from trino_tpu.metadata import Metadata, Session
 from trino_tpu.ops import AggSpec, SortKey, Step, hash_aggregate, order_by
 from trino_tpu.ops.aggregate import get_aggregate
-from trino_tpu.page import Column, Page, union_dictionaries
+from trino_tpu.page import (Column, Page, count_host_staging,
+                            union_dictionaries)
 from trino_tpu.parallel.exchange import (all_to_all_by_key, broadcast_page)
 from trino_tpu.parallel.mesh import QueryMesh
 from trino_tpu.planner.nodes import (
@@ -167,11 +168,11 @@ class ShardExecutionPlanner(LocalExecutionPlanner):
                 for split in mine:
                     self._fault_site("scan",
                                      f"{node.table} part {split.part}")
-                    for page in conn.page_source.pages(split, columns,
-                                                       cap):
+                    for page, moved in count_host_staging(
+                            conn.page_source.pages(split, columns, cap)):
                         self._checkpoint()
                         if col is not None:
-                            col.add_scan_staging(page_bytes(page))
+                            col.add_scan_staging(page_bytes(page), moved)
                         if self.device is not None:
                             page = jax.device_put(page, self.device)
                         if staged is not None:
@@ -704,7 +705,9 @@ class DistributedQueryRunner(LocalQueryRunner):
             else:
                 def prog(page):
                     return broadcast_page(page)
-            fn = jax.jit(self.mesh.shard_map(prog))
+            from trino_tpu.exec.jit_cache import named
+            fn = jax.jit(named(self.mesh.shard_map(prog),
+                               ("exchange-" + kind,)))
             self._exchange_jits[key] = fn
         return fn
 

@@ -44,12 +44,15 @@ disk entry serves every literal variant of a shape across processes.
 from __future__ import annotations
 
 import collections
+import re
 import threading
 import time
 from typing import Any, Callable, Dict, Hashable, Optional, Tuple
 
 import jax
 import numpy as np
+
+from trino_tpu.page import family_context
 
 # key -> [jitted kernel, last-seen flattened param signature or None,
 #         {input signature -> AOT compiled executable} (profiled path)]
@@ -113,6 +116,78 @@ def _param_signature(params) -> Tuple:
     return tuple(out)
 
 
+# Program names (round 25): every jitted program is named from its cache
+# key, `<family>__<tag>[_<tag>...]` in [a-z0-9_], so the device trace's
+# `XLA Modules` line and each op's scope path say which operator a device
+# second belongs to. Tags only — never expression text or literals — so
+# the set of names is bounded by the set of kernel kinds. A tag matches a
+# family by its longest listed prefix; an unlisted tag is `misc`
+# (tests/test_program_names.py lists those).
+NAME_GRAMMAR = re.compile(
+    r"^(scan_filter|aggregate|join|sort|window|exchange|misc)"
+    r"__[a-z0-9]+(_[a-z0-9]+)*$")
+_FAMILY_PREFIXES = (
+    ("scan_filter", ("filter", "project", "select", "dconcat", "unnest",
+                     "assign-unique-id", "tpch-generate")),
+    ("aggregate", ("agg", "mxu-agg")),
+    ("join", ("join", "uprobe", "uattach", "semijoin", "markjoin",
+              "fulljoin", "cross-attach", "dense-table", "dfbounds",
+              "dfrange", "probe-compact", "spill-prep", "spill-probe",
+              "mxu-table", "mxu-ndistinct", "mxu-key-bounds")),
+    ("sort", ("sort", "topn", "merge-sort")),
+    ("window", ("window",)),
+    ("exchange", ("exchange", "mesh-prog", "mesh-sconcat")),
+)
+
+
+def _tag(part) -> Optional[str]:
+    """The leading tag of a key or of one chain step's key."""
+    while isinstance(part, tuple) and part:
+        part = part[0]
+    return part if isinstance(part, str) else None
+
+
+def family_of(tag: str) -> str:
+    best, family = -1, "misc"
+    for name, prefixes in _FAMILY_PREFIXES:
+        for p in prefixes:
+            if len(p) > best and (tag == p or tag.startswith(p + "-")):
+                best, family = len(p), name
+    return family
+
+
+def program_name(key: Hashable) -> str:
+    """`<family>__<tag>[_<tag>...]` for a cache key. A chain is named by
+    its steps' tags and takes the family of its blocking tail (the last
+    step that is not scan/filter work), else `scan_filter`."""
+    head = _tag(key) or "untagged"
+    if head == "chain":
+        steps = [_tag(k) or "untagged" for k in key[1:]]
+        blocking = [f for f in map(family_of, steps) if f != "scan_filter"]
+        family = blocking[-1] if blocking else "scan_filter"
+        tags = [head] + steps
+    else:
+        family, tags = family_of(head), [head]
+    clean = (re.sub(r"[^a-z0-9]+", "_", t.lower()).strip("_") or "x"
+             for t in tags)
+    return f"{family}__{'_'.join(clean)}"
+
+
+def named(fn: Callable, key: Hashable) -> Callable:
+    """`fn` under `program_name(key)`: jax.jit names the module, and the
+    root of every op's scope path, after the function it is given. A
+    wrapper rather than a rename, so a builder may return a shared
+    function. Traced once per signature; not on the dispatch path."""
+    name = program_name(key)
+    family = name.partition("__")[0]
+
+    def program(*args):
+        with family_context(family):
+            return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 def _lookup(key: Hashable, build: Callable[[], Callable],
             params: Optional[Any]) -> list:
     """Shared LRU lookup: returns the entry list, counting hit/miss and
@@ -122,7 +197,7 @@ def _lookup(key: Hashable, build: Callable[[], Callable],
     with _LOCK:
         entry = _CACHE.get(key)
         if entry is None:
-            fn = jax.jit(build())
+            fn = jax.jit(named(build(), key))
             while len(_CACHE) >= _MAX_KERNELS:
                 _CACHE.popitem(last=False)
                 _STATS["evictions"] += 1
@@ -156,7 +231,36 @@ def cached_kernel(key: Hashable, build: Callable[[], Callable],
     to the kernel — used ONLY for hit attribution (param-hit vs plain hit),
     never for keying: the whole point is that the key excludes it.
     """
-    return _lookup(key, build, params)[0]
+    fn = _lookup(key, build, params)[0]
+    fenced = _fencing_observer()
+    if fenced is None:
+        return fn                   # the plain path: the jitted callable
+
+    def dispatch(*args):
+        return _timed(fn, args, fenced)
+    return dispatch
+
+
+def _fencing_observer():
+    """This thread's observer if its query measures device time (operator-
+    level collection or EXPLAIN ANALYZE), else None. Asked when a kernel
+    is looked up — once per operator, not per dispatch."""
+    observer = get_observer()
+    return observer if getattr(observer, "fenced", False) else None
+
+
+def _timed(fn, args: tuple, observer):
+    """One fenced dispatch: pin the asynchronously dispatched work with
+    block_until_ready and add its wall to the query's device time. Every
+    kernel of a fenced query comes through here — chains, joins,
+    aggregates, sorts, mesh programs — so what execution has left after
+    device and compile time is the host's. (A `cached_kernel` program's
+    first call compiles inside `fn`: cold, that wall lands here.)"""
+    t0 = time.perf_counter()
+    out = fn(*args)
+    jax.block_until_ready(out)
+    observer.add_device_time(time.perf_counter() - t0)
+    return out
 
 
 def _aot_compile(key: Hashable, fn, args: tuple, arg_sig, aot: dict):
@@ -170,10 +274,11 @@ def _aot_compile(key: Hashable, fn, args: tuple, arg_sig, aot: dict):
     duplicated compile is rare and harmless, the double-count would
     not be)."""
     from trino_tpu.obs import profiler
-    t0 = time.perf_counter()
+    t0 = time.monotonic()       # the spans' clock: this wall is a span
     lowered = fn.lower(*args)
     compiled = lowered.compile()
-    wall = time.perf_counter() - t0
+    t1 = time.monotonic()
+    wall = t1 - t0
     ops = profiler.hlo_op_count(lowered)
     cost = profiler.cost_dict(lowered)
     with _LOCK:
@@ -188,7 +293,7 @@ def _aot_compile(key: Hashable, fn, args: tuple, arg_sig, aot: dict):
     if observer is not None and hasattr(observer, "add_compile"):
         observer.add_compile(wall, hlo_ops=ops,
                              flops=cost.get("flops", 0.0),
-                             nbytes=cost.get("bytes", 0.0))
+                             nbytes=cost.get("bytes", 0.0), end_s=t1)
     return compiled
 
 
@@ -207,6 +312,7 @@ def profiled_kernel(key: Hashable, build: Callable[[], Callable],
             while len(entry) < 3:
                 entry.append({})
     aot: Dict[Any, Any] = entry[2]
+    fenced = _fencing_observer()
     from trino_tpu.obs import profiler
 
     def _fallback(*args):
@@ -230,7 +336,9 @@ def profiled_kernel(key: Hashable, build: Callable[[], Callable],
         except Exception:
             return _fallback(*args)
         try:
-            return compiled(*args)
+            if fenced is None:
+                return compiled(*args)
+            return _timed(compiled, args, fenced)   # compile wall is out
         except (TypeError, ValueError):
             # aval/sharding mismatch at CALL time (signature drift the
             # tree signature failed to capture) — re-dispatch through

@@ -38,7 +38,8 @@ from trino_tpu.ops import (AggSpec, JoinType, SortKey, Step, hash_aggregate,
                            hash_join, order_by, prepare_build, top_n,
                            top_n_masked)
 from trino_tpu.ops.join import unique_inner_probe
-from trino_tpu.page import Column, Page, concat_pages
+from trino_tpu.page import (Column, Page, concat_pages,
+                            count_host_staging, op_scope)
 from trino_tpu.planner.nodes import (
     AggregationNode, AggStep, DistinctLimitNode, EnforceSingleRowNode,
     ExchangeNode, FilterNode, GroupIdNode, JoinClause, JoinKind, JoinNode,
@@ -165,15 +166,21 @@ def compose_chain(pending, tail_key=None, tail_builder=None,
     def build():
         fns = [e[1]() for e in pending]
         tail = tail_builder() if tail_builder is not None else None
+        # one scope per step and the tail's: the trace gives each fused
+        # op's device time to the operator it came from (HLO metadata
+        # only: the executable is the same)
+        scopes = [program_name((k,)) for k in key[1:]]
 
         def run(page, groups):
-            for f, g in zip(fns, groups):
-                page = f(page, g)
+            for f, g, scope in zip(fns, groups, scopes):
+                with op_scope(scope):
+                    page = f(page, g)
             if tail is not None:
-                page = tail(page)
+                with op_scope(scopes[-1]):
+                    page = tail(page)
             return page
         return run
-    from trino_tpu.exec.jit_cache import profiled_kernel
+    from trino_tpu.exec.jit_cache import profiled_kernel, program_name
     kernel = profiled_kernel(key, build, params=param_groups)
 
     slots = tuple(e[3] if len(e) > 3 else None for e in pending)
@@ -198,12 +205,12 @@ class DeviceShareSlot:
 
 def _attributed_chain_call(kernel, key, pending, param_groups, slots,
                            tail_builder, tail_slot):
-    """The operator-attribution dispatch wrapper: fence once per chain
-    dispatch, subtract any compile wall that landed inside the timed
-    region (a first-signature dispatch AOT-compiles in place), and split
-    the remaining device wall across the tagged operators by the
-    profiler's cost weights. Fused chain operators jointly report the
-    chain's EXIT rows/pages/bytes (they are one kernel — intermediate
+    """The operator-attribution dispatch wrapper: take the chain
+    dispatch's fenced device wall (the jit cache times every dispatch of
+    a fenced query, compile wall excluded — `jit_cache._timed`) and split
+    it across the tagged operators by the profiler's cost weights.
+    Fused chain operators jointly report the chain's EXIT
+    rows/pages/bytes (they are one kernel — intermediate
     row counts are not observable without splitting the program, which
     is exactly what this path exists to avoid). Cost weights resolve
     ONCE per stream from the first page (they are ratios of a static
@@ -219,15 +226,15 @@ def _attributed_chain_call(kernel, key, pending, param_groups, slots,
 
     def call(page):
         observer = jit_cache.get_observer()
-        pre_compile = getattr(observer, "compile_time_s", 0.0)
-        t0 = _time.perf_counter()
-        out = kernel(page, param_groups)
-        jax.block_until_ready(out)
-        wall = _time.perf_counter() - t0
-        wall = max(wall - (getattr(observer, "compile_time_s", 0.0)
-                           - pre_compile), 0.0)
-        if observer is not None and hasattr(observer, "add_device_time"):
-            observer.add_device_time(wall)
+        if getattr(observer, "fenced", False):
+            before = observer.device_time_s
+            out = kernel(page, param_groups)
+            wall = observer.device_time_s - before
+        else:       # no collector on this thread: fence here, count nowhere
+            t0 = _time.perf_counter()
+            out = kernel(page, param_groups)
+            jax.block_until_ready(out)
+            wall = _time.perf_counter() - t0
         if not weights_box:
             weights_box.append(profiler.chain_weights(
                 key, pending, page, param_groups, tail_builder))
@@ -563,11 +570,11 @@ class LocalExecutionPlanner:
             try:
                 for split in splits:
                     self._fault_site("scan", str(node.table))
-                    for page in conn.page_source.pages(split, columns,
-                                                       cap):
+                    for page, moved in count_host_staging(
+                            conn.page_source.pages(split, columns, cap)):
                         self._checkpoint()
                         if col is not None:
-                            col.add_scan_staging(page_bytes(page))
+                            col.add_scan_staging(page_bytes(page), moved)
                         if staging is not None:
                             staging.append(page)
                         yield page

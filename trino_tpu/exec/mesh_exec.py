@@ -66,7 +66,8 @@ from trino_tpu.expr.compiler import compile_expression, compile_filter
 from trino_tpu.ops import (AggSpec, JoinType, SortKey, Step, hash_aggregate,
                            hash_join, order_by, top_n)
 from trino_tpu.ops.aggregate import (COLLECT_AGGREGATES, get_aggregate)
-from trino_tpu.page import Column, Page, union_dictionaries
+from trino_tpu.page import (Column, Page, count_host_staging,
+                            union_dictionaries)
 from trino_tpu.parallel.exchange import (AXIS, all_to_all_by_key,
                                          all_to_all_replicate,
                                          broadcast_page, detect_heavy_keys)
@@ -722,9 +723,10 @@ def _stage_scan(runner, node: TableScanNode) -> Tuple[List[Page], int]:
             mine = [s for s in splits if s.part % n == shard]
             pages: List[Page] = []
             for split in mine:
-                for page in conn.page_source.pages(split, columns, cap):
+                for page, moved in count_host_staging(
+                        conn.page_source.pages(split, columns, cap)):
                     if col is not None:
-                        col.add_scan_staging(page_bytes(page))
+                        col.add_scan_staging(page_bytes(page), moved)
                     pages.append(page)
             if not pages:
                 per_shard.append(None)
@@ -799,25 +801,19 @@ def run_co_scheduled(runner, frag: PlanFragment,
     program_wall = 0.0
     try:
         ladder: Dict[int, int] = {}
-        import time as _time
         for _round in range(_MAX_LADDER_ROUNDS):
             runner._check_deadline()
-            pre_compile = col.compile_time_s if col is not None else 0.0
-            t0 = _time.perf_counter()
+            pre_device = col.device_time_s if stats_on else 0.0
             out_global, aux = _run_program(
                 runner, lowerer, top_fn, staged, struct_key, ladder)
             if stats_on:
                 # the round's device wall: the program is ONE XLA call,
-                # so fencing it costs nothing extra. The clock stops at
-                # block_until_ready — BEFORE the aux host transfer and
-                # the ladder's NumPy analysis (those are host time), and
-                # any in-flight compile wall (profiled dispatch compiled
-                # this signature just now) comes out, so device means
-                # device. Only the CONVERGED round's wall is kept.
-                jax.block_until_ready(out_global)
-                round_wall = max(
-                    _time.perf_counter() - t0
-                    - (col.compile_time_s - pre_compile), 0.0)
+                # and the jit cache's fenced dispatch timed it (clock
+                # stopped at block_until_ready — BEFORE the aux host
+                # transfer and the ladder's NumPy analysis, compile wall
+                # out) and added it to the query's device time. The
+                # CONVERGED round's wall is what the operators share.
+                round_wall = col.device_time_s - pre_device
             host_aux = jax.device_get(aux)
             bumps = _ladder_bumps(lowerer, host_aux)
             if not bumps:
@@ -868,7 +864,6 @@ def run_co_scheduled(runner, frag: PlanFragment,
                 rows=int(np.max(np.asarray(d.get("rows", 0)))),
                 nbytes=int(np.max(np.asarray(d.get("bytes", 0)))))
     if stats_on:
-        col.add_device_time(program_wall)
         _record_program_stats(col, lowerer, frag, program_wall, host_aux)
     return per_shard
 

@@ -86,10 +86,13 @@ class QueryInfo:
 
     @property
     def wall_ms(self) -> Optional[int]:
+        """Elapsed since the query was created — for a served query the
+        submit, so the executor queue is in it (stats.queued_ms says how
+        much) — or None while it has not begun to run."""
         if self.started is None:
             return None
         end = self.ended if self.ended is not None else time.monotonic()
-        return int((end - self.started) * 1000)
+        return int((end - self.created) * 1000)
 
     @property
     def pool_reserved_bytes(self) -> int:

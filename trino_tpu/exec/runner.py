@@ -9,6 +9,7 @@ coordinator resources do.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import datetime
 import decimal
@@ -242,6 +243,7 @@ class LocalQueryRunner:
 
     def execute(self, sql: str, *, query_id: Optional[str] = None,
                 queued_at: Optional[float] = None,
+                dequeued_at: Optional[float] = None,
                 wall_cap_s: Optional[float] = None,
                 cancel_event=None, result_sink=None) -> MaterializedResult:
         """Run one statement through the query lifecycle registry
@@ -253,7 +255,9 @@ class LocalQueryRunner:
         lets the HTTP DELETE handler cancel cooperatively), the seeded
         FaultInjector when chaos is on, and the retry loop for
         retry_policy=QUERY (fragment-level TASK retry lives in the
-        execution paths)."""
+        execution paths). `queued_at`/`dequeued_at` are the server's
+        `time.monotonic()` stamps of the submit and of the executor
+        thread taking the query: the `queued` span of its stats."""
         from trino_tpu.errors import (QueryCanceledError, classify,
                                       is_retryable)
         from trino_tpu.exec.deadline import QueryDeadline
@@ -280,7 +284,8 @@ class LocalQueryRunner:
         # forced by EXPLAIN ANALYZE. The jit-cache observer is
         # thread-local, so concurrent queries attribute their own
         # hits/misses (each runs on its own executor thread)
-        self._collector = QueryStatsCollector(info.query_id)
+        self._collector = QueryStatsCollector(
+            info.query_id, queued_at=queued_at, dequeued_at=dequeued_at)
         jit_cache.set_observer(self._collector)
         # stamp the statement in flight BEFORE any work that could kill
         # the process; cleared in the finally. Observer failures must
@@ -515,10 +520,12 @@ class LocalQueryRunner:
             col.retries = self._retries
             col.faults_injected = faults
             col.finish()
-            # cpu_time_ms means HOST time (round 13): execution wall
-            # minus the measured device walls (fenced chain dispatches)
+            # cpu_time_ms means HOST time: execution wall minus the
+            # measured device walls (every dispatch of a fenced query)
             # minus the measured XLA compile walls — the device/compile
-            # halves live in stats as device_time_ms/compile_time_ms
+            # halves live in stats as device_time_ms/compile_time_ms.
+            # Unfenced there is no device wall to take out: stats say
+            # null, and this wire field keeps execution less compiles
             info.cpu_time_ms = int(col.host_time_s * 1000)
             info.output_bytes = col.output_bytes
             # mesh shape the query executed over (QueryMesh axis), for
@@ -1132,27 +1139,37 @@ class LocalQueryRunner:
         total = 0
         nbytes = 0
         from trino_tpu.exec.memory import live_page_bytes
-        for page in stream.iter_pages():
-            self._check_deadline()      # page-batch cancellation point
-            n = int(page.num_rows)
-            if n == 0:
-                continue
-            nbytes += live_page_bytes(page, n)
-            cols = page.to_host(n)
-            chunk = [tuple(_to_python(cols[j][i], types[j])
-                           for j in range(len(cols)))
-                     for i in range(n)]
-            total += n
-            if sink is not None:
-                sink.put(chunk, checkpoint=self._check_deadline)
-                if collecting and (collect_cap is None
-                                   or total <= collect_cap):
-                    rows.extend(chunk)
+        with contextlib.ExitStack() as fetching:
+            # ONE `result_fetch` span per attempt, opened when the first
+            # result page has landed (its row count is on the host) and
+            # closed after the last: device -> host transfer and row
+            # conversion. A one-page result (any blocking root) is just
+            # that; a streamed many-page result also holds the pulls
+            # that produce the later pages.
+            for page in stream.iter_pages():
+                self._check_deadline()      # page-batch cancellation point
+                n = int(page.num_rows)
+                if n == 0:
+                    continue
+                if self._collector is not None and not total:
+                    fetching.enter_context(self._collector.span(
+                        "result_fetch", kind="phase"))
+                nbytes += live_page_bytes(page, n)
+                cols = page.to_host(n)
+                chunk = [tuple(_to_python(cols[j][i], types[j])
+                               for j in range(len(cols)))
+                         for i in range(n)]
+                total += n
+                if sink is not None:
+                    sink.put(chunk, checkpoint=self._check_deadline)
+                    if collecting and (collect_cap is None
+                                       or total <= collect_cap):
+                        rows.extend(chunk)
+                    else:
+                        collecting = False
+                        rows = []
                 else:
-                    collecting = False
-                    rows = []
-            else:
-                rows.extend(chunk)
+                    rows.extend(chunk)
         if sink is not None:
             # publish the staged partial final chunk while still inside
             # execution (the FINISHING window opens only after the whole

@@ -3,9 +3,16 @@
 Reference parity: the reference engine emits OpenTelemetry spans from
 `Trace`-annotated scopes (io.opentelemetry wiring in trino-main's
 ServerMainModule); here a span is a plain host-side record — name, kind,
-monotonic start/end, attributes, children — cheap enough to record on
-every query, and the structured JSON dump replaces the OTLP exporter
-(QueryInfo.trace / the event payload carry it per query).
+start/end, attributes, children — cheap enough to record on every query,
+and the structured JSON dump replaces the OTLP exporter (QueryInfo.trace /
+the event payload carry it per query).
+
+One clock: every stamp is `time.monotonic()`, the clock the server stamps
+a submitted query with (`_Query.started`) and the one a device trace is
+tied to by whoever starts the profiler (benchmark/run.py stamps it at its
+slice annotation), so spans of concurrent queries and device events lie
+on one axis. The absolute stamps stay on the Span; only the JSON dump is
+relative.
 
 Spans are built single-threaded by the owning query's executor thread
 (the same contract as FaultInjector); readers only see the dump taken at
@@ -23,24 +30,25 @@ from typing import Any, Dict, List, Optional
 class Span:
     name: str
     kind: str = "internal"     # query | phase | fragment | exchange | operator
-    start_s: float = dataclasses.field(default_factory=time.perf_counter)
+    start_s: float = dataclasses.field(default_factory=time.monotonic)
     end_s: Optional[float] = None
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
     children: List["Span"] = dataclasses.field(default_factory=list)
 
     def finish(self) -> "Span":
         if self.end_s is None:
-            self.end_s = time.perf_counter()
+            self.end_s = time.monotonic()
         return self
 
     @property
     def wall_s(self) -> float:
-        end = self.end_s if self.end_s is not None else time.perf_counter()
+        end = self.end_s if self.end_s is not None else time.monotonic()
         return max(0.0, end - self.start_s)
 
     def to_json(self) -> Dict[str, Any]:
         """Structured dump; times are relative to the span's own start so
-        the tree is self-contained (monotonic origins don't travel)."""
+        the tree is self-contained (a monotonic origin means nothing in
+        another process)."""
         return self._to_json(self.start_s)
 
     def _to_json(self, origin: float) -> Dict[str, Any]:

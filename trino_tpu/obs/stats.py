@@ -24,12 +24,23 @@ timed inclusively at their output boundary; under EXPLAIN ANALYZE
 `fence` additionally pins their asynchronously dispatched device work
 with `block_until_ready` (the OperationTimer discipline, TPU edition).
 
-Device-time truth: `device_time_ms` is the summed measured chain wall
-(collected only when operator-level collection fences chains),
-`compile_time_ms` is the summed wall of every XLA compile this query
-triggered (measured at the jit cache's AOT compile sites, always on),
-and host time = execution - device - compile is what
-`QueryInfo.cpu_time_ms` now means.
+Device-time truth: under operator-level collection or EXPLAIN ANALYZE
+the query is FENCED — the jit cache pins every kernel dispatch (chains,
+joins, aggregates, sorts, mesh programs) with `block_until_ready` and
+`device_time_ms` is their summed wall; `compile_time_ms` is the summed
+wall of every XLA compile this query triggered (measured at the jit
+cache's AOT compile sites, always on); `host_time_ms` = execution -
+device - compile is what is left. Unfenced, device work runs
+asynchronously and neither can be told from the execution wall: both
+are null.
+
+The request's life (round 25): five spans of the query's tree, stamped
+on `time.monotonic()` — `queued` (submit -> an executor thread took it),
+`planning`, `execution`, and under execution `compile` (one per AOT
+compile) and `result_fetch` (first result page on the host -> last).
+`execution`'s self time, its wall minus those children, is dispatch.
+snapshot() carries them as absolute [name, start, end] triples, so
+spans of concurrent queries and a device trace lay on one axis.
 
 Threading contract: one collector belongs to one query, mutated by that
 query's executor thread only (distributed shards dispatch sequentially
@@ -72,13 +83,27 @@ class OperatorStats:
     source_ids: Tuple[int, ...] = ()
 
 
+REQUEST_SPANS = ("queued", "planning", "execution", "compile",
+                 "result_fetch")
+
+
 class QueryStatsCollector:
     def __init__(self, query_id: str = "", operator_level: bool = False,
-                 fence: bool = False):
+                 fence: bool = False, queued_at: Optional[float] = None,
+                 dequeued_at: Optional[float] = None):
+        """`queued_at`/`dequeued_at`: `time.monotonic()` when the server
+        accepted the statement and when an executor thread took it; the
+        query's tree then starts at the submit, with a `queued` span."""
         self.query_id = query_id
         self.operator_level = bool(operator_level)
         self.fence = bool(fence)
         self.root = Span(query_id or "query", kind="query")
+        if queued_at is not None:
+            self.root.start_s = float(queued_at)
+            self.root.children.append(Span(
+                "queued", kind="phase", start_s=float(queued_at),
+                end_s=max(float(queued_at), dequeued_at
+                          if dequeued_at is not None else time.monotonic())))
         self._stack: List[Span] = [self.root]
         self.phases: Dict[str, float] = {}
         self.operators: Dict[int, OperatorStats] = {}
@@ -87,10 +112,10 @@ class QueryStatsCollector:
         self.spilled_bytes = 0
         self.jit_hits = 0
         self.jit_misses = 0
-        # device-time truth (round 13, obs/profiler.py + exec/jit_cache):
-        # device_time_s sums the measured per-dispatch chain walls
-        # (fenced at chain granularity under operator-level collection;
-        # 0.0 when the query ran unfenced — device time then remains
+        # device-time truth (obs/profiler.py + exec/jit_cache):
+        # device_time_s sums the measured walls of every kernel
+        # dispatch of a fenced query (`fenced` below; unfenced it stays
+        # 0.0 and reads null in the snapshot — device time then remains
         # folded into execution wall). compile_time_s sums the wall of
         # every XLA compile this query triggered, measured at the jit
         # cache's AOT compile sites with the compiled program's HLO
@@ -124,6 +149,11 @@ class QueryStatsCollector:
         self.table_cache_hits = 0
         self.table_cache_misses = 0
         self.scan_staging_bytes = 0
+        # what of that a scan really moved host -> device (Column.
+        # from_numpy under the page source): 0 when the source handed
+        # back arrays already on the device (tpch's generated columns,
+        # any connector-side device cache)
+        self.scan_host_staging_bytes = 0
         # lake connector pruning (connector/lake/): whole data files
         # and row groups skipped via partition values + min/max zone
         # maps evaluated against the scan's TupleDomain (static
@@ -245,14 +275,26 @@ class QueryStatsCollector:
     def jit_param_hit(self, key=None) -> None:
         self.jit_param_hits += 1
 
+    @property
+    def fenced(self) -> bool:
+        """True when this query measures device time: the jit cache then
+        pins and times every kernel dispatch (`jit_cache._timed`)."""
+        return self.operator_level or self.fence
+
     def add_device_time(self, wall_s: float) -> None:
-        """One fused chain dispatch's measured device wall (the whole
-        chain fenced once); per-operator shares land on OperatorStats."""
+        """One fenced kernel dispatch's measured device wall (a fused
+        chain's per-operator shares land on OperatorStats)."""
         self.device_time_s += float(wall_s)
 
     def add_compile(self, wall_s: float, hlo_ops: int = 0,
-                    flops: float = 0.0, nbytes: float = 0.0) -> None:
-        """One XLA compile this query triggered (jit-cache AOT site)."""
+                    flops: float = 0.0, nbytes: float = 0.0,
+                    end_s: Optional[float] = None) -> None:
+        """One XLA compile this query triggered (jit-cache AOT site);
+        `end_s` is `time.monotonic()` when it ended: the `compile` span."""
+        end = time.monotonic() if end_s is None else float(end_s)
+        self._stack[-1].children.append(Span(
+            "compile", kind="phase", start_s=end - float(wall_s),
+            end_s=end, attrs={"hlo_ops": int(hlo_ops)}))
         self.compile_time_s += float(wall_s)
         self.jit_compiles += 1
         self.compiled_hlo_ops += int(hlo_ops)
@@ -283,10 +325,12 @@ class QueryStatsCollector:
     def table_cache_miss(self) -> None:
         self.table_cache_misses += 1
 
-    def add_scan_staging(self, nbytes: int) -> None:
-        """Host->device bytes staged by table scans (connector pages);
-        cached scans add nothing — the zero-transfer proof."""
+    def add_scan_staging(self, nbytes: int, host_bytes: int = 0) -> None:
+        """Bytes of the pages a table scan pulled from its connector
+        (scan- and table-cache hits add nothing), and how many of them
+        the page source moved host -> device to make the page."""
         self.scan_staging_bytes += int(nbytes)
+        self.scan_host_staging_bytes += int(host_bytes)
 
     def add_pruned(self, files: int = 0, row_groups: int = 0) -> None:
         self.files_pruned += int(files)
@@ -332,12 +376,28 @@ class QueryStatsCollector:
     @property
     def host_time_s(self) -> float:
         """Execution wall with measured device and compile time taken
-        out: what the HOST spent scheduling, staging, and shuffling —
-        the number cpu_time_ms now reports. Without fenced device
-        measurement (plain queries) device_time_s is 0 and this still
-        subtracts the always-measured compile walls."""
+        out: what the HOST spent scheduling, staging, and shuffling.
+        Host time only for a fenced query; unfenced device_time_s is 0,
+        this is the execution wall less compiles, and the snapshot
+        reports null (cpu_time_ms keeps the number: the wire wants one)."""
         return max(self.execution_s - self.device_time_s
                    - self.compile_time_s, 0.0)
+
+    def request_spans(self) -> List[List[Any]]:
+        """[[name, start, end], ...] on time.monotonic() for the spans
+        named in REQUEST_SPANS, in tree order (not the operator tree)."""
+        out: List[List[Any]] = []
+
+        def walk(span: Span) -> None:
+            if span.name in REQUEST_SPANS and span.kind == "phase":
+                end = span.end_s if span.end_s is not None \
+                    else time.monotonic()
+                out.append([span.name, round(span.start_s, 6),
+                            round(end, 6)])
+            for child in span.children:
+                walk(child)
+        walk(self.root)
+        return out
 
     def operator_rows(self) -> List[Dict[str, Any]]:
         out = []
@@ -356,6 +416,7 @@ class QueryStatsCollector:
     def snapshot(self) -> Dict[str, Any]:
         """The immutable query-end rollup (QueryStats.java wire shape):
         what QueryInfo.stats, event payloads, and bench.py carry."""
+        spans = self.request_spans()
         snap: Dict[str, Any] = {
             "query_id": self.query_id,
             "wall_s": round(self.root.wall_s, 6),
@@ -367,9 +428,14 @@ class QueryStatsCollector:
             "jit_hits": self.jit_hits,
             "jit_misses": self.jit_misses,
             "jit_param_hits": self.jit_param_hits,
-            "device_time_ms": round(self.device_time_s * 1000, 3),
+            "queued_ms": round(sum(
+                e - s for n, s, e in spans if n == "queued") * 1000, 3),
+            "spans": spans,
+            "device_time_ms": round(self.device_time_s * 1000, 3)
+            if self.fenced else None,
             "compile_time_ms": round(self.compile_time_s * 1000, 3),
-            "host_time_ms": round(self.host_time_s * 1000, 3),
+            "host_time_ms": round(self.host_time_s * 1000, 3)
+            if self.fenced else None,
             "jit_compiles": self.jit_compiles,
             "compiled_hlo_ops": self.compiled_hlo_ops,
             "estimated_flops": self.estimated_flops,
@@ -383,6 +449,7 @@ class QueryStatsCollector:
             "table_cache_hits": self.table_cache_hits,
             "table_cache_misses": self.table_cache_misses,
             "scan_staging_bytes": self.scan_staging_bytes,
+            "scan_host_staging_bytes": self.scan_host_staging_bytes,
             "files_pruned": self.files_pruned,
             "row_groups_pruned": self.row_groups_pruned,
             "streamed_chunks": self.streamed_chunks,

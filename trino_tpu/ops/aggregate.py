@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from trino_tpu import types as T
 from trino_tpu.ops.radix import sort_by_keys
-from trino_tpu.page import Column, Page
+from trino_tpu.page import Column, Page, op_scope
 
 
 class Step:
@@ -566,35 +566,42 @@ def hash_aggregate(
             if has_collect:
                 raise NotImplementedError(
                     "global array_agg/histogram/map_agg (no GROUP BY)")
-            return _global_aggregate(page, aggs, resolved, step,
-                                     partial_state_channels)
+            with op_scope("aggregate__global_reduce"):
+                return _global_aggregate(page, aggs, resolved, step,
+                                         partial_state_channels)
         sizes = None if has_collect else \
             _direct_key_sizes(page, key_channels, aggs)
         if sizes is not None:
-            return _direct_aggregate(page, key_channels, aggs, resolved,
-                                     step, partial_state_channels, sizes)
-        sorted_keys, perm_sorted = sort_by_keys(
-            _sort_key_arrays(page, key_channels))
-        # boundary detection on the *sorted* key operands (incl. null flags)
-        live_sorted = ~sorted_keys[0]
-        boundary = _boundary_scan(sorted_keys[1:], n) & live_sorted
-        group_of_sorted = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-        num_groups = jnp.sum(boundary).astype(jnp.int32)
-        # route dead rows to an out-of-range segment id so they drop out
-        seg = jnp.where(live_sorted, group_of_sorted, n)
+            with op_scope("aggregate__direct_segment_reduce"):
+                return _direct_aggregate(page, key_channels, aggs, resolved,
+                                         step, partial_state_channels, sizes)
+        with op_scope("aggregate__group_sort"):
+            sorted_keys, perm_sorted = sort_by_keys(
+                _sort_key_arrays(page, key_channels))
+        with op_scope("aggregate__group_bounds"):
+            # boundary detection on the *sorted* key operands (incl. null
+            # flags)
+            live_sorted = ~sorted_keys[0]
+            boundary = _boundary_scan(sorted_keys[1:], n) & live_sorted
+            group_of_sorted = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+            num_groups = jnp.sum(boundary).astype(jnp.int32)
+            # route dead rows to an out-of-range segment id so they drop out
+            seg = jnp.where(live_sorted, group_of_sorted, n)
 
         out_cols: List[Column] = []
-        # group key output = first sorted row of each segment
-        first_idx = jnp.zeros(n, dtype=jnp.int32).at[
-            jnp.where(boundary, group_of_sorted, n)].set(
-            jnp.arange(n, dtype=jnp.int32), mode="drop")
-        key_row = jnp.take(perm_sorted, first_idx, mode="clip")
-        for ch in key_channels:
-            out_cols.append(page.column(ch).gather(key_row))
+        with op_scope("aggregate__key_gather"):
+            # group key output = first sorted row of each segment
+            first_idx = jnp.zeros(n, dtype=jnp.int32).at[
+                jnp.where(boundary, group_of_sorted, n)].set(
+                jnp.arange(n, dtype=jnp.int32), mode="drop")
+            key_row = jnp.take(perm_sorted, first_idx, mode="clip")
+            for ch in key_channels:
+                out_cols.append(page.column(ch).gather(key_row))
 
-        agg_cols = _accumulate(page, aggs, resolved, step,
-                               partial_state_channels, perm_sorted, seg, n,
-                               key_channels, list_len)
+        with op_scope("aggregate__segment_reduce"):
+            agg_cols = _accumulate(page, aggs, resolved, step,
+                                   partial_state_channels, perm_sorted, seg,
+                                   n, key_channels, list_len)
         out_cols.extend(agg_cols)
         return Page(tuple(out_cols), num_groups)
 
@@ -939,14 +946,15 @@ def passthrough_partial(key_channels: Sequence[int],
     def op(page: Page) -> Page:
         live = page.row_mask()
         out_cols: List[Column] = [page.column(ch) for ch in key_channels]
-        for spec, fn in zip(aggs, resolved):
-            states = fn.state(spec.input_type)
-            vals, mask, dictionary = _agg_inputs(page, spec, fn, live)
-            for sc in states:
-                d = dictionary if T.is_string(sc.type) else None
-                out_cols.append(Column(
-                    sc.contrib(vals, mask).astype(sc.type.dtype), None,
-                    sc.type, d))
+        with op_scope("aggregate__passthrough_states"):
+            for spec, fn in zip(aggs, resolved):
+                states = fn.state(spec.input_type)
+                vals, mask, dictionary = _agg_inputs(page, spec, fn, live)
+                for sc in states:
+                    d = dictionary if T.is_string(sc.type) else None
+                    out_cols.append(Column(
+                        sc.contrib(vals, mask).astype(sc.type.dtype), None,
+                        sc.type, d))
         return Page(tuple(out_cols), page.num_rows)
 
     return op

@@ -18,7 +18,7 @@ from typing import Callable, Optional, Sequence
 
 from trino_tpu.expr.compiler import compile_expression, compile_filter
 from trino_tpu.expr.ir import RowExpression
-from trino_tpu.page import Page
+from trino_tpu.page import Page, op_scope
 
 
 def filter_project(
@@ -35,8 +35,11 @@ def filter_project(
 
     def op(page: Page, call_params: tuple = params) -> Page:
         if filter_fn is not None:
-            page = page.filter(filter_fn(page, call_params))
-        cols = tuple(fn(page, call_params) for fn in project_fns)
+            with op_scope("scan_filter__predicate"):
+                mask = filter_fn(page, call_params)
+            page = page.filter(mask)    # scan_filter__compact_*
+        with op_scope("scan_filter__project_exprs"):
+            cols = tuple(fn(page, call_params) for fn in project_fns)
         return Page(cols, page.num_rows)
 
     return op

@@ -33,7 +33,7 @@ import numpy as np
 
 from trino_tpu import types as T
 from trino_tpu.ops.radix import sort_by_keys
-from trino_tpu.page import Column, Page
+from trino_tpu.page import Column, Page, op_scope
 
 
 class JoinType:
@@ -109,44 +109,46 @@ def prepare_build(build_keys: Sequence[int]):
     build_keys = tuple(build_keys)
 
     def prep(build: Page):
-        bkey, bnull = _key_u64(build, build_keys)
-        # dead/null build rows: mask their key to u64::MAX and sort by
-        # (key, dead) — keeps the key array globally sorted for
-        # searchsorted while live rows occupy the prefix [0, n_live)
-        b_dead = ~build.row_mask() | bnull
-        u64max = jnp.uint64(0xFFFFFFFFFFFFFFFF)
-        bkey_masked = jnp.where(b_dead, u64max, bkey)
-        (bkey_s, b_dead_s), bperm = sort_by_keys([bkey_masked, b_dead])
-        n_live_build = jnp.sum(~b_dead_s).astype(jnp.int32)
-        live_b = build.row_mask()
-        n_build_rows = jnp.sum(live_b).astype(jnp.int32)
-        build_has_null = jnp.any(bnull & live_b)
-        # per-position run length of equal keys: lets the probe derive its
-        # upper bound from the lower bound (hi = lo + run_len[lo]) with no
-        # second searchsorted — each probe-side searchsorted costs a full
-        # sort-engine pass at scale
-        n = build.capacity
-        idx = jnp.arange(n, dtype=jnp.int32)
-        boundary = (bkey_s != jnp.roll(bkey_s, 1)).at[0].set(True)
-        run_start = jax.lax.cummax(jnp.where(boundary, idx, 0))
-        nxt = jnp.where(boundary, idx, n)
-        suffix_min = jnp.flip(jax.lax.cummin(jnp.flip(nxt)))
-        next_start = jnp.concatenate(
-            [suffix_min[1:], jnp.full((1,), n, dtype=suffix_min.dtype)])
-        run_len = (next_start - run_start).astype(jnp.int32)
-        # max duplicate-key run among LIVE build rows: 1 means the build
-        # side is unique (a primary/dimension key) and probes can take the
-        # no-expansion fast path (unique_inner_probe) — the executor
-        # fetches this once per join
-        max_run_live = jnp.max(jnp.where(jnp.arange(n, dtype=jnp.int32)
-                                         < n_live_build, run_len, 0))
-        # live-key min/max (u64 space): the executor fetches these with
-        # max_run and, when the span is small (dense surrogate keys — every
-        # TPC-H/DS key), builds a direct-address lookup table so probes
-        # cost ONE gather instead of a sort-engine searchsorted pass
-        live_key = ~b_dead
-        kmin = jnp.min(jnp.where(live_key, bkey, u64max))
-        kmax = jnp.max(jnp.where(live_key, bkey, jnp.uint64(0)))
+        with op_scope("join__build_sort"):
+            bkey, bnull = _key_u64(build, build_keys)
+            # dead/null build rows: mask their key to u64::MAX and sort by
+            # (key, dead) — keeps the key array globally sorted for
+            # searchsorted while live rows occupy the prefix [0, n_live)
+            b_dead = ~build.row_mask() | bnull
+            u64max = jnp.uint64(0xFFFFFFFFFFFFFFFF)
+            bkey_masked = jnp.where(b_dead, u64max, bkey)
+            (bkey_s, b_dead_s), bperm = sort_by_keys([bkey_masked, b_dead])
+        with op_scope("join__build_runs"):
+            n_live_build = jnp.sum(~b_dead_s).astype(jnp.int32)
+            live_b = build.row_mask()
+            n_build_rows = jnp.sum(live_b).astype(jnp.int32)
+            build_has_null = jnp.any(bnull & live_b)
+            # per-position run length of equal keys: lets the probe derive its
+            # upper bound from the lower bound (hi = lo + run_len[lo]) with no
+            # second searchsorted — each probe-side searchsorted costs a full
+            # sort-engine pass at scale
+            n = build.capacity
+            idx = jnp.arange(n, dtype=jnp.int32)
+            boundary = (bkey_s != jnp.roll(bkey_s, 1)).at[0].set(True)
+            run_start = jax.lax.cummax(jnp.where(boundary, idx, 0))
+            nxt = jnp.where(boundary, idx, n)
+            suffix_min = jnp.flip(jax.lax.cummin(jnp.flip(nxt)))
+            next_start = jnp.concatenate(
+                [suffix_min[1:], jnp.full((1,), n, dtype=suffix_min.dtype)])
+            run_len = (next_start - run_start).astype(jnp.int32)
+            # max duplicate-key run among LIVE build rows: 1 means the build
+            # side is unique (a primary/dimension key) and probes can take the
+            # no-expansion fast path (unique_inner_probe) — the executor
+            # fetches this once per join
+            max_run_live = jnp.max(jnp.where(jnp.arange(n, dtype=jnp.int32)
+                                             < n_live_build, run_len, 0))
+            # live-key min/max (u64 space): the executor fetches these with
+            # max_run and, when the span is small (dense surrogate keys — every
+            # TPC-H/DS key), builds a direct-address lookup table so probes
+            # cost ONE gather instead of a sort-engine searchsorted pass
+            live_key = ~b_dead
+            kmin = jnp.min(jnp.where(live_key, bkey, u64max))
+            kmax = jnp.max(jnp.where(live_key, bkey, jnp.uint64(0)))
         return (build, bkey_s, bperm, n_live_build, n_build_rows,
                 build_has_null, run_len, max_run_live, kmin, kmax)
     return prep
@@ -158,13 +160,14 @@ _DENSE_SENTINEL = np.int32(0x7FFFFFFF)
 def _dense_scatter(size: int, bkey_s, n_live, kmin, payload):
     """Shared scatter for the direct-address builders: dead positions and
     out-of-span keys route to the dropped slot `size`."""
-    n = bkey_s.shape[0]
-    idx = jnp.arange(n, dtype=jnp.int32)
-    raw = (bkey_s - kmin).astype(jnp.int64)
-    oob = (idx >= n_live) | (raw < 0) | (raw >= size)
-    slot = jnp.where(oob, size, raw)
-    return jnp.full(size, _DENSE_SENTINEL, jnp.int32) \
-        .at[slot].min(payload, mode="drop")
+    with op_scope("join__build_dense_table"):
+        n = bkey_s.shape[0]
+        idx = jnp.arange(n, dtype=jnp.int32)
+        raw = (bkey_s - kmin).astype(jnp.int64)
+        oob = (idx >= n_live) | (raw < 0) | (raw >= size)
+        slot = jnp.where(oob, size, raw)
+        return jnp.full(size, _DENSE_SENTINEL, jnp.int32) \
+            .at[slot].min(payload, mode="drop")
 
 
 def build_dense_table(size: int):
@@ -271,80 +274,81 @@ def hash_join(
                     "string join keys across distinct dictionaries; "
                     "re-encode to a shared dictionary first")
 
-        pkey, pnull = _key_u64(probe, probe_keys)
+        with op_scope("join__probe_lookup"):
+            pkey, pnull = _key_u64(probe, probe_keys)
 
-        p_dead = ~probe.row_mask() | pnull
-        n_build_m1 = jnp.maximum(n_build - 1, 0)
-        # the mesh in-program variant: shapes are static but the key span
-        # is a traced per-shard value, so BOTH probe strategies compile
-        # and lax.cond picks per shard (f32 exactness gate is static:
-        # positions must stay under 2^24)
-        inline_mxu = (mxu_slots is not None and not prepared
-                      and n_build < (1 << 24))
+            p_dead = ~probe.row_mask() | pnull
+            n_build_m1 = jnp.maximum(n_build - 1, 0)
+            # the mesh in-program variant: shapes are static but the key span
+            # is a traced per-shard value, so BOTH probe strategies compile
+            # and lax.cond picks per shard (f32 exactness gate is static:
+            # positions must stay under 2^24)
+            inline_mxu = (mxu_slots is not None and not prepared
+                          and n_build < (1 << 24))
 
-        def _search_lookup():
-            # ONE searchsorted over the live prefix (method="sort" routes
-            # the lookup through the TPU sort engine — 20x faster at
-            # millions of keys than the default per-level binary-search
-            # gathers: 0.14 s against 2.8 s for 6.3M probes on a v5e, PR
-            # 23 — at a minute more of compile time); the upper bound
-            # comes from the build side's precomputed run lengths
-            s_lo = jnp.searchsorted(bkey_s, pkey, side="left",
-                                    method="sort").astype(jnp.int32)
-            s_lo_c = jnp.minimum(s_lo, n_build_m1)
-            s_found = (jnp.take(bkey_s, s_lo_c, mode="clip") == pkey) & \
-                (s_lo < n_live_build)
-            s_cnt = jnp.where(s_found,
-                              jnp.take(run_len, s_lo_c, mode="clip"), 0)
-            return s_cnt, s_lo
+            def _search_lookup():
+                # ONE searchsorted over the live prefix (method="sort" routes
+                # the lookup through the TPU sort engine — 20x faster at
+                # millions of keys than the default per-level binary-search
+                # gathers: 0.14 s against 2.8 s for 6.3M probes on a v5e, PR
+                # 23 — at a minute more of compile time); the upper bound
+                # comes from the build side's precomputed run lengths
+                s_lo = jnp.searchsorted(bkey_s, pkey, side="left",
+                                        method="sort").astype(jnp.int32)
+                s_lo_c = jnp.minimum(s_lo, n_build_m1)
+                s_found = (jnp.take(bkey_s, s_lo_c, mode="clip") == pkey) & \
+                    (s_lo < n_live_build)
+                s_cnt = jnp.where(s_found,
+                                  jnp.take(run_len, s_lo_c, mode="clip"), 0)
+                return s_cnt, s_lo
 
-        if lookup == "mxu" and aux_table is not None:
-            # matrix-unit probe: blocked indicator matmuls against the
-            # per-key [count, first-pos] table (ops/join_mxu.py)
-            from trino_tpu.ops.join_mxu import matmul_lookup
-            cnt, lo = matmul_lookup(aux_table, kmin, pkey)
-            found = cnt > 0
-            lo = jnp.where(found, lo, _DENSE_SENTINEL)
-            lo_c = jnp.minimum(lo, n_build_m1)
-            hi = lo + cnt
-        elif inline_mxu:
-            # both lookups compute and a per-shard `where` selects: the
-            # key span is a traced per-shard value, and jnp.where keeps
-            # the program SPMD-uniform (an earlier lax.cond formulation
-            # miscompiled under shard_map fusion — any fusion barrier
-            # "fixed" it — so the branchless select is also the safe
-            # choice, at the cost of the searchsorted pass running on
-            # in-span shards too)
-            from trino_tpu.ops.join_mxu import (build_count_pos_table,
-                                                matmul_lookup)
-            table = build_count_pos_table(mxu_slots)(
-                bkey_s, n_live_build, kmin)
-            m_cnt, m_lo = matmul_lookup(table, kmin, pkey)
-            s_cnt, s_lo = _search_lookup()
-            span_ok = (kmax >= kmin) & \
-                ((kmax - kmin) < jnp.uint64(mxu_slots))
-            cnt = jnp.where(span_ok, m_cnt, s_cnt)
-            lo = jnp.where(span_ok, m_lo, s_lo)
-            found = cnt > 0
-            lo = jnp.where(found, lo, _DENSE_SENTINEL)
-            lo_c = jnp.minimum(lo, n_build_m1)
-            hi = lo + cnt
-        elif lookup == "dense" and aux_table is not None:
-            # dense surrogate keys: ONE gather against the direct-address
-            # table (slot identity implies key equality — no verify gather)
-            lo = _dense_lo(aux_table, kmin, pkey)
-            lo_c = jnp.minimum(lo, n_build_m1)
-            found = lo < n_live_build
-            hi = lo + jnp.where(found,
-                                jnp.take(run_len, lo_c, mode="clip"), 0)
-        else:
-            cnt, lo = _search_lookup()
-            lo_c = jnp.minimum(lo, n_build_m1)
-            found = cnt > 0
-            hi = lo + cnt
-        lo = jnp.minimum(lo, n_live_build)
-        hi = jnp.minimum(hi, n_live_build)
-        counts = jnp.where(p_dead, 0, hi - lo).astype(jnp.int64)
+            if lookup == "mxu" and aux_table is not None:
+                # matrix-unit probe: blocked indicator matmuls against the
+                # per-key [count, first-pos] table (ops/join_mxu.py)
+                from trino_tpu.ops.join_mxu import matmul_lookup
+                cnt, lo = matmul_lookup(aux_table, kmin, pkey)
+                found = cnt > 0
+                lo = jnp.where(found, lo, _DENSE_SENTINEL)
+                lo_c = jnp.minimum(lo, n_build_m1)
+                hi = lo + cnt
+            elif inline_mxu:
+                # both lookups compute and a per-shard `where` selects: the
+                # key span is a traced per-shard value, and jnp.where keeps
+                # the program SPMD-uniform (an earlier lax.cond formulation
+                # miscompiled under shard_map fusion — any fusion barrier
+                # "fixed" it — so the branchless select is also the safe
+                # choice, at the cost of the searchsorted pass running on
+                # in-span shards too)
+                from trino_tpu.ops.join_mxu import (build_count_pos_table,
+                                                    matmul_lookup)
+                table = build_count_pos_table(mxu_slots)(
+                    bkey_s, n_live_build, kmin)
+                m_cnt, m_lo = matmul_lookup(table, kmin, pkey)
+                s_cnt, s_lo = _search_lookup()
+                span_ok = (kmax >= kmin) & \
+                    ((kmax - kmin) < jnp.uint64(mxu_slots))
+                cnt = jnp.where(span_ok, m_cnt, s_cnt)
+                lo = jnp.where(span_ok, m_lo, s_lo)
+                found = cnt > 0
+                lo = jnp.where(found, lo, _DENSE_SENTINEL)
+                lo_c = jnp.minimum(lo, n_build_m1)
+                hi = lo + cnt
+            elif lookup == "dense" and aux_table is not None:
+                # dense surrogate keys: ONE gather against the direct-address
+                # table (slot identity implies key equality — no verify gather)
+                lo = _dense_lo(aux_table, kmin, pkey)
+                lo_c = jnp.minimum(lo, n_build_m1)
+                found = lo < n_live_build
+                hi = lo + jnp.where(found,
+                                    jnp.take(run_len, lo_c, mode="clip"), 0)
+            else:
+                cnt, lo = _search_lookup()
+                lo_c = jnp.minimum(lo, n_build_m1)
+                found = cnt > 0
+                hi = lo + cnt
+            lo = jnp.minimum(lo, n_live_build)
+            hi = jnp.minimum(hi, n_live_build)
+            counts = jnp.where(p_dead, 0, hi - lo).astype(jnp.int64)
 
         def anti_keep(matched: jnp.ndarray) -> jnp.ndarray:
             live = probe.row_mask()
@@ -375,27 +379,28 @@ def hash_join(
                 out = probe.filter(anti_keep(counts > 0))
             return out, out.num_rows.astype(jnp.int64)
 
-        emit = counts
-        if join_type in (JoinType.LEFT, JoinType.FULL):
-            # unmatched live probe rows (incl. null keys) emit one null-extended row
-            live_probe = probe.row_mask()
-            emit = jnp.where(live_probe & (counts == 0), 1, counts)
-            emit = jnp.where(live_probe, emit, 0)
-        offsets = jnp.cumsum(emit)
-        total = offsets[-1]
-        starts = offsets - emit  # exclusive prefix
+        with op_scope("join__probe_expand"):
+            emit = counts
+            if join_type in (JoinType.LEFT, JoinType.FULL):
+                # unmatched live probe rows (incl. null keys) emit one null-extended row
+                live_probe = probe.row_mask()
+                emit = jnp.where(live_probe & (counts == 0), 1, counts)
+                emit = jnp.where(live_probe, emit, 0)
+            offsets = jnp.cumsum(emit)
+            total = offsets[-1]
+            starts = offsets - emit  # exclusive prefix
 
-        out_idx = jnp.arange(cap, dtype=jnp.int64)
-        # which probe row produced output slot j: last start <= j
-        prow = jnp.searchsorted(offsets, out_idx, side="right",
-                                method="sort").astype(jnp.int32)
-        prow_c = jnp.minimum(prow, n_probe - 1)
-        j_within = out_idx - jnp.take(starts, prow_c, mode="clip")
-        brow_sorted = jnp.take(lo, prow_c, mode="clip") + j_within
-        brow = jnp.take(bperm, jnp.minimum(brow_sorted, n_build - 1),
-                        mode="clip").astype(jnp.int32)
-        slot_live = out_idx < jnp.minimum(total, cap)
-        matched = jnp.take(counts, prow_c, mode="clip") > 0
+            out_idx = jnp.arange(cap, dtype=jnp.int64)
+            # which probe row produced output slot j: last start <= j
+            prow = jnp.searchsorted(offsets, out_idx, side="right",
+                                    method="sort").astype(jnp.int32)
+            prow_c = jnp.minimum(prow, n_probe - 1)
+            j_within = out_idx - jnp.take(starts, prow_c, mode="clip")
+            brow_sorted = jnp.take(lo, prow_c, mode="clip") + j_within
+            brow = jnp.take(bperm, jnp.minimum(brow_sorted, n_build - 1),
+                            mode="clip").astype(jnp.int32)
+            slot_live = out_idx < jnp.minimum(total, cap)
+            matched = jnp.take(counts, prow_c, mode="clip") > 0
 
         if join_type in (JoinType.SEMI, JoinType.ANTI, JoinType.MARK):
             # composite keys: re-check real key equality on each expanded
@@ -447,17 +452,18 @@ def hash_join(
 
         # PruneJoinColumns: gather only emitted channels (the probe/build
         # gathers at output capacity are the kernel's dominant cost)
-        p_idx = range(probe.num_columns) if probe_out is None else probe_out
-        b_idx = range(build.num_columns) if build_out is None else build_out
-        pcols = tuple(probe.columns[i].gather(prow_c) for i in p_idx)
-        bcols = []
-        for i in b_idx:
-            c = build.columns[i]
-            g = c.gather(brow)
-            valid = g.valid_mask() & ~build_is_null
-            bcols.append(Column(g.values, valid, c.type, c.dictionary))
-        out_rows = jnp.minimum(total, cap).astype(jnp.int32)
-        out_page = Page(pcols + tuple(bcols), out_rows)
+        with op_scope("join__output_gather"):
+            p_idx = range(probe.num_columns) if probe_out is None else probe_out
+            b_idx = range(build.num_columns) if build_out is None else build_out
+            pcols = tuple(probe.columns[i].gather(prow_c) for i in p_idx)
+            bcols = []
+            for i in b_idx:
+                c = build.columns[i]
+                g = c.gather(brow)
+                valid = g.valid_mask() & ~build_is_null
+                bcols.append(Column(g.values, valid, c.type, c.dictionary))
+            out_rows = jnp.minimum(total, cap).astype(jnp.int32)
+            out_page = Page(pcols + tuple(bcols), out_rows)
 
         if composite and verify_composite:
             # drop collision slots (null-extension slots pass: matched=False
@@ -496,13 +502,14 @@ def prepare_build_spilled(build_keys: Sequence[int]):
     build_keys = tuple(build_keys)
 
     def prep(build: Page):
-        bkey, bnull = _key_u64(build, build_keys)
-        b_dead = ~build.row_mask() | bnull
-        u64max = jnp.uint64(0xFFFFFFFFFFFFFFFF)
-        bkey_masked = jnp.where(b_dead, u64max, bkey)
-        bkey_s, b_dead_s, bperm = jax.lax.sort(
-            [bkey_masked, b_dead,
-             jnp.arange(build.capacity, dtype=jnp.int32)], num_keys=2)
+        with op_scope("join__build_sort"):
+            bkey, bnull = _key_u64(build, build_keys)
+            b_dead = ~build.row_mask() | bnull
+            u64max = jnp.uint64(0xFFFFFFFFFFFFFFFF)
+            bkey_masked = jnp.where(b_dead, u64max, bkey)
+            bkey_s, b_dead_s, bperm = jax.lax.sort(
+                [bkey_masked, b_dead,
+                 jnp.arange(build.capacity, dtype=jnp.int32)], num_keys=2)
         n_live = jnp.sum(~b_dead_s).astype(jnp.int32)
         live_b = build.row_mask()
         n_build_rows = jnp.sum(live_b).astype(jnp.int32)
@@ -538,10 +545,11 @@ def spilled_dense_probe(probe_keys: Sequence[int],
     probe_keys = tuple(probe_keys)
 
     def op(probe: Page, table, kmin):
-        pkey, pnull = _key_u64(probe, probe_keys)
-        p_dead = ~probe.row_mask() | pnull
-        brow = _dense_lo(table, kmin, pkey)
-        found = (brow != _DENSE_SENTINEL) & ~p_dead
+        with op_scope("join__probe_lookup"):
+            pkey, pnull = _key_u64(probe, probe_keys)
+            p_dead = ~probe.row_mask() | pnull
+            brow = _dense_lo(table, kmin, pkey)
+            found = (brow != _DENSE_SENTINEL) & ~p_dead
         brow_col = Column(jnp.where(found, brow, 0).astype(jnp.int64),
                           None, T.BIGINT, None)
         p_idx = range(probe.num_columns) if probe_out is None else probe_out
@@ -590,14 +598,15 @@ def spilled_unique_probe(probe_keys: Sequence[int],
     probe_keys = tuple(probe_keys)
 
     def op(probe: Page, bkey_s, bperm, n_live):
-        n_build = bkey_s.shape[0]
-        pkey, pnull = _key_u64(probe, probe_keys)
-        p_dead = ~probe.row_mask() | pnull
-        lo = _searchsorted_anchored(bkey_s, pkey)
-        lo_c = jnp.minimum(lo, jnp.maximum(n_build - 1, 0))
-        found = (jnp.take(bkey_s, lo_c, mode="clip") == pkey) & \
-            (lo < n_live) & ~p_dead
-        brow = jnp.take(bperm, lo_c, mode="clip").astype(jnp.int64)
+        with op_scope("join__probe_lookup"):
+            n_build = bkey_s.shape[0]
+            pkey, pnull = _key_u64(probe, probe_keys)
+            p_dead = ~probe.row_mask() | pnull
+            lo = _searchsorted_anchored(bkey_s, pkey)
+            lo_c = jnp.minimum(lo, jnp.maximum(n_build - 1, 0))
+            found = (jnp.take(bkey_s, lo_c, mode="clip") == pkey) & \
+                (lo < n_live) & ~p_dead
+            brow = jnp.take(bperm, lo_c, mode="clip").astype(jnp.int64)
         brow_col = Column(brow, None, T.BIGINT, None)
         p_idx = range(probe.num_columns) if probe_out is None else probe_out
         pre = Page(tuple(probe.columns[i] for i in p_idx) + (brow_col,),
@@ -706,30 +715,31 @@ def unique_inner_probe(
                 raise NotImplementedError(
                     "string join keys across distinct dictionaries; "
                     "re-encode to a shared dictionary first")
-        pkey, pnull = _key_u64(probe, probe_keys)
-        p_dead = ~probe.row_mask() | pnull
-        n_build_m1 = jnp.maximum(n_build - 1, 0)
-        if lookup == "mxu" and aux_table is not None:
-            from trino_tpu.ops.join_mxu import matmul_lookup
-            cnt, lo = matmul_lookup(aux_table, kmin, pkey)
-            lo_c = jnp.minimum(lo, n_build_m1)
-            found = (cnt > 0) & ~p_dead
-        elif lookup == "dense" and aux_table is not None:
-            lo = _dense_lo(aux_table, kmin, pkey)
-            lo_c = jnp.minimum(lo, n_build_m1)
-            found = (lo < n_live_build) & ~p_dead
-        else:
-            lo = jnp.searchsorted(bkey_s, pkey, side="left", method="sort")
-            lo_c = jnp.minimum(lo, n_build_m1)
-            found = (jnp.take(bkey_s, lo_c, mode="clip") == pkey) & \
-                (lo < n_live_build) & ~p_dead
-        brow = jnp.take(bperm, lo_c, mode="clip").astype(jnp.int64)
-        if composite and verify_composite:
-            # unique build: at most one candidate — verify it directly
-            for pk, bk in zip(probe_keys, build_keys):
-                bv = jnp.take(build.column(bk).values, brow, mode="clip")
-                found = found & (probe.column(pk).values == bv)
-        brow_col = Column(jnp.where(found, brow, 0), None, T.BIGINT, None)
+        with op_scope("join__probe_lookup"):
+            pkey, pnull = _key_u64(probe, probe_keys)
+            p_dead = ~probe.row_mask() | pnull
+            n_build_m1 = jnp.maximum(n_build - 1, 0)
+            if lookup == "mxu" and aux_table is not None:
+                from trino_tpu.ops.join_mxu import matmul_lookup
+                cnt, lo = matmul_lookup(aux_table, kmin, pkey)
+                lo_c = jnp.minimum(lo, n_build_m1)
+                found = (cnt > 0) & ~p_dead
+            elif lookup == "dense" and aux_table is not None:
+                lo = _dense_lo(aux_table, kmin, pkey)
+                lo_c = jnp.minimum(lo, n_build_m1)
+                found = (lo < n_live_build) & ~p_dead
+            else:
+                lo = jnp.searchsorted(bkey_s, pkey, side="left", method="sort")
+                lo_c = jnp.minimum(lo, n_build_m1)
+                found = (jnp.take(bkey_s, lo_c, mode="clip") == pkey) & \
+                    (lo < n_live_build) & ~p_dead
+            brow = jnp.take(bperm, lo_c, mode="clip").astype(jnp.int64)
+            if composite and verify_composite:
+                # unique build: at most one candidate — verify it directly
+                for pk, bk in zip(probe_keys, build_keys):
+                    bv = jnp.take(build.column(bk).values, brow, mode="clip")
+                    found = found & (probe.column(pk).values == bv)
+            brow_col = Column(jnp.where(found, brow, 0), None, T.BIGINT, None)
         p_idx = range(probe.num_columns) if probe_out is None else probe_out
         pre = Page(tuple(probe.columns[i] for i in p_idx) + (brow_col,),
                    probe.num_rows)
@@ -790,12 +800,13 @@ def attach_build(n_probe_cols: int,
     and restore the probe++build output layout."""
 
     def op(pre: Page, prepared) -> Page:
-        build = prepared[0]
-        brow = pre.columns[n_probe_cols].values.astype(jnp.int32)
-        live = pre.row_mask()
-        brow = jnp.where(live, brow, 0)
-        b_idx = range(build.num_columns) if build_out is None else build_out
-        bcols = tuple(build.columns[i].gather(brow) for i in b_idx)
+        with op_scope("join__output_gather"):
+            build = prepared[0]
+            brow = pre.columns[n_probe_cols].values.astype(jnp.int32)
+            live = pre.row_mask()
+            brow = jnp.where(live, brow, 0)
+            b_idx = range(build.num_columns) if build_out is None else build_out
+            bcols = tuple(build.columns[i].gather(brow) for i in b_idx)
         return Page(tuple(pre.columns[:n_probe_cols]) + bcols, pre.num_rows)
 
     return op

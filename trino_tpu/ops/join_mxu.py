@@ -47,6 +47,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from trino_tpu.page import shared_scope
+
 # MXU-aligned key-range block width (the 128x128 systolic array tiles
 # 512-wide operands without padding waste; CPU Eigen likes it too)
 BLOCK = 512
@@ -92,6 +94,10 @@ def build_count_pos_table(slots: int):
     op(bkey_s, n_live, kmin) -> f32 (slots, 2)."""
 
     def op(bkey_s, n_live, kmin):
+        with shared_scope("mxu_table_build", "join"):
+            return build(bkey_s, n_live, kmin)
+
+    def build(bkey_s, n_live, kmin):
         n = bkey_s.shape[0]
         idx = jnp.arange(n, dtype=jnp.int32)
         live = idx < n_live
@@ -136,8 +142,9 @@ def blocked_lookup(table: jnp.ndarray, kmin, pkey: jnp.ndarray,
         rows = jax.lax.dynamic_slice_in_dim(table, start, step, axis=0)
         return acc + jnp.dot(onehot, rows, preferred_element_type=dtype)
 
-    return jax.lax.fori_loop(0, nblocks, one_block,
-                             jnp.zeros((n, ncols), dtype=dtype))
+    with shared_scope("mxu_lookup", "join"):
+        return jax.lax.fori_loop(0, nblocks, one_block,
+                                 jnp.zeros((n, ncols), dtype=dtype))
 
 
 def matmul_lookup(table: jnp.ndarray, kmin, pkey: jnp.ndarray,
@@ -171,6 +178,10 @@ def scatter_agg_table(slots: int, vec_specs, key_channel: int,
     vec_specs = tuple(vec_specs)
 
     def op(build, kmin):
+        with shared_scope("mxu_table_build", "join"):
+            return scatter(build, kmin)
+
+    def scatter(build, kmin):
         dt = accum_dtype() if dtype is None else dtype
         bkey, bnull = _key_u64(build, (key_channel,))
         live = build.row_mask() & ~bnull

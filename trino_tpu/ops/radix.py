@@ -24,6 +24,8 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 
+from trino_tpu.page import shared_scope
+
 _U32 = jnp.uint32
 _U64 = jnp.uint64
 
@@ -88,14 +90,19 @@ def stable_argsort(keys: Sequence[jnp.ndarray]) -> jnp.ndarray:
     """int32 permutation putting rows in ascending lexicographic order of
     `keys` (first key most significant), equal rows in input order."""
     perm = jnp.arange(keys[0].shape[0], dtype=jnp.int32)
-    for i, digit in enumerate(reversed(_all_digits(keys))):
-        if i:
-            digit = jnp.take(digit, perm, mode="clip")
-        perm = jax.lax.sort([digit, perm], num_keys=1, is_stable=True)[1]
+    digits = _all_digits(keys)
+    # every pass under one name; the family is the calling operator's
+    with shared_scope("radix_pass", "sort"):
+        for i, digit in enumerate(reversed(digits)):
+            if i:
+                digit = jnp.take(digit, perm, mode="clip")
+            perm = jax.lax.sort([digit, perm], num_keys=1,
+                                is_stable=True)[1]
     return perm
 
 
 def sort_by_keys(keys: Sequence[jnp.ndarray]):
     """-> (keys in sorted order, the permutation that sorted them)."""
     perm = stable_argsort(keys)
-    return [jnp.take(k, perm, mode="clip") for k in keys], perm
+    with shared_scope("radix_gather", "sort"):
+        return [jnp.take(k, perm, mode="clip") for k in keys], perm

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from trino_tpu import types as T
 from trino_tpu.ops.radix import stable_argsort
-from trino_tpu.page import Page
+from trino_tpu.page import Page, op_scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,8 +75,10 @@ def order_by(keys: Sequence[SortKey]) -> Callable[[Page], Page]:
     keys = tuple(keys)
 
     def op(page: Page) -> Page:
-        order = stable_argsort(_sort_operands(page, keys))
-        return page.gather(order, page.num_rows)
+        with op_scope("sort__order_keys"):
+            order = stable_argsort(_sort_operands(page, keys))
+        with op_scope("sort__payload_gather"):
+            return page.gather(order, page.num_rows)
 
     return op
 

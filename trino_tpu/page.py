@@ -21,8 +21,10 @@ spi/block/ (68 files). Design decisions (SURVEY.md §7.1):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
+import threading
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
@@ -32,6 +34,62 @@ import numpy as np
 from trino_tpu import types as T
 
 _dict_ids = itertools.count()
+
+
+# ---------------------------------------------------------------------------
+# trace scopes: the operator names a device trace reads back (HLO metadata
+# only — a scope changes no executable). Grammar `<family>__<tag>`, as
+# exec/jit_cache.program_name. A kernel shared by several operators
+# (Page.filter's compaction, ops/radix.py's passes) takes the family of
+# the operator being traced, so a join's sorts count as join time.
+
+# per thread: .family while a program is being traced; .host_staged, below
+_THREAD = threading.local()
+
+
+@contextlib.contextmanager
+def family_context(family: str):
+    """Trace-time only: the operator family shared kernels belong to."""
+    prev = getattr(_THREAD, "family", None)
+    _THREAD.family = family
+    try:
+        yield
+    finally:
+        _THREAD.family = prev
+
+
+@contextlib.contextmanager
+def op_scope(name: str):
+    """`jax.named_scope(name)` for one phase of an operator; `name` is
+    `<family>__<tag>` and sets the family for the kernels it calls."""
+    with family_context(name.partition("__")[0]), jax.named_scope(name):
+        yield
+
+
+def host_staged_bytes() -> int:
+    """Bytes this thread has moved host -> device through
+    Column.from_numpy so far: the one door host data takes to the device."""
+    return getattr(_THREAD, "host_staged", 0)
+
+
+def count_host_staging(pages):
+    """A page source's pages as (page, bytes the source moved host ->
+    device to make it). Read around each pull: what the consumers stage
+    between pulls is not the scan's."""
+    it = iter(pages)
+    while True:
+        mark = host_staged_bytes()
+        try:
+            page = next(it)
+        except StopIteration:
+            return
+        yield page, host_staged_bytes() - mark
+
+
+def shared_scope(tag: str, default: str = "scan_filter"):
+    """Scope of a kernel several operators share: `<caller's family>__tag`."""
+    family = getattr(_THREAD, "family", None) or default
+    return jax.named_scope(f"{family}__{tag}")
 
 
 class Dictionary:
@@ -243,6 +301,8 @@ class Column:
             data = codes
         arr = jnp.asarray(np.asarray(data, dtype=T.to_numpy_dtype(typ)))
         v = None if valid is None else jnp.asarray(valid, dtype=jnp.bool_)
+        _THREAD.host_staged = host_staged_bytes() + arr.nbytes \
+            + (0 if v is None else v.nbytes)
         return cls(arr, v, typ, dictionary)
 
     def to_numpy(self, num_rows: Optional[int] = None) -> np.ndarray:
@@ -343,13 +403,15 @@ class Page:
         mask = mask & self.row_mask()
         if not self.columns:
             return Page((), jnp.sum(mask).astype(jnp.int32))
-        kept = _running_count(mask)
-        count = kept[-1]
-        idx = jnp.arange(self.capacity, dtype=jnp.int32)
-        target = jnp.where(mask, kept - 1, count + idx - kept)
-        perm = jnp.zeros(self.capacity, dtype=jnp.int32).at[target].set(
-            idx, unique_indices=True, mode="promise_in_bounds")
-        return Page(tuple(c.gather(perm) for c in self.columns), count)
+        with shared_scope("compact_slots"):
+            kept = _running_count(mask)
+            count = kept[-1]
+            idx = jnp.arange(self.capacity, dtype=jnp.int32)
+            target = jnp.where(mask, kept - 1, count + idx - kept)
+            perm = jnp.zeros(self.capacity, dtype=jnp.int32).at[target].set(
+                idx, unique_indices=True, mode="promise_in_bounds")
+        with shared_scope("compact_gather"):
+            return Page(tuple(c.gather(perm) for c in self.columns), count)
 
     def gather(self, indices: jnp.ndarray, count) -> "Page":
         cols = tuple(c.gather(indices) for c in self.columns)

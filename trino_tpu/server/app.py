@@ -146,10 +146,26 @@ class _Query:
         self.cancel_event = CancelEvent()
         self.info = None               # QueryTracker entry
         self.started = time.monotonic()
+        # when an executor thread took it off its resource group's
+        # queue (same clock): started -> dequeued is the queue wait
+        self.dequeued: Optional[float] = None
 
     @property
     def elapsed_ms(self) -> int:
         return int((time.monotonic() - self.started) * 1000)
+
+    @property
+    def queued_ms(self) -> int:
+        """Submit -> an executor thread took it (so far, while queued;
+        0 for a result-cache hit answered on the HTTP thread)."""
+        if self.dequeued is None:
+            return self.elapsed_ms if self.state == "QUEUED" else 0
+        return int((self.dequeued - self.started) * 1000)
+
+    @property
+    def times(self) -> dict:
+        """The statement response's clock fields (protocol.stats_json)."""
+        return {"elapsed_ms": self.elapsed_ms, "queued_ms": self.queued_ms}
 
     @property
     def done(self) -> bool:
@@ -517,6 +533,7 @@ class TrinoServer:
         group = self._group_for(q.headers)
         q.info = TRACKER.begin(sql, user=user, query_id=qid,
                                resource_group=group)
+        q.started = q.info.created      # one submit stamp for both clocks
         with self._lock:
             self._queries[qid] = q
             self._prune_locked()
@@ -658,7 +675,7 @@ class TrinoServer:
             if got is None:
                 continue
             group, q = got
-            slice_t0 = time.monotonic()
+            slice_t0 = q.dequeued = time.monotonic()
             try:
                 if q.cancelled:
                     q.state = "CANCELED"
@@ -781,7 +798,7 @@ class TrinoServer:
             # q.cancel_event so DELETE cancels cooperatively
             result = runner.execute(
                 q.sql, query_id=q.query_id, queued_at=q.started,
-                wall_cap_s=self.query_timeout_s,
+                dequeued_at=q.dequeued, wall_cap_s=self.query_timeout_s,
                 cancel_event=q.cancel_event, result_sink=sink)
             m = _SET_SESSION.match(q.sql)
             if m:
@@ -920,7 +937,7 @@ class TrinoServer:
         if q.error is not None:
             return protocol.query_results(
                 q.query_id, self.base_uri, state="FAILED", error=q.error,
-                elapsed_ms=q.elapsed_ms, peak_memory_bytes=peak,
+                **q.times, peak_memory_bytes=peak,
                 warnings=self._warnings_for(q))
         # a materialized result outranks a cancel flag: the query beat the
         # cancel to the finish line, so its buffered pages stay servable
@@ -931,7 +948,7 @@ class TrinoServer:
                 error=protocol.error_json(
                     "Query was canceled", error_name="USER_CANCELED",
                     error_code=3, error_type="USER_ERROR"),
-                elapsed_ms=q.elapsed_ms)
+                **q.times)
         stream = q.stream
         res = q.result
         if stream is not None and stream.opened and (
@@ -948,7 +965,7 @@ class TrinoServer:
             return protocol.query_results(
                 q.query_id, self.base_uri,
                 next_uri=self._page_uri(q, token), state=q.state,
-                elapsed_ms=q.elapsed_ms, peak_memory_bytes=peak)
+                **q.times, peak_memory_bytes=peak)
         res = q.result
         cols = protocol.columns_json(res.column_names, res.column_types)
         lo, hi = token * PAGE_ROWS, (token + 1) * PAGE_ROWS
@@ -963,7 +980,7 @@ class TrinoServer:
             next_uri=self._page_uri(q, token + 1) if has_more else None,
             state="RUNNING" if has_more else "FINISHED",
             update_type=q.update_type, rows=len(res.rows),
-            elapsed_ms=q.elapsed_ms, peak_memory_bytes=peak,
+            **q.times, peak_memory_bytes=peak,
             cpu_time_ms=info.cpu_time_ms if info is not None else None,
             processed_bytes=info.output_bytes if info is not None else 0,
             spilled_bytes=spilled,
@@ -990,11 +1007,11 @@ class TrinoServer:
                     error=protocol.error_json(
                         "Query was canceled", error_name="USER_CANCELED",
                         error_code=3, error_type="USER_ERROR"),
-                    elapsed_ms=q.elapsed_ms)
+                    **q.times)
             return protocol.query_results(
                 q.query_id, self.base_uri, state="FAILED",
                 error=q.error or protocol.error_from_exception(exc),
-                elapsed_ms=q.elapsed_ms, peak_memory_bytes=peak,
+                **q.times, peak_memory_bytes=peak,
                 warnings=self._warnings_for(q))
         if status == "gone":
             # behind the ack horizon: the client advanced past this
@@ -1005,12 +1022,12 @@ class TrinoServer:
                     f"result page {token} was already consumed",
                     error_name="PAGE_TRANSPORT_ERROR", error_code=65545,
                     error_type="INTERNAL_ERROR"),
-                elapsed_ms=q.elapsed_ms)
+                **q.times)
         if status == "pending":
             return protocol.query_results(
                 q.query_id, self.base_uri, columns=cols,
                 next_uri=self._page_uri(q, token), state=state,
-                elapsed_ms=q.elapsed_ms, peak_memory_bytes=peak)
+                **q.times, peak_memory_bytes=peak)
         spilled = 0
         cpu_ms = None
         nbytes = 0
@@ -1023,7 +1040,7 @@ class TrinoServer:
             return protocol.query_results(
                 q.query_id, self.base_uri, columns=cols,
                 state="FINISHED", update_type=q.update_type,
-                rows=stream.total_rows, elapsed_ms=q.elapsed_ms,
+                rows=stream.total_rows, **q.times,
                 peak_memory_bytes=peak, cpu_time_ms=cpu_ms,
                 processed_bytes=nbytes, spilled_bytes=spilled,
                 warnings=self._warnings_for(q))
@@ -1031,7 +1048,7 @@ class TrinoServer:
         return protocol.query_results(
             q.query_id, self.base_uri, columns=cols, data=data,
             next_uri=self._page_uri(q, token + 1), state=state,
-            rows=stream.total_rows, elapsed_ms=q.elapsed_ms,
+            rows=stream.total_rows, **q.times,
             peak_memory_bytes=peak, cpu_time_ms=cpu_ms,
             processed_bytes=nbytes, spilled_bytes=spilled,
             warnings=self._warnings_for(q))
@@ -1112,7 +1129,7 @@ class TrinoServer:
                 self._send_json(protocol.query_results(
                     q.query_id, server.base_uri,
                     next_uri=server._page_uri(q, 0), state="QUEUED",
-                    elapsed_ms=q.elapsed_ms), q)
+                    **q.times), q)
 
             def do_GET(self):
                 parts = self.path.strip("/").split("/")

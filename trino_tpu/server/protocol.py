@@ -102,14 +102,19 @@ def warning_json(message: str, code: int = 1,
 
 
 def stats_json(state: str, *, queued: bool = False, done: bool = False,
-               rows: int = 0, elapsed_ms: int = 0,
+               rows: int = 0, elapsed_ms: int = 0, queued_ms: int = 0,
                peak_memory_bytes: int = 0,
                cpu_time_ms: Optional[int] = None,
                processed_bytes: int = 0,
                spilled_bytes: int = 0) -> Dict[str, Any]:
     """StatementStats.java — the CLI renders progress from these fields.
     cpu/bytes/spill come from the query's stats collector (obs/stats.py)
-    when the server has them; cpuTimeMillis falls back to elapsed."""
+    when the server has them. Times as the reference means them:
+    elapsed runs from the submit, queued is the part of it spent waiting
+    for an executor thread, and wall (cpuTimeMillis' fallback too) is
+    what is left — the execution."""
+    queued_ms = min(max(int(queued_ms), 0), int(elapsed_ms))
+    running_ms = elapsed_ms - queued_ms
     return {
         "state": state,
         "queued": queued,
@@ -119,9 +124,9 @@ def stats_json(state: str, *, queued: bool = False, done: bool = False,
         "queuedSplits": 1 if queued else 0,
         "runningSplits": 0,
         "completedSplits": 0 if queued else 1,
-        "cpuTimeMillis": elapsed_ms if cpu_time_ms is None else cpu_time_ms,
-        "wallTimeMillis": elapsed_ms,
-        "queuedTimeMillis": 0,
+        "cpuTimeMillis": running_ms if cpu_time_ms is None else cpu_time_ms,
+        "wallTimeMillis": running_ms,
+        "queuedTimeMillis": queued_ms,
         "elapsedTimeMillis": elapsed_ms,
         "processedRows": rows,
         "processedBytes": processed_bytes,
@@ -140,6 +145,7 @@ def query_results(query_id: str, base_uri: str, *,
                   update_type: Optional[str] = None,
                   rows: int = 0,
                   elapsed_ms: int = 0,
+                  queued_ms: int = 0,
                   peak_memory_bytes: int = 0,
                   cpu_time_ms: Optional[int] = None,
                   processed_bytes: int = 0,
@@ -151,7 +157,7 @@ def query_results(query_id: str, base_uri: str, *,
         "infoUri": f"{base_uri}/ui/query.html?{query_id}",
         "stats": stats_json(state, queued=(state == "QUEUED"),
                             done=next_uri is None, rows=rows,
-                            elapsed_ms=elapsed_ms,
+                            elapsed_ms=elapsed_ms, queued_ms=queued_ms,
                             peak_memory_bytes=peak_memory_bytes,
                             cpu_time_ms=cpu_time_ms,
                             processed_bytes=processed_bytes,
