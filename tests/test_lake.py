@@ -392,6 +392,42 @@ def test_table_cache_budget_eviction(runner):
     assert isinstance(cache, TableCache)
 
 
+def test_over_budget_promotion_is_refused_before_any_copy(monkeypatch):
+    """What an entry would hold is known from shapes: a candidate one
+    byte over the budget is refused without building a single column
+    (at SF10 every scan of lineitem paid a full-length copy of each
+    column, then was refused), one that just fits is admitted at
+    exactly that size."""
+    import jax.numpy as jnp
+
+    from trino_tpu.exec import table_cache
+    from trino_tpu.exec.table_cache import TableCache
+    from trino_tpu.page import Column, Page
+    pages = [Page((Column.from_numpy(np.arange(600, dtype=np.int64),
+                                     T.BIGINT),
+                   Column.from_numpy(np.arange(600, dtype=np.int32),
+                                     T.INTEGER, np.arange(600) % 3 > 0)),
+                  600) for _ in range(2)]
+    cols = [("a", Column(None, None, T.BIGINT, None)),
+            ("b", Column(None, None, T.INTEGER, None))]
+    need = 2048 * (8 + 4 + 1)          # 1 200 rows -> capacity 2 048
+    key = ("lake", "default", "budget_probe_pr26")
+
+    tight = TableCache(max_bytes=need - 1, min_scans=1)
+    denied = table_cache.table_cache_stats()["admission_denied"]
+    with monkeypatch.context() as m:
+        m.setattr(jnp, "concatenate", lambda *a, **k: pytest.fail(
+            "built a column for an entry that cannot be admitted"))
+        assert not tight.promote_from_pages(key, cols, pages, [600, 600])
+    assert table_cache.table_cache_stats()["admission_denied"] == denied + 1
+    assert len(tight) == 0
+
+    fits = TableCache(max_bytes=need, min_scans=1)
+    assert fits.promote_from_pages(key, cols, pages, [600, 600])
+    assert fits.resident_bytes == need
+    fits.clear()
+
+
 def test_node_pool_accounts_cache_residency(runner):
     from trino_tpu.exec.memory import NODE_POOL
     runner.execute("CREATE TABLE lake.default.acct AS SELECT * FROM region")

@@ -119,11 +119,13 @@ def test_names_carry_no_literals_and_stay_bounded():
         == "sort__chain_project_topn_masked"
 
 
-def _chain_text():
-    """Lowered text of a filter -> project -> partial-aggregate chain
-    built the way the planner builds it (compose_chain)."""
+def _chain_text(tail="agg-partial"):
+    """Lowered text of a filter -> project [-> aggregate tail] chain built
+    the way the planner builds it (compose_chain). `tail`: `agg-partial`,
+    `agg-bypass` or None (a plain chain, as `iter_pages` composes)."""
     from trino_tpu.exec.local_planner import compose_chain
     from trino_tpu.ops import AggSpec, Step, hash_aggregate
+    from trino_tpu.ops.aggregate import passthrough_partial
     from trino_tpu.page import Column
 
     def filt():
@@ -137,10 +139,15 @@ def _chain_text():
     specs = [AggSpec("sum", 1, T.BIGINT)]
     pending = ((("filter", "x"), filt, (jnp.int64(5),)),
                (("project", "y"), proj, ()))
-    key = ("chain", ("filter", "x"), ("project", "y"),
-           ("agg-partial", (0,), "sum"))
-    compose_chain(pending, ("agg-partial", (0,), "sum"),
-                  lambda: hash_aggregate([0], specs, Step.PARTIAL))
+    key = ("chain", ("filter", "x"), ("project", "y"))
+    if tail is None:
+        compose_chain(pending)
+    else:
+        tail_key = (tail, (0,), "sum")
+        key += (tail_key,)
+        compose_chain(pending, tail_key, {
+            "agg-partial": lambda: hash_aggregate([0], specs, Step.PARTIAL),
+            "agg-bypass": lambda: passthrough_partial([0], specs)}[tail])
     fn = jit_cache._CACHE[key][0]
     page = Page.from_numpy(
         [jnp.arange(64) % 7, jnp.arange(64)], [T.BIGINT, T.BIGINT])
@@ -154,13 +161,30 @@ def test_chain_steps_and_tail_each_have_a_scope():
     for scope in ("scan_filter__filter", "scan_filter__project",
                   "aggregate__agg_partial"):
         assert f"/{scope}/" in text, scope
+    # the partial aggregate takes the filter's mask as a selection: the
+    # chain compacts nothing (PR 26)
+    assert "compact_gather" not in text and "compact_slots" not in text
     # shared kernels take the family of the operator that called them
-    assert "scan_filter__filter/scan_filter__compact_gather" in text
     assert "aggregate__agg_partial/aggregate__group_sort/" \
            "aggregate__radix_pass" in text
     assert "sort__radix_pass" not in text
     for scope in set(re.findall(r"(?<=/)[a-z_]+__[a-z0-9_]+(?=/)", text)):
         assert jit_cache.NAME_GRAMMAR.match(scope), scope
+
+
+@pytest.mark.parametrize("tail, program", [
+    (None, "scan_filter__chain_filter_project"),
+    ("agg-bypass", "aggregate__chain_filter_project_agg_bypass")])
+def test_chains_without_a_partial_aggregate_tail_still_compact(tail,
+                                                                program):
+    """A plain chain's page leaves its program and the bypass tail emits a
+    state row per input row under num_rows: both need the live rows as a
+    prefix, so their filter keeps its compaction, under the filter's
+    scope and family."""
+    text = _chain_text(tail)
+    assert f"jit({program})/" in text
+    assert "scan_filter__filter/scan_filter__compact_slots" in text
+    assert "scan_filter__filter/scan_filter__compact_gather" in text
 
 
 def test_scopes_do_not_change_the_program():

@@ -77,6 +77,42 @@ def test_q6_chain_at_scan_width(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
 
 
+def test_deferred_chain_into_partial_aggregate_moves_no_row(one_chip):
+    """q6's shape as the planner composes it (PR 26): filter -> project ->
+    partial global aggregate in one chain, the filter deferred to a
+    selection mask — no permutation, no gather, no scatter, no sort."""
+    from trino_tpu.exec import jit_cache
+    from trino_tpu.exec.local_planner import compose_chain
+    from trino_tpu.ops import AggSpec, Step, hash_aggregate
+
+    def filt():
+        return lambda p, g: p.filter((p.column(0).values >= g[0])
+                                     & (p.column(2).values < g[1]))
+
+    def proj():
+        return lambda p, g: Page(
+            (Column(p.column(3).values * p.column(1).values, None,
+                    T.DecimalType(18, 4), None),), p.num_rows)
+    specs = (AggSpec("sum", 0, T.DecimalType(18, 4)),)
+    steps = ((("filter", "q6 shape"), filt,
+              (jnp.int32(8766), jnp.int64(2400))),
+             (("project", "q6 shape"), proj, ()))
+    tail_key = ("agg-partial", (), specs)
+    compose_chain(steps, tail_key,
+                  lambda: hash_aggregate((), specs, Step.PARTIAL))
+    fn = jit_cache._CACHE[("chain", steps[0][0], steps[1][0], tail_key)][0]
+    page = _page(one_chip, SCAN_WIDTH, (T.DATE, D12_2, D12_2, D12_2))
+    groups = ((jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+               jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)), ())
+    t0 = time.perf_counter()
+    compiled = fn.lower(page, groups).compile()
+    assert time.perf_counter() - t0 < 60
+    text = compiled.as_text()
+    for op in (" gather(", " scatter(", " sort("):
+        assert op not in text, op
+    assert compiled.memory_analysis().temp_size_in_bytes < (64 << 20)
+
+
 def test_q3_join_build_sort(one_chip):
     """The sort-bearing build kernel of q3's joins: 64-bit keys ordered by
     passes of one 32-bit sort (ops/radix.py)."""

@@ -140,7 +140,7 @@ _FAMILY_PREFIXES = (
 )
 
 
-def _tag(part) -> Optional[str]:
+def key_tag(part) -> Optional[str]:
     """The leading tag of a key or of one chain step's key."""
     while isinstance(part, tuple) and part:
         part = part[0]
@@ -160,9 +160,9 @@ def program_name(key: Hashable) -> str:
     """`<family>__<tag>[_<tag>...]` for a cache key. A chain is named by
     its steps' tags and takes the family of its blocking tail (the last
     step that is not scan/filter work), else `scan_filter`."""
-    head = _tag(key) or "untagged"
+    head = key_tag(key) or "untagged"
     if head == "chain":
-        steps = [_tag(k) or "untagged" for k in key[1:]]
+        steps = [key_tag(k) or "untagged" for k in key[1:]]
         blocking = [f for f in map(family_of, steps) if f != "scan_filter"]
         family = blocking[-1] if blocking else "scan_filter"
         tags = [head] + steps

@@ -29,7 +29,8 @@ import numpy as np
 from trino_tpu import types as T
 from trino_tpu.connector.spi import Split
 from trino_tpu.errors import GENERIC_INTERNAL_ERROR, TrinoError
-from trino_tpu.exec.jit_cache import cached_kernel
+from trino_tpu.exec.jit_cache import (cached_kernel, get_observer, key_tag,
+                                      profiled_kernel, program_name)
 from trino_tpu.expr.compiler import compile_expression, compile_filter
 from trino_tpu.expr.ir import (Call, InputRef, Literal, RowExpression,
                                SpecialForm, SpecialKind, SymbolRef)
@@ -39,7 +40,7 @@ from trino_tpu.ops import (AggSpec, JoinType, SortKey, Step, hash_aggregate,
                            top_n_masked)
 from trino_tpu.ops.join import unique_inner_probe
 from trino_tpu.page import (Column, Page, concat_pages,
-                            count_host_staging, op_scope)
+                            count_host_staging, defer_compaction, op_scope)
 from trino_tpu.planner.nodes import (
     AggregationNode, AggStep, DistinctLimitNode, EnforceSingleRowNode,
     ExchangeNode, FilterNode, GroupIdNode, JoinClause, JoinKind, JoinNode,
@@ -141,6 +142,60 @@ def chain_params(pending) -> Tuple:
     return tuple(tuple(e[2]) for e in pending)
 
 
+# chain steps that touch each lane on its own (no row moves, no row is
+# read by position): the only steps a deferred filter may sit among
+_LANE_WISE_STEPS = frozenset({"filter", "project", "select"})
+
+
+def chain_defers_compaction(key) -> bool:
+    """Whether the chain `key` names runs its filters deferred (a selection
+    mask, no compaction): its tail is the partial hash aggregate, which
+    takes liveness from `Page.row_mask()` alone, and every step before it
+    is lane-wise. Read off the cache key, so the two modes can never share
+    a program. Everything else compacts: tail-less chains (their pages
+    leave the program), `agg-bypass` (one state row per input row under
+    `num_rows`), TopN, and any step kind not listed."""
+    return len(key) > 1 and key_tag(key[-1]) == "agg-partial" and all(
+        key_tag(k) in _LANE_WISE_STEPS for k in key[1:-1])
+
+
+def chain_steps(key, pending, tail_builder=None):
+    """(step functions, tail function or None) exactly as the chain
+    program `key` runs them, each under its scope: the one place that
+    decides a chain's mode (the profiler costs a chain through it).
+
+    In deferred mode the chain owns the selection: a filter ANDs its mask
+    into it (`Page.filter` under `defer_compaction`), and a step that
+    builds a fresh `Page(cols, page.num_rows)` (project, select) gets the
+    carried one re-attached, so no step can lose it."""
+    defer = chain_defers_compaction(key)
+    # one scope per step and the tail's: the trace gives each fused
+    # op's device time to the operator it came from (HLO metadata
+    # only: the executable is the same)
+    scopes = [program_name((k,)) for k in key[1:]]
+
+    def step_of(f, scope):
+        def step(page, g):
+            with defer_compaction(defer), op_scope(scope):
+                out = f(page, g)
+            if defer and out.selection is None \
+                    and page.selection is not None:
+                assert out.capacity == page.capacity, key
+                out = out.with_selection(page.selection)
+            return out
+        return step
+
+    def tail_of(f, scope):
+        def tail(page):
+            with op_scope(scope):
+                return f(page)
+        return tail
+    steps = [step_of(e[1](), scope) for e, scope in zip(pending, scopes)]
+    tail = None if tail_builder is None else \
+        tail_of(tail_builder(), scopes[-1])
+    return steps, tail
+
+
 def compose_chain(pending, tail_key=None, tail_builder=None,
                   tail_slot=None):
     """One cached jitted kernel running every pending transform (+ optional
@@ -148,6 +203,11 @@ def compose_chain(pending, tail_key=None, tail_builder=None,
     cache key holds only canonical (literal-free) op keys; hoisted literal
     values are passed per call, so `fn(page)` for a new literal variant of
     a warm chain dispatches the existing executable.
+
+    A chain that ends in the partial hash aggregate over lane-wise steps
+    runs its filters deferred (`chain_defers_compaction`); each dispatch
+    of a chain with a filter step counts on the query's collector as
+    `compactions_deferred` or `compactions_run`.
 
     Dispatch goes through the jit cache's profiled path, so every XLA
     compile a chain triggers is a timed, query-attributed event
@@ -164,24 +224,27 @@ def compose_chain(pending, tail_key=None, tail_builder=None,
     param_groups = chain_params(pending)
 
     def build():
-        fns = [e[1]() for e in pending]
-        tail = tail_builder() if tail_builder is not None else None
-        # one scope per step and the tail's: the trace gives each fused
-        # op's device time to the operator it came from (HLO metadata
-        # only: the executable is the same)
-        scopes = [program_name((k,)) for k in key[1:]]
+        steps, tail = chain_steps(key, pending, tail_builder)
 
         def run(page, groups):
-            for f, g, scope in zip(fns, groups, scopes):
-                with op_scope(scope):
-                    page = f(page, g)
+            for step, g in zip(steps, groups):
+                page = step(page, g)
             if tail is not None:
-                with op_scope(scopes[-1]):
-                    page = tail(page)
+                page = tail(page)
+            # trace time: a page with a selection never leaves its program
+            assert getattr(page, "selection", None) is None, key
             return page
         return run
-    from trino_tpu.exec.jit_cache import profiled_kernel, program_name
     kernel = profiled_kernel(key, build, params=param_groups)
+    if any(key_tag(k) == "filter" for k in key[1:]):
+        deferred = chain_defers_compaction(key)
+        dispatch = kernel
+
+        def kernel(page, groups):
+            count = getattr(get_observer(), "count_compaction", None)
+            if count is not None:
+                count(deferred)
+            return dispatch(page, groups)
 
     slots = tuple(e[3] if len(e) > 3 else None for e in pending)
     if all(s is None for s in slots) and tail_slot is None:
@@ -237,7 +300,8 @@ def _attributed_chain_call(kernel, key, pending, param_groups, slots,
             wall = _time.perf_counter() - t0
         if not weights_box:
             weights_box.append(profiler.chain_weights(
-                key, pending, page, param_groups, tail_builder))
+                key, lambda: chain_steps(key, pending, tail_builder),
+                page, param_groups))
         shares = profiler.apportion(wall, weights_box[0])
         count_exit = isinstance(out, Page)
         n = int(out.num_rows) if count_exit else 0
@@ -1041,7 +1105,10 @@ class LocalExecutionPlanner:
             return PageStream(gen_distinct(), node.outputs)
         # fuse the upstream filter/project chain into the partial-agg kernel:
         # scan -> filter -> project -> partial agg is ONE device program per
-        # page (ScanFilterAndProjectOperator + partial-agg fusion)
+        # page (ScanFilterAndProjectOperator + partial-agg fusion). The
+        # aggregate reads liveness from row_mask() alone, so when every
+        # fused step is lane-wise the filters compact nothing and hand it
+        # a selection mask (chain_defers_compaction)
         partial_op = compose_chain(
             src.pending, ("agg-partial", key_channels_t, specs_t),
             lambda: hash_aggregate(key_channels, specs, Step.PARTIAL),
@@ -1049,7 +1116,9 @@ class LocalExecutionPlanner:
         # the adaptive bypass kernel: same fused chain, but the tail maps
         # each row to a PARTIAL-layout state row with NO sort (O(n) — the
         # "Partial Partial Aggregates" bypass for effectively-high NDV);
-        # layout-identical to partial_op's output so both mix in one buffer
+        # layout-identical to partial_op's output so both mix in one buffer.
+        # Its filters keep compacting: it emits a state row per input row
+        # under page.num_rows, so the live rows must be a prefix
         from trino_tpu.ops.aggregate import passthrough_partial
         bypass_op = compose_chain(
             src.pending, ("agg-bypass", key_channels_t, specs_t),

@@ -32,6 +32,7 @@ with `for_query()` clones under a lock.
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 import time
 import weakref
@@ -194,6 +195,17 @@ class TableCache(_GenerationGuard):
         live = [(p, int(c)) for p, c in zip(pages, counts) if int(c) > 0]
         columns: Dict[str, object] = {}
         cap = _next_pow2(rows)
+        # what the entry would hold, from shapes alone: a table that
+        # cannot be admitted (SF10 lineitem against 1 GiB) must not pay
+        # a full-length copy of every column on every scan first
+        first = live[0][0]
+        need = sum(
+            cap * (c.values.dtype.itemsize * math.prod(c.values.shape[1:])
+                   + any(p.columns[i].valid is not None for p, _ in live))
+            for i, c in enumerate(first.columns))
+        if need > self.max_bytes:
+            _count("admission_denied")
+            return False
         for i, (name, ch) in enumerate(symbols_cols):
             cols = [p.columns[i] for p, _ in live]
             dicts = {c.dictionary.fingerprint for c in cols

@@ -128,41 +128,33 @@ def _tail_weight(fn, aval_in) -> float:
         return 1.0
 
 
-def chain_weights(key, pending, page, params, tail_builder=None
-                  ) -> Tuple[float, ...]:
+def chain_weights(key, build_chain, page, params) -> Tuple[float, ...]:
     """Per-step apportionment weights for a fused chain: one weight per
-    `pending` entry plus, when the chain fuses a blocking tail (partial
-    aggregation), one trailing weight for the tail. Cached per
+    step plus, when the chain fuses a blocking tail (partial
+    aggregation), one trailing weight for the tail. `build_chain()` is
+    `local_planner.chain_steps(...)`: the steps and the tail as the
+    program runs them, deferred filters included. Cached per
     (canonical chain key, input signature); derivation walks avals
     through the chain with eval_shape and costs each step with the XLA
     cost model — no device work, no backend compile."""
-    n = len(pending) + (1 if tail_builder is not None else 0)
     try:
         sig = (key, tree_signature((page,)))
-    except Exception:
-        return (1.0,) * n
-    with _LOCK:
-        got = _WEIGHTS.get(sig)
-    if got is not None and len(got) == n:
-        return got
-    weights = []
-    try:
         aval = jax.eval_shape(lambda p: p, page)
     except Exception:
-        return (1.0,) * n
-    for entry in pending:
-        try:
-            fn = entry[1]()
-        except Exception:
-            weights.append(1.0)
-            continue
-        w, aval = _step_weight(fn, aval, tuple(entry[2]))
+        sig = None
+    with _LOCK:
+        got = _WEIGHTS.get(sig)
+    if got is not None:
+        return got
+    steps, tail = build_chain()
+    if sig is None:
+        return (1.0,) * (len(steps) + (tail is not None))
+    weights = []
+    for fn, group in zip(steps, params):
+        w, aval = _step_weight(fn, aval, tuple(group))
         weights.append(w)
-    if tail_builder is not None:
-        try:
-            weights.append(_tail_weight(tail_builder(), aval))
-        except Exception:
-            weights.append(1.0)
+    if tail is not None:
+        weights.append(_tail_weight(tail, aval))
     out = tuple(weights)
     with _LOCK:
         while len(_WEIGHTS) >= _MAX_WEIGHT_ENTRIES:

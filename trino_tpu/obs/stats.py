@@ -154,6 +154,12 @@ class QueryStatsCollector:
         # back arrays already on the device (tpch's generated columns,
         # any connector-side device cache)
         self.scan_host_staging_bytes = 0
+        # chain dispatches with a filter step (local_planner.
+        # compose_chain): `deferred` ran a program whose filters hand a
+        # selection mask to the partial aggregate and compact nothing,
+        # `run` one whose filters compact the page
+        self.compactions_deferred = 0
+        self.compactions_run = 0
         # lake connector pruning (connector/lake/): whole data files
         # and row groups skipped via partition values + min/max zone
         # maps evaluated against the scan's TupleDomain (static
@@ -332,6 +338,12 @@ class QueryStatsCollector:
         self.scan_staging_bytes += int(nbytes)
         self.scan_host_staging_bytes += int(host_bytes)
 
+    def count_compaction(self, deferred: bool) -> None:
+        if deferred:
+            self.compactions_deferred += 1
+        else:
+            self.compactions_run += 1
+
     def add_pruned(self, files: int = 0, row_groups: int = 0) -> None:
         self.files_pruned += int(files)
         self.row_groups_pruned += int(row_groups)
@@ -450,6 +462,8 @@ class QueryStatsCollector:
             "table_cache_misses": self.table_cache_misses,
             "scan_staging_bytes": self.scan_staging_bytes,
             "scan_host_staging_bytes": self.scan_host_staging_bytes,
+            "compactions_deferred": self.compactions_deferred,
+            "compactions_run": self.compactions_run,
             "files_pruned": self.files_pruned,
             "row_groups_pruned": self.row_groups_pruned,
             "streamed_chunks": self.streamed_chunks,

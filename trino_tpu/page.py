@@ -43,7 +43,8 @@ _dict_ids = itertools.count()
 # (Page.filter's compaction, ops/radix.py's passes) takes the family of
 # the operator being traced, so a join's sorts count as join time.
 
-# per thread: .family while a program is being traced; .host_staged, below
+# per thread: .family and .defer while a program is being traced;
+# .host_staged, below
 _THREAD = threading.local()
 
 
@@ -90,6 +91,20 @@ def shared_scope(tag: str, default: str = "scan_filter"):
     """Scope of a kernel several operators share: `<caller's family>__tag`."""
     family = getattr(_THREAD, "family", None) or default
     return jax.named_scope(f"{family}__{tag}")
+
+
+@contextlib.contextmanager
+def defer_compaction(on: bool = True):
+    """Trace-time only: while on, `Page.filter` keeps every lane where it
+    is and hands its mask on as the page's selection. Entered by the chain
+    composer (exec/local_planner.compose_chain) for a chain whose tail
+    reads liveness from `row_mask()` alone; nothing else may enter it."""
+    prev = getattr(_THREAD, "defer", False)
+    _THREAD.defer = bool(on)
+    try:
+        yield
+    finally:
+        _THREAD.defer = prev
 
 
 class Dictionary:
@@ -349,18 +364,26 @@ class Page:
 
     Reference: spi/Page.java:33. `num_rows` may be a traced scalar under jit;
     `capacity` (static) is the shared array length of all columns.
+
+    `selection`, when present, is a boolean lane mask: the live rows are
+    those of the prefix [0, num_rows) that it marks. Only a deferred
+    `filter` makes one, and only inside a fused chain (see `filter`): a
+    page that leaves its program has none, and everything that reads
+    rows by position (`to_host`, `shrink_to`, `pad_to`, `gather`, the
+    concat helpers) refuses one.
     """
 
     columns: Tuple[Column, ...]
     num_rows: jnp.ndarray  # int32 scalar (python int ok outside jit)
+    selection: Optional[jnp.ndarray] = None
 
     def tree_flatten(self):
-        return (tuple(self.columns), self.num_rows), None
+        return (tuple(self.columns), self.num_rows, self.selection), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        columns, num_rows = children
-        return cls(tuple(columns), num_rows)
+        columns, num_rows, selection = children
+        return cls(tuple(columns), num_rows, selection)
 
     @property
     def capacity(self) -> int:
@@ -374,22 +397,49 @@ class Page:
         return self.columns[i]
 
     def row_mask(self) -> jnp.ndarray:
-        """Mask of live rows ([0, num_rows))."""
-        return jnp.arange(self.capacity, dtype=jnp.int32) < self.num_rows
+        """Mask of live rows: the prefix [0, num_rows), less what a
+        deferred filter dropped (`selection`). The one definition of
+        liveness."""
+        live = jnp.arange(self.capacity, dtype=jnp.int32) < self.num_rows
+        return live if self.selection is None else live & self.selection
+
+    def with_selection(self, selection: Optional[jnp.ndarray]) -> "Page":
+        return Page(self.columns, self.num_rows, selection)
+
+    def _require_compact(self, what: str) -> None:
+        if self.selection is not None:
+            raise ValueError(
+                f"{what}: the page carries a selection mask (a deferred "
+                "filter); only a fused chain's mask-consuming tail may "
+                "read it")
 
     def append_column(self, col: Column) -> "Page":
-        return Page(self.columns + (col,), self.num_rows)
+        return Page(self.columns + (col,), self.num_rows, self.selection)
 
     def select_columns(self, indices: Sequence[int]) -> "Page":
-        return Page(tuple(self.columns[i] for i in indices), self.num_rows)
+        return Page(tuple(self.columns[i] for i in indices), self.num_rows,
+                    self.selection)
 
     def filter(self, mask: jnp.ndarray) -> "Page":
-        """Compact rows where mask is true (Page.getPositions analog).
+        """Keep the rows where mask is true (Page.getPositions analog).
 
-        jit-safe: output keeps this page's capacity; selected rows move to
-        the front, num_rows becomes the selected count.
+        jit-safe: the output keeps this page's capacity either way.
 
-        Implementation: a stable partition without a sort. A running count
+        Compacting (the default): selected rows move to the front and
+        num_rows becomes the selected count. Every consumer that reads
+        rows by position needs this: joins, sorts, TopN, pass-through
+        partial states, pages that leave their program.
+
+        Deferred (while the chain composer holds `defer_compaction`, i.e.
+        in a fused chain of lane-wise steps that ends in the partial hash
+        aggregate): no row moves. The columns and num_rows stay, and the
+        mask ANDed with the rows live so far becomes the page's
+        `selection`, which `row_mask()` folds in and every aggregation
+        path reads. The permutation and one gather per column, 63 % of
+        the SF10 scan cell's device time (PERF.md, PR 25), are not
+        emitted at all.
+
+        Compaction: a stable partition without a sort. A running count
         of the mask gives every row its target slot (kept rows to the
         front, dropped rows behind them, both in input order — the
         permutation a stable sort on the drop-flag produces), one int32
@@ -401,6 +451,8 @@ class Page:
         against a second for this form (PR 23).
         """
         mask = mask & self.row_mask()
+        if getattr(_THREAD, "defer", False):
+            return self.with_selection(mask)
         if not self.columns:
             return Page((), jnp.sum(mask).astype(jnp.int32))
         with shared_scope("compact_slots"):
@@ -414,6 +466,7 @@ class Page:
             return Page(tuple(c.gather(perm) for c in self.columns), count)
 
     def gather(self, indices: jnp.ndarray, count) -> "Page":
+        self._require_compact("gather")
         cols = tuple(c.gather(indices) for c in self.columns)
         return Page(cols, jnp.asarray(count, dtype=jnp.int32))
 
@@ -425,6 +478,7 @@ class Page:
         must know num_rows <= capacity (e.g. after a batched count fetch).
         Blocking operators shrink oversized intermediates so sorts/builds
         run at live size instead of scan-page capacity."""
+        self._require_compact("shrink_to")
         if capacity >= self.capacity:
             return self
         cols = tuple(
@@ -439,6 +493,7 @@ class Page:
 
     def pad_to(self, capacity: int) -> "Page":
         """Grow capacity (static) without changing live rows."""
+        self._require_compact("pad_to")
         if capacity < self.capacity:
             raise ValueError("pad_to cannot shrink")
         if capacity == self.capacity:
@@ -471,6 +526,7 @@ class Page:
     def to_host(self, num_rows: Optional[int] = None) -> list:
         """All columns as decoded host arrays in ONE batched transfer.
         List (ARRAY/MAP) columns decode to python lists / dicts per row."""
+        self._require_compact("to_host")
         n = int(self.num_rows) if num_rows is None else num_rows
         fetch = []
         for c in self.columns:
@@ -540,6 +596,8 @@ def concat_pages(pages: Sequence[Page]) -> Page:
     """
     if not pages:
         raise ValueError("no pages")
+    for p in pages:
+        p._require_compact("concat_pages")
     if len(pages) == 1:
         return pages[0]
     ncols = pages[0].num_columns
@@ -598,6 +656,8 @@ def device_concat(pages: Sequence[Page]) -> Page:
     as concat_pages)."""
     if not pages:
         raise ValueError("no pages")
+    for p in pages:
+        p._require_compact("device_concat")
     if len(pages) == 1:
         return pages[0]
     ncols = pages[0].num_columns
