@@ -1,7 +1,8 @@
 """ops kernels: the least time the chip could take to read the bytes the
 traced slice's executed queries must read (each named column once, at
-the width the engine stores it: the shape's `needed_bytes`), over the
-seconds the device was busy in the slice. HBM-bound by construction —
+the width the engine stores it: the shape's `needed_bytes`) — all the
+cell's chips reading at once, each at its peak — over the seconds a
+chip was busy in the slice, the mean over them. HBM-bound by construction —
 these shapes do a few integer operations per byte. A request that lies
 partly in the slice counts by the share of its time that does."""
 
@@ -21,7 +22,8 @@ def read(ctx):
             needed += ctx["shapes"][r["shape"]].needed_bytes(
                 ctx["config"]["rows"], ctx["config"]["column_bytes"]) \
                 * overlap / (r["t_done"] - r["t_send"])
-    share = 100.0 * needed / ctx["peaks"]["hbm_bytes_per_s"] \
+    share = 100.0 * needed \
+        / (len(ctx["chips"]) * ctx["peaks"]["hbm_bytes_per_s"]) \
         / trace["busy_s"]
     if share > 100.0:
         raise ValueError(

@@ -7,7 +7,9 @@ metrics are found by name from BENCHMARK.json and the files beside this
 one; nothing here knows a cell. The phases:
 
   set-up   TPU or exit non-zero (no CPU fallback); persistent compile
-           cache; `TrinoServer(LocalQueryRunner.tpch(schema))` on a
+           cache; the deployment the configuration file names — its
+           `runner` ("local", the default, or "mesh") over the cell's
+           chips, `jax.devices()[:chips]` — behind one `TrinoServer` on a
            loopback port with the configuration's columns warmed on the
            device; the load generator (`loadgen.py`, a child process that
            never imports JAX) PREPAREs, warms each shape once and prefills
@@ -49,6 +51,7 @@ for path in (HERE, ROOT):
 import loadgen              # noqa: E402
 import reference            # noqa: E402
 import tpch_columns         # noqa: E402
+import trace_programs       # noqa: E402
 import trace_reduce         # noqa: E402
 import traffic_gen          # noqa: E402
 
@@ -74,7 +77,8 @@ def metrics_of(entries: list, cell: str) -> list:
 
 
 def require_devices(chips: int):
-    """The devices to run on, or a non-zero exit and no result line."""
+    """Every device JAX reports, or a non-zero exit and no result line;
+    the cell's chips are the first `chips` of them."""
     import jax
     devices = jax.devices()
     if devices[0].platform != "tpu":
@@ -86,22 +90,35 @@ def require_devices(chips: int):
     return devices
 
 
-def peak_bytes(devices):
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
-             for d in devices]       # None on the CPU backend
-    return max(p for p in peaks if p) if any(peaks) else None
+def peak_bytes(chips) -> list:
+    """Peak bytes in use on each of the cell's chips (None on the CPU
+    backend): a sharded table that lands on one chip shows here."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in chips]
 
 
 # ----------------------------------------------------------------- set-up
 
-def start_server(config: dict):
-    from trino_tpu.exec import LocalQueryRunner
+def make_runner(config: dict, chips):
+    """The query runner the configuration names, over the cell's chips."""
+    kind = config.get("runner", "local")
+    if kind == "local":
+        from trino_tpu.exec import LocalQueryRunner
+        return LocalQueryRunner.tpch(config["schema"])
+    if kind == "mesh":
+        from trino_tpu.exec.distributed import DistributedQueryRunner
+        return DistributedQueryRunner.tpch(config["schema"], devices=chips)
+    raise SystemExit(f"run.py: configuration {config['name']!r} names the "
+                     f"runner {kind!r}; there are 'local' and 'mesh'")
+
+
+def start_server(config: dict, chips):
     from trino_tpu.server import TrinoServer
     manifest = {"tables": [
         {"table": f"{config['catalog']}.{config['schema']}.{t}",
          "columns": names} for t, names in config["columns"].items()]}
     t0 = time.monotonic()
-    server = TrinoServer(LocalQueryRunner.tpch(config["schema"]),
+    server = TrinoServer(make_runner(config, chips),
                          warmup_manifest=manifest,
                          **config["server"]).start()
     failed = [e for e in server.warmup_report if "error" in e]
@@ -217,6 +234,12 @@ def verify(requests: list, config: dict, traffic: dict, seed: int) -> dict:
     return out
 
 
+def compared(checks: dict) -> dict:
+    """Each number `correct` was decided on, beside its limit."""
+    return {name: {"value": checks[name], "limit": checks[f"limit_{name}"]}
+            for name in ("answers_mismatched", "requests_failed")}
+
+
 # ---------------------------------------------------------------- metrics
 
 def end_to_end(requests, t_go, seconds, traffic, setup_s) -> dict:
@@ -252,8 +275,14 @@ def window_line(requests: list, generator: dict, missing: int) -> None:
         s["lat"].append(r["latency_s"])
         s["hits"] += bool(r["info"]
                           and r["info"]["stats"]["result_cache_hits"])
+    ran = [r["info"]["stats"]
+           for r in trace_programs.executed({"requests": requests})]
     emit("window", requests=len(requests), infos_missing=missing,
          generator=generator,
+         # how the executed queries crossed the deployment's chips: the
+         # distinct values of each counter (a local runner reads 0)
+         mesh={k: sorted({st.get(k, 0) for st in ran}) for k in (
+             "mesh_devices", "exchanges_fused", "exchanges_staged")},
          polls_per_request=sum(r["polls"] for r in requests) / len(requests),
          compiles_in_window=sum(
              r["info"]["stats"]["jit_misses"] for r in requests
@@ -270,6 +299,12 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
              trace: bool, devices) -> dict:
     config_entry = find(bench["configs"], cell["config"], "configuration")
     config = load_json(os.path.join(ROOT, config_entry["file"]))
+    if config["chips"] != cell["chips"]:
+        raise SystemExit(f"run.py: cell {cell['name']!r} takes "
+                         f"{cell['chips']} chip(s), its configuration "
+                         f"{config['name']!r} is laid out on "
+                         f"{config['chips']} — not run")
+    chips = devices[:cell["chips"]]
     traffic = traffic_gen.load_traffic(cell["traffic"])
     peaks = load_json(os.path.join(HERE, "peaks.json"))
     kind = devices[0].device_kind
@@ -285,7 +320,7 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
                          f"{config['data_fingerprint']}")
 
     plan = traffic_gen.make_plan(traffic, seed, seconds)
-    server = start_server(config)
+    server = start_server(config, chips)
     child = None
     try:
         plan.update(host="127.0.0.1", port=server.port)
@@ -304,7 +339,7 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
         window = json.loads(line)
         requests = sorted(window["requests"], key=lambda r: r["t_send"])
         t_go = window["t_go"]
-        memory_peak = peak_bytes(devices)
+        chip_peaks = peak_bytes(chips)
         conn = loadgen.Conn("127.0.0.1", server.port, "bench-after")
         try:
             missing = query_infos(conn, requests)
@@ -319,23 +354,27 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
     checks = verify(requests, config, traffic, seed)
     measured = end_to_end(requests, t_go, seconds, traffic, setup_s)
     window_line(requests, window["generator"], missing)
+    memory_peak = max(chip_peaks, key=lambda p: p or 0)
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(devices), "memory_peak_bytes": memory_peak}
     result = {"correct": checks["correct"], "attempted": len(requests),
               "failed": checks["requests_failed"], "metrics": {},
               "device": device}
+    per_chip = {"ids": [d.id for d in chips], "peak_bytes": chip_peaks}
     if not trace:
         wanted = metrics_of(bench["end_to_end"], cell["name"])
         values = measured
     else:
         emit("end_to_end_while_traced", **measured)
         reduced = trace_reduce.reduce_xplane(
-            trace_reduce.find_xplane(trace_dir), requests, slice_[0])
+            trace_reduce.find_xplane(trace_dir), requests, slice_[0],
+            per_chip["ids"])
         emit("trace", **reduced)
         if "busy_s" not in reduced:
             reduced = None          # no device plane: a CPU rehearsal
-        ctx = {"requests": requests, "trace": reduced,
-               "slice": slice_, "config": config, "peaks": peaks.get(kind),
+        ctx = {"requests": requests, "trace": reduced, "slice": slice_,
+               "config": config, "peaks": peaks.get(kind),
+               "chips": per_chip["ids"],
                "memory_peak_bytes": memory_peak, "seconds": seconds,
                "shapes": {e["shape"]: reference.load_by_path(
                    "queries", e["shape"]) for e in traffic["shapes"]}}
@@ -343,14 +382,24 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
         values = {m["name"]: reference.load_by_path(
             "layer_metrics", m["name"]).read(ctx) for m in wanted}
         if reduced:
+            per_chip["busy_s"] = reduced["busy_s_per_chip"]
             device.update(busy_s=reduced["busy_s"],
                           window_s=reduced["window_s"])
-            result["breakdown"] = {"device_ops": reduced["device_ops"],
-                                   "idle_gaps": reduced["idle_gaps"]}
+            result["breakdown"] = {
+                # "<program>/<scope> <instruction>": which operator XLA's
+                # `%fusion.3` is (the plain names stay in the trace line)
+                "device_ops": [[f"{owner} {op}", s] for owner, op, s
+                               in trace_programs.table(ctx)["top_ops"]],
+                "idle_gaps": reduced["idle_gaps"]}
+    emit("chips", **per_chip)
     for m in wanted:
         if values.get(m["name"]) is not None:
             result["metrics"][m["name"]] = {"value": values[m["name"]],
                                             "unit": m["unit"]}
+    result["checks"] = compared(checks)     # last, as the contract has it
+    for name, c in result["checks"].items():
+        print(f"compared {name} {c['value']} limit {c['limit']}",
+              file=sys.stderr, flush=True)
     return result
 
 

@@ -11,7 +11,9 @@ import pytest
 
 import rehearsal
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# the contract's keys, and last the numbers compared beside their limits
+RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device",
+               "checks"]
 
 
 @pytest.fixture(scope="module")
@@ -36,7 +38,13 @@ def test_cell_end_to_end_at_tiny(copy, cell):
     metrics, none of them 0, and nothing compiled inside the window."""
     proc, last = rehearsal.drive(copy, cell, 2147483659, 2, 0)
     assert proc.returncode == 0, proc.stderr[-2000:]
-    assert last is not None and set(last) == RESULT_KEYS
+    assert last is not None and list(last) == RESULT_KEYS
+    assert last["checks"] == {
+        "answers_mismatched": {"value": 0, "limit": 0},
+        "requests_failed": {"value": 0, "limit": 0}}
+    assert proc.stderr.strip().splitlines()[-2:] == [
+        "compared answers_mismatched 0 limit 0",
+        "compared requests_failed 0 limit 0"]
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] >= 2
     assert set(last["metrics"]) == _names(_bench(copy)["end_to_end"], cell)
@@ -49,10 +57,22 @@ def test_cell_end_to_end_at_tiny(copy, cell):
     assert phases["window"]["infos_missing"] == 0
     assert phases["verify"]["answers_checked"] >= 2
     assert "late_max_ms" in phases["window"]["generator"]
+    chips = next(c["chips"] for c in _bench(copy)["workloads"]
+                 if c["name"] == cell)
+    assert phases["chips"]["ids"] == list(range(chips))
+    assert len(phases["chips"]["peak_bytes"]) == chips
     if cell == "tiny-dashboard":
         shapes = phases["window"]["by_shape"]
         assert set(shapes) == {"q6", "q1", "q3"}
         assert any(s["hit_share"] > 0 for s in shapes.values())
+    if cell == "tiny-mesh4-power":
+        # the deployment its file names: every query crossed the mesh,
+        # in one program per stage (none staged through the host)
+        assert set(phases["window"]["by_shape"]) == {"q1", "q3"}
+        mesh = phases["window"]["mesh"]
+        assert mesh["mesh_devices"] == [4]
+        assert mesh["exchanges_staged"] == [0]
+        assert min(mesh["exchanges_fused"]) >= 1
 
 
 @pytest.mark.parametrize("cell", ("tiny-dashboard", "tiny-count"))
@@ -66,7 +86,10 @@ def test_traced_run_reports_the_layer_metrics(copy, cell):
     assert last["correct"] is True
     want = _names(_bench(copy)["per_layer"], cell)
     device_only = {"device_idle_share", "query_hbm_roofline", "peak_hbm_GB",
-                   "idle_in_request_share"}
+                   "idle_in_request_share", "idle_unattributed_share",
+                   "device_time_attributed_share",
+                   "scan_filter_device_ms_per_q", "aggregate_device_ms_per_q",
+                   "join_device_ms_per_q", "sort_device_ms_per_q"}
     assert set(last["metrics"]) == want - device_only
     assert "breakdown" not in last and "busy_s" not in last["device"]
     if cell == "tiny-count":        # the metric added by a file alone

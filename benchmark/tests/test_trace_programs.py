@@ -54,6 +54,22 @@ def test_same_file_same_numbers(sample, reduced):
     assert reduced == sample["reduced"]
 
 
+def test_a_mesh_of_chips_is_the_mean_over_them(sample, reduced):
+    """Two chips of which the second has no plane: every second halves,
+    the gaps in which no chip ran stay; a cell on another chip reads
+    nothing of chip 0's."""
+    two = trace_programs.reduce(XPLANE, sample["spans"], sample["t_begin"],
+                                chips=(0, 1))
+    assert two["busy_s"] == pytest.approx(reduced["busy_s"] / 2)
+    assert two["by_family"]["join"] == pytest.approx(
+        reduced["by_family"]["join"] / 2)
+    assert two["top_ops"][0][:2] == reduced["top_ops"][0][:2]
+    assert two["idle_by_span"] == reduced["idle_by_span"]
+    assert two["modules"] == reduced["modules"]
+    assert trace_programs.reduce(XPLANE, sample["spans"], sample["t_begin"],
+                                 chips=(1,)) is None
+
+
 def test_busy_is_trace_reduces_busy(sample, reduced):
     old = trace_reduce.reduce_xplane(XPLANE, [], sample["t_begin"])
     assert reduced["busy_s"] == pytest.approx(old["busy_s"], rel=1e-9)

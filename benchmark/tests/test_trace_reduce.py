@@ -33,6 +33,22 @@ def test_same_file_same_numbers(sample, reduced):
         assert reduced[key] == sample["reduced"][key], key
 
 
+def test_only_the_cells_chips_are_read(sample, reduced):
+    """A chip of the cell's that ran nothing is an idle chip, not a
+    missing one: the mean falls, the per-chip seconds say which; and a
+    cell on another chip does not read chip 0's plane."""
+    four = trace_reduce.reduce_xplane(XPLANE, sample["requests"],
+                                      sample["t_begin"], chips=(0, 1, 2, 3))
+    assert four["busy_s_per_chip"] == [reduced["busy_s"], 0.0, 0.0, 0.0]
+    assert four["busy_s"] == pytest.approx(reduced["busy_s"] / 4)
+    assert dict(four["device_ops"])["%sort.6 sort"] == pytest.approx(
+        dict(reduced["device_ops"])["%sort.6 sort"] / 4)
+    assert four["idle_gaps"] == reduced["idle_gaps"]
+    assert reduced["busy_s_per_chip"] == [reduced["busy_s"]]
+    assert "busy_s" not in trace_reduce.reduce_xplane(
+        XPLANE, sample["requests"], sample["t_begin"], chips=(1,))
+
+
 def test_busy_is_the_union_of_the_device_ops(reduced):
     assert reduced["planes"]["/device:TPU:0"]["XLA Ops"] == 48
     ops = dict(reduced["device_ops"])

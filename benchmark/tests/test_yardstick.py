@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 import reference
+import rehearsal
 import tpch_columns as C
 import traffic_gen
 
@@ -71,9 +72,16 @@ def test_every_seed_gets_the_same_work_in_another_order():
     assert max(shares) - min(shares) < 0.06, shares
 
 
-@pytest.mark.parametrize("name", CONFIGS)
+@pytest.mark.parametrize("name", CONFIGS + ["tpch-sf30-4chip, made here"])
 def test_configuration_states_its_data(name):
-    config = _config(name)
+    if name in CONFIGS:
+        config = _config(name)
+    else:   # a deployment above SF10, as the PR that adds it would state it
+        config = rehearsal.at_scale(_config("tpch-sf10-1chip"),
+                                    "tpch-sf30-4chip", "sf30",
+                                    runner="mesh", chips=4)
+        assert config["rows"] == {"lineitem": 180000093,
+                                  "orders": 45000000, "customer": 4500000}
     sf = config["scale_factor"]
     assert C.SCALE_FACTORS[config["schema"]] == sf
     assert C.fingerprint(sf) == config["data_fingerprint"]
@@ -138,12 +146,16 @@ def test_a_roofline_share_above_100_raises():
     request = {"shape": "q6", "t_send": 0.0, "t_done": 4.0,
                "info": {"stats": {"result_cache_hits": 0}}}
     ctx = {"requests": [request], "slice": (0.0, 4.0), "config": config,
-           "peaks": {"hbm_bytes_per_s": 819e9},
+           "peaks": {"hbm_bytes_per_s": 819e9}, "chips": [0],
            "shapes": {"q6": reference.load_by_path("queries", "q6")},
            "trace": {"busy_s": 3.5, "window_s": 4.0}}
     share = metric.read(ctx)
     needed = config["rows"]["lineitem"] * 28
     assert share == pytest.approx(100 * needed / 819e9 / 3.5)
+    # four chips read four times as fast: the same busy seconds are a
+    # quarter of the share
+    assert metric.read({**ctx, "chips": [0, 1, 2, 3]}) \
+        == pytest.approx(share / 4)
     ctx["trace"] = {"busy_s": 1e-3, "window_s": 4.0}
     with pytest.raises(ValueError, match="above 100"):
         metric.read(ctx)

@@ -9,13 +9,18 @@ in the xplane with no further machinery: the `XLA Modules` line of a
 device plane has one event per program run, and every `XLA Ops` event's
 metadata keeps the op's scope path. This module reads them back:
 
-  reduce(path, spans, t_begin)  -> the table below, or None without a
-                                   device plane (a CPU rehearsal)
+  reduce(path, spans, t_begin, chips)
+                                -> the table below over the planes of the
+                                   cell's chips (device ids), or None
+                                   without one (a CPU rehearsal)
   table(ctx)                    -> the same for the xplane this process
                                    wrote, cached on `ctx` and kept whole
                                    as .bench_out/trace_programs.json
 
-  busy_s          as trace_reduce's: union of the op intervals
+  busy_s          as trace_reduce's: union of the op intervals, the mean
+                  over the cell's chips — as are the seconds of
+                  by_owner, by_family and top_ops; idle is taken where
+                  none of the chips ran an operation
   by_owner        {"<program>/<scope>": seconds}; an op's owner is the
                   innermost scope of its path that matches the grammar,
                   else its module's name if that does, else `unattributed`
@@ -275,10 +280,11 @@ def self_time_spans(spans: list) -> list:
     return out
 
 
-def reduce(path: str, spans_by_query: list, t_begin: float):
+def reduce(path: str, spans_by_query: list, t_begin: float, chips=(0,)):
     """`spans_by_query`: one list of [name, start, end] (on
     time.monotonic(), `stats.spans` of a query) per executed query;
-    `t_begin`: that clock at `bench_slice_begin`."""
+    `t_begin`: that clock at `bench_slice_begin`; `chips`: the ids of the
+    cell's devices."""
     planes = read_xspace(path)
     lo = hi = None
     for plane in planes:
@@ -292,32 +298,40 @@ def reduce(path: str, spans_by_query: list, t_begin: float):
                     hi = start
     if lo is None or hi is None or hi <= lo:
         raise ValueError(f"{path}: slice annotations missing ({lo}, {hi})")
-    device = next((p for p in planes
-                   if p["name"].startswith("/device:TPU:")), None)
-    if device is None:
+    mine = {trace_reduce.plane_name(chip) for chip in chips}
+    devices = [p for p in planes if p["name"] in mine]
+    if not devices:
         return None
-    modules, ops = [], []
-    for line in device["lines"]:
-        if line["name"] == MODULES_LINE:
-            modules = sorted((s, s + d, m["name"])
-                             for m, s, d in line["events"])
-        elif line["name"] == trace_reduce.OPS_LINE:
-            ops = line["events"]
-    starts = [m[0] for m in modules]
-    by_owner, by_family, by_op, intervals = {}, {}, {}, []
-    for meta, start, duration in ops:
-        a, b = max(start, lo), min(start + duration, hi)
-        if b <= a:
-            continue
-        intervals.append((a, b))
-        owner, family = owner_of(scope_path(meta),
-                                 _module_at(modules, starts, start))
-        by_owner[owner] = by_owner.get(owner, 0.0) + (b - a) * 1e-9
-        by_family[family] = by_family.get(family, 0.0) + (b - a) * 1e-9
-        op = (owner, trace_reduce.short_name(meta["name"]))
-        by_op[op] = by_op.get(op, 0.0) + (b - a) * 1e-9
-    busy = trace_reduce._union(intervals) * 1e-9
-    # idle gaps, laid on the requests' spans through the begin stamp
+    n_chips = len(chips)    # one of them without a plane ran nothing
+    by_owner, by_family, by_op = {}, {}, {}
+    intervals, busy, module_names = [], 0.0, set()
+    for device in devices:
+        modules, ops = [], []
+        for line in device["lines"]:
+            if line["name"] == MODULES_LINE:
+                modules = sorted((s, s + d, m["name"])
+                                 for m, s, d in line["events"])
+            elif line["name"] == trace_reduce.OPS_LINE:
+                ops = line["events"]
+        starts = [m[0] for m in modules]
+        module_names |= {m[2] for m in modules if m[1] > lo and m[0] < hi}
+        own = []
+        for meta, start, duration in ops:
+            a, b = max(start, lo), min(start + duration, hi)
+            if b <= a:
+                continue
+            own.append((a, b))
+            owner, family = owner_of(scope_path(meta),
+                                     _module_at(modules, starts, start))
+            seconds = (b - a) * 1e-9 / n_chips
+            by_owner[owner] = by_owner.get(owner, 0.0) + seconds
+            by_family[family] = by_family.get(family, 0.0) + seconds
+            op = (owner, trace_reduce.short_name(meta["name"]))
+            by_op[op] = by_op.get(op, 0.0) + seconds
+        busy += trace_reduce._union(own) * 1e-9 / n_chips
+        intervals += own
+    # gaps in which no chip of the cell's ran, laid on the requests'
+    # spans through the begin stamp
     to_mono = lambda ns: t_begin + (ns - lo) * 1e-9  # noqa: E731
     flat = []
     for spans in spans_by_query:
@@ -336,8 +350,7 @@ def reduce(path: str, spans_by_query: list, t_begin: float):
         "idle_by_span": idle_by_span,
         "top_ops": [[owner, name, seconds] for (owner, name), seconds
                     in sorted(by_op.items(), key=lambda kv: -kv[1])[:10]],
-        "modules": sorted({m[2] for m in modules
-                           if m[1] > lo and m[0] < hi}),
+        "modules": sorted(module_names),
     }
 
 
@@ -381,7 +394,7 @@ def table(ctx):
         if ctx.get("trace") and ctx.get("slice") and path:
             spans = [r["info"]["stats"].get("spans") or []
                      for r in executed(ctx)]
-            out = reduce(path, spans, ctx["slice"][0])
+            out = reduce(path, spans, ctx["slice"][0], ctx["chips"])
             if out:     # the whole table, for PERF.md: run.py prints none
                 with open(os.path.join(root, "trace_programs.json"),
                           "w") as f:

@@ -4,15 +4,19 @@ The traced slice is what lies between the two host annotations
 `bench_slice_begin` and `bench_slice_end` that `run.py` emits; each is
 also stamped with `time.monotonic()`, which lays the load generator's
 request intervals on the trace's clock. On every device plane
-(`/device:TPU:<n>`) the line `XLA Ops` holds one event per operation that
-ran there:
+(`/device:TPU:<n>`, n the device's id) the line `XLA Ops` holds one event
+per operation that ran there. Only the planes of the cell's chips are
+read: on a four-chip host a one-chip cell is not averaged over four.
 
-  busy_s      union of those events' intervals inside the slice, averaged
-              over the device planes
+  busy_s      union of those events' intervals inside the slice, the mean
+              over the cell's chips; `busy_s_per_chip` has each
   window_s    length of the slice
   device_ops  the ten operation names with most summed time (the instruction's name
-              and opcode as XLA printed them), [[name, seconds], ...]
-  idle_gaps   the device's idle time inside the slice, summed by what the
+              and opcode as XLA printed them), [[name, seconds], ...], the
+              mean over the cell's chips
+  idle_gaps   the time inside the slice in which none of the cell's chips
+              ran an operation (the gaps of the union of their intervals),
+              summed by what the
               load generator had in flight at the middle of each gap:
               `between_requests`, or `in_request:<shapes>`;
               [[name, seconds], ...], the ten largest sums
@@ -84,26 +88,34 @@ def _gaps(intervals: list, lo: int, hi: int) -> list:
     return gaps
 
 
-def reduce_xplane(path: str, requests: list, t_begin: float) -> dict:
+def plane_name(chip: int) -> str:
+    return f"/device:TPU:{chip}"
+
+
+def reduce_xplane(path: str, requests: list, t_begin: float,
+                  chips=(0,)) -> dict:
     """`requests`: [{"shape", "t_send", "t_done"}] on `time.monotonic()`;
-    `t_begin`: that clock's reading when `bench_slice_begin` was emitted.
-    Without a device plane (a CPU rehearsal) only `planes` comes back:
-    every plane's lines with their event counts."""
+    `t_begin`: that clock's reading when `bench_slice_begin` was emitted;
+    `chips`: the ids of the cell's devices — one of them that ran nothing
+    counts as a chip that was idle. Without an `XLA Ops` line on any of
+    their planes (a CPU rehearsal) only `planes` comes back: every
+    plane's lines with their event counts."""
     from jax.profiler import ProfileData
     profile = ProfileData.from_file(path)
     lo, hi = _anchor(profile, BEGIN), _anchor(profile, END)
     if lo is None or hi is None or hi <= lo:
         raise ValueError(f"{path}: slice annotations missing ({lo}, {hi})")
-    per_device, by_name, planes = [], {}, {}
+    by_plane, by_name, planes = {}, {}, {}
+    mine = {plane_name(chip) for chip in chips}
     for plane in profile.planes:
         planes[plane.name] = {line.name: sum(1 for _ in line.events)
                               for line in plane.lines}
-        if not plane.name.startswith("/device:TPU:"):
+        if plane.name not in mine:
             continue
         for line in plane.lines:
             if line.name != OPS_LINE:
                 continue
-            spans = []
+            spans = by_plane.setdefault(plane.name, [])
             for event in line.events:
                 a = max(int(event.start_ns), lo)
                 b = min(int(event.start_ns + event.duration_ns), hi)
@@ -111,13 +123,13 @@ def reduce_xplane(path: str, requests: list, t_begin: float) -> dict:
                     spans.append((a, b))
                     name = short_name(event.name)
                     by_name[name] = by_name.get(name, 0) + b - a
-            per_device.append(spans)
-    if not per_device:
+    if not by_plane:
         return {"planes": planes}
+    per_device = [by_plane.get(plane_name(chip), []) for chip in chips]
     # ns on the trace's clock -> seconds on time.monotonic()
     to_mono = lambda ns: t_begin + (ns - lo) * 1e-9  # noqa: E731
     idle = {}
-    for a, b in _gaps(per_device[0], lo, hi):
+    for a, b in _gaps([s for spans in per_device for s in spans], lo, hi):
         mid = to_mono((a + b) / 2)
         shapes = sorted({r["shape"] for r in requests
                          if r["t_send"] <= mid <= r["t_done"]})
@@ -127,8 +139,10 @@ def reduce_xplane(path: str, requests: list, t_begin: float) -> dict:
     n = len(per_device)
     top = lambda d: [[k, v * 1e-9] for k, v in sorted(  # noqa: E731
         d.items(), key=lambda kv: -kv[1])[:10]]
+    busy = [_union(s) for s in per_device]
     return {
-        "busy_s": sum(_union(s) for s in per_device) * 1e-9 / n,
+        "busy_s": sum(busy) * 1e-9 / n,
+        "busy_s_per_chip": [b * 1e-9 for b in busy],
         "window_s": (hi - lo) * 1e-9,
         "devices_traced": n, "planes": planes,
         "device_ops": [[k, v / n] for k, v in top(by_name)],
