@@ -202,3 +202,84 @@ def test_scopes_do_not_change_the_program():
     a = jax.jit(plain).lower(x).as_text()
     b = jax.jit(scoped).lower(x).as_text()
     assert a.replace("jit_plain", "F") == b.replace("jit_scoped", "F")
+
+
+def test_a_mesh_program_names_every_op(monkeypatch):
+    """Inside `exchange__mesh_prog` (PR 28) every lowering and every
+    collective helper runs under a `<family>__<tag>` scope, partitioning
+    apart from the collective itself, so a device trace can tell whose
+    second a kernel's is: q3 under PARTITIONED carries exchange, join,
+    aggregate and scan scopes, and no op of the per-shard body lies
+    outside one."""
+    from trino_tpu.exec import mesh_exec
+    from trino_tpu.exec.distributed import DistributedQueryRunner
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices")
+    from jax.experimental.compilation_cache import compilation_cache
+    calls = []
+    run_program = mesh_exec._run_program
+
+    def spy(runner, top_fn, staged, struct_key, ladder, params):
+        calls.append((struct_key + (tuple(sorted(ladder.items())),),
+                      params, staged))
+        return run_program(runner, top_fn, staged, struct_key, ladder,
+                           params)
+    monkeypatch.setattr(mesh_exec, "_run_program", spy)
+    runner = DistributedQueryRunner.tpch("tiny", devices=jax.devices()[:4])
+    runner.session.set("join_distribution_type", "PARTITIONED")
+    runner.execute(chip_smoke.Q3)
+    # customer's filter keys on a string and is a program of its own
+    # first; the one with the joins in it is the last
+    assert len(calls) == 2
+    key, params, staged = calls[-1]
+    assert jit_cache.program_name(key) == "exchange__mesh_prog"
+    # compiled here and now: an executable read back from the persistent
+    # cache has lost most of its op names
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jit_cache._CACHE[key][0].lower(params, *staged).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    names = set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+    # the per-shard body's ops: all but the arguments and what shard_map
+    # itself lays around the body (its own name, broadcasts of constants)
+    body = set()
+    for name in names:
+        path = [s for s in name.split("/")
+                if not (s.startswith("jit(") or s == "shard_map")]
+        if path and not name.startswith("args[") \
+                and not re.fullmatch(r"broadcast\.\d+", "/".join(path)):
+            body.add("/".join(path))
+    assert len(body) > 100
+    for scope in ("exchange__partition", "exchange__all_to_all",
+                  "exchange__compact", "exchange__broadcast",
+                  "exchange__heavy_keys", "exchange__psum",
+                  "join__hash_join", "join__probe_lookup",
+                  "aggregate__partial", "scan_filter__filter",
+                  "scan_filter__project"):
+        assert any(scope in n.split("/") for n in body), scope
+    # q3's three filters feed hash repartitions, which drop dead rows as
+    # they bucket them: none compacts its page first
+    assert not any("scan_filter__compact_gather" in n for n in body)
+    # (XLA keeps a few ops of inner jits — `cummin`'s windows — under
+    # their primitive's bare name: too few to matter to a trace)
+    bare = {n for n in body
+            if "/" not in n and not jit_cache.NAME_GRAMMAR.match(n)}
+    assert len(bare) <= 0.02 * len(body), sorted(bare)
+    for name in body - bare:
+        scopes = [s for s in name.split("/")
+                  if jit_cache.NAME_GRAMMAR.match(s)]
+        assert scopes, f"no scope on {name}"
+        # the collectives lie under their own tags, the work that
+        # prepares rows for them and unpacks them under others
+        op = name.rsplit("/", 1)[-1]
+        if op.startswith(("all_to_all", "all_gather")):
+            assert scopes[-1] in ("exchange__all_to_all",
+                                  "exchange__broadcast",
+                                  "exchange__all_gather"), name
+        if op.startswith(("sort", "scatter", "gather", "cumsum")):
+            assert scopes[-1] not in ("exchange__all_to_all",
+                                      "exchange__broadcast"), name

@@ -147,3 +147,117 @@ def test_mesh_all_to_all_on_four_chips(topo):
         mesh.shard_map(lambda p: all_to_all_by_key(p, [0], 1 << 13)),
         page, limit_s=150)
     assert "all-to-all" in compiled.as_text()
+
+
+# ------------------------------------------------- q1 and q3 on four chips
+
+SF30_PAGE = 1 << 22         # scan_page_capacity: a shard is whole pages
+
+
+def _mesh_programs(topo, sql):
+    """[(program, params, pages)] — the co-scheduled mesh programs of
+    `sql` at TPC-H SF30 under PARTITIONED, as `MeshLowerer` composes them
+    and in the order they run, for four described chips: shapes in the place of the resident shards
+    (`exec/table_cache.ShardedTable`: 11 pages of 4 194 304 lanes of
+    lineitem a chip)."""
+    from trino_tpu.connector import tpch
+    from trino_tpu.exec.distributed import (DistributedQueryRunner,
+                                            _find_remote)
+    from trino_tpu.exec.mesh_exec import MeshLowerer, _Env
+    from trino_tpu.parallel.mesh import QueryMesh
+    from trino_tpu.planner.optimizer import fragment_plan
+    from trino_tpu.sql.parser import parse_statement
+    mesh = QueryMesh(topo.devices[:4])
+    sharded = NamedSharding(mesh.mesh, P(QueryMesh.AXIS))
+    replicated = NamedSharding(mesh.mesh, P())
+    runner = DistributedQueryRunner.tpch("sf30", devices=jax.devices()[:4])
+    runner.session.set("join_distribution_type", "PARTITIONED")
+    frag = fragment_plan(runner._plan_for_execution(parse_statement(sql)))
+
+    def scan_page(scan):
+        table = scan.table.name.table
+        rows = -(-tpch.table_row_count(table, 30.0) // 4)
+        lanes = -(-rows // SF30_PAGE) * SF30_PAGE
+        cols = []
+        for _, ch in scan.assignments:
+            pool = tpch.table_dictionary(table, 30.0, ch.name) \
+                if T.is_string(ch.type) else None
+            dtype = jnp.int32 if pool is not None \
+                else T.to_numpy_dtype(ch.type)
+            cols.append(Column(jax.ShapeDtypeStruct(
+                (4, lanes), dtype, sharding=sharded), None, ch.type, pool))
+        return Page(tuple(cols), jax.ShapeDtypeStruct(
+            (4,), jnp.int32, sharding=sharded))
+
+    def compose(child, remote, exchange=True):
+        """The program of `child` and, before it, those of the fragments
+        under it that key on a string and feed it their page."""
+        lowerer = MeshLowerer(runner.session, runner.metadata, 4, ())
+        top = lowerer.lower_child(child, remote, exchange)
+        fed, pages = [], []
+        for leaf in lowerer.leaves:
+            if not isinstance(leaf, tuple):
+                pages.append(scan_page(leaf))
+                continue
+            fed += compose(*leaf, exchange=False)
+            program, params, leaves = fed[-1]
+            page = jax.eval_shape(program, params, *leaves)[0]
+            pages.append(jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=sharded), page))
+        params = tuple(jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                            sharding=replicated)
+                       for v in lowerer.param_values)
+
+        def per_shard(params, *pages):
+            env = _Env(pages, {}, params)
+            return top(env), env.aux
+        return fed + [(mesh.shard_map(per_shard, replicated=1), params,
+                       pages)]
+    return [program for child in frag.children for program in compose(
+        child, _find_remote(frag.root, child.fragment_id))]
+
+
+def test_q1_mesh_program_at_the_sf30_shard_shape(topo):
+    """q1 over a 46 M-lane shard a chip: the chain under the partial
+    aggregate runs 1 048 576 lanes at a time in one loop (whole, its
+    stacked scatter-add asked the chip for 23.6 GB), its filter moves no
+    row, and the four chips' partial states meet in a collective."""
+    import chip_smoke
+    (program, params, pages), = _mesh_programs(topo, chip_smoke.Q1)
+    assert pages[0].columns[0].values.shape == (4, 11 * SF30_PAGE)
+    compiled = _compile(program, params, *pages, limit_s=120)
+    text = compiled.as_text()
+    # (the compiler spells an all-gather of a few rows as all-reduces)
+    assert ("all-gather" in text or "all-reduce" in text) \
+        and " while(" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < (5 << 30)
+    assert memory.argument_size_in_bytes < (3 << 30)
+
+
+@pytest.mark.slow
+def test_q3_mesh_program_at_the_sf30_shard_shape(topo):
+    """q3 under PARTITIONED over the SF30 shards: seven exchanges, two
+    joins, GROUP BY and the partial TopN in one program. Not tier-1: the
+    TPU compiler takes about ten minutes over it here (89 sorts of 46 M
+    lanes), which is also what a cold start of the benchmark's four-chip
+    cell pays once. Run by hand before a four-chip call:
+    `pytest tests/test_tpu_compile.py -m slow`."""
+    import chip_smoke
+    (small, sparams, customer), (program, params, pages) = _mesh_programs(
+        topo, chip_smoke.Q3)
+    assert [p.columns[0].values.shape[1] // SF30_PAGE
+            for p in pages + customer] == [11, 3, 1, 1]
+    # customer's filter keys on the SEGMENT: a program of its own, which
+    # a new SEGMENT compiles in seconds, and which moves no row
+    assert pages[2].selection is not None
+    assert "all-to-all" not in _compile(small, sparams, *customer,
+                                        limit_s=60).as_text()
+    compiled = _compile(program, params, *pages, limit_s=1500)
+    text = compiled.as_text()
+    assert "all-to-all" in text and "all-gather" in text
+    memory = compiled.memory_analysis()
+    # what the program holds beside the 2.7 GB of resident shards
+    assert memory.temp_size_in_bytes + memory.output_size_in_bytes \
+        < (9 << 30)

@@ -38,7 +38,7 @@ from trino_tpu.page import Column, Dictionary, Page
 _D12_2 = T.DecimalType(12, 2)
 
 SCHEMAS = {
-    "tiny": 0.01, "sf1": 1.0, "sf10": 10.0, "sf100": 100.0,
+    "tiny": 0.01, "sf1": 1.0, "sf10": 10.0, "sf30": 30.0, "sf100": 100.0,
     "sf300": 300.0, "sf1000": 1000.0,
 }
 
@@ -309,9 +309,14 @@ def _staged_column(table: str, sf: float, name: str, typ: T.Type,
     every execution would only re-measure PCIe. Real-table residency analog:
     Trino's memory connector / a warmed OS page cache."""
     global _DEVICE_COL_CACHE_USED
+    import jax
+    # a caller that pins its pages to a chip (`jax.default_device`: a mesh
+    # scan makes shard i on chip i and keeps it there itself) gets fresh
+    # arrays and leaves none here: this LRU is the default device's
+    pinned = jax.config.jax_default_device is not None
     key = (table, round(sf * 1000), name, off, hi, page_capacity)
     with _CACHE_LOCK:
-        col = _DEVICE_COL_CACHE.get(key)
+        col = None if pinned else _DEVICE_COL_CACHE.get(key)
         if col is not None:
             _DEVICE_COL_CACHE.move_to_end(key)
             return col
@@ -345,8 +350,8 @@ def _staged_column(table: str, sf: float, name: str, typ: T.Type,
         col = Column.from_numpy(arr, typ)
     nbytes = col.nbytes
     with _CACHE_LOCK:
-        if nbytes > _DEVICE_COL_CACHE_BYTES:
-            return col   # larger than the whole budget: never cache
+        if pinned or nbytes > _DEVICE_COL_CACHE_BYTES:
+            return col   # the caller's, or larger than the whole budget
         if key not in _DEVICE_COL_CACHE:
             while (_DEVICE_COL_CACHE_USED + nbytes
                    > _DEVICE_COL_CACHE_BYTES and _DEVICE_COL_CACHE):

@@ -121,7 +121,9 @@ def _oidx_fn(sf: float, cap: int):
 
 
 def _device_oidx(sf: float, start: int, end: int, cap: int) -> jnp.ndarray:
-    key = (round(sf * 1000), start, end, cap)
+    # the chip the caller generates on is part of the key: a mesh scan
+    # makes shard i on chip i, and chip 0's array is not chip 2's
+    key = (round(sf * 1000), start, end, cap, jax.config.jax_default_device)
     got = _OIDX_CACHE.get(key)
     if got is not None:
         _OIDX_CACHE.move_to_end(key)

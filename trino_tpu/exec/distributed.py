@@ -112,15 +112,26 @@ class ShardExecutionPlanner(LocalExecutionPlanner):
             if memo is not None and memo_key in memo:
                 entry = memo[memo_key]
             else:
-                entry = tcache.lookup(tkey, names, count=self.shard == 0)
+                # the mesh's resident shards first (block i is on chip i
+                # already), then a full-length entry to slice
+                entry = None if node.table.limit is not None else \
+                    tcache.lookup_sharded(tkey, names, self.n_shards,
+                                          count=self.shard == 0)
+                if entry is None:
+                    entry = tcache.lookup(tkey, names,
+                                          count=self.shard == 0)
                 if memo is not None:
                     memo[memo_key] = entry
             if entry is not None:
                 if col is not None and self.shard == 0:
                     col.table_cache_hit()
-                from trino_tpu.exec.table_cache import build_shard_page
-                my_page = build_shard_page(entry, names, self.shard,
-                                           self.n_shards)
+                from trino_tpu.exec.table_cache import (ShardedTable,
+                                                        build_shard_page)
+                if isinstance(entry, ShardedTable):
+                    my_page = entry.shard_page(names, self.shard)
+                else:
+                    my_page = build_shard_page(entry, names, self.shard,
+                                               self.n_shards)
 
                 def gen_resident(page=my_page):
                     if page is None:
@@ -223,7 +234,7 @@ class ShardExecutionPlanner(LocalExecutionPlanner):
             gen=None if decision is None else decision[1])
 
     def _split_capacity(self, conn, node: TableScanNode, splits) -> int:
-        cap = split_scan_capacity(self.session, conn, node, splits)
+        cap = split_scan_capacity(self.session, conn, node.table, splits)
         if self.slices is not None:
             # same bound as the local scan: one page <= one slice
             cap = min(cap, self.slices.capacity_cap(self.page_capacity))
@@ -716,14 +727,14 @@ class DistributedQueryRunner(LocalQueryRunner):
 # page plumbing for the collective data plane
 
 
-def split_scan_capacity(session, conn, node: TableScanNode, splits) -> int:
+def split_scan_capacity(session, conn, table, splits) -> int:
     """Scan page capacity for a sharded split set: the session page
     floor, grown to the per-split row envelope up to scan_page_capacity.
     Shared by the per-shard dispatch loop and mesh staging so the two
     data planes size identical pages for the same query."""
     cap = int(session.get("page_capacity"))
     try:
-        stats = conn.metadata.get_table_statistics(node.table)
+        stats = conn.metadata.get_table_statistics(table)
         rows = int(stats.row_count) if stats and stats.row_count else 0
     except Exception:
         rows = 0

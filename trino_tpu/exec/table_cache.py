@@ -25,6 +25,18 @@ Invalidation rides the PlanCache hook fan-out: ONE DDL/INSERT call
 drops cached plans, result sets, staged scan pages, AND the device
 columns — a resident column can never outlive a table change.
 
+Sharded entries (`ShardedTable`, the mesh runner's form of residency):
+chip i of the runner's mesh holds the rows of ITS splits, made on chip i
+(`mesh_exec.read_shard_pages`), as one array per column sharded over the
+`workers` axis. A co-scheduled mesh program is handed those arrays as they
+are — no slice, no copy between chips, nothing from the host — and the
+dispatch loop reads its shard's block. They are made by the server's
+`tables:` warm-up on a mesh runner and by a mesh scan's own promotion, and
+admitted PER CHIP against the node pool (`reserve_cache(shard bytes,
+device=i)`, sized from the chip's measured HBM): `table_cache_max_bytes`
+bounds the full-length entries of one chip, as it always did, and says
+nothing about a table spread over several.
+
 Like the other serving caches, one instance per owning runner, shared
 with `for_query()` clones under a lock.
 """
@@ -83,15 +95,56 @@ class ResidentTable:
         return (self.freq, self.last_used)
 
 
+@dataclasses.dataclass
+class ShardedTable:
+    """Columns of one table resident across a mesh: every array has the
+    mesh as its leading axis, block i on chip i."""
+
+    table: TableKey
+    n_shards: int
+    columns: Dict[str, object]      # name -> page.Column, values (n, cap)
+    num_rows: object                # (n,) int32, sharded like the columns
+    rows: int
+    shard_bytes: int                # on each chip
+    freq: int = 0
+    last_used: float = 0.0
+
+    @property
+    def capacity(self) -> int:
+        """Lanes of one shard."""
+        return next(iter(self.columns.values())).values.shape[1]
+
+    def page(self, column_names: Sequence[str]):
+        """The workers-sharded global Page a mesh program takes."""
+        from trino_tpu.page import Page
+        return Page(tuple(self.columns[n] for n in column_names),
+                    self.num_rows)
+
+    def shard_page(self, column_names: Sequence[str], shard: int):
+        """Shard `shard`'s page, on its chip (the dispatch loop)."""
+        import jax
+        import jax.numpy as jnp
+
+        def block(x):
+            mine = next(s for s in x.addressable_shards
+                        if (s.index[0].start or 0) == shard)
+            return jnp.squeeze(mine.data, axis=0)
+        return jax.tree_util.tree_map(block, self.page(column_names))
+
+
 class TableCache(_GenerationGuard):
     def __init__(self, max_bytes: int = DEFAULT_MAX_BYTES,
                  min_scans: int = DEFAULT_MIN_SCANS):
         self._lock = threading.RLock()
         self.max_bytes = int(max_bytes)
         self.min_scans = int(min_scans)
-        self.resident_bytes = 0
+        self.resident_bytes = 0     # full-length entries: max_bytes' side
+        self.sharded_bytes = 0      # over all chips; the node pool's side
         # key = (table, frozenset of column names)
         self._entries: Dict[tuple, ResidentTable] = {}
+        # key = (table, mesh size): one sharded entry per table, its
+        # column set growing as more of the table is warmed
+        self._sharded: Dict[tuple, ShardedTable] = {}
         # scan-frequency ledger feeding admission (kept separate from
         # entries: a candidate earns its promotion before it costs HBM)
         self._scan_counts: Dict[tuple, int] = {}
@@ -169,6 +222,75 @@ class TableCache(_GenerationGuard):
         """lookup() without counters (eligibility probes)."""
         with self._lock:
             return self._find_locked(table, column_names) is not None
+
+    # ------------------------------------------------------------ sharded
+
+    def lookup_sharded(self, table: TableKey, column_names: Sequence[str],
+                       n_shards: int, count: bool = True
+                       ) -> Optional[ShardedTable]:
+        """The table's entry over a mesh of `n_shards` if it holds every
+        requested column, else None (a miss is counted by the
+        full-length probe that follows it)."""
+        with self._lock:
+            entry = self._sharded.get((table, n_shards))
+            if entry is None or not set(column_names) <= set(entry.columns):
+                return None
+            if count:
+                entry.freq += 1
+                entry.last_used = time.monotonic()
+                _count("hits")
+            return entry
+
+    def admit_sharded(self, table: TableKey, column_names: Sequence[str],
+                      page, rows: int, collector=None,
+                      gen: Optional[int] = None) -> bool:
+        """Keep a workers-sharded global Page (block i already on chip i)
+        as the table's resident shards: no copy is made. Admission is per
+        chip, against the node pool; columns join the table's entry when
+        their shards line up with it, else they replace it."""
+        from trino_tpu.exec.memory import NODE_POOL
+        n = int(page.num_rows.shape[0])
+        if any(c.lengths is not None for c in page.columns):
+            return False    # list layouts: as for full-length entries
+        with self._lock:
+            if self._stale_locked((table,), gen):
+                return False
+            key = (table, n)
+            old = self._sharded.get(key)
+            if old is not None and (old.rows != rows or old.capacity
+                                    != page.columns[0].values.shape[1]):
+                self._release_sharded_locked(self._sharded.pop(key))
+                old = None
+            columns = dict(old.columns) if old is not None else {}
+            fresh = {name: c for name, c in zip(column_names, page.columns)
+                     if name not in columns}
+            need = sum(c.nbytes for c in fresh.values()) // n
+            reserved = []
+            for shard in range(n):
+                if not NODE_POOL.reserve_cache(need, shard):
+                    for done in reserved:
+                        NODE_POOL.free_cache(need, done)
+                    _count("admission_denied")
+                    return False
+                reserved.append(shard)
+            columns.update(fresh)
+            self._sharded[key] = ShardedTable(
+                table, n, columns,
+                page.num_rows if old is None else old.num_rows, rows,
+                need + (old.shard_bytes if old is not None else 0),
+                freq=1 + (old.freq if old is not None else 0),
+                last_used=time.monotonic())
+            self.sharded_bytes += need * n
+            _count("promotions")
+        self._span(collector, "table-cache-promote", table=table,
+                   bytes=need * n, rows=rows, columns=len(fresh), shards=n)
+        return True
+
+    def _release_sharded_locked(self, entry: ShardedTable) -> None:
+        from trino_tpu.exec.memory import NODE_POOL
+        self.sharded_bytes -= entry.shard_bytes * entry.n_shards
+        for shard in range(entry.n_shards):
+            NODE_POOL.free_cache(entry.shard_bytes, shard)
 
     # ---------------------------------------------------------- promotion
 
@@ -291,6 +413,10 @@ class TableCache(_GenerationGuard):
             stale = [k for k in self._entries if k[0] == table]
             for k in stale:
                 self._release_locked(self._entries.pop(k))
+            sharded = [k for k in self._sharded if k[0] == table]
+            for k in sharded:
+                self._release_sharded_locked(self._sharded.pop(k))
+            stale += sharded
             for k in [k for k in self._scan_counts if k[0] == table]:
                 del self._scan_counts[k]
         if stale:
@@ -301,12 +427,15 @@ class TableCache(_GenerationGuard):
         with self._lock:
             for entry in self._entries.values():
                 self._release_locked(entry)
+            for entry in self._sharded.values():
+                self._release_sharded_locked(entry)
             self._entries.clear()
+            self._sharded.clear()
             self._scan_counts.clear()
 
     def __len__(self) -> int:
         with self._lock:
-            return len(self._entries)
+            return len(self._entries) + len(self._sharded)
 
     # -------------------------------------------------------------- spans
 
@@ -415,7 +544,7 @@ def table_cache_stats() -> Dict[str, int]:
         out = dict(_STATS)
     caches = list(_INSTANCES)
     out["entries"] = sum(len(c) for c in caches)
-    out["bytes"] = sum(c.resident_bytes for c in caches)
+    out["bytes"] = sum(c.resident_bytes + c.sharded_bytes for c in caches)
     return out
 
 
@@ -427,4 +556,7 @@ def device_residency() -> Dict[Optional[int], int]:
         with cache._lock:
             for entry in cache._entries.values():
                 out[entry.device] = out.get(entry.device, 0) + entry.nbytes
+            for entry in cache._sharded.values():
+                for shard in range(entry.n_shards):
+                    out[shard] = out.get(shard, 0) + entry.shard_bytes
     return out

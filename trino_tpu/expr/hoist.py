@@ -114,6 +114,15 @@ def hoist_literal_seq(exprs: Sequence[RowExpression], bound: Tuple = ()
     return outs, tuple(values)
 
 
+def hoist_into(expr: RowExpression, values: List[np.ndarray],
+               bound: Tuple = ()) -> RowExpression:
+    """Canonicalize one more expression of a program whose expressions
+    all index ONE values list (a co-scheduled mesh program passes the
+    whole list as a single replicated operand): Param indices run on
+    from len(values)."""
+    return _walk(expr, values, bound)
+
+
 def materialize_bound(expr: RowExpression, bound: Tuple) -> RowExpression:
     """Replace BoundParam leaves with their bound values as Literals —
     the hoist-disabled execution path for prepared statements (kernels

@@ -37,7 +37,9 @@ are null.
 The request's life (round 25): five spans of the query's tree, stamped
 on `time.monotonic()` — `queued` (submit -> an executor thread took it),
 `planning`, `execution`, and under execution `compile` (one per AOT
-compile) and `result_fetch` (first result page on the host -> last).
+compile) and `result_fetch` (first result page on the host -> last);
+on a mesh runner also `mesh_stage` (a co-scheduled program's leaf scans
+being staged: nothing of it once the shards are resident).
 `execution`'s self time, its wall minus those children, is dispatch.
 snapshot() carries them as absolute [name, start, end] triples, so
 spans of concurrent queries and a device trace lay on one axis.
@@ -84,7 +86,7 @@ class OperatorStats:
 
 
 REQUEST_SPANS = ("queued", "planning", "execution", "compile",
-                 "result_fetch")
+                 "result_fetch", "mesh_stage")
 
 
 class QueryStatsCollector:
@@ -186,6 +188,17 @@ class QueryStatsCollector:
         self.exchange_bytes = 0
         # mesh shape the query executed over (0 = single-device)
         self.mesh_devices = 0
+        # co-scheduled mesh programs (exec/mesh_exec.py): programs the
+        # query ran, and dispatches of them — a round more for every
+        # climb of the capacity ladder, so the two are equal once a
+        # shape's capacities are remembered; bytes its mesh scans
+        # generated, copied between chips or staged from the host (0 on
+        # resident shards); literals and EXECUTE values that went in as
+        # the programs' replicated operand instead of into their keys
+        self.mesh_programs = 0
+        self.mesh_program_rounds = 0
+        self.mesh_scan_moved_bytes = 0
+        self.mesh_params = 0
         # preemptible sliced execution (exec/sliced/): bounded-work
         # slices the query executed, operator checkpoints saved/restored
         # (restored > 0 on a retried query = the retry RESUMED instead
@@ -475,6 +488,10 @@ class QueryStatsCollector:
             "exchange_rows": self.exchange_rows,
             "exchange_bytes": self.exchange_bytes,
             "mesh_devices": self.mesh_devices,
+            "mesh_programs": self.mesh_programs,
+            "mesh_program_rounds": self.mesh_program_rounds,
+            "mesh_scan_moved_bytes": self.mesh_scan_moved_bytes,
+            "mesh_params": self.mesh_params,
             "slices_executed": self.slices_executed,
             "checkpoints_saved": self.checkpoints_saved,
             "checkpoints_restored": self.checkpoints_restored,

@@ -26,6 +26,15 @@ heavy probe rows round-robin across the mesh and REPLICATES the matching
 build rows to every shard, so correctness is preserved (each probe row
 still sees all of its key's build rows exactly once) while no shard
 receives more than ~1/n of a hot key's probe rows.
+
+Names (`page.op_scope`, the grammar `benchmark/trace_programs.py` reads):
+the work that prepares rows for the wire runs under `exchange__partition`
+(destination hash, bucket sort, scatter into per-peer buckets) and
+`exchange__heavy_keys`, the collectives themselves under
+`exchange__all_to_all`, `exchange__broadcast` (all_gather),
+`exchange__all_gather` (the heavy-key candidates) and `exchange__psum`,
+and the receiver's compaction under `exchange__compact` — so the time in
+the wire can be told from the time around it.
 """
 
 from __future__ import annotations
@@ -38,7 +47,7 @@ import numpy as np
 
 from trino_tpu.ops.join import _key_u64, _mix64
 from trino_tpu.ops.radix import stable_argsort
-from trino_tpu.page import Column, Page
+from trino_tpu.page import Column, Page, op_scope
 
 AXIS = "workers"
 
@@ -83,61 +92,84 @@ def detect_heavy_keys(page: Page, key_channels: Sequence[int], k: int,
     frequent, so the global sum is exact for the keys that matter;
     borderline keys may be undercounted and simply stay un-spread."""
     n = jax.lax.psum(1, axis)
-    key, is_null = _key_u64(page, key_channels)
-    live = page.row_mask() & ~is_null
-    masked = jnp.where(live, key, _U64MAX)
-    s = jnp.sort(masked)
-    cap = page.capacity
-    idx = jnp.arange(cap, dtype=jnp.int32)
-    boundary = (s != jnp.roll(s, 1)).at[0].set(True)
-    run_start = jax.lax.cummax(jnp.where(boundary, idx, 0))
-    nxt = jnp.where(boundary, idx, cap)
-    suffix_min = jnp.flip(jax.lax.cummin(jnp.flip(nxt)))
-    next_start = jnp.concatenate(
-        [suffix_min[1:], jnp.full((1,), cap, dtype=suffix_min.dtype)])
-    run_len = (next_start - run_start).astype(jnp.int32)
-    cand_count = jnp.where(boundary & (s != _U64MAX), run_len, 0)
-    top_counts, top_idx = jax.lax.top_k(cand_count, k)
-    cand_keys = jnp.take(s, top_idx)
-    all_keys = jax.lax.all_gather(cand_keys, axis).reshape(n * k)
-    all_counts = jax.lax.all_gather(top_counts, axis).reshape(n * k)
-    eq = all_keys[:, None] == all_keys[None, :]
-    glob = jnp.sum(eq * all_counts[None, :].astype(jnp.int64), axis=1)
-    nk = n * k
-    first = ~jnp.any(eq & (jnp.arange(nk)[None, :] < jnp.arange(nk)[:, None]),
-                     axis=1)
-    score = jnp.where((all_keys != _U64MAX) & first
-                      & (glob >= min_global_count), glob, -1)
-    sel_score, sel = jax.lax.top_k(score, k)
-    return jnp.where(sel_score > 0, jnp.take(all_keys, sel), _U64MAX)
+    with op_scope("exchange__heavy_keys"):
+        key, is_null = _key_u64(page, key_channels)
+        live = page.row_mask() & ~is_null
+        masked = jnp.where(live, key, _U64MAX)
+        s = jnp.sort(masked)
+        cap = page.capacity
+        idx = jnp.arange(cap, dtype=jnp.int32)
+        boundary = (s != jnp.roll(s, 1)).at[0].set(True)
+        run_start = jax.lax.cummax(jnp.where(boundary, idx, 0))
+        nxt = jnp.where(boundary, idx, cap)
+        suffix_min = jnp.flip(jax.lax.cummin(jnp.flip(nxt)))
+        next_start = jnp.concatenate(
+            [suffix_min[1:], jnp.full((1,), cap, dtype=suffix_min.dtype)])
+        run_len = (next_start - run_start).astype(jnp.int32)
+        cand_count = jnp.where(boundary & (s != _U64MAX), run_len, 0)
+        top_counts, top_idx = jax.lax.top_k(cand_count, k)
+        cand_keys = jnp.take(s, top_idx)
+    with op_scope("exchange__all_gather"):
+        all_keys = jax.lax.all_gather(cand_keys, axis).reshape(n * k)
+        all_counts = jax.lax.all_gather(top_counts, axis).reshape(n * k)
+    with op_scope("exchange__heavy_keys"):
+        eq = all_keys[:, None] == all_keys[None, :]
+        glob = jnp.sum(eq * all_counts[None, :].astype(jnp.int64), axis=1)
+        nk = n * k
+        first = ~jnp.any(
+            eq & (jnp.arange(nk)[None, :] < jnp.arange(nk)[:, None]), axis=1)
+        score = jnp.where((all_keys != _U64MAX) & first
+                          & (glob >= min_global_count), glob, -1)
+        sel_score, sel = jax.lax.top_k(score, k)
+        return jnp.where(sel_score > 0, jnp.take(all_keys, sel), _U64MAX)
+
+
+def _send_columns(page: Page, src: jnp.ndarray):
+    """The send buffer's columns: slot j carries row `src[j]`. One gather
+    per column (a scatter of 46 M lanes takes the v5e 1.0-1.4 s, a gather
+    a fraction of that), and a validity mask only for a column that has
+    nulls: which slots are occupied travels once, beside the columns."""
+    return [Column(jnp.take(c.values, src, mode="clip"),
+                   None if c.valid is None
+                   else jnp.take(c.valid, src, mode="clip"),
+                   c.type, c.dictionary) for c in page.columns]
 
 
 def _exchange_compact(cols, occ, n: int, bucket_capacity: int,
                       axis: str) -> Page:
     """The receive half of an all_to_all exchange: swap the per-destination
-    buckets over the mesh, mask validity by received occupancy, and compact
-    live rows to a dense prefix so downstream operators see a normal page."""
+    buckets over the mesh and move the live rows to a dense prefix so
+    downstream operators see a normal page. Every sender fills a bucket
+    from its front, so the live rows of the receive buffer are n dense
+    runs: row j of the output is found from the runs' lengths alone, and
+    each column is one gather (no sort, no scatter)."""
     def a2a(x):
         return jax.lax.all_to_all(
             x.reshape(n, bucket_capacity, *x.shape[1:]), axis,
             split_axis=0, concat_axis=0).reshape(n * bucket_capacity,
                                                  *x.shape[1:])
 
-    occ_recv = a2a(occ)
-    out_cols = []
-    for c in cols:
-        vals = a2a(c.values)
-        valid = a2a(c.valid) & occ_recv
-        out_cols.append(Column(vals, valid if c.valid is not None else None,
-                               c.type, c.dictionary))
-
-    perm = stable_argsort([~occ_recv])
-    num = jnp.sum(occ_recv).astype(jnp.int32)
-    out_cols = [Column(jnp.take(c.values, perm),
-                       None if c.valid is None else jnp.take(c.valid, perm),
-                       c.type, c.dictionary)
-                for c in out_cols]
-    return Page(tuple(out_cols), num)
+    with op_scope("exchange__all_to_all"):
+        occ_recv = a2a(occ)
+        received = [(a2a(c.values),
+                     None if c.valid is None else a2a(c.valid))
+                    for c in cols]
+    with op_scope("exchange__compact"):
+        counts = jnp.sum(occ_recv.reshape(n, bucket_capacity), axis=1,
+                         dtype=jnp.int32)
+        ends = jnp.cumsum(counts)
+        j = jnp.arange(n * bucket_capacity, dtype=jnp.int32)
+        # the run output row j lies in: how many runs end at or before it
+        peer = jnp.zeros_like(j)
+        for p in range(n - 1):
+            peer = peer + (j >= ends[p]).astype(jnp.int32)
+        src = peer * bucket_capacity + j - jnp.take(ends - counts, peer)
+        out_cols = [Column(jnp.take(vals, src, mode="clip"),
+                           None if valid is None
+                           else jnp.take(valid, src, mode="clip"),
+                           c.type, c.dictionary)
+                    for c, (vals, valid) in zip(cols, received)]
+        return Page(tuple(out_cols), ends[-1])
 
 
 def all_to_all_by_key(page: Page, key_channels: Sequence[int],
@@ -156,48 +188,24 @@ def all_to_all_by_key(page: Page, key_channels: Sequence[int],
     half replicates via all_to_all_replicate with the SAME heavy set).
     """
     n = jax.lax.psum(1, axis)
-    part = _partition_of(page, key_channels, n, heavy=heavy)
-
-    # stable sort rows by destination, then slot rows into per-destination
-    # fixed-capacity buckets: position within bucket = rank within partition
-    order = stable_argsort([part])
-    part_sorted = jnp.take(part, order)
-    idx = jnp.arange(page.capacity, dtype=jnp.int32)
-    # rank within run of equal destinations
-    start_of_run = jnp.searchsorted(part_sorted, jnp.arange(
-        n + 1, dtype=part_sorted.dtype))
-    rank = idx - jnp.take(start_of_run,
-                          part_sorted.astype(jnp.int32).clip(0, n))
-    counts = jnp.diff(start_of_run)  # rows per destination
-    overflow_local = jnp.sum(jnp.maximum(counts - bucket_capacity, 0))
-
-    live = (part_sorted < n) & (rank < bucket_capacity)
-    slot = part_sorted.astype(jnp.int32).clip(0, n - 1) * bucket_capacity + \
-        jnp.minimum(rank, bucket_capacity - 1)
-    # dead/overflow rows must not clobber occupied slots: send them
-    # out-of-bounds where scatter mode="drop" discards them
-    slot = jnp.where(live, slot, n * bucket_capacity)
-
-    send_rows = jnp.take(order, idx)  # row index per sorted position
-
-    def scatter_col(col: Column) -> Column:
-        vals = jnp.take(col.values, send_rows)
-        buf = jnp.zeros((n * bucket_capacity,), dtype=col.values.dtype)
-        buf = buf.at[slot].set(vals, mode="drop")
-        valid_buf = jnp.zeros((n * bucket_capacity,), dtype=jnp.bool_)
-        src_valid = live
-        if col.valid is not None:
-            src_valid = live & jnp.take(col.valid, send_rows)
-        valid_buf = valid_buf.at[slot].set(src_valid, mode="drop")
-        return Column(buf, valid_buf, col.type, col.dictionary)
-
-    # occupancy mask rides as an extra column so receivers know live rows
-    occ = jnp.zeros((n * bucket_capacity,), dtype=jnp.bool_)
-    occ = occ.at[slot].set(live, mode="drop")
-
-    cols = [scatter_col(c) for c in page.columns]
+    with op_scope("exchange__partition"):
+        part = _partition_of(page, key_channels, n, heavy=heavy)
+        # rows in destination order (dead rows last), each destination's
+        # rows a run: slot (d, r) of the send buffer takes the r-th row
+        # of run d, by one gather through the sort's permutation
+        order = stable_argsort([part])
+        counts = jnp.stack([jnp.sum(part == d, dtype=jnp.int32)
+                            for d in range(n)])
+        starts = jnp.cumsum(counts) - counts
+        overflow_local = jnp.sum(jnp.maximum(counts - bucket_capacity, 0))
+        slot = jnp.arange(n * bucket_capacity, dtype=jnp.int32)
+        dest, rank = slot // bucket_capacity, slot % bucket_capacity
+        occ = rank < jnp.take(counts, dest)
+        src = jnp.take(order, jnp.take(starts, dest) + rank, mode="clip")
+        cols = _send_columns(page, src)
     out = _exchange_compact(cols, occ, n, bucket_capacity, axis)
-    total_overflow = jax.lax.psum(overflow_local, axis)
+    with op_scope("exchange__psum"):
+        total_overflow = jax.lax.psum(overflow_local, axis)
     return out, total_overflow
 
 
@@ -212,41 +220,31 @@ def all_to_all_replicate(page: Page, key_channels: Sequence[int],
     Returns (page, global_overflow_count) with the same overflow-ladder
     contract as all_to_all_by_key."""
     n = jax.lax.psum(1, axis)
-    key, is_null = _key_u64(page, key_channels)
-    live = page.row_mask()
-    hpart = (_mix64(key) % jnp.uint64(n)).astype(jnp.int32)
-    hpart = jnp.where(is_null, 0, hpart)
-    hvy = _is_heavy(key, heavy) & ~is_null
-    total_slots = n * bucket_capacity
-    overflow_local = jnp.int32(0)
-    dests = []
-    for d in range(n):
-        m = live & ((hpart == d) | hvy)
-        rank = jnp.cumsum(m) - 1
-        cnt = jnp.sum(m)
-        overflow_local = overflow_local + jnp.maximum(
-            cnt - bucket_capacity, 0).astype(jnp.int32)
-        ok = m & (rank < bucket_capacity)
-        slot = jnp.where(ok, d * bucket_capacity + rank, total_slots)
-        dests.append((slot, ok))
-
-    def scatter_col(col: Column) -> Column:
-        buf = jnp.zeros((total_slots,), dtype=col.values.dtype)
-        vbuf = jnp.zeros((total_slots,), dtype=jnp.bool_)
-        for slot, ok in dests:
-            buf = buf.at[slot].set(col.values, mode="drop")
-            src_valid = ok
-            if col.valid is not None:
-                src_valid = ok & col.valid
-            vbuf = vbuf.at[slot].set(src_valid, mode="drop")
-        return Column(buf, vbuf, col.type, col.dictionary)
-
-    occ = jnp.zeros((total_slots,), dtype=jnp.bool_)
-    for slot, ok in dests:
-        occ = occ.at[slot].set(ok, mode="drop")
-    cols = [scatter_col(c) for c in page.columns]
+    with op_scope("exchange__partition"):
+        key, is_null = _key_u64(page, key_channels)
+        live = page.row_mask()
+        hpart = (_mix64(key) % jnp.uint64(n)).astype(jnp.int32)
+        hpart = jnp.where(is_null, 0, hpart)
+        hvy = _is_heavy(key, heavy) & ~is_null
+        total_slots = n * bucket_capacity
+        overflow_local = jnp.int32(0)
+        # the row each slot carries: one scatter of row numbers per
+        # destination, then every column is a gather through them
+        rows = jnp.arange(page.capacity, dtype=jnp.int32)
+        src = jnp.full((total_slots,), page.capacity, dtype=jnp.int32)
+        for d in range(n):
+            m = live & ((hpart == d) | hvy)
+            rank = jnp.cumsum(m, dtype=jnp.int32) - 1
+            overflow_local = overflow_local + jnp.maximum(
+                jnp.sum(m, dtype=jnp.int32) - bucket_capacity, 0)
+            ok = m & (rank < bucket_capacity)
+            src = src.at[jnp.where(ok, d * bucket_capacity + rank,
+                                   total_slots)].set(rows, mode="drop")
+        occ = src < page.capacity
+        cols = _send_columns(page, src)
     out = _exchange_compact(cols, occ, n, bucket_capacity, axis)
-    return out, jax.lax.psum(overflow_local, axis)
+    with op_scope("exchange__psum"):
+        return out, jax.lax.psum(overflow_local, axis)
 
 
 def broadcast_page(page: Page, axis: str = AXIS) -> Page:
@@ -262,25 +260,26 @@ def broadcast_page(page: Page, axis: str = AXIS) -> Page:
         g = jax.lax.all_gather(x, axis)  # (n, cap, ...)
         return g.reshape(n * x.shape[0], *x.shape[1:])
 
-    rows_per_shard = jax.lax.all_gather(my_rows, axis)  # (n,)
-    cap = page.capacity
-    idx = jnp.arange(n * cap, dtype=jnp.int32)
-    shard_of = idx // cap
-    within = idx % cap
-    live = within < jnp.take(rows_per_shard, shard_of)
-    cols = []
-    for c in page.columns:
-        vals = gather(c.values)
-        valid = None
-        if c.valid is not None:
-            valid = gather(c.valid) & live
-        cols.append(Column(vals, valid, c.type, c.dictionary))
-    # compact live rows to the front
-    perm = stable_argsort([~live])
-    cols = [Column(jnp.take(c.values, perm),
-                   None if c.valid is None else jnp.take(c.valid, perm),
-                   c.type, c.dictionary) for c in cols]
-    return Page(tuple(cols), jnp.sum(rows_per_shard).astype(jnp.int32))
+    with op_scope("exchange__broadcast"):
+        rows_per_shard = jax.lax.all_gather(my_rows, axis)  # (n,)
+        gathered = [(gather(c.values),
+                     None if c.valid is None else gather(c.valid))
+                    for c in page.columns]
+    with op_scope("exchange__compact"):
+        cap = page.capacity
+        idx = jnp.arange(n * cap, dtype=jnp.int32)
+        shard_of = idx // cap
+        within = idx % cap
+        live = within < jnp.take(rows_per_shard, shard_of)
+        # compact live rows to the front
+        perm = stable_argsort([~live])
+        cols = [Column(jnp.take(vals, perm),
+                       None if valid is None
+                       else jnp.take(valid & live, perm),
+                       c.type, c.dictionary)
+                for c, (vals, valid) in zip(page.columns, gathered)]
+        return Page(tuple(cols),
+                    jnp.sum(rows_per_shard).astype(jnp.int32))
 
 
 def gather_page(page: Page, axis: str = AXIS) -> Page:

@@ -19,7 +19,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from trino_tpu.page import Column, Page
+from trino_tpu.page import Column, Page, op_scope
 
 
 class QueryMesh:
@@ -65,26 +65,40 @@ class QueryMesh:
         return jax.tree_util.tree_map(stack, *pages)
 
     def shard_map(self, fn: Callable, *, in_specs=None, out_specs=None,
-                  check_rep: bool = False) -> Callable:
+                  check_rep: bool = False, replicated: int = 0) -> Callable:
         """Wrap fn as a per-shard program over the mesh (one Trino 'task'
         per device; collectives inside fn are the exchange data plane).
 
         Inputs stacked by shard_pages arrive as (1, ...) blocks per shard;
         fn sees them squeezed to per-worker shapes and its outputs are
         re-expanded so the global result keeps the sharded leading axis.
+        The first `replicated` arguments are the same on every shard (a
+        program's hoisted literals) and reach fn as they are.
         """
-        in_specs = in_specs if in_specs is not None else P(self.AXIS)
+        if in_specs is None:
+            in_specs = P(self.AXIS) if not replicated else None
         out_specs = out_specs if out_specs is not None else P(self.AXIS)
 
         def wrapped(*args):
-            squeezed = jax.tree_util.tree_map(
-                lambda x: jnp.squeeze(x, axis=0), args)
-            out = fn(*squeezed)
-            return jax.tree_util.tree_map(
-                lambda x: jnp.expand_dims(x, axis=0), out)
+            with op_scope("exchange__shard_view"):
+                squeezed = jax.tree_util.tree_map(
+                    lambda x: jnp.squeeze(x, axis=0), args[replicated:])
+            out = fn(*args[:replicated], *squeezed)
+            with op_scope("exchange__shard_view"):
+                return jax.tree_util.tree_map(
+                    lambda x: jnp.expand_dims(x, axis=0), out)
 
-        return shard_map(wrapped, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=check_rep)
+        if in_specs is not None:
+            return shard_map(wrapped, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=check_rep)
+
+        def program(*args):
+            # one spec per argument: its count is the call's
+            specs = (P(),) * replicated \
+                + (P(self.AXIS),) * (len(args) - replicated)
+            return shard_map(wrapped, mesh=self.mesh, in_specs=specs,
+                             out_specs=out_specs, check_vma=check_rep)(*args)
+        return program
 
     def unshard(self, tree):
         """Fetch a sharded tree to host as per-shard list (axis 0)."""

@@ -43,6 +43,10 @@ host->device staging:
 
 `table` is catalog.schema.table (or schema.table / table, resolved
 against the runner's session); `columns` defaults to every column.
+On a mesh runner (DistributedQueryRunner) the table becomes RESIDENT
+SHARDS instead: chip i reads its own splits and keeps them, and every
+mesh scan of those columns is handed the arrays
+(exec/table_cache.ShardedTable).
 """
 
 from __future__ import annotations
@@ -136,29 +140,47 @@ def preload_table(runner, table: str,
         handles = [by_name[c] for c in columns]
     else:
         handles = list(all_handles)
+    cache = runner._table_cache
+    gen = cache.generation()    # before reading: the promotion guard
+    tkey = (qname.catalog, qname.schema, qname.table)
+    names = [c.name for c in handles]
+    mesh = getattr(runner, "mesh", None)
+    if mesh is not None and mesh.n > 1 \
+            and bool(runner.session.get("mesh_execution")):
+        # a mesh runner keeps the table as resident shards: chip i reads
+        # its own splits and holds them, nothing passes through chip 0
+        from trino_tpu.exec.mesh_exec import admit_shards, stage_shards
+        page, _ = stage_shards(runner, conn, handle, handles)
+        _drop_scan_stats(conn)
+        resident = admit_shards(cache, tkey, names, page, gen=gen)
+        return {"table": str(qname), "columns": len(handles),
+                "rows": int(sum(jax.device_get(page.num_rows))),
+                "resident": bool(resident), "shards": mesh.n}
     stats = conn.metadata.get_table_statistics(handle)
     rows = int(stats.row_count or 0)
     cap = 1 << 16
     while cap < rows and cap < (1 << 22):
         cap *= 2
-    cache = runner._table_cache
-    gen = cache.generation()    # before reading: the promotion guard
     pages = []
     for split in conn.split_manager.get_splits(handle, target_splits=1):
         pages.extend(conn.page_source.pages(split, handles, cap))
-    take = getattr(conn, "take_scan_stats", None)
-    if take is not None:
-        take()      # drop the preload's thread-local scan counters
+    _drop_scan_stats(conn)
     counts = [int(c) for c in jax.device_get(
         [p.num_rows for p in pages])] if pages else []
-    tkey = (qname.catalog, qname.schema, qname.table)
     cache.configure(int(runner.session.get("table_cache_max_bytes")),
                     int(runner.session.get("table_cache_min_scans")))
-    cache.note_scan(tkey, [c.name for c in handles])
+    cache.note_scan(tkey, names)
     resident = cache.promote_from_pages(
         tkey, [(c.name, c) for c in handles], pages, counts, gen=gen)
     return {"table": str(qname), "columns": len(handles),
             "rows": int(sum(counts)), "resident": bool(resident)}
+
+
+def _drop_scan_stats(conn) -> None:
+    """The preload's thread-local scan counters are nobody's query's."""
+    take = getattr(conn, "take_scan_stats", None)
+    if take is not None:
+        take()
 
 
 def apply_warmup(runner, source: Union[str, dict, list]
