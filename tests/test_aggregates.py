@@ -305,3 +305,218 @@ def test_checksum(runner):
     c = runner.execute("SELECT checksum(n_nationkey) FROM nation "
                        "WHERE n_nationkey < 0").only_value()
     assert c is None        # ChecksumAggregationFunction: NULL on empty
+
+
+# ------------------------------------------------------------------
+# The direct GROUP BY's two reduce forms (PR 29): a tiny static slot table
+# reduces lane-wise under slot masks, a larger one scatters, and both must
+# give the rows the sort-based path gives — on every step.
+
+def _direct_page(selection):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from trino_tpu import types as T
+    from trino_tpu.page import Column, Dictionary, Page
+    cap, rows = 256, 200
+    rng = np.random.default_rng(29)
+    big = rng.integers(2**62, 2**63 - 1, cap)      # three of them wrap
+    page = Page((
+        Column(jnp.asarray(rng.integers(0, 3, cap).astype(np.int32)),
+               jnp.asarray(rng.random(cap) > 0.15), T.VARCHAR,
+               Dictionary(np.array(["A", "N", "R"], dtype=object))),
+        Column(jnp.asarray(rng.integers(0, 2, cap).astype(np.int32)), None,
+               T.VARCHAR, Dictionary(np.array(["F", "O"], dtype=object))),
+        Column(jnp.asarray(big), jnp.asarray(rng.random(cap) > 0.2),
+               T.BIGINT, None),
+        Column(jnp.asarray(rng.integers(-10**7, 10**7, cap)), None,
+               T.DecimalType(12, 2), None),
+        Column(jnp.asarray(rng.normal(0.0, 1e6, cap)),
+               jnp.asarray(rng.random(cap) > 0.1), T.DOUBLE, None),
+        Column(jnp.asarray(rng.random(cap) > 0.4),
+               jnp.asarray(rng.random(cap) > 0.1), T.BOOLEAN, None),
+    ), jnp.asarray(rows, dtype=jnp.int32))
+    if selection == "selection":
+        page = page.with_selection(jnp.asarray(rng.random(cap) > 0.3))
+    elif selection == "empty":
+        page = Page(page.columns, jnp.asarray(0, dtype=jnp.int32))
+    return page
+
+
+def _direct_specs():
+    from trino_tpu import types as T
+    from trino_tpu.ops import AggSpec
+    dec = T.DecimalType(12, 2)
+    return {
+        "int64_sum_wraps": [AggSpec("sum", 2, T.BIGINT),
+                            AggSpec("count", 2, T.BIGINT)],
+        "decimal_sum_avg_count": [AggSpec("sum", 3, dec),
+                                  AggSpec("avg", 3, dec),
+                                  AggSpec("count", None, None)],
+        "min_max": [AggSpec("min", 2, T.BIGINT), AggSpec("max", 3, dec),
+                    AggSpec("min", 4, T.DOUBLE), AggSpec("max", 4, T.DOUBLE)],
+        "bool_and_or_count_if": [AggSpec("bool_and", 5, T.BOOLEAN),
+                                 AggSpec("bool_or", 5, T.BOOLEAN),
+                                 AggSpec("count_if", 5, T.BOOLEAN)],
+        "checksum": [AggSpec("checksum", 2, T.BIGINT),
+                     AggSpec("checksum", 4, T.DOUBLE)],
+        "float64_sum_avg": [AggSpec("sum", 4, T.DOUBLE),
+                            AggSpec("avg", 4, T.DOUBLE),
+                            AggSpec("geometric_mean", 3, dec)],
+        "filter_where": [AggSpec("sum", 3, dec, 5),
+                         AggSpec("count", None, None, 5),
+                         AggSpec("min", 3, dec, 5)],
+    }
+
+
+def _agg_rows(page, nkeys):
+    """{key tuple: [values]} of a result page; NULL by the valid mask (the
+    direct path leaves the NULL slot's code in the value lane)."""
+    import numpy as np
+    assert page.selection is None
+    n = int(page.num_rows)
+    cols = []
+    for c in page.columns:
+        vals = np.asarray(c.values)[:n]
+        valid = np.ones(n, dtype=bool) if c.valid is None \
+            else np.asarray(c.valid)[:n]
+        cols.append([None if not ok else
+                     str(c.dictionary.values[v]) if c.dictionary is not None
+                     else v.item() for v, ok in zip(vals, valid)])
+    out = {}
+    for i in range(n):
+        key = tuple(c[i] for c in cols[:nkeys])
+        assert key not in out, f"group {key} twice"
+        out[key] = [c[i] for c in cols[nkeys:]]
+    return out
+
+
+def _state_channels(nkeys, specs):
+    from trino_tpu.ops.aggregate import get_aggregate
+    out, ch = [], nkeys
+    for spec in specs:
+        k = len(get_aggregate(spec.name, spec.input_type)
+                .state(spec.input_type))
+        out.append(list(range(ch, ch + k)))
+        ch += k
+    return out
+
+
+def _run_steps(page, keys, specs, steps):
+    """The aggregate as a plan runs it: SINGLE; PARTIAL then FINAL over
+    the partial page; or PARTIAL, INTERMEDIATE, FINAL."""
+    import jax
+
+    from trino_tpu.ops import Step, hash_aggregate
+    nkeys = len(keys)
+    if steps == "single":
+        return jax.jit(hash_aggregate(keys, specs, Step.SINGLE))(page)
+    chans = _state_channels(nkeys, specs)
+    merged = list(range(nkeys))
+    out = jax.jit(hash_aggregate(keys, specs, Step.PARTIAL))(page)
+    if steps == "partial_intermediate_final":
+        out = jax.jit(hash_aggregate(merged, specs, Step.INTERMEDIATE,
+                                     chans))(out)
+    return jax.jit(hash_aggregate(merged, specs, Step.FINAL, chans))(out)
+
+
+def _assert_same_rows(got, want, what):
+    assert set(got) == set(want), what
+    for key, vals in want.items():
+        for g, w in zip(got[key], vals):
+            if isinstance(w, float) and w == w:
+                assert g == pytest.approx(w, rel=1e-9), (what, key)
+            else:
+                assert g == w or (g != g and w != w), (what, key, g, w)
+
+
+_FORMS = {"masked": {"_MASKED_MAX_SLOT_STATES": 1 << 30},
+          "scatter": {"_MASKED_MAX_SLOT_STATES": 0},
+          "sorted": {"_DIRECT_MAX_GROUPS": 0}}
+
+
+# the merge steps never see the input page: a selection or an empty page
+# goes through SINGLE and PARTIAL -> FINAL
+@pytest.mark.parametrize("selection, steps", [
+    ("rows", "single"), ("rows", "partial_final"),
+    ("rows", "partial_intermediate_final"),
+    ("selection", "single"), ("selection", "partial_final"),
+    ("empty", "single"), ("empty", "partial_final")])
+@pytest.mark.parametrize("case", list(_direct_specs()))
+def test_direct_reduce_forms_and_the_sorted_path_give_the_same_rows(
+        monkeypatch, case, selection, steps):
+    from trino_tpu.ops import aggregate
+    from trino_tpu.page import trace_notes
+    specs = _direct_specs()[case]
+    page = _direct_page(selection)
+    keys = [0, 1]           # NULL flags take the last slot of key 0's space
+    rows, notes = {}, {}
+    for form, consts in _FORMS.items():
+        with monkeypatch.context() as m:
+            for name, value in consts.items():
+                m.setattr(aggregate, name, value)
+            with trace_notes() as said:
+                rows[form] = _agg_rows(_run_steps(page, keys, specs, steps),
+                                       len(keys))
+            notes[form] = set(said)
+    assert notes == {"masked": {"direct_reduce_masked"},
+                     "scatter": {"direct_reduce_scattered"},
+                     "sorted": set()}
+    if selection == "empty":
+        assert rows["masked"] == {}
+    elif selection == "rows":
+        assert (None, "F") in rows["masked"]        # the NULL-key group
+    ints_exact = case not in ("float64_sum_avg", "min_max")
+    if ints_exact:
+        # int64, decimal, count, checksum: bit for bit, wrapped or not
+        assert rows["masked"] == rows["scatter"] == rows["sorted"]
+    _assert_same_rows(rows["masked"], rows["scatter"], "masked vs scatter")
+    _assert_same_rows(rows["masked"], rows["sorted"], "masked vs sorted")
+
+
+def test_int64_sums_wrap_the_same_in_every_form():
+    """The wrap is real: the page's bigints sum past 2**63."""
+    import numpy as np
+    page = _direct_page("rows")
+    col = page.column(2)
+    live = np.arange(256) < 200
+    total = sum(int(v) for v, ok, keep in zip(
+        np.asarray(col.values), np.asarray(col.valid), live) if ok and keep)
+    assert total >= 2**63
+
+
+@pytest.mark.parametrize("past, masked", [(0, True), (1, False)])
+def test_slot_count_at_the_crossover_and_one_past_it(monkeypatch, past,
+                                                     masked):
+    """Four sums are eight state columns: with the crossover's slots the
+    masked form runs, with one slot more the scatter, as the module's own
+    constant says, and both give the sorted path's rows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from trino_tpu import types as T
+    from trino_tpu.ops import AggSpec, Step, aggregate, hash_aggregate
+    from trino_tpu.page import Column, Dictionary, Page, trace_notes
+    slots = aggregate._MASKED_MAX_SLOT_STATES // 8 + past
+    assert slots <= aggregate._DIRECT_MAX_GROUPS
+    nvalues = slots - 1             # the NULL slot is the last
+    rng = np.random.default_rng(slots)
+    cap = 4096
+    pool = Dictionary(np.array([f"v{i:04d}" for i in range(nvalues)],
+                               dtype=object))
+    page = Page((
+        Column(jnp.asarray(rng.integers(0, nvalues, cap).astype(np.int32)),
+               None, T.VARCHAR, pool),) + tuple(
+        Column(jnp.asarray(rng.integers(-99, 99, cap)), None, T.BIGINT,
+               None) for _ in range(4)), jnp.asarray(4000, dtype=jnp.int32))
+    specs = [AggSpec("sum", ch, T.BIGINT) for ch in (1, 2, 3, 4)]
+    with trace_notes() as said:
+        got = _agg_rows(jax.jit(hash_aggregate([0], specs, Step.SINGLE))(
+            page), 1)
+    assert said == {"direct_reduce_masked" if masked
+                    else "direct_reduce_scattered"}
+    monkeypatch.setattr(aggregate, "_DIRECT_MAX_GROUPS", 0)
+    want = _agg_rows(jax.jit(hash_aggregate([0], specs, Step.SINGLE))(
+        page), 1)
+    assert got == want and len(got) > 1000

@@ -265,6 +265,25 @@ CASES = {
     "select_then_filter_sorted": (
         [_select(), _below(55), _at_least(5)], (K,),
         [_spec("min", D), _spec("max", D), _spec("count", X)], 200),
+    # the direct path's masked reduce (PR 29) reads the selection through
+    # its slot masks: q1's eight aggregates and fifteen states, min/max,
+    # FILTER (WHERE) masks, a short and a full page
+    "direct_q1_shape_eight_aggregates": (
+        [_below(70), _project()], (FLAG,),
+        [_spec("sum", X), _spec("sum", D), _spec("sum", X2),
+         _spec("avg", X), _spec("avg", D), _spec("avg", X2),
+         _spec("count", X), _spec("count")], 200),
+    "direct_min_max_under_two_filters": (
+        [_below(75), _at_least(20)], (FLAG,),
+        [_spec("min", X), _spec("max", X), _spec("min", D),
+         _spec("max", D), _spec("count")], 200),
+    "direct_where_aggregates_short_page": (
+        [_below(80), _project()], (FLAG,),
+        [_spec("sum", X2, M), _spec("min", X2, M), _spec("max", X, M),
+         _spec("count", None, M)], 37),
+    "direct_full_page": (
+        [_below(40)], (FLAG,),
+        [_spec("sum", X), _spec("avg", X), _spec("count")], CAP),
 }
 
 
@@ -318,6 +337,10 @@ def test_deferred_chain_aggregates_the_rows_the_compacting_chain_does(name):
         text = jit_cache._CACHE[chain_key][0].lower(page, groups).as_text(
             debug_info=True)
         assert "compact_gather" not in text and "compact_slots" not in text
+        # a dictionary key's four slots reduce under slot masks (PR 29)
+        assert ("aggregate__direct_masked_reduce" in text) \
+            == (key_channels == (FLAG,))
+        assert "aggregate__direct_segment_reduce" not in text
         deferred = final(deferred_partial)
         # the same steps with no tail: a plain chain, whose filters compact
         compact_page = compose_chain(steps)(page)
@@ -521,3 +544,83 @@ def test_served_queries_count_their_compactions(served, shape):
     else:
         assert stats["compactions_deferred"] >= 1
         assert stats["compactions_run"] == 0
+
+
+def _scatter_operands(text):
+    """[[dims of each operand]] of every scatter in a StableHLO module."""
+    import re
+    found = re.findall(r'"stablehlo\.scatter"\(.*?\) : \((.*?)\) ->', text,
+                       flags=re.S)
+    return [[tuple(int(d) for d in re.findall(r"(\d+)x", t))
+             for t in re.findall(r"tensor<([^>]*)>", sig)] for sig in found]
+
+
+def test_q1s_chain_reduces_its_slot_table_under_masks(served):
+    """q1's aggregating chain at `tiny`, lowered again from the signature
+    it was dispatched with: the direct GROUP BY runs under the masked
+    scope and no scatter touches an operand as long as the page — what is
+    left are `compact()`'s, over the twelve slots."""
+    import chip_smoke
+    served(chip_smoke.Q1)
+    def dispatched_with(entry):
+        sig = next(iter(entry[2]))      # the AOT executables' signatures
+        return sig[0].unflatten([
+            leaf() if isinstance(leaf, type)    # a Python scalar operand
+            else jax.ShapeDtypeStruct(leaf[1], np.dtype(leaf[0]))
+            for leaf in sig[1:]])
+    # this file's own cases made chains of the same name, 256 lanes wide
+    fn, args = next(
+        (e[0], dispatched_with(e)) for k, e in jit_cache._CACHE.items()
+        if jit_cache.program_name(k)
+        == "aggregate__chain_filter_project_agg_partial" and e[2]
+        and len(k[-1][1]) == 2      # q6's has no key, q1's two
+        and dispatched_with(e)[0].capacity >= 4096)
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert "aggregate__agg_partial/aggregate__direct_masked_reduce" in text
+    assert "aggregate__direct_segment_reduce" not in text
+    scatters = _scatter_operands(text)
+    assert scatters, "compact()'s scatters over the slots stay"
+    assert all(max(dims, default=0) <= 12
+               for op in scatters for dims in op), scatters
+
+
+@pytest.mark.parametrize("shape", ["q6", "q1", "q3"])
+def test_served_queries_count_their_direct_reduces(served, shape):
+    """`direct_reduces_masked` counts one per dispatch of q1's aggregating
+    chain (the chain has a filter, so that is `compactions_deferred`);
+    q6's GROUP BY is global and q3's sorted: neither counts."""
+    import chip_smoke
+    sql = {"q6": chip_smoke.Q6.format(date="1994-01-01", disc="0.06",
+                                      qty=24),
+           "q1": chip_smoke.Q1, "q3": chip_smoke.Q3}[shape]
+    _, stats = served(sql)
+    assert stats["direct_reduces_scattered"] == 0
+    assert stats["direct_reduces_masked"] == (
+        stats["compactions_deferred"] if shape == "q1" else 0)
+
+
+@pytest.mark.parametrize("limit, form, where", [
+    (24, "masked", "l_quantity < 30"), (23, "scattered", "30 > l_quantity")])
+def test_the_crossover_decides_the_form_and_the_counter_says_which(
+        monkeypatch, limit, form, where):
+    """Twelve slots x two states sit at the crossover or one past it: the
+    same rows either way, counted under the form that ran. (Literals are
+    operands, so each case and the sorted run spell the predicate their
+    own way: a program is traced once per shape.)"""
+    from trino_tpu.exec import LocalQueryRunner
+    from trino_tpu.ops import aggregate
+    monkeypatch.setattr(aggregate, "_MASKED_MAX_SLOT_STATES", limit)
+    sql = ("SELECT l_returnflag, l_linestatus, sum(l_quantity) FROM lineitem"
+           " WHERE {} GROUP BY 1, 2 ORDER BY 1, 2")
+    runner = LocalQueryRunner.tpch("tiny")
+    got = runner.execute(sql.format(where)).rows
+    stats = runner.last_query_stats
+    other = {"masked": "scattered", "scattered": "masked"}[form]
+    assert stats[f"direct_reduces_{form}"] == stats["compactions_deferred"] \
+        >= 1
+    assert stats[f"direct_reduces_{other}"] == 0
+    monkeypatch.setattr(aggregate, "_DIRECT_MAX_GROUPS", 0)
+    assert got == runner.execute(
+        sql.format("NOT (l_quantity >= 30)")).rows
+    assert runner.last_query_stats[f"direct_reduces_{form}"] == 0
+    assert len(got) == 4

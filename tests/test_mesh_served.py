@@ -124,6 +124,24 @@ def test_served_mesh_answers_as_local_and_reference(mesh, local, wanted, i):
     assert "mesh_stage" in {name for name, _, _ in stats["spans"]}
 
 
+@pytest.mark.parametrize("where", ["mesh", "local"])
+def test_q1_reduces_its_twelve_slots_under_masks_on_both_runners(
+        mesh, local, where):
+    """PR 29: q1's direct GROUP BY takes the masked form (12 slots x 15
+    states), counted once per dispatch of the program that holds it — the
+    one mesh program, or each of the local runner's chain dispatches — and
+    nothing of q1 scatters over the page; q3's GROUP BY is sorted."""
+    served = {"mesh": mesh, "local": local}[where]
+    stats = {shape: served.run(shape, params)[1]
+             for shape, params in REQUESTS[:2]}
+    assert stats["q1"]["direct_reduces_scattered"] == 0
+    assert stats["q1"]["direct_reduces_masked"] == (
+        stats["q1"]["mesh_program_rounds"] if where == "mesh"
+        else stats["q1"]["compactions_deferred"]) >= 1
+    assert stats["q3"]["direct_reduces_masked"] == 0
+    assert stats["q3"]["direct_reduces_scattered"] == 0
+
+
 def test_the_window_draws_new_parameters_and_compiles_nothing(mesh):
     """DELTA and DATE differ from request to request, SEGMENT is the
     run's: after one cycle every request dispatches warm executables."""

@@ -113,6 +113,36 @@ def test_deferred_chain_into_partial_aggregate_moves_no_row(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < (64 << 20)
 
 
+def test_q1_partial_aggregate_reduces_under_slot_masks(one_chip):
+    """q1's partial GROUP BY over one scan page (PR 29): twelve slots x
+    fifteen states reduce lane-wise under slot masks. The scatter form
+    stacked the states into a [lanes, 15] operand the chip tiles to 128
+    columns, 1 GB of temporaries a page; the masked form materialises
+    nothing as long as the page."""
+    from trino_tpu.ops import AggSpec, Step, hash_aggregate
+    from trino_tpu.page import Dictionary
+    import numpy as np
+    page = _page(one_chip, SCAN_WIDTH, (T.VARCHAR, T.VARCHAR, D12_2, D12_2,
+                                        D12_2, D12_2, D12_2))
+    pools = (Dictionary(np.array(["A", "N", "R"], dtype=object)),
+             Dictionary(np.array(["F", "O"], dtype=object)))
+    keys = tuple(Column(jax.ShapeDtypeStruct((SCAN_WIDTH,), jnp.int32,
+                                             sharding=one_chip),
+                        None, T.VARCHAR, pool) for pool in pools)
+    page = Page(keys + page.columns[2:], page.num_rows)
+    specs = [AggSpec("sum", 2, D12_2), AggSpec("sum", 3, D12_2),
+             AggSpec("sum", 4, D12_2), AggSpec("sum", 5, D12_2),
+             AggSpec("avg", 2, D12_2), AggSpec("avg", 3, D12_2),
+             AggSpec("avg", 6, D12_2), AggSpec("count", None, None)]
+    compiled = _compile(hash_aggregate([0, 1], specs, Step.PARTIAL), page,
+                        limit_s=60)
+    assert compiled.memory_analysis().temp_size_in_bytes < (64 << 20)
+    # what scatters is `compact()`, over the twelve slots
+    for line in compiled.as_text().splitlines():
+        if " scatter(" in line:
+            assert str(SCAN_WIDTH) not in line.split(" scatter(")[1], line
+
+
 def test_q3_join_build_sort(one_chip):
     """The sort-bearing build kernel of q3's joins: 64-bit keys ordered by
     passes of one 32-bit sort (ops/radix.py)."""

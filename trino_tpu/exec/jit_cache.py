@@ -52,10 +52,11 @@ from typing import Any, Callable, Dict, Hashable, Optional, Tuple
 import jax
 import numpy as np
 
-from trino_tpu.page import family_context
+from trino_tpu.page import family_context, trace_notes
 
 # key -> [jitted kernel, last-seen flattened param signature or None,
-#         {input signature -> AOT compiled executable} (profiled path)]
+#         {input signature -> AOT compiled executable} (profiled path),
+#         {input signature -> what the program's trace noted} (`named`)]
 _CACHE: "collections.OrderedDict[Hashable, list]" = \
     collections.OrderedDict()
 # concurrent queries (the server's executor pool) share this cache; the
@@ -173,18 +174,38 @@ def program_name(key: Hashable) -> str:
     return f"{family}__{'_'.join(clean)}"
 
 
+def _aval_signature(args) -> Tuple:
+    """treedef + per-leaf (dtype, shape): what a trace depends on, the
+    same for the tracers a program is traced with and for the arrays it
+    is dispatched with (`profiler.tree_signature` adds the sharding, which
+    a trace does not see)."""
+    leaves, treedef = jax.tree_util.tree_flatten(args)
+    return (treedef,) + tuple(
+        (jax.numpy.result_type(leaf).str, np.shape(leaf))
+        for leaf in leaves)
+
+
 def named(fn: Callable, key: Hashable) -> Callable:
     """`fn` under `program_name(key)`: jax.jit names the module, and the
     root of every op's scope path, after the function it is given. A
     wrapper rather than a rename, so a builder may return a shared
-    function. Traced once per signature; not on the dispatch path."""
+    function. Traced once per signature; not on the dispatch path.
+
+    `program.noted` keeps, per input signature, what the traced code said
+    through `page.note_trace`: static facts of the program that
+    `profiled_kernel` counts on the query's collector at each dispatch."""
     name = program_name(key)
     family = name.partition("__")[0]
+    noted: Dict[Tuple, frozenset] = {}
 
     def program(*args):
-        with family_context(family):
-            return fn(*args)
+        with family_context(family), trace_notes() as notes:
+            out = fn(*args)
+        if notes:
+            noted[_aval_signature(args)] = frozenset(notes)
+        return out
     program.__name__ = program.__qualname__ = name
+    program.noted = noted
     return program
 
 
@@ -197,11 +218,12 @@ def _lookup(key: Hashable, build: Callable[[], Callable],
     with _LOCK:
         entry = _CACHE.get(key)
         if entry is None:
-            fn = jax.jit(named(build(), key))
+            program = named(build(), key)
+            fn = jax.jit(program)
             while len(_CACHE) >= _MAX_KERNELS:
                 _CACHE.popitem(last=False)
                 _STATS["evictions"] += 1
-            entry = _CACHE[key] = [fn, sig, {}]
+            entry = _CACHE[key] = [fn, sig, {}, program.noted]
             _STATS["misses"] += 1
             miss = True
         else:
@@ -305,13 +327,7 @@ def profiled_kernel(key: Hashable, build: Callable[[], Callable],
     hidden inside jax.jit's first call. Same key space, same hit/miss/
     param-hit counters as cached_kernel — a key warmed by one path is
     warm for the other."""
-    entry = _lookup(key, build, params)
-    fn = entry[0]
-    if len(entry) < 3:          # entry created by an older layout
-        with _LOCK:
-            while len(entry) < 3:
-                entry.append({})
-    aot: Dict[Any, Any] = entry[2]
+    fn, _, aot, noted = _lookup(key, build, params)
     fenced = _fencing_observer()
     from trino_tpu.obs import profiler
 
@@ -335,6 +351,17 @@ def profiled_kernel(key: Hashable, build: Callable[[], Callable],
                 compiled = _aot_compile(key, fn, args, arg_sig, aot)
         except Exception:
             return _fallback(*args)
+        notes = noted.get(arg_sig)
+        if notes is None:
+            # first dispatch of this signature: look its trace's notes up
+            # by what a trace sees, and keep them under the signature the
+            # dispatch already has in hand
+            notes = noted[arg_sig] = noted.get(_aval_signature(args),
+                                               frozenset())
+        if notes:
+            count = getattr(get_observer(), "count_program_notes", None)
+            if count is not None:
+                count(notes)
         try:
             if fenced is None:
                 return compiled(*args)

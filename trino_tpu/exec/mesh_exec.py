@@ -98,9 +98,11 @@ _MAX_LADDER_ROUNDS = 10
 
 # lanes a partial aggregate with small state (global, or keyed by
 # dictionary codes: ops/aggregate's direct path) takes at a time inside a
-# mesh program — the local scan's page. Its scatter-adds stack every state
-# into one [lanes, states] operand, which the TPU tiles to 128 columns: a
-# whole 46 M-lane SF30 shard at once would ask for 23.6 GB
+# mesh program — the local scan's page. The direct path's scatter form
+# stacks every state into one [lanes, states] operand, which the TPU tiles
+# to 128 columns: a whole 46 M-lane SF30 shard at once asked for 23.6 GB.
+# (q1 takes the masked form since PR 29 and stacks nothing; whether the
+# chunks are still needed is a question for a later PR)
 _CHUNK_LANES = 1 << 20
 
 

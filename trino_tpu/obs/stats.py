@@ -162,6 +162,12 @@ class QueryStatsCollector:
         # `run` one whose filters compact the page
         self.compactions_deferred = 0
         self.compactions_run = 0
+        # dispatches of a chain or mesh program (jit_cache.
+        # profiled_kernel) whose direct GROUP BY (ops/aggregate.
+        # _direct_aggregate) reduced its slot table lane-wise under slot
+        # masks / with scatter-adds; a program holding both counts in both
+        self.direct_reduces_masked = 0
+        self.direct_reduces_scattered = 0
         # lake connector pruning (connector/lake/): whole data files
         # and row groups skipped via partition values + min/max zone
         # maps evaluated against the scan's TupleDomain (static
@@ -357,6 +363,12 @@ class QueryStatsCollector:
         else:
             self.compactions_run += 1
 
+    def count_program_notes(self, notes) -> None:
+        """One dispatch of a program whose trace noted `notes`
+        (page.note_trace)."""
+        self.direct_reduces_masked += "direct_reduce_masked" in notes
+        self.direct_reduces_scattered += "direct_reduce_scattered" in notes
+
     def add_pruned(self, files: int = 0, row_groups: int = 0) -> None:
         self.files_pruned += int(files)
         self.row_groups_pruned += int(row_groups)
@@ -477,6 +489,8 @@ class QueryStatsCollector:
             "scan_host_staging_bytes": self.scan_host_staging_bytes,
             "compactions_deferred": self.compactions_deferred,
             "compactions_run": self.compactions_run,
+            "direct_reduces_masked": self.direct_reduces_masked,
+            "direct_reduces_scattered": self.direct_reduces_scattered,
             "files_pruned": self.files_pruned,
             "row_groups_pruned": self.row_groups_pruned,
             "streamed_chunks": self.streamed_chunks,

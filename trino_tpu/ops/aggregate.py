@@ -21,6 +21,7 @@ is NULL, COUNT is 0.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
@@ -28,7 +29,7 @@ import jax.numpy as jnp
 
 from trino_tpu import types as T
 from trino_tpu.ops.radix import sort_by_keys
-from trino_tpu.page import Column, Page, op_scope
+from trino_tpu.page import Column, Page, note_trace, op_scope
 
 
 class Step:
@@ -572,9 +573,14 @@ def hash_aggregate(
         sizes = None if has_collect else \
             _direct_key_sizes(page, key_channels, aggs)
         if sizes is not None:
-            with op_scope("aggregate__direct_segment_reduce"):
+            masked = _direct_reduces_masked(sizes, aggs, resolved)
+            note_trace("direct_reduce_masked" if masked
+                       else "direct_reduce_scattered")
+            with op_scope("aggregate__direct_masked_reduce" if masked
+                          else "aggregate__direct_segment_reduce"):
                 return _direct_aggregate(page, key_channels, aggs, resolved,
-                                         step, partial_state_channels, sizes)
+                                         step, partial_state_channels, sizes,
+                                         masked)
         with op_scope("aggregate__group_sort"):
             sorted_keys, perm_sorted = sort_by_keys(
                 _sort_key_arrays(page, key_channels))
@@ -653,6 +659,14 @@ def _final_state_contribs(page: Page, states, chans, live_mask, gather=None):
 
 
 _DIRECT_MAX_GROUPS = 4096
+# slots x state columns up to which the direct path reduces lane-wise under
+# slot masks; above it the scatter runs. On a v5e at 1 048 576 lanes of
+# int64 the masked form costs 0.4 ms + 2.6-3.6 us a slot and state, the
+# scatter 72-91 ms whatever the table: 15 360 (1 024 slots x 15 states) is
+# 39 ms against 90, 61 440 is 159 against 88 (PERF.md section 6, PR 29). The
+# power of two under the last measured win, on the safe side of a crossover
+# that a line through those points puts near 34 000
+_MASKED_MAX_SLOT_STATES = 16384
 
 
 def _direct_key_sizes(page: Page, key_channels, aggs):
@@ -677,18 +691,48 @@ def _direct_key_sizes(page: Page, key_channels, aggs):
     return tuple(sizes)
 
 
+def _direct_reduces_masked(sizes, aggs, resolved) -> bool:
+    """Which form the direct path's reduce takes, from what a trace can
+    see alone: the slot count and the number of state columns."""
+    states = sum(len(fn.state(spec.input_type))
+                 for spec, fn in zip(aggs, resolved))
+    return math.prod(sizes) * states <= _MASKED_MAX_SLOT_STATES
+
+
+def _masked_reduce(contrib, hit, reducer):
+    """Per-slot reduction of `contrib` [n] under the slot masks `hit`
+    [nseg, n]: a compare-select-reduce along the lanes that XLA fuses into
+    one pass per state, the [nseg, n] operand never materialised. The lane
+    axis stays minor — a minor axis of nseg slots or of k stacked states
+    would be padded to 128 lanes."""
+    if reducer == "sum":
+        return jnp.sum(jnp.where(hit, contrib[None, :],
+                                 jnp.zeros((), contrib.dtype)),
+                       axis=1, dtype=contrib.dtype)
+    ident = _ident_for(contrib.dtype, reducer == "min")
+    red = jnp.min if reducer == "min" else jnp.max
+    return red(jnp.where(hit, contrib[None, :], ident), axis=1,
+               initial=ident)
+
+
 def _direct_aggregate(page: Page, key_channels, aggs, resolved, step,
-                      partial_state_channels, sizes) -> Page:
+                      partial_state_channels, sizes, masked) -> Page:
     """Group-by over a small static key space WITHOUT sorting: segment ids
-    are computed arithmetically from dictionary codes, states reduce with
-    jax.ops.segment_*, and present groups compact to a tiny output page.
-    Replaces an O(n log n) multi-operand lax.sort with O(n) scatters — the
-    difference between ~10s and ~1s for q1-shaped aggregations on TPU."""
+    are computed arithmetically from dictionary codes, each state reduces
+    into a static table of `nseg` slots, and present groups compact to a
+    tiny output page.
+
+    The reduce takes one of two forms (`_direct_reduces_masked`). A small
+    table reduces each state lane-wise under one mask per slot
+    (`_masked_reduce`): nseg x states select-and-add passes over the page,
+    1.4 ms for q1's 12 slots x 15 states over a 1 048 576-lane page on a
+    v5e. A larger one scatters with jax.ops.segment_*: its cost does not
+    grow with nseg, but every lane collides and the batched [n, k] operand
+    is tiled to 128 columns, 1 GB of temporaries — 90 ms for the same q1
+    page, 72 ms for a single state."""
     n = page.capacity
     live = page.row_mask()
-    nseg = 1
-    for s in sizes:
-        nseg *= s
+    nseg = math.prod(sizes)
     # combined code; NULL key -> last slot of its key's code space
     combined = jnp.zeros(n, dtype=jnp.int32)
     stride = nseg
@@ -704,9 +748,14 @@ def _direct_aggregate(page: Page, key_channels, aggs, resolved, step,
     seg = jnp.where(live, combined, nseg)       # dead rows drop out
     n_out = nseg + 1
 
-    cnt_live = jax.ops.segment_sum(live.astype(jnp.int32), seg,
-                                   num_segments=n_out)[:nseg]
-    present = cnt_live > 0
+    if masked:
+        # [nseg, n], lanes minor; a dead row's slot nseg matches none
+        hit = seg[None, :] == jnp.arange(nseg, dtype=jnp.int32)[:, None]
+        present = jnp.any(hit, axis=1)
+    else:
+        cnt_live = jax.ops.segment_sum(live.astype(jnp.int32), seg,
+                                       num_segments=n_out)[:nseg]
+        present = cnt_live > 0
     num_groups = jnp.sum(present).astype(jnp.int32)
     pos = jnp.cumsum(present.astype(jnp.int32)) - 1
     scatter_idx = jnp.where(present, pos, nseg)
@@ -730,10 +779,7 @@ def _direct_aggregate(page: Page, key_channels, aggs, resolved, step,
                        ~is_null if col.valid is not None else None)
         out_cols.append(Column(v, m, col.type, col.dictionary))
 
-    # two-phase accumulation: first collect EVERY state's contribution
-    # array, then reduce all "sum" states of one dtype in ONE batched
-    # segment_sum ([n, k] data) — per-call scatter cost on TPU (~350ms at
-    # 4M rows) dominates, so q1's 19 sum states must share one scatter
+    # first collect EVERY state's contribution array, then reduce
     pending: List[dict] = []
     for ai, (spec, fn) in enumerate(zip(aggs, resolved)):
         states = fn.state(spec.input_type)
@@ -751,6 +797,36 @@ def _direct_aggregate(page: Page, key_channels, aggs, resolved, step,
                                           sc.reducer))
         pending.append(entry)
 
+    if masked:
+        # nothing stacked: identical contributions (the non-null counts of
+        # columns with no valid mask are all `live`) are XLA's CSE to fold
+        def reduced(contrib, reducer):
+            return _masked_reduce(contrib, hit, reducer)
+    else:
+        reduced = _scatter_reducer(pending, seg, nseg)
+
+    for (spec, fn), entry in zip(zip(aggs, resolved), pending):
+        state_arrays = [reduced(c, r) for c, r in entry["contribs"]]
+        states = entry["states"]
+        dictionary = entry["dictionary"]
+        if step in (Step.PARTIAL, Step.INTERMEDIATE):
+            for sc, arr in zip(states, state_arrays):
+                d = dictionary if T.is_string(sc.type) else None
+                v, _ = compact(arr.astype(sc.type.dtype))
+                out_cols.append(Column(v, None, sc.type, d))
+        else:
+            values, valid = fn.final(state_arrays, None)
+            v, m = compact(values, valid)
+            out_cols.append(_agg_out_column(fn, spec, v, m, dictionary))
+    return Page(tuple(out_cols), num_groups)
+
+
+def _scatter_reducer(pending, seg, nseg):
+    """The scatter form of the direct reduce: all "sum" states of one
+    dtype in ONE batched segment_sum ([n, k] data) — a scatter's per-call
+    cost dominates, so the sum states share one — and a segment_min/max
+    per other state."""
+    n_out = nseg + 1
     sum_batches: dict = {}       # dtype -> list of contrib arrays
     sum_slots: dict = {}         # id(contrib) -> (dtype, index)
     for entry in pending:
@@ -769,21 +845,7 @@ def _direct_aggregate(page: Page, key_channels, aggs, resolved, step,
             dt, j = sum_slots[id(contrib)]
             return sum_results[dt][:, j]
         return _segment_reduce(contrib, seg, n_out, reducer)[:nseg]
-
-    for (spec, fn), entry in zip(zip(aggs, resolved), pending):
-        state_arrays = [reduced(c, r) for c, r in entry["contribs"]]
-        states = entry["states"]
-        dictionary = entry["dictionary"]
-        if step in (Step.PARTIAL, Step.INTERMEDIATE):
-            for sc, arr in zip(states, state_arrays):
-                d = dictionary if T.is_string(sc.type) else None
-                v, _ = compact(arr.astype(sc.type.dtype))
-                out_cols.append(Column(v, None, sc.type, d))
-        else:
-            values, valid = fn.final(state_arrays, None)
-            v, m = compact(values, valid)
-            out_cols.append(_agg_out_column(fn, spec, v, m, dictionary))
-    return Page(tuple(out_cols), num_groups)
+    return reduced
 
 
 def _boundary_scan(key_ops, n) -> jnp.ndarray:

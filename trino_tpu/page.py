@@ -43,8 +43,8 @@ _dict_ids = itertools.count()
 # (Page.filter's compaction, ops/radix.py's passes) takes the family of
 # the operator being traced, so a join's sorts count as join time.
 
-# per thread: .family and .defer while a program is being traced;
-# .host_staged, below
+# per thread: .family, .defer and .trace_notes while a program is being
+# traced; .host_staged, below
 _THREAD = threading.local()
 
 
@@ -65,6 +65,30 @@ def op_scope(name: str):
     `<family>__<tag>` and sets the family for the kernels it calls."""
     with family_context(name.partition("__")[0]), jax.named_scope(name):
         yield
+
+
+def note_trace(fact: str) -> None:
+    """Trace-time only: a static fact about the program being traced that
+    its dispatcher wants to count (which form its direct aggregate took).
+    Heard by `trace_notes`; said to nobody outside one."""
+    notes = getattr(_THREAD, "trace_notes", None)
+    if notes is not None:
+        notes.add(fact)
+
+
+@contextlib.contextmanager
+def trace_notes():
+    """Collect what the code traced inside says through `note_trace`
+    (exec/jit_cache.named enters it around every program's trace). A
+    program traced inside another tells the outer one too."""
+    prev = getattr(_THREAD, "trace_notes", None)
+    notes = _THREAD.trace_notes = set()
+    try:
+        yield notes
+    finally:
+        _THREAD.trace_notes = prev
+        if prev is not None:
+            prev |= notes
 
 
 def host_staged_bytes() -> int:
