@@ -5,7 +5,7 @@ the compacting chain aggregates.
 
 Each case runs the chain three ways and compares row for row:
   deferred  — `compose_chain(steps, agg-partial)` then `agg-final`, the way
-              `_agg_over_stream` builds it;
+              `_exec_AggregationNode` builds it;
   compacted — the same steps as a tail-less chain (its filters compact),
               then the same partial and final aggregates;
   reference — plain Python over the NumPy columns.

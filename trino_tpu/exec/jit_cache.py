@@ -130,11 +130,10 @@ NAME_GRAMMAR = re.compile(
 _FAMILY_PREFIXES = (
     ("scan_filter", ("filter", "project", "select", "dconcat", "unnest",
                      "assign-unique-id", "tpch-generate")),
-    ("aggregate", ("agg", "mxu-agg")),
+    ("aggregate", ("agg",)),
     ("join", ("join", "uprobe", "uattach", "semijoin", "markjoin",
               "fulljoin", "cross-attach", "dense-table", "dfbounds",
-              "dfrange", "probe-compact", "spill-prep", "spill-probe",
-              "mxu-table", "mxu-ndistinct", "mxu-key-bounds")),
+              "dfrange", "probe-compact", "spill-prep", "spill-probe")),
     ("sort", ("sort", "topn", "merge-sort")),
     ("window", ("window",)),
     ("exchange", ("exchange", "mesh-prog", "mesh-sconcat")),

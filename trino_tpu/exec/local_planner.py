@@ -42,7 +42,7 @@ from trino_tpu.ops.join import unique_inner_probe
 from trino_tpu.page import (Column, Page, concat_pages,
                             count_host_staging, defer_compaction, op_scope)
 from trino_tpu.planner.nodes import (
-    AggregationNode, AggStep, DistinctLimitNode, EnforceSingleRowNode,
+    AggregationNode, DistinctLimitNode, EnforceSingleRowNode,
     ExchangeNode, FilterNode, GroupIdNode, JoinClause, JoinKind, JoinNode,
     LimitNode, OffsetNode, OutputNode, PlanNode, ProjectNode, SemiJoinNode,
     SortNode, Symbol, TableScanNode, TopNNode, UnionNode, ValuesNode,
@@ -1032,14 +1032,7 @@ class LocalExecutionPlanner:
                              device=self.mem_device)
 
     def _exec_AggregationNode(self, node: AggregationNode) -> PageStream:
-        fused = self._mxu_agg_join(node)
-        if fused is not None:
-            return fused
         src = self.execute(node.source)
-        return self._agg_over_stream(node, src)
-
-    def _agg_over_stream(self, node: AggregationNode,
-                         src: PageStream) -> PageStream:
         lay, typ = _layout(src.symbols)
         key_channels = [lay[s.name] for s in node.group_by]
         specs = []
@@ -1443,312 +1436,6 @@ class LocalExecutionPlanner:
         if state is not None:
             yield final_op(state)
 
-    # aggregate functions the matmul path can factor through per-key
-    # build vectors (arXiv 2206.04995's M = A·Bᵀ multiplicities)
-    _MXU_FUSABLE_AGGS = ("count", "sum")
-
-    def _mxu_agg_join(self, node: AggregationNode
-                      ) -> Optional[PageStream]:
-        """The many-to-many AGGREGATING join on the matrix unit (the
-        TPC-DS q64/q72 shapes — ops/join_mxu.py): when a SINGLE
-        aggregation consumes an INNER single-clause equi-join directly
-        (optionally through a pure column-select projection), every
-        group key is probe-side, and every aggregate is a factorable
-        COUNT/SUM over one side's column, the join's match
-        multiplicities feed SUM/COUNT directly WITHOUT materializing
-        the cross product: the build side scatters to per-key
-        [pair count, Σw, #valid-w] vectors, each probe row matmul-looks
-        up its key's vector, and one standard SINGLE aggregation over
-        the derived (probe-sized!) rows yields the exact result.
-        DISTINCT-projections over a join (group-by, no aggregates) ride
-        the same path. Returns None when the plan shape is ineligible;
-        runtime ineligibility (sparse or over-span keys, over-memory
-        build) falls back to the gather join + the normal aggregation
-        over its output."""
-        if getattr(self, "n_shards", None) is not None:
-            return None     # dispatch-loop shards keep the gather path
-        if node.step != AggStep.SINGLE:
-            return None
-        if not bool(self.session.get("mxu_join_enabled")):
-            return None
-        source = node.source
-        proj = None
-        if isinstance(source, ProjectNode) and all(
-                isinstance(e, SymbolRef) for _, e in source.assignments):
-            proj = source
-            source = source.source
-        if not isinstance(source, JoinNode):
-            return None
-        join = source
-        if join.kind != JoinKind.INNER or len(join.criteria) != 1 \
-                or join.filter is not None:
-            return None
-        pmap = {s.name: i for i, s in enumerate(join.left.outputs)}
-        bmap = {s.name: i for i, s in enumerate(join.right.outputs)}
-        rename = None if proj is None else \
-            {s.name: e.name for s, e in proj.assignments}
-
-        def resolve(name):
-            if rename is not None:
-                name = rename.get(name)
-                if name is None:
-                    return None
-            if name in pmap:
-                return ("p", pmap[name])
-            if name in bmap:
-                return ("b", bmap[name])
-            return None
-
-        group_chs = []
-        for s in node.group_by:
-            r = resolve(s.name)
-            if r is None or r[0] != "p":
-                return None     # group keys must be probe-side
-            group_chs.append(r[1])
-        ptypes = [s.type for s in join.left.outputs]
-        btypes = [s.type for s in join.right.outputs]
-
-        def num_kind(t):
-            try:
-                dt = np.dtype(T.to_numpy_dtype(t))
-            except Exception:
-                return None
-            if dt.kind in ("i", "u"):
-                return "i"
-            if dt.kind == "f" and dt.itemsize == 8:
-                return "f"
-            return None
-
-        vec_specs = [("cnt",)]
-
-        def vec(spec):
-            if spec not in vec_specs:
-                vec_specs.append(spec)
-            return vec_specs.index(spec)
-
-        derive: List[tuple] = []
-        helpers: List[int] = []
-        out_types: List = []
-        for out_sym, call in node.aggregations:
-            if call.distinct or call.filter is not None \
-                    or call.name not in self._MXU_FUSABLE_AGGS \
-                    or len(call.args) > 1:
-                return None
-            if not call.args:
-                if call.name != "count":
-                    return None
-                derive.append(("pairs",))
-                out_types.append(out_sym.type)
-                continue
-            arg = call.args[0]
-            if not isinstance(arg, SymbolRef):
-                return None
-            r = resolve(arg.name)
-            if r is None:
-                return None
-            side, ch = r
-            in_t = ptypes[ch] if side == "p" else btypes[ch]
-            if call.name == "count":
-                if side == "p":
-                    derive.append(("cntp", ch))
-                else:
-                    derive.append(("cntb", vec(("validcnt", ch))))
-            else:
-                kind = num_kind(in_t)
-                if kind is None or num_kind(out_sym.type) != kind:
-                    return None
-                if side == "p":
-                    derive.append(("sump", ch, kind))
-                else:
-                    v = vec(("sum", ch, kind))
-                    hvec = vec(("validcnt", ch))
-                    if hvec not in helpers:
-                        helpers.append(hvec)
-                    derive.append(("sumb", v, kind,
-                                   helpers.index(hvec)))
-            out_types.append(out_sym.type)
-        clause = join.criteria[0]
-        if clause.left.name not in pmap or clause.right.name not in bmap:
-            return None
-        return PageStream(
-            self._mxu_agg_join_run(
-                node, join, proj, tuple(group_chs), tuple(derive),
-                tuple(helpers), tuple(vec_specs), tuple(out_types),
-                pmap[clause.left.name], bmap[clause.right.name]),
-            node.outputs)
-
-    def _mxu_agg_join_run(self, node, join, proj, group_chs, derive,
-                          helpers, vec_specs, out_types, pkey_ch,
-                          bkey_ch) -> Iterator[Page]:
-        """Drive the fused aggregating join (see _mxu_agg_join): scatter
-        the build vectors, matmul-lookup per probe page, feed ONE
-        standard SINGLE aggregation over the derived rows, and restore
-        output types/nullability in a post kernel. Keeps the gather
-        path's robustness contracts: over-memory builds hand off to the
-        streaming partitioned join, sparse/over-span keys fall back to
-        the gather join + the normal aggregation over its output."""
-        from trino_tpu.exec.jit_cache import profiled_kernel
-        from trino_tpu.ops import join_mxu
-        probe_stream = self.execute(join.left)
-        build_stream = self.execute(join.right)
-        build_iter = None
-        if bool(self.session.get("spill_enabled")) \
-                and int(self.session.get("spill_partition_count")) > 1:
-            build_page, build_iter = \
-                self._collect_build_resilient(build_stream)
-        else:
-            build_page = self._collect(build_stream)
-        handed_off = False
-
-        def gather_fallback(bp, bit=None):
-            # the one gather-fallback shape, shared by every runtime
-            # decline: _join_with_build OWNS the collected page / the
-            # streaming iterator, then the normal aggregation runs over
-            # its (re-projected) output
-            jstream = self._join_with_build(
-                join, probe_stream, join.right.outputs, bp, bit)
-            if proj is not None:
-                jstream = self._select_stream(jstream, proj)
-            return self._agg_over_stream(node, jstream).iter_pages()
-
-        try:
-            if build_iter is not None:
-                # mid-collect memory overflow: the streaming partitioned
-                # hybrid join owns the pages; aggregate its output
-                handed_off = True
-                yield from gather_fallback(None, build_iter)
-                return
-            if build_page is None:
-                if not node.group_by:
-                    yield self._empty_global_agg(node, node.aggregations)
-                return
-            from trino_tpu.exec.memory import page_bytes
-            if bool(self.session.get("spill_enabled")) and \
-                    page_bytes(build_page) > int(self.session.get(
-                        "join_spill_threshold_bytes")):
-                # over-threshold build: keep the gather path's memory
-                # discipline (spilled keys-on-device / partitioned
-                # hybrid) instead of pinning the whole side for the
-                # scatter — the fused matmul is not worth an OOM ladder
-                # regression
-                handed_off = True
-                yield from gather_fallback(build_page)
-                return
-            bounds_op = cached_kernel(
-                ("mxu-key-bounds", bkey_ch),
-                lambda: join_mxu.key_bounds(bkey_ch))
-            kmin_d, kmax_d = bounds_op(build_page)
-            kmin, kmax = (int(x) for x in jax.device_get(
-                [kmin_d, kmax_d]))
-            span = kmax - kmin + 1 if kmax >= kmin else 0
-            size = 1 << max((span - 1).bit_length(), 7) if span else 0
-            table = None
-            if 0 < span <= int(self.session.get("mxu_join_max_slots")) \
-                    and build_page.capacity < join_mxu.MAX_EXACT_ROWS:
-                table_op = profiled_kernel(
-                    ("mxu-agg-table", bkey_ch, vec_specs, size),
-                    lambda: join_mxu.scatter_agg_table(
-                        size, vec_specs, bkey_ch))
-                table, ndistinct_d, mag_ok_d = table_op(build_page,
-                                                        kmin_d)
-                ndistinct, mag_ok = jax.device_get(
-                    [ndistinct_d, mag_ok_d])
-                if not bool(mag_ok) or int(ndistinct) < span * float(
-                        self.session.get("mxu_join_density_threshold")):
-                    table = None
-            if table is None:
-                # sparse / over-span / magnitude-unsafe build keys:
-                # the gather join + the normal aggregation
-                handed_off = True
-                yield from gather_fallback(build_page)
-                return
-            col = self.collector
-            if col is not None:
-                col.mxu_join()
-            self._adaptive_span("join-mxu-agg", slots=size,
-                                aggs=len(derive))
-            # dynamic filtering, exactly like the gather join: the
-            # build-side key range prefilters probe pages AND pushes
-            # into connector file/row-group pruning (the scan's lazy
-            # generator has not been pulled yet — build-before-probe)
-            prefilter = None
-            if self.session.get("enable_dynamic_filtering") and \
-                    not T.is_string(join.left.outputs[pkey_ch].type):
-                from trino_tpu.ops.join import (build_key_bounds,
-                                                range_prefilter)
-                b_op = cached_kernel(
-                    ("dfbounds", bkey_ch),
-                    lambda: build_key_bounds([bkey_ch]))
-                pf_op = cached_kernel(
-                    ("dfrange", pkey_ch),
-                    lambda: range_prefilter(pkey_ch))
-                prefilter = (pf_op, b_op(build_page))
-                target = self._dyn_scan_target(
-                    join.left, join.left.outputs[pkey_ch].name)
-                if target is not None:
-                    scan_node, col_name, col_type = target
-                    lo_h, hi_h = jax.device_get(prefilter[1])
-                    self.register_dynamic_domain(
-                        scan_node, col_name, col_type,
-                        lo_h.item(), hi_h.item())
-            aligned = self._align_join_dictionaries(
-                probe_stream, build_page, [pkey_ch], [bkey_ch])
-            lookup_op = profiled_kernel(
-                ("mxu-agg-lookup", pkey_ch, group_chs, derive, helpers,
-                 size),
-                lambda: join_mxu.agg_join_lookup(pkey_ch, group_chs,
-                                                 derive, helpers))
-            ncols = len(vec_specs)
-            derived: List[Page] = []
-            for page in self._coalesce_stream(
-                    aligned, prefilter=prefilter).iter_pages():
-                self._checkpoint()
-                if col is not None:
-                    col.add_mxu_flops(join_mxu.lookup_flops(
-                        page.capacity, size, ncols))
-                derived.append(lookup_op(page, table, kmin_d))
-            merged, _rows = self.merge_counted_rows(derived)
-            if merged is None:
-                if not node.group_by:
-                    yield self._empty_global_agg(node, node.aggregations)
-                return
-            nk = len(group_chs)
-
-            def dtyp(d):
-                if d[0] in ("pairs", "cntp", "cntb"):
-                    return T.BIGINT
-                return T.BIGINT if d[2] == "i" else T.DOUBLE
-
-            spec_types = tuple(dtyp(d) for d in derive) \
-                + (T.BIGINT,) * len(helpers)
-            agg_specs = tuple(AggSpec("sum", nk + i, t)
-                              for i, t in enumerate(spec_types))
-            single_op = profiled_kernel(
-                ("mxu-agg-single", nk, agg_specs),
-                lambda: hash_aggregate(list(range(nk)), list(agg_specs),
-                                       Step.SINGLE))
-            post_op = cached_kernel(
-                ("mxu-agg-post", nk, derive, len(helpers), out_types),
-                lambda: join_mxu.agg_join_post(nk, derive, len(helpers),
-                                               out_types))
-            yield post_op(single_op(merged))
-        finally:
-            if not handed_off:
-                self._free_collected(build_page)
-
-    def _select_stream(self, stream: PageStream, proj) -> PageStream:
-        """Apply a pure column-select/rename ProjectNode over a stream
-        (the unwrap _mxu_agg_join performed, re-applied on its gather
-        fallback so the aggregation sees its declared layout)."""
-        lay = {s.name: i for i, s in enumerate(stream.symbols)}
-        order = tuple(lay[e.name] for _, e in proj.assignments)
-        return PageStream(
-            stream.pages, tuple(s for s, _ in proj.assignments),
-            stream.pending + ((("select", order),
-                               lambda: lambda p, g, o=order: Page(
-                                   tuple(p.columns[i] for i in o),
-                                   p.num_rows), ()),))
-
     def _empty_global_agg(self, node: AggregationNode, specs) -> Page:
         cols = []
         for (sym, call), spec in zip(node.aggregations, specs):
@@ -1946,20 +1633,8 @@ class LocalExecutionPlanner:
                 self._collect_build_resilient(build_stream)
         else:
             build_page = self._collect(build_stream)
-        return self._join_with_build(node, probe_stream,
-                                     build_stream.symbols, build_page,
-                                     build_iter)
-
-    def _join_with_build(self, node: JoinNode, probe_stream: PageStream,
-                         build_symbols, build_page,
-                         build_iter=None) -> PageStream:
-        """INNER/LEFT equi-join over an already-collected build side
-        (the body of _exec_JoinNode, split out so the MXU aggregating
-        join's runtime fallback can hand its collected build to the
-        gather path without re-executing the build subtree). Owns
-        freeing the collected page."""
         probe_lay, probe_typ = _layout(probe_stream.symbols)
-        build_lay, _ = _layout(build_symbols)
+        build_lay, _ = _layout(build_stream.symbols)
         probe_keys = [probe_lay[c.left.name] for c in node.criteria]
         build_keys = [build_lay[c.right.name] for c in node.criteria]
         # PruneJoinColumns: node.outputs may be a subset of left+right
@@ -1969,7 +1644,7 @@ class LocalExecutionPlanner:
         out_names = {s.name for s in out_symbols}
         probe_keep = tuple(i for i, s in enumerate(probe_stream.symbols)
                            if s.name in out_names)
-        build_keep = tuple(i for i, s in enumerate(build_symbols)
+        build_keep = tuple(i for i, s in enumerate(build_stream.symbols)
                            if s.name in out_names)
         join_kind = JoinType.INNER if node.kind == JoinKind.INNER \
             else JoinType.LEFT
@@ -2044,7 +1719,7 @@ class LocalExecutionPlanner:
                 node_id = ("join",
                            tuple(c.left.name for c in node.criteria),
                            tuple(c.right.name for c in node.criteria))
-                if any(T.is_string(build_symbols[bk].type)
+                if any(T.is_string(build_stream.symbols[bk].type)
                        for bk in build_keys):
                     # string keys: stage the build host-side and rebase
                     # every page onto ONE union pool first — the
@@ -2110,9 +1785,7 @@ class LocalExecutionPlanner:
                 return
             try:
                 prepared, max_run, mode = self._prepare_probe(
-                    build_keys, bp,
-                    mxu_ok=(join_kind == JoinType.INNER
-                            and len(build_keys) == 1))
+                    build_keys, bp)
                 prefilter = None
                 if join_kind == JoinType.INNER and \
                         self.session.get("enable_dynamic_filtering") and \
@@ -2144,12 +1817,8 @@ class LocalExecutionPlanner:
                         self.register_dynamic_domain(
                             scan_node, col_name, col_type,
                             lo_h.item(), hi_h.item())
-                coalesced = self._coalesce_stream(aligned,
-                                                  prefilter=prefilter)
-                probe_in = coalesced
-                if mode == "mxu":
-                    probe_in = self._mxu_stream(
-                        coalesced, prepared[10].shape[0])
+                probe_in = self._coalesce_stream(aligned,
+                                                 prefilter=prefilter)
                 if join_kind == JoinType.INNER and max_run <= 1:
                     # unique build side (primary/dimension key): the
                     # no-expansion probe + live-size build attach
@@ -2231,12 +1900,11 @@ class LocalExecutionPlanner:
             # partitioning disabled (spill_partition_count <= 1):
             # legacy in-memory expansion join
             try:
-                prepared, _max_run, dense = self._prepare_with_dense(
+                prepared, _max_run, mode = self._prepare_probe(
                     build_keys, build_page)
                 yield from _run_with_overflow(
                     self._coalesce_stream(probe_stream), prepared,
-                    lambda cap: fallback_join_op(
-                        cap, "dense" if dense else "search"),
+                    lambda cap: fallback_join_op(cap, mode),
                     self.page_capacity)
             finally:
                 self._free_collected(build_page)
@@ -2628,13 +2296,13 @@ class LocalExecutionPlanner:
         self.memory.reserve(held, "join-part-build",
                             device=self.mem_device)
         try:
-            prepared, _max_run, dense = self._prepare_with_dense(
+            prepared, _max_run, mode = self._prepare_probe(
                 list(bkeys), bpage)
             yield from _run_with_overflow(
                 pstore.drain_partition_chunks(
                     p, pstore.chunk_rows_for(p, threshold)),
                 prepared,
-                lambda cap: join_op(cap, "dense" if dense else "search"),
+                lambda cap: join_op(cap, mode),
                 self.page_capacity)
             pstore.drop(p)
         finally:
@@ -2660,13 +2328,12 @@ class LocalExecutionPlanner:
             self.memory.reserve(held, "join-chunk-build",
                                 device=self.mem_device)
             try:
-                prepared, _mr, dense = self._prepare_with_dense(
+                prepared, _mr, mode = self._prepare_probe(
                     list(bkeys), bchunk)
                 yield from _run_with_overflow(
                     pstore.iter_partition_chunks(p, pchunk_rows),
                     prepared,
-                    lambda cap, m=("dense" if dense else "search"):
-                        join_op(cap, m),
+                    lambda cap, m=mode: join_op(cap, m),
                     self.page_capacity)
             finally:
                 self.memory.free(held, "join-chunk-build",
@@ -2854,110 +2521,30 @@ class LocalExecutionPlanner:
     # slot cap bounds HBM (64M slots = 256MB int32 for in-memory builds)
     _DENSE_MAX_SLOTS = 1 << 26
 
-    def _prepare_with_dense(self, build_keys, build_page):
-        """prepare_build + the dense-key decision: fetch (max_run, kmin,
-        kmax) in ONE round trip; when the live-key span is small (dense
-        surrogate keys — every TPC-H/DS join), append a direct-address
-        lookup table so probe kernels cost one gather instead of a
-        sort-engine searchsorted pass per buffer.
+    def _prepare_probe(self, build_keys, build_page):
+        """prepare_build + the ONE probe-lookup decision of every join,
+        in memory or spilled: fetch (max_run, kmin, kmax) in one round
+        trip; when the live-key span is small (dense surrogate keys —
+        every TPC-H/DS join), append a direct-address lookup table so
+        probe kernels cost one gather ('dense') instead of a sort-engine
+        searchsorted pass per buffer ('search').
 
-        Returns (prepared [+ table], max_run, dense)."""
+        Returns (prepared [+ table], max_run, lookup)."""
+        from trino_tpu.ops.join import build_dense_table
         prepared = self._prepare_build(build_keys, build_page)
         max_run, kmin, kmax = (int(x) for x in jax.device_get(
             [prepared[7], prepared[8], prepared[9]]))
         span = kmax - kmin + 1 if kmax >= kmin else 0
-        with_table = self._dense_table_for(prepared, build_page, span)
-        if with_table is not None:
-            return with_table, max_run, True
-        return prepared, max_run, False
-
-    def _dense_table_for(self, prepared, build_page, span: int):
-        """The ONE dense-gather decision + table build (shared by the
-        spill paths' _prepare_with_dense and the router's
-        _prepare_probe — the limit formula and kernel key must never
-        diverge between them): prepared + direct-address table when the
-        live-key span qualifies, else None."""
-        from trino_tpu.ops.join import build_dense_table
         limit = min(max(4 * build_page.capacity, 1 << 20),
                     self._DENSE_MAX_SLOTS)
         if not 0 < span <= limit:
-            return None
+            return prepared, max_run, "search"
         size = _next_pow2(span)
         table_op = cached_kernel(
             ("dense-table", size),
             lambda: build_dense_table(size))
-        return prepared + (table_op(prepared[1], prepared[3],
-                                    prepared[8]),)
-
-    def _prepare_probe(self, build_keys, build_page, mxu_ok: bool = True):
-        """prepare_build + the per-join PROBE-STRATEGY router (the MXU
-        path's decision point — ROADMAP item 1): fetch (max_run, kmin,
-        kmax, distinct live keys) in ONE round trip, then pick
-
-          'mxu'    — mxu_join_enabled, the live-key span fits
-                     mxu_join_max_slots, the OBSERVED density (distinct
-                     live build keys / span) clears
-                     mxu_join_density_threshold, and the build stays
-                     under the f32-exactness bound: probes run as
-                     blocked indicator matmuls on the matrix unit
-                     against a per-key [count, pos] table
-                     (ops/join_mxu.py);
-          'dense'  — small span, mxu declined: direct-address gather;
-          'search' — everything else: sort-engine searchsorted.
-
-        The CBO stamp (JoinNode.join_strategy, EXPLAIN's `join
-        strategy:` line) is the plan-time candidate; this router holds
-        the runtime truth — `mxu_joins` counts what actually ran.
-        Returns (prepared [+ table], max_run, mode)."""
-        from trino_tpu.ops import join_mxu
-        prepared = self._prepare_build(build_keys, build_page)
-        mxu_on = mxu_ok and bool(self.session.get("mxu_join_enabled"))
-        fetch = [prepared[7], prepared[8], prepared[9]]
-        if mxu_on:
-            nd_op = cached_kernel(("mxu-ndistinct",),
-                                  lambda: join_mxu.distinct_live_keys)
-            fetch.append(nd_op(prepared[1], prepared[3]))
-        got = [int(x) for x in jax.device_get(fetch)]
-        max_run, kmin, kmax = got[:3]
-        ndistinct = got[3] if mxu_on else 0
-        span = kmax - kmin + 1 if kmax >= kmin else 0
-        if mxu_on \
-                and 0 < span <= int(self.session.get(
-                    "mxu_join_max_slots")) \
-                and build_page.capacity < join_mxu.MAX_EXACT_ROWS \
-                and ndistinct >= span * float(self.session.get(
-                    "mxu_join_density_threshold")):
-            size = 1 << max((span - 1).bit_length(), 7)
-            table_op = cached_kernel(
-                ("mxu-table", size),
-                lambda: join_mxu.build_count_pos_table(size))
-            table = table_op(prepared[1], prepared[3], prepared[8])
-            return prepared + (table,), max_run, "mxu"
-        with_table = self._dense_table_for(prepared, build_page, span)
-        if with_table is not None:
-            return with_table, max_run, "dense"
-        return prepared, max_run, "search"
-
-    def _mxu_stream(self, stream, slots: int, ncols: int = 2):
-        """Wrap a probe stream in matrix-unit accounting: one mxu_joins
-        count per routed join, and each probe dispatch's cost-model MACs
-        on mxu_flops — the counters the bench and PR 12's attribution
-        read."""
-        from trino_tpu.ops.join_mxu import lookup_flops
-        col = self.collector
-        if col is not None:
-            col.mxu_join()
-        self._adaptive_span("join-mxu-route", slots=slots)
-        it = stream.iter_pages() if hasattr(stream, "iter_pages") \
-            else iter(stream)
-
-        def gen():
-            for page in it:
-                if col is not None:
-                    col.add_mxu_flops(
-                        lookup_flops(page.capacity, slots, ncols))
-                yield page
-        return gen()
+        table = table_op(prepared[1], prepared[3], prepared[8])
+        return prepared + (table,), max_run, "dense"
 
     def _exec_right_join(self, node: JoinNode) -> PageStream:
         flipped = JoinNode(
@@ -3163,13 +2750,9 @@ class LocalExecutionPlanner:
                 bp = self._null_build_page(semi.filtering_source.outputs)
             try:
                 prepared, _max_run, mode = self._prepare_probe(
-                    build_keys, bp, mxu_ok=len(build_keys) == 1)
-                probe_in = self._coalesce_stream(probe_stream)
-                if mode == "mxu":
-                    probe_in = self._mxu_stream(probe_in,
-                                                prepared[10].shape[0])
+                    build_keys, bp)
                 yield from _run_with_overflow(
-                    probe_in, prepared,
+                    self._coalesce_stream(probe_stream), prepared,
                     lambda cap: semi_op(cap, mode), self.page_capacity)
             finally:
                 self._free_collected(build_page)
@@ -3211,13 +2794,9 @@ class LocalExecutionPlanner:
                 return
             try:
                 prepared, _max_run, mode = self._prepare_probe(
-                    build_keys, bp, mxu_ok=len(build_keys) == 1)
-                probe_in = self._coalesce_stream(probe_stream)
-                if mode == "mxu":
-                    probe_in = self._mxu_stream(probe_in,
-                                                prepared[10].shape[0])
+                    build_keys, bp)
                 yield from _run_with_overflow(
-                    probe_in, prepared,
+                    self._coalesce_stream(probe_stream), prepared,
                     lambda cap: mark_op(cap, mode), self.page_capacity)
             finally:
                 self._free_collected(build_page)

@@ -223,19 +223,6 @@ class MeshLowerer:
         self.fragment_sites: Dict[int, int] = {}
         self._skew = bool(session.get("skewed_exchange_enabled"))
         self._skew_k = max(1, int(session.get("skew_heavy_key_limit")))
-        # MXU join bodies (ops/join_mxu.py): when the optimizer stamped
-        # a join `mxu-matmul`, its in-program probe computes BOTH the
-        # blocked-indicator-matmul and the searchsorted lookup and
-        # selects per shard with a branchless `where` on the traced key
-        # span (a lax.cond formulation miscompiled under shard_map
-        # fusion — do not reintroduce it). The matmul body composes
-        # with the fused all_to_all exchanges (spans are per-shard
-        # values a co-partitioned exchange just changed); each mxu site
-        # reports whether any shard actually took the matmul result
-        # through its aux, feeding the query's mxu_joins/mxu_flops.
-        self._mxu_slots = int(session.get("mxu_join_max_slots")) \
-            if bool(session.get("mxu_join_enabled")) else None
-        self.mxu_sites: List[int] = []   # join sites with an mxu body
 
     # ------------------------------------------------------------ plumbing
 
@@ -664,13 +651,8 @@ class MeshLowerer:
             psite = bsite = None
 
         site = self._site("join")
-        mxu = self._mxu_slots \
-            if (getattr(node, "join_strategy", None) == "mxu-matmul"
-                and len(node.criteria) == 1) else None
-        if mxu is not None:
-            self.mxu_sites.append(site)
         self._key("join", probe_keys, build_keys, join_kind, post_pred,
-                  probe_keep, build_keep, mxu)
+                  probe_keep, build_keep)
 
         def fn(env: _Env) -> Page:
             if psite is None:
@@ -687,7 +669,7 @@ class MeshLowerer:
                                                 build_keys)
                 op = hash_join(list(probe_keys), list(build_keys),
                                join_kind, output_capacity=cap,
-                               prepared=False, mxu_slots=mxu,
+                               prepared=False,
                                probe_out=probe_keep, build_out=build_keep)
                 out, total = op(probe, build)
                 # per-shard: the host takes the max over shards
@@ -699,9 +681,6 @@ class MeshLowerer:
             if post_filter is not None:
                 with op_scope("join__post_filter"):
                     out = out.filter(post_filter(out, env.params))
-            if mxu is not None:
-                with op_scope("join__mxu_aux"):
-                    aux.update(_mxu_aux(probe, build, build_keys[0], mxu))
             env.aux[site] = aux
             return out
         return fn
@@ -776,13 +755,7 @@ class MeshLowerer:
         probe_keys = tuple(probe_lay[s.name] for s in node.source_keys)
         build_keys = tuple(build_lay[s.name] for s in node.filtering_keys)
         site = self._site("join")
-        mxu = self._mxu_slots \
-            if (getattr(node, "join_strategy", None) == "mxu-matmul"
-                and len(node.source_keys) == 1) else None
-        if mxu is not None:
-            self.mxu_sites.append(site)
-        self._key("semijoin", probe_keys, build_keys, node.null_aware,
-                  mxu)
+        self._key("semijoin", probe_keys, build_keys, node.null_aware)
 
         def fn(env: _Env) -> Page:
             probe = probe_fn(env)
@@ -793,15 +766,12 @@ class MeshLowerer:
                                                 build_keys)
                 op = hash_join(list(probe_keys), list(build_keys),
                                JoinType.MARK, output_capacity=cap,
-                               prepared=False, mxu_slots=mxu,
+                               prepared=False,
                                null_aware=node.null_aware)
                 out, total = op(probe, build)
                 # per-shard total, as in the join lowering above
                 aux = {"total": total.astype(jnp.int64),
                        "cap": jnp.int32(cap)}
-            if mxu is not None:
-                with op_scope("join__mxu_aux"):
-                    aux.update(_mxu_aux(probe, build, build_keys[0], mxu))
             env.aux[site] = aux
             return out
         return fn
@@ -816,25 +786,6 @@ class MeshLowerer:
             col = Column(idx, None, T.BIGINT, None)
             return Page(tuple(page.columns) + (col,), page.num_rows)
         return self._scoped("scan_filter__assign_unique_id", src, op)
-
-
-def _mxu_aux(probe: Page, build: Page, build_key: int,
-             mxu_slots: int) -> dict:
-    """Per-shard truth for the mxu counters: whether this shard's key
-    span fits the matmul table (the same predicate hash_join's inline
-    body selects on, incl. the static f32-exactness gate) and the MAC
-    count its lookup issued — psum'd so every shard carries the global
-    counts."""
-    from trino_tpu.ops.join_mxu import key_bounds, lookup_flops
-    if build.capacity >= (1 << 24):     # hash_join's static mxu gate
-        zero = jnp.int64(0)
-        return {"mxu": jnp.int32(0), "mxu_flops": zero}
-    kmin, kmax = key_bounds(build_key)(build)
-    ok = (kmax >= kmin) & ((kmax - kmin) < jnp.uint64(mxu_slots))
-    flops = jnp.where(ok, lookup_flops(probe.capacity, mxu_slots, 2),
-                      0).astype(jnp.int64)
-    return {"mxu": jax.lax.psum(ok.astype(jnp.int32), AXIS),
-            "mxu_flops": jax.lax.psum(flops, AXIS)}
 
 
 def _align_key_dictionaries(probe: Page, build: Page, probe_keys,
@@ -1137,19 +1088,6 @@ def _co_schedule(runner, frag: PlanFragment, remote: RemoteSourceNode,
 
     if col is not None:
         col.mesh_devices = mesh.n
-        # count the joins whose matmul result was ACTUALLY selected on
-        # at least one shard (the per-site psum'd span-ok aux), with the
-        # summed cost-model MACs those shards issued — 'what ran', not
-        # 'what lowered'
-        mxu_ran = 0
-        for site in lowerer.mxu_sites:
-            d = host_aux.get(site, {})
-            if int(np.max(np.asarray(d.get("mxu", 0)))) > 0:
-                mxu_ran += 1
-                col.add_mxu_flops(
-                    int(np.max(np.asarray(d.get("mxu_flops", 0)))))
-        if mxu_ran:
-            col.mxu_join(mxu_ran)
         for site in lowerer.exchange_sites:
             d = host_aux.get(site, {})
             col.add_exchange(

@@ -52,8 +52,7 @@ DEFAULT_MAX_ENTRIES = 256
 # fragment the key
 PLAN_PROPERTIES = ("join_distribution_type", "join_reordering_strategy",
                    "join_broadcast_threshold_rows", "distributed_sort",
-                   "partitioned_agg_min_ndv", "mxu_join_enabled",
-                   "mxu_join_density_threshold", "mxu_join_max_slots")
+                   "partitioned_agg_min_ndv")
 
 TableKey = Tuple[str, str, str]   # (catalog, schema, table)
 

@@ -176,8 +176,8 @@ class LocalQueryRunner:
         # $TRINO_TPU_TRACE_DIR); None defers to the session's
         # trace_export property with a tempdir default
         self._trace_dir: Optional[str] = None
-        # cumulative counters across the runner's lifetime (bench.py
-        # emits these alongside timings) + the last query's snapshot
+        # cumulative counters across the runner's lifetime + the last
+        # query's snapshot
         # (the collector's full snapshot dict after each execute)
         self.stats = {"retries": 0, "faults_injected": 0}
         self.last_query_stats = {"retries": 0, "faults_injected": 0}

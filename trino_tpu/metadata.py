@@ -74,28 +74,6 @@ SESSION_PROPERTY_DEFAULTS: Dict[str, Any] = {
     # of silently exhausting host RAM. 0 = default: half of physical
     # host RAM (exec/spill.default_spill_limit_bytes).
     "spill_max_bytes": 0,
-    # MXU-native join-project (ops/join_mxu.py; router in
-    # exec/local_planner._prepare_probe): eligible INNER join-project,
-    # semijoin/anti-semijoin, distinct-project, and many-to-many
-    # AGGREGATING joins (the TPC-DS q64/q72 shapes — match
-    # multiplicities feed SUM/COUNT without materializing the cross
-    # product) execute as density-partitioned indicator MATMULS on the
-    # matrix unit instead of gather/searchsorted probes. Routing is
-    # per-join from the OBSERVED build-key density at runtime; EXPLAIN
-    # prints the plan-time candidate (`join strategy: mxu-matmul |
-    # gather`) and the mxu_joins / mxu_flops counters report what
-    # actually ran. All three properties are plan-affecting (the plan
-    # cache keys on them).
-    "mxu_join_enabled": True,
-    # minimum observed key-range density (distinct live build keys /
-    # key span) to route onto the matmul path; sparser builds keep the
-    # gather path — their indicator matrices would be mostly zeros
-    # (the density partitioning of arXiv 2206.04995)
-    "mxu_join_density_threshold": 0.05,
-    # maximum key-span slots for the indicator tables: bounds the
-    # per-probe-page matmul cost (O(rows x slots) MACs) and the
-    # table's HBM footprint
-    "mxu_join_max_slots": 4096,
     # fault-tolerant execution (RetryPolicy / SystemSessionProperties
     # retry_policy + task_retry_attempts_per_task analogs): TASK retries
     # individual fragments, QUERY re-runs the whole statement, NONE fails
@@ -351,15 +329,6 @@ SESSION_PROPERTY_DOCS: Dict[str, str] = {
     "spill_max_bytes":
         "Host-RAM budget for a query's spill stores; exceeding fails "
         "EXCEEDED_SPILL_LIMIT. 0 = half of physical host RAM.",
-    "mxu_join_enabled":
-        "Route eligible joins as density-partitioned indicator matmuls "
-        "on the matrix unit (ops/join_mxu.py). Plan-affecting.",
-    "mxu_join_density_threshold":
-        "Minimum observed build-key density to take the matmul path; "
-        "sparser builds keep gather probes. Plan-affecting.",
-    "mxu_join_max_slots":
-        "Max key-span slots for MXU indicator tables (bounds per-page "
-        "matmul cost and HBM footprint). Plan-affecting.",
     "retry_policy":
         "Fault-tolerant execution: NONE fails fast, TASK retries "
         "fragments, QUERY re-runs the whole statement.",

@@ -11,7 +11,7 @@ This package is the engine's measurement layer: the runner owns one
 `QueryStatsCollector` per query, execution threads it through the local
 planner, the distributed scheduler, and the jit cache, and everything
 downstream — EXPLAIN ANALYZE, system.runtime.{queries,metrics}, event
-listeners, Prometheus scrapes, bench.py — reads the same numbers.
+listeners, Prometheus scrapes, benchmark/ — reads the same numbers.
 """
 
 from trino_tpu.obs.listeners import (EventListener, LoggingEventListener,

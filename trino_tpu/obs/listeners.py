@@ -183,8 +183,6 @@ def _record_terminal_metrics(info) -> None:
             n = info.stats.get(kind, 0)
             if n:
                 m.ADAPTIVE_EVENTS_TOTAL.inc(n, kind=kind)
-        m.MXU_JOINS_TOTAL.inc(info.stats.get("mxu_joins", 0))
-        m.MXU_FLOPS_TOTAL.inc(info.stats.get("mxu_flops", 0))
     if info.stats:
         m.COMPILE_SECONDS_TOTAL.inc(
             float(info.stats.get("compile_time_ms", 0) or 0) / 1000.0)

@@ -329,14 +329,6 @@ ADAPTIVE_EVENTS_TOTAL = REGISTRY.counter(
     "join_recursions), heavy-hitter key splits (heavy_key_splits), and "
     "bounded chunked fallbacks at max recursion depth "
     "(spill_fallbacks).", labeled=True)
-MXU_JOINS_TOTAL = REGISTRY.counter(
-    "trino_tpu_mxu_joins_total",
-    "Joins executed as density-partitioned indicator matmuls on the "
-    "matrix unit (ops/join_mxu.py) across the process lifetime.")
-MXU_FLOPS_TOTAL = REGISTRY.counter(
-    "trino_tpu_mxu_flops_total",
-    "Cost-model MACs (2 flops each) issued by matrix-unit join probe "
-    "dispatches across the process lifetime.")
 PREEMPT_LATENCY_SECONDS = REGISTRY.histogram(
     "trino_tpu_preempt_latency_seconds",
     "Cancel-request to unwind wall per preempted query — bounded by "

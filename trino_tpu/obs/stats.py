@@ -6,8 +6,8 @@ operator/OperatorStats.java (per-operator wall time, positions, bytes,
 rolled up by PlanNodeStatsSummarizer for EXPLAIN ANALYZE). The collector
 is created once per query by the runner and threaded through the local
 planner, the distributed scheduler, and the jit cache, so every surface —
-EXPLAIN ANALYZE, system.runtime.queries, event listeners, bench.py —
-reports the SAME numbers.
+EXPLAIN ANALYZE, system.runtime.queries, event listeners, the
+benchmark — reports the SAME numbers.
 
 Two collection levels, because per-operator instrumentation is not free
 on this engine. Query-level collection (phases, output rows/bytes, jit
@@ -229,15 +229,6 @@ class QueryStatsCollector:
         self.join_recursions = 0
         self.heavy_key_splits = 0
         self.spill_fallbacks = 0
-        # MXU join path (ops/join_mxu.py + the exec/local_planner
-        # router): joins this query actually ran as density-partitioned
-        # indicator matmuls on the matrix unit, and the summed
-        # cost-model MACs those dispatches issued (2 flops per
-        # multiply-accumulate — the same convention as the XLA
-        # cost-model estimated_flops above, which additionally counts
-        # the matmul flops of every mxu kernel at its compile)
-        self.mxu_joins = 0
-        self.mxu_flops = 0
 
     # ----------------------------------------------------------- spans
 
@@ -377,14 +368,6 @@ class QueryStatsCollector:
         self.streamed_chunks += int(chunks)
         self.streamed_rows += int(rows)
 
-    def mxu_join(self, n: int = 1) -> None:
-        """One join routed onto the matrix-unit matmul path."""
-        self.mxu_joins += int(n)
-
-    def add_mxu_flops(self, flops: int) -> None:
-        """One mxu probe dispatch's cost-model MAC count."""
-        self.mxu_flops += int(flops)
-
     def add_exchange(self, mode: str, rows: int = 0, nbytes: int = 0
                      ) -> None:
         """One inter-fragment exchange applied; mode 'fused' (collective
@@ -452,7 +435,8 @@ class QueryStatsCollector:
 
     def snapshot(self) -> Dict[str, Any]:
         """The immutable query-end rollup (QueryStats.java wire shape):
-        what QueryInfo.stats, event payloads, and bench.py carry."""
+        what QueryInfo.stats and event payloads carry, and what
+        benchmark/ reads."""
         spans = self.request_spans()
         snap: Dict[str, Any] = {
             "query_id": self.query_id,
@@ -517,8 +501,6 @@ class QueryStatsCollector:
             "join_recursions": self.join_recursions,
             "heavy_key_splits": self.heavy_key_splits,
             "spill_fallbacks": self.spill_fallbacks,
-            "mxu_joins": self.mxu_joins,
-            "mxu_flops": self.mxu_flops,
         }
         if self.operators:
             snap["operators"] = self.operator_rows()
@@ -620,9 +602,6 @@ def render_analyzed_plan(plan, collector: QueryStatsCollector,
              f"{collector.plan_cache_misses} misses")
     if collector.spilled_bytes:
         text += f", spilled {_fmt_bytes(collector.spilled_bytes)}"
-    if collector.mxu_joins:
-        text += (f"\nmxu: {collector.mxu_joins} matmul joins, "
-                 f"{collector.mxu_flops:.3g} probe flops")
     if (collector.agg_mode_downgrades or collector.agg_mode_upgrades
             or collector.agg_recursions or collector.join_recursions
             or collector.heavy_key_splits or collector.spill_fallbacks):

@@ -198,6 +198,12 @@ def _dense_lo(table: jnp.ndarray, kmin, pkey: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(inb, lo, _DENSE_SENTINEL)
 
 
+def _check_lookup(lookup: str) -> None:
+    if lookup not in ("search", "dense"):
+        raise ValueError(
+            f"join lookup must be 'search' or 'dense', not {lookup!r}")
+
+
 def hash_join(
     probe_keys: Sequence[int],
     build_keys: Sequence[int],
@@ -207,7 +213,6 @@ def hash_join(
     prepared: bool = False,
     null_aware: bool = True,
     lookup: str = "search",
-    mxu_slots: Optional[int] = None,
     probe_out: Optional[Sequence[int]] = None,
     build_out: Optional[Sequence[int]] = None,
 ) -> Callable[[Page, Page], Tuple[Page, jnp.ndarray]]:
@@ -221,15 +226,10 @@ def hash_join(
     the executor re-plans at a larger bucket (never silently truncates).
 
     `lookup` picks the probe strategy (exec/local_planner._prepare_probe
-    routes by density/span): 'search' = sort-engine searchsorted,
-    'dense' = one gather against a direct-address table (prepared[10]),
-    'mxu' = blocked indicator matmuls against the per-key [count, pos]
-    table (prepared[10], ops/join_mxu.py) — the matrix-unit probe.
-    `mxu_slots` (prepared=False only — the mesh shard_map bodies, which
-    prep inline) computes BOTH the matmul and the searchsorted probe
-    and selects per shard with a branchless `where` on the traced key
-    span: in-span shards use the MXU result, over-span shards the
-    searchsorted one, inside one SPMD-uniform program.
+    routes by the build's live key span): 'search' = sort-engine
+    searchsorted, 'dense' = one gather against a direct-address table
+    (prepared[10]). The mesh shard_map bodies prep inline
+    (prepared=False), have no table and probe by 'search'.
 
     null_aware governs SEMI/ANTI/MARK null semantics (reference:
     sql/planner/QueryPlanner IN-predicate planning vs correlated-EXISTS
@@ -247,17 +247,18 @@ def hash_join(
     probe_keys = tuple(probe_keys)
     build_keys = tuple(build_keys)
     composite = len(probe_keys) > 1
+    _check_lookup(lookup)
 
     def op(probe: Page, build) -> Tuple[Page, jnp.ndarray]:
         aux_table = None
         if prepared:
-            if lookup in ("dense", "mxu"):
+            if lookup == "dense":
                 aux_table = build[10]
             (build, bkey_s, bperm, n_live_build, n_build_rows,
-             build_has_null, run_len, _max_run, kmin, kmax) = build[:10]
+             build_has_null, run_len, _max_run, kmin, _kmax) = build[:10]
         else:
             (build, bkey_s, bperm, n_live_build, n_build_rows,
-             build_has_null, run_len, _max_run, kmin, kmax) = \
+             build_has_null, run_len, _max_run, kmin, _kmax) = \
                 prepare_build(build_keys)(build)
         n_build = build.capacity
         n_probe = probe.capacity
@@ -279,73 +280,27 @@ def hash_join(
 
             p_dead = ~probe.row_mask() | pnull
             n_build_m1 = jnp.maximum(n_build - 1, 0)
-            # the mesh in-program variant: shapes are static but the key span
-            # is a traced per-shard value, so BOTH probe strategies compile
-            # and lax.cond picks per shard (f32 exactness gate is static:
-            # positions must stay under 2^24)
-            inline_mxu = (mxu_slots is not None and not prepared
-                          and n_build < (1 << 24))
-
-            def _search_lookup():
-                # ONE searchsorted over the live prefix (method="sort" routes
-                # the lookup through the TPU sort engine — 20x faster at
-                # millions of keys than the default per-level binary-search
-                # gathers: 0.14 s against 2.8 s for 6.3M probes on a v5e, PR
-                # 23 — at a minute more of compile time); the upper bound
-                # comes from the build side's precomputed run lengths
-                s_lo = jnp.searchsorted(bkey_s, pkey, side="left",
-                                        method="sort").astype(jnp.int32)
-                s_lo_c = jnp.minimum(s_lo, n_build_m1)
-                s_found = (jnp.take(bkey_s, s_lo_c, mode="clip") == pkey) & \
-                    (s_lo < n_live_build)
-                s_cnt = jnp.where(s_found,
-                                  jnp.take(run_len, s_lo_c, mode="clip"), 0)
-                return s_cnt, s_lo
-
-            if lookup == "mxu" and aux_table is not None:
-                # matrix-unit probe: blocked indicator matmuls against the
-                # per-key [count, first-pos] table (ops/join_mxu.py)
-                from trino_tpu.ops.join_mxu import matmul_lookup
-                cnt, lo = matmul_lookup(aux_table, kmin, pkey)
-                found = cnt > 0
-                lo = jnp.where(found, lo, _DENSE_SENTINEL)
-                lo_c = jnp.minimum(lo, n_build_m1)
-                hi = lo + cnt
-            elif inline_mxu:
-                # both lookups compute and a per-shard `where` selects: the
-                # key span is a traced per-shard value, and jnp.where keeps
-                # the program SPMD-uniform (an earlier lax.cond formulation
-                # miscompiled under shard_map fusion — any fusion barrier
-                # "fixed" it — so the branchless select is also the safe
-                # choice, at the cost of the searchsorted pass running on
-                # in-span shards too)
-                from trino_tpu.ops.join_mxu import (build_count_pos_table,
-                                                    matmul_lookup)
-                table = build_count_pos_table(mxu_slots)(
-                    bkey_s, n_live_build, kmin)
-                m_cnt, m_lo = matmul_lookup(table, kmin, pkey)
-                s_cnt, s_lo = _search_lookup()
-                span_ok = (kmax >= kmin) & \
-                    ((kmax - kmin) < jnp.uint64(mxu_slots))
-                cnt = jnp.where(span_ok, m_cnt, s_cnt)
-                lo = jnp.where(span_ok, m_lo, s_lo)
-                found = cnt > 0
-                lo = jnp.where(found, lo, _DENSE_SENTINEL)
-                lo_c = jnp.minimum(lo, n_build_m1)
-                hi = lo + cnt
-            elif lookup == "dense" and aux_table is not None:
+            if lookup == "dense" and aux_table is not None:
                 # dense surrogate keys: ONE gather against the direct-address
                 # table (slot identity implies key equality — no verify gather)
                 lo = _dense_lo(aux_table, kmin, pkey)
                 lo_c = jnp.minimum(lo, n_build_m1)
                 found = lo < n_live_build
-                hi = lo + jnp.where(found,
-                                    jnp.take(run_len, lo_c, mode="clip"), 0)
             else:
-                cnt, lo = _search_lookup()
+                # ONE searchsorted over the live prefix (method="sort" routes
+                # the lookup through the TPU sort engine — 20x faster at
+                # millions of keys than the default per-level binary-search
+                # gathers: 0.14 s against 2.8 s for 6.3M probes on a v5e, PR
+                # 23 — at a minute more of compile time)
+                lo = jnp.searchsorted(bkey_s, pkey, side="left",
+                                      method="sort").astype(jnp.int32)
                 lo_c = jnp.minimum(lo, n_build_m1)
-                found = cnt > 0
-                hi = lo + cnt
+                found = (jnp.take(bkey_s, lo_c, mode="clip") == pkey) & \
+                    (lo < n_live_build)
+            # the upper bound comes from the build side's precomputed run
+            # lengths
+            hi = lo + jnp.where(found,
+                                jnp.take(run_len, lo_c, mode="clip"), 0)
             lo = jnp.minimum(lo, n_live_build)
             hi = jnp.minimum(hi, n_live_build)
             counts = jnp.where(p_dead, 0, hi - lo).astype(jnp.int64)
@@ -687,8 +642,7 @@ def unique_inner_probe(
     capacity-sized gathers (round-4 profiling: those cost ~0.7s per
     MILLION probe rows in the general kernel). With lookup='dense' the
     searchsorted collapses to one gather against the direct-address table
-    (prepared[10]); lookup='mxu' runs the same lookup as blocked
-    indicator matmuls on the matrix unit (ops/join_mxu.py).
+    (prepared[10]).
 
     Returns (pre_page, found_mask, match_count): pre_page is probe columns
     ++ a BIGINT `brow` channel at PROBE order. The executor compacts with
@@ -699,9 +653,10 @@ def unique_inner_probe(
     probe_keys = tuple(probe_keys)
     build_keys = tuple(build_keys)
     composite = len(probe_keys) > 1
+    _check_lookup(lookup)
 
     def op(probe: Page, prepared):
-        aux_table = prepared[10] if lookup in ("dense", "mxu") else None
+        aux_table = prepared[10] if lookup == "dense" else None
         (build, bkey_s, bperm, n_live_build, n_build_rows,
          build_has_null, run_len, _max_run, kmin, _kmax) = prepared[:10]
         n_build = build.capacity
@@ -719,12 +674,7 @@ def unique_inner_probe(
             pkey, pnull = _key_u64(probe, probe_keys)
             p_dead = ~probe.row_mask() | pnull
             n_build_m1 = jnp.maximum(n_build - 1, 0)
-            if lookup == "mxu" and aux_table is not None:
-                from trino_tpu.ops.join_mxu import matmul_lookup
-                cnt, lo = matmul_lookup(aux_table, kmin, pkey)
-                lo_c = jnp.minimum(lo, n_build_m1)
-                found = (cnt > 0) & ~p_dead
-            elif lookup == "dense" and aux_table is not None:
+            if lookup == "dense" and aux_table is not None:
                 lo = _dense_lo(aux_table, kmin, pkey)
                 lo_c = jnp.minimum(lo, n_build_m1)
                 found = (lo < n_live_build) & ~p_dead

@@ -287,14 +287,6 @@ class JoinNode(PlanNode):
     # (`is_unique` from prepare) still re-decides when the estimate is
     # missing or wrong.
     build_skew_estimate: Optional[float] = None
-    # plan-time probe-strategy candidate (optimizer.annotate_adaptive_
-    # hints): 'mxu-matmul' = eligible for the density-partitioned
-    # indicator-matmul probe on the matrix unit (ops/join_mxu.py),
-    # 'gather' = the classic dense-gather/searchsorted path. EXPLAIN
-    # prints it; the executor's runtime router (exec/local_planner.
-    # _prepare_probe) re-decides from the OBSERVED key density, so
-    # `mxu_joins` on the query stats reports what actually ran.
-    join_strategy: Optional[str] = None
 
     @property
     def sources(self):
@@ -309,7 +301,7 @@ class JoinNode(PlanNode):
     def with_sources(self, sources):
         return JoinNode(self.kind, sources[0], sources[1], self.criteria,
                         self.filter, self.distribution, self.output_symbols,
-                        self.build_skew_estimate, self.join_strategy)
+                        self.build_skew_estimate)
 
 
 @_node
@@ -358,8 +350,6 @@ class SemiJoinNode(PlanNode):
     # EXISTS semantics (NULL correlation keys just never match); see
     # ops/join.py hash_join(null_aware=...)
     null_aware: bool = True
-    # plan-time probe-strategy candidate (see JoinNode.join_strategy)
-    join_strategy: Optional[str] = None
 
     @property
     def sources(self):
@@ -372,8 +362,7 @@ class SemiJoinNode(PlanNode):
     def with_sources(self, sources):
         return SemiJoinNode(sources[0], sources[1], self.source_keys,
                             self.filtering_keys, self.match_symbol,
-                            self.negate, self.null_aware,
-                            self.join_strategy)
+                            self.negate, self.null_aware)
 
 
 @_D
@@ -669,16 +658,10 @@ def format_plan(node: PlanNode, indent: int = 0, annotate=None) -> str:
         crit = " AND ".join(f"{c.left.name} = {c.right.name}"
                             for c in node.criteria)
         detail = f"[{node.kind}; {crit or 'cross'}; {node.distribution}]"
-        if node.join_strategy is not None:
-            detail = detail[:-1] + \
-                f"; join strategy: {node.join_strategy}]"
     elif isinstance(node, SemiJoinNode):
         sk = ", ".join(s.name for s in node.source_keys)
         fk = ", ".join(s.name for s in node.filtering_keys)
         detail = f"[({sk}) IN ({fk}) -> {node.match_symbol.name}]"
-        if node.join_strategy is not None:
-            detail = detail[:-1] + \
-                f"; join strategy: {node.join_strategy}]"
     elif isinstance(node, (SortNode, TopNNode)):
         keys = ", ".join(
             o.symbol.name + ("" if o.ascending else " DESC")

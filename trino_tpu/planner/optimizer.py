@@ -1648,26 +1648,6 @@ def annotate_adaptive_hints(node: PlanNode,
                         float(brows) / max(min(known), 1.0)))
     except Exception:
         pass    # estimates are hints: a stats failure must not fail planning
-    try:
-        # MXU probe-strategy candidate (surfaced by EXPLAIN as `join
-        # strategy: mxu-matmul | gather`): an INNER single-clause
-        # equi-join is matmul-ELIGIBLE when the session enables the
-        # path; the executor's runtime router re-decides from the
-        # OBSERVED build-key density (the CBO has NDV but no key span),
-        # so this stamp is the plan-time candidate, not the verdict.
-        # Plan-cache-safe: mxu_join_* are PLAN_PROPERTIES.
-        mxu_on = bool(ctx.session.get("mxu_join_enabled"))
-        if isinstance(node, JoinNode) and node.criteria:
-            strategy = "mxu-matmul" if (
-                mxu_on and node.kind == JoinKind.INNER
-                and len(node.criteria) == 1) else "gather"
-            node = dataclasses.replace(node, join_strategy=strategy)
-        elif isinstance(node, SemiJoinNode):
-            strategy = "mxu-matmul" if (
-                mxu_on and len(node.source_keys) == 1) else "gather"
-            node = dataclasses.replace(node, join_strategy=strategy)
-    except Exception:
-        pass
     return node
 
 

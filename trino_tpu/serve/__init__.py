@@ -17,8 +17,8 @@ statement cost approximately one HTTP round trip:
   and pre-executed at server startup so the first real user request hits
   a warm plan cache and warm (persistent-compilation-cache-backed)
   kernels.
-- `serve/bench_serve.py` — the closed-loop QPS benchmark behind
-  `bench.py --qps`.
+- `serve/bench_serve.py` — a closed-loop QPS benchmark over prepared
+  EXECUTEs (`run_qps_bench`).
 """
 
 from trino_tpu.serve.caches import (CachedResult, ResultSetCache,  # noqa: F401

@@ -1,7 +1,7 @@
 """Closed-loop QPS benchmark: N clients hammer prepared EXECUTEs over
 HTTP.
 
-The serving tier's acceptance instrument (`bench.py --qps`): start a
+The serving tier's acceptance instrument (`run_qps_bench`): start a
 TrinoServer over the tiny TPC-H catalog, warm it through the warmup
 manifest (PREPARE + one priming EXECUTE per parameter value), then run
 `clients` closed-loop threads — each POSTs `EXECUTE qps_probe USING k`
