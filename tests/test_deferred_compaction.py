@@ -546,6 +546,20 @@ def test_served_queries_count_their_compactions(served, shape):
         assert stats["compactions_run"] == 0
 
 
+def test_served_q3_reports_its_probe_compactions(served):
+    """`GET /v1/query/<id>` carries the five probe-path counters (PR 31)
+    beside `compactions_run`; q3's two joins each hand their probe
+    buffers to `_compact_counted`."""
+    import chip_smoke
+    _rows, stats = served(chip_smoke.Q3)
+    ran = stats["probe_compactions_tight"] + stats["probe_compactions_full"]
+    assert ran + stats["probe_compactions_skipped"] >= 2
+    assert stats["probe_compactions_tight"] >= 1
+    assert 0 < stats["probe_compaction_lanes_gathered"] \
+        < stats["probe_compaction_lanes_in"]
+    assert stats["compactions_run"] >= 1
+
+
 def _scatter_operands(text):
     """[[dims of each operand]] of every scatter in a StableHLO module."""
     import re

@@ -141,6 +141,123 @@ def test_spilled_build_chunked_staging(oracle, monkeypatch):
     assert_same(got.rows, oracle.execute(sql).fetchall(), False)
 
 
+# ------------------------- the probe side's two-step compaction (PR 31)
+
+_PROBE_COUNTERS = ("probe_compactions_tight", "probe_compactions_full",
+                   "probe_compactions_skipped")
+
+
+def _spy_compactions(monkeypatch):
+    """[(tag, kept, live, capacity in, capacity out)] of every page the
+    probe path hands `_compact_counted`."""
+    from trino_tpu.exec.local_planner import LocalExecutionPlanner
+    seen = []
+    real = LocalExecutionPlanner._compact_counted
+
+    def spy(self, page, mask, kept, live, tag="probe-compact"):
+        out = real(self, page, mask, kept, live, tag)
+        seen.append((tag, kept, live, page.capacity, out.capacity))
+        return out
+    monkeypatch.setattr(LocalExecutionPlanner, "_compact_counted", spy)
+    return seen
+
+
+@pytest.mark.parametrize("caller", ["memory", "spill"])
+@pytest.mark.parametrize("build_filter, form", [
+    ("o_custkey % 100 = 0", "tight"),       # ~1 % of lineitem matches
+    ("o_custkey % 5 < 3", "full"),          # ~60 %
+    ("o_orderkey > 0", "skipped")])         # every row
+def test_probe_compaction_takes_the_form_the_counts_show(
+        oracle, monkeypatch, caller, build_filter, form):
+    """A unique-build join whose build keys span the probe's range (so the
+    prefilter measures itself useless and moves nothing): the probe's
+    matched rows are compacted at their own pow2 rung when the buffer is
+    more than twice it, by a full filter when not, and not at all when
+    every row matched — in memory and against a spilled build alike, the
+    counters saying which, the oracle saying the rows are the same."""
+    seen = _spy_compactions(monkeypatch)
+    r = LocalQueryRunner.tpch("tiny")
+    if caller == "spill":
+        r.execute("SET SESSION join_spill_threshold_bytes = 1")
+    sql = ("SELECT count(*), sum(l_extendedprice), sum(o_totalprice) "
+           "FROM lineitem, orders WHERE l_orderkey = o_orderkey "
+           f"AND {build_filter}")
+    got = r.execute(sql)
+    stats = r.last_query_stats
+    assert_same(got.rows, oracle.execute(sql).fetchall(), False)
+    assert (stats.get("spilled_bytes", 0) > 0) == (caller == "spill")
+    for name in _PROBE_COUNTERS:
+        if name.endswith(form):
+            assert stats[name] >= 1, (name, stats[name])
+        else:
+            assert stats[name] == 0, (name, stats[name])
+    lanes_in = stats["probe_compaction_lanes_in"]
+    gathered = stats["probe_compaction_lanes_gathered"]
+    if form == "tight":
+        assert 0 < gathered < lanes_in
+    else:
+        assert gathered == lanes_in == (0 if form == "skipped"
+                                        else seen[0][3])
+    assert {tag for tag, *_ in seen} == {"probe-compact"}
+    for _tag, kept, live, cap_in, cap_out in seen:
+        # the page that goes on to the attach has the capacity `_tight`
+        # gave it before there was a tight form
+        rung = 1 << max(kept - 1, 0).bit_length()
+        assert cap_out == (rung if cap_in > 2 * rung else cap_in)
+        assert (kept == live) == (form == "skipped")
+
+
+@pytest.mark.parametrize("build_filter, selective", [
+    ("o_orderkey < 2000", True),     # a narrow key range: prunes ~87 %
+    ("o_custkey % 3 = 1", False)])   # half of the keys, all over
+def test_prefilter_measures_before_it_moves_anything(
+        oracle, monkeypatch, build_filter, selective):
+    """The dynamic-filter prefilter is a mask and a count per page. A
+    build in a narrow key range keeps it, and its pages are compacted by
+    their fetched counts (here at a tight rung; every row that is left
+    then matches, so the probe's own compaction is skipped). A build
+    that spans the probe's range drops it after the first window, and
+    not one page was gathered to find that out. NULL probe keys match
+    nothing either way."""
+    from trino_tpu.exec import jit_cache
+    seen = _spy_compactions(monkeypatch)
+    r = LocalQueryRunner.tpch("tiny")
+    _ctas(r, oracle, f"pf_probe_{int(selective)}",
+          "SELECT CASE WHEN l_linenumber = 7 THEN NULL ELSE l_orderkey "
+          "END AS k, l_quantity AS v FROM lineitem")
+    sql = ("SELECT count(*), sum(v), sum(o_totalprice), count(k) "
+           f"FROM {{0}}pf_probe_{int(selective)}, orders "
+           f"WHERE k = o_orderkey AND {build_filter}")
+    got = r.execute(sql.format("memory.default."))
+    stats = r.last_query_stats
+    want = oracle.execute(sql.format("")).fetchall()
+    assert_same(got.rows, want, False)
+    assert want[0][0] == want[0][3] > 0      # no NULL key joined
+    with jit_cache._LOCK:
+        tags = {jit_cache.key_tag(k) for k in jit_cache._CACHE}
+    assert "dfrange-mask" in tags
+    by_tag = {}
+    for tag, kept, live, cap_in, cap_out in seen:
+        by_tag.setdefault(tag, []).append((kept, live, cap_in, cap_out))
+    if selective:
+        assert by_tag["dfrange"], seen
+        for kept, live, cap_in, cap_out in by_tag["dfrange"]:
+            assert kept < live and cap_out < cap_in
+        # what the range let through all matches
+        assert all(kept == live for kept, live, *_ in
+                   by_tag["probe-compact"])
+        assert stats["probe_compactions_tight"] == len(by_tag["dfrange"])
+        assert stats["probe_compactions_skipped"] >= 1
+        assert stats["probe_compactions_full"] == 0
+    else:
+        assert "dfrange" not in by_tag, seen
+        # the NULL keys reached the probe and fell to its liveness test
+        live_in = sum(live for _kept, live, *_ in by_tag["probe-compact"])
+        assert live_in == oracle.execute(
+            "SELECT count(*) FROM pf_probe_0").fetchall()[0][0]
+        assert stats["probe_compactions_skipped"] == 0
+
+
 # -------------------------------- dispatch-loop cache promotion
 
 

@@ -30,7 +30,7 @@ KNOWN_TAGS = {
     "join": ["join", "join-prep", "join-spill-part", "uprobe", "uattach",
              "semijoin", "markjoin", "fulljoin", "cross-attach",
              "dense-table", "dense-table-rows", "dfbounds", "dfrange",
-             "probe-compact", "spill-prep", "spill-probe",
+             "dfrange-mask", "probe-compact", "spill-prep", "spill-probe",
              "spill-probe-dense"],
     "sort": ["sort", "sort-spill-bounds", "sort-spill-part",
              "sort-spill-rank", "topn-masked", "topn", "merge-sort"],

@@ -928,13 +928,18 @@ class LocalExecutionPlanner:
         return self._merge_buf(live, total), total
 
     @staticmethod
-    def _tight(page: Page, n: int) -> Page:
+    def _tight_capacity(page: Page, n: int) -> int:
+        """The capacity a page of `n` live rows is worth: the pow2
+        envelope of `n` where the page is more than twice that, else the
+        page's own (a rung below is not worth a program of its own)."""
+        tight = _next_pow2(max(n, 1))
+        return tight if page.capacity > 2 * tight else page.capacity
+
+    @classmethod
+    def _tight(cls, page: Page, n: int) -> Page:
         """Shrink a page to the pow2 envelope of its live count (free
         device slice; downstream sorts/builds then run at live size)."""
-        tight = _next_pow2(max(n, 1))
-        if page.capacity > 2 * tight:
-            return page.shrink_to(tight)
-        return page
+        return page.shrink_to(cls._tight_capacity(page, n))
 
     def _device_concat(self, pages: List[Page]) -> Page:
         """Jitted device-side page concatenation (page.device_concat) —
@@ -957,10 +962,14 @@ class LocalExecutionPlanner:
         count fetch per window, JAX dispatch stays async).
 
         `prefilter` is an optional (op, args) dynamic filter (build-side
-        key range) applied per page BEFORE buffering; it is adaptive — if
-        the first window prunes less than 25% of rows, the filter is
-        dropped for the rest of the stream (its compaction sort would only
-        add cost on uniformly-spread keys)."""
+        key range) that measures before it moves anything: `op` yields each
+        page's keep mask and count, fetched with the window's row counts
+        in the one transfer. It is adaptive — if the first window's masks
+        prune less than 25% of its rows, the filter is dropped for the
+        stream and the window's pages go on as they came (no gather ran;
+        the NULL keys it would have dropped fall to the probe's own
+        liveness test, as on every later page). A filter that is kept
+        compacts each page by its fetched count (`_compact_counted`)."""
         if target_rows is None:
             target_rows = int(self.session.get("probe_coalesce_rows"))
         row_bytes = 8 * max(len(stream.symbols), 1)
@@ -983,22 +992,25 @@ class LocalExecutionPlanner:
                     break
                 if use_df:
                     pf_op, pf_args = prefilter
-                    filtered = [pf_op(p, *pf_args) for p in window]
+                    masks = [pf_op(p, *pf_args) for p in window]
+                    fetched = jax.device_get(
+                        ([p.num_rows for p in window],
+                         [k for _, k in masks]))
+                    live, kept = ([int(c) for c in cs] for cs in fetched)
                     if not df_measured:
-                        pre = jax.device_get(
-                            [p.num_rows for p in window])
-                        post = jax.device_get(
-                            [p.num_rows for p in filtered])
                         df_measured = True
-                        if sum(int(c) for c in post) > 0.75 * max(
-                                sum(int(c) for c in pre), 1):
+                        if sum(kept) > 0.75 * max(sum(live), 1):
                             use_df = False   # not selective enough
-                        window = filtered
-                        counts = post
+                    if use_df:
+                        # a page that keeps nothing is dropped below
+                        window = [
+                            self._compact_counted(p, m, k, n, "dfrange")
+                            if k else p
+                            for p, (m, _), k, n in zip(
+                                window, masks, kept, live)]
+                        counts = kept
                     else:
-                        window = filtered
-                        counts = jax.device_get(
-                            [p.num_rows for p in window])
+                        counts = live
                 else:
                     counts = jax.device_get([p.num_rows for p in window])
                 for p, c in zip(window, counts):
@@ -1799,7 +1811,7 @@ class LocalExecutionPlanner:
                         ("dfbounds", build_keys[0]),
                         lambda: build_key_bounds(build_keys))
                     pf_op = cached_kernel(
-                        ("dfrange", probe_keys[0]),
+                        ("dfrange-mask", probe_keys[0]),
                         lambda: range_prefilter(probe_keys[0]))
                     prefilter = (pf_op, bounds_op(bp))
                     # the same build-side range, pushed into connector
@@ -1991,7 +2003,7 @@ class LocalExecutionPlanner:
                     total, live = int(total), int(live)
                     if total == 0:
                         continue
-                    pre = self._compact_probe(pre, found, total, live)
+                    pre = self._compact_counted(pre, found, total, live)
                     pre = self._tight(pre, total)
                     out = attach_build_host(pre, n_pre_cols, host_cols,
                                             verify=verify, emit=emit)
@@ -2341,25 +2353,51 @@ class LocalExecutionPlanner:
         bstore.drop(p)
         pstore.drop(p)
 
-    def _compact_probe(self, pre: Page, found, total: int,
-                       live: int) -> Page:
-        """Compact a probe result to its matched rows — SKIPPED when every
-        live row matched (fact-to-dim joins after dynamic filtering often
-        match ~100%; the compaction (scatter + gathers) is the single biggest
-        per-buffer cost once the lookup itself is a dense gather)."""
-        if total == live:
-            return pre
-        op = cached_kernel(("probe-compact",),
-                           lambda: lambda p, f: p.filter(f))
-        return op(pre, found)
+    def _compact_counted(self, page: Page, mask, kept: int, live: int,
+                         tag: str = "probe-compact") -> Page:
+        """Compact a page of the probe path to the rows `mask` keeps, the
+        host holding their count `kept` and the page's live count `live`
+        — the second of two steps; the first (a lookup, a range mask)
+        made `mask` and both counts and moved nothing. By what the counts
+        show, with `_tight`'s rule:
+
+        - kept == live: nothing was dropped, nothing moves (fact-to-dim
+          joins often match every row);
+        - `_tight_capacity` puts `kept` rows on a rung below the page:
+          `Page.compact_to` at that rung — the kept prefix alone is
+          gathered, not the lanes `_tight` would slice off next (q3's
+          second join keeps 0.5 % of a 16-32 M-lane buffer; a gather
+          costs by the index, PERF.md PR 31);
+        - otherwise one full-capacity `Page.filter`.
+
+        The result has the capacity `_tight(page.filter(mask), kept)`
+        would have, so what follows (attach, coalesce) compiles for the
+        same pow2 rungs either way. Counted on the query's collector as
+        `probe_compactions_skipped` / `_tight` / `_full`."""
+        rung = self._tight_capacity(page, kept)
+        if kept == live:
+            gathered, out = 0, page
+        elif rung < page.capacity:
+            op = cached_kernel((tag, rung), lambda: lambda p, m:
+                               p.compact_to(m, rung))
+            gathered, out = rung, op(page, mask)
+        else:
+            op = cached_kernel((tag,), lambda: lambda p, m: p.filter(m))
+            gathered, out = rung, op(page, mask)
+        if self.collector is not None:
+            self.collector.count_probe_compaction(page.capacity, gathered)
+        return out
 
     def _run_unique_inner(self, probe_stream, prepared, probe_op,
                           attach_op) -> Iterator[Page]:
-        """Drive the unique-build INNER fast path: gather-probe kernel per
-        page, batched count fetch, compact ONLY partially-matching buffers,
-        shrink to live size, THEN gather build columns — so the attach
-        gathers run at match count, not probe capacity. No overflow loop:
-        output rows <= probe rows always."""
+        """Drive the unique-build INNER fast path in two steps with the
+        host's counts between them: the gather-probe kernel per page
+        yields the match mask and count and moves nothing; one batched
+        fetch of (matched, live) per batch; then `_compact_counted` per
+        buffer (skip / matched prefix at its pow2 rung / full) and the
+        build columns gathered at that rung — so neither the compaction
+        nor the attach runs at probe capacity. No overflow loop: output
+        rows <= probe rows always."""
         it = probe_stream if isinstance(probe_stream, Iterator) \
             else probe_stream.iter_pages()
         for batch in _byte_bounded_batches(it, 1 << 29):
@@ -2370,7 +2408,7 @@ class LocalExecutionPlanner:
                 total, live = int(total), int(live)
                 if total == 0:
                     continue
-                out = self._compact_probe(pre, found, total, live)
+                out = self._compact_counted(pre, found, total, live)
                 yield attach_op(self._tight(out, total), prepared)
 
     def _align_join_dictionaries(self, probe_stream: PageStream,

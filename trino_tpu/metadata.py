@@ -220,7 +220,7 @@ SESSION_PROPERTY_DEFAULTS: Dict[str, Any] = {
     # Sized by the OWNING runner's session (server deployments:
     # TrinoServer(history_max_entries=...)); eviction is FIFO by
     # completion order.
-    "history_max_entries": 512,
+    "history_max_entries": 4096,
     # multi-chip sharded execution (exec/mesh_exec.py): co-schedule
     # eligible fragment chains as ONE jitted shard_map program over the
     # device mesh — per-shard scan/filter/join/aggregate pipelines with

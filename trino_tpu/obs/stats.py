@@ -162,6 +162,18 @@ class QueryStatsCollector:
         # `run` one whose filters compact the page
         self.compactions_deferred = 0
         self.compactions_run = 0
+        # compactions on the join's probe path, made by the host with
+        # the kept count in hand (local_planner._compact_counted): `tight`
+        # gathered the kept prefix alone at the count's pow2 capacity,
+        # `full` ran Page.filter over the whole page, `skipped` moved
+        # nothing because nothing was dropped. The lane sums are over the
+        # compactions that ran: page capacities in, gather indices out —
+        # their quotient is the share of lanes still gathered
+        self.probe_compactions_tight = 0
+        self.probe_compactions_full = 0
+        self.probe_compactions_skipped = 0
+        self.probe_compaction_lanes_in = 0
+        self.probe_compaction_lanes_gathered = 0
         # dispatches of a chain or mesh program (jit_cache.
         # profiled_kernel) whose direct GROUP BY (ops/aggregate.
         # _direct_aggregate) reduced its slot table lane-wise under slot
@@ -354,6 +366,21 @@ class QueryStatsCollector:
         else:
             self.compactions_run += 1
 
+    def count_probe_compaction(self, lanes_in: int, lanes_gathered: int
+                               ) -> None:
+        """One page of the probe path compacted from `lanes_in` lanes
+        through `lanes_gathered` gather indices: 0 of them skipped it,
+        all of them is the full form, fewer the tight one."""
+        if lanes_gathered == 0:
+            self.probe_compactions_skipped += 1
+            return
+        if lanes_gathered < lanes_in:
+            self.probe_compactions_tight += 1
+        else:
+            self.probe_compactions_full += 1
+        self.probe_compaction_lanes_in += int(lanes_in)
+        self.probe_compaction_lanes_gathered += int(lanes_gathered)
+
     def count_program_notes(self, notes) -> None:
         """One dispatch of a program whose trace noted `notes`
         (page.note_trace)."""
@@ -473,6 +500,12 @@ class QueryStatsCollector:
             "scan_host_staging_bytes": self.scan_host_staging_bytes,
             "compactions_deferred": self.compactions_deferred,
             "compactions_run": self.compactions_run,
+            "probe_compactions_tight": self.probe_compactions_tight,
+            "probe_compactions_full": self.probe_compactions_full,
+            "probe_compactions_skipped": self.probe_compactions_skipped,
+            "probe_compaction_lanes_in": self.probe_compaction_lanes_in,
+            "probe_compaction_lanes_gathered":
+                self.probe_compaction_lanes_gathered,
             "direct_reduces_masked": self.direct_reduces_masked,
             "direct_reduces_scattered": self.direct_reduces_scattered,
             "files_pruned": self.files_pruned,

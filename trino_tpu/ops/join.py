@@ -549,7 +549,8 @@ def spilled_unique_probe(probe_keys: Sequence[int],
     but consuming only (bkey_s, bperm, n_live) — no build Page on device.
     Composite-key verification happens host-side in attach_build_host
     (the build columns live there). Returns (pre, found, count); the
-    executor compacts (or skips compaction when all rows matched)."""
+    executor compacts by the fetched count as it does behind
+    unique_inner_probe."""
     probe_keys = tuple(probe_keys)
 
     def op(probe: Page, bkey_s, bperm, n_live):
@@ -645,11 +646,14 @@ def unique_inner_probe(
     (prepared[10]).
 
     Returns (pre_page, found_mask, match_count): pre_page is probe columns
-    ++ a BIGINT `brow` channel at PROBE order. The executor compacts with
-    one filter kernel — or skips compaction when every live row matched
-    (count == num_rows; the common fact-to-dim case) — then runs
-    attach_build at live size. Output can never overflow (<= probe rows),
-    so no capacity re-run loop is needed."""
+    ++ a BIGINT `brow` channel at PROBE order, nothing moved yet. The
+    executor fetches the count and then compacts in a second program
+    (exec/local_planner._compact_counted): not at all when every live row
+    matched (count == num_rows; the common fact-to-dim case), the matched
+    prefix alone at the count's pow2 capacity when the buffer is more than
+    twice that, else one full-capacity filter — then runs attach_build at
+    live size. Output can never overflow (<= probe rows), so no capacity
+    re-run loop is needed."""
     probe_keys = tuple(probe_keys)
     build_keys = tuple(build_keys)
     composite = len(probe_keys) > 1
@@ -706,9 +710,11 @@ def build_key_bounds(build_keys: Sequence[int]):
     no coordinator round trip, the scalars never leave the device.
 
     Exact-set pruning (Trino's small-build IN-list filter) is deliberately
-    NOT a separate pass here: the unique-build probe path already compacts
-    non-matching probe rows with one stable partition before any build-column
-    gather, which is the same work an exact-set semi prefilter would do."""
+    NOT a separate pass here: the unique-build probe path already drops
+    non-matching probe rows before any build-column gather — its lookup
+    yields the match mask and count, and the executor compacts the matched
+    prefix at the count's own capacity — which is the same work an
+    exact-set semi prefilter would do."""
     build_keys = tuple(build_keys)
 
     def op(build: Page):
@@ -729,15 +735,20 @@ def build_key_bounds(build_keys: Sequence[int]):
 
 
 def range_prefilter(probe_key: int):
-    """Probe-side dynamic-filter application: drop rows whose key can't be
-    in [lo, hi] (NULL keys never match an INNER join, so they drop too)."""
+    """Probe-side dynamic-filter application, measured before anything
+    moves: the mask of live rows whose key can be in [lo, hi] (NULL keys
+    never match an INNER join, so they drop too) and its count — one
+    lane-wise program, no gather. The executor fetches the count with the
+    window's others and compacts only where the filter turned out to be
+    worth keeping (exec/local_planner._coalesce_stream)."""
 
-    def op(page: Page, lo, hi) -> Page:
-        c = page.column(probe_key)
-        keep = (c.values >= lo) & (c.values <= hi)
-        if c.valid is not None:
-            keep = keep & c.valid
-        return page.filter(keep)
+    def op(page: Page, lo, hi):
+        with op_scope("join__range_mask"):
+            c = page.column(probe_key)
+            keep = (c.values >= lo) & (c.values <= hi) & page.row_mask()
+            if c.valid is not None:
+                keep = keep & c.valid
+            return keep, jnp.sum(keep).astype(jnp.int32)
 
     return op
 

@@ -447,7 +447,10 @@ class Page:
     def filter(self, mask: jnp.ndarray) -> "Page":
         """Keep the rows where mask is true (Page.getPositions analog).
 
-        jit-safe: the output keeps this page's capacity either way.
+        jit-safe: the output keeps this page's capacity either way. This
+        is the form for a caller INSIDE a program, which cannot know the
+        kept count; a host driver that has fetched it compacts with
+        `compact_to` at the count's own capacity instead.
 
         Compacting (the default): selected rows move to the front and
         num_rows becomes the selected count. Every consumer that reads
@@ -463,22 +466,34 @@ class Page:
         the SF10 scan cell's device time (PERF.md, PR 25), are not
         emitted at all.
 
-        Compaction: a stable partition without a sort. A running count
-        of the mask gives every row its target slot (kept rows to the
-        front, dropped rows behind them, both in input order — the
-        permutation a stable sort on the drop-flag produces), one int32
-        scatter inverts it, and every column is gathered through it.
-        Carrying the columns as payload of one `lax.sort` runs faster
-        (1.4 ms against 8 ms for eight columns of 65 536 rows on a v5e)
-        but the TPU compiler builds a sort network per operand: 290 s of
-        compile time for five int64 operands, paid in EVERY fused chain,
-        against a second for this form (PR 23).
+        Compaction: a stable partition without a sort (`_partition_perm`),
+        then every column gathered through the whole permutation — one
+        index per lane of the page, kept or not, and the gather costs by
+        the index (PERF.md, PR 31). Carrying the columns as payload of
+        one `lax.sort` runs faster (1.4 ms against 8 ms for eight columns
+        of 65 536 rows on a v5e) but the TPU compiler builds a sort
+        network per operand: 290 s of compile time for five int64
+        operands, paid in EVERY fused chain, against a second for this
+        form (PR 23).
         """
         mask = mask & self.row_mask()
         if getattr(_THREAD, "defer", False):
             return self.with_selection(mask)
         if not self.columns:
             return Page((), jnp.sum(mask).astype(jnp.int32))
+        perm, count = self._partition_perm(mask)
+        with shared_scope("compact_gather"):
+            return Page(tuple(c.gather(perm) for c in self.columns), count)
+
+    def _partition_perm(self, mask: jnp.ndarray):
+        """(permutation, kept count) of a stable partition by `mask`: a
+        running count gives every row its target slot (kept rows to the
+        front, dropped rows behind them, both in input order — the
+        permutation a stable sort on the drop-flag produces) and one
+        int32 scatter inverts it. (The TPU compiler makes of that scatter
+        a two-operand 32-bit sort of the lanes and a sorted scatter:
+        0.24 s for 33 554 432 lanes on a v5e, whatever the mask; PERF.md,
+        PR 31.)"""
         with shared_scope("compact_slots"):
             kept = _running_count(mask)
             count = kept[-1]
@@ -486,8 +501,27 @@ class Page:
             target = jnp.where(mask, kept - 1, count + idx - kept)
             perm = jnp.zeros(self.capacity, dtype=jnp.int32).at[target].set(
                 idx, unique_indices=True, mode="promise_in_bounds")
+        return perm, count
+
+    def compact_to(self, mask: jnp.ndarray, capacity: int) -> "Page":
+        """`filter(mask).shrink_to(capacity)`, lane for lane, without the
+        lanes that `shrink_to` would slice off ever being gathered: the
+        same permutation, then every column gathered through its first
+        `capacity` entries only. Output capacity `capacity` (static),
+        num_rows the kept count, kept rows in input order.
+
+        For a host driver that holds the kept count before it compacts
+        (the join's probe path, exec/local_planner._compact_counted): the
+        caller promises count <= capacity. Never deferred — the page it
+        returns leaves its program — and, reading rows by position, it
+        refuses a page that carries a selection."""
+        self._require_compact("compact_to")
+        if capacity > self.capacity:
+            raise ValueError("compact_to cannot grow a page")
+        perm, count = self._partition_perm(mask & self.row_mask())
         with shared_scope("compact_gather"):
-            return Page(tuple(c.gather(perm) for c in self.columns), count)
+            head = perm[:capacity]
+            return Page(tuple(c.gather(head) for c in self.columns), count)
 
     def gather(self, indices: jnp.ndarray, count) -> "Page":
         self._require_compact("gather")
