@@ -249,3 +249,81 @@ def test_per_device_enforcement_for_measured_budgets():
         assert all(v == 0 for v in pool.device_reserved.values())
     finally:
         assert ctx.close() == 0
+
+
+def _device_refusal():
+    import jax
+    return jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting "
+        "to allocate 1.50G. That was not possible. There are 1.2G free.")
+
+
+@pytest.mark.parametrize("make, name, retryable", [
+    (_device_refusal, "EXCEEDED_DEVICE_MEMORY_LIMIT", True),
+    (lambda: type(_device_refusal())("INVALID_ARGUMENT: bad shape"),
+     "GENERIC_INTERNAL_ERROR", False),
+    (lambda: RuntimeError("RESOURCE_EXHAUSTED: not XLA's"),
+     "GENERIC_INTERNAL_ERROR", False),
+], ids=["resource_exhausted", "another_status", "another_class"])
+def test_device_refusal_classifies_as_the_memory_error(make, name,
+                                                       retryable):
+    """An XLA runtime error whose status is RESOURCE_EXHAUSTED is the
+    engine's retryable memory error; other statuses and other classes
+    stay internal errors."""
+    from trino_tpu.errors import classify, is_retryable
+    code = classify(make())
+    assert code.name == name and code.retryable is retryable
+    assert is_retryable(make()) is retryable
+    if retryable:
+        assert code.type == "INSUFFICIENT_RESOURCES"
+
+
+@pytest.mark.parametrize("policy", ["NONE", "QUERY"])
+def test_device_refusal_is_counted_and_releases_the_ledger(policy,
+                                                           monkeypatch):
+    """A dispatch the device refuses fails the query under its own name
+    with `device_oom_errors` 1 and nothing left reserved; under
+    retry_policy=QUERY the query is run again and answers."""
+    from trino_tpu.exec.query_tracker import TRACKER
+    r = LocalQueryRunner.tpch("tiny")
+    r.execute(f"SET SESSION retry_policy = '{policy}'")
+    run, refused = LocalQueryRunner._execute_statement, []
+
+    def refuse_once(self, stmt):
+        if not refused:
+            refused.append(stmt)
+            self._memory.reserve(4096, "collect")
+            raise _device_refusal()
+        return run(self, stmt)
+    monkeypatch.setattr(LocalQueryRunner, "_execute_statement", refuse_once)
+    sql = "SELECT count(*) FROM nation"
+    if policy == "NONE":
+        with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+            r.execute(sql, query_id="refused_none")
+    else:
+        assert r.execute(sql, query_id="refused_query").rows == [(25,)]
+    info = next(q for q in TRACKER.list()
+                if q.query_id == f"refused_{policy.lower()}")
+    assert info.stats["device_oom_errors"] == 1
+    assert info.stats["memory_kills"] == 0
+    assert info.stats["retries"] == (policy == "QUERY")
+    if policy == "NONE":
+        assert info.state == "FAILED"
+        assert info.error_name == "EXCEEDED_DEVICE_MEMORY_LIMIT"
+    assert NODE_POOL.reserved == 0
+
+
+def test_the_killers_victim_says_so_in_its_stats():
+    """A query alone in a pool too small for it is its own victim:
+    `memory_kills` 1 in the stats `GET /v1/query/<id>` serves."""
+    from trino_tpu.exec.query_tracker import TRACKER
+    r = LocalQueryRunner.tpch("tiny")
+    kills = NODE_POOL.kills
+    with NODE_POOL.limited(8 << 10):
+        with pytest.raises(ClusterOutOfMemoryError):
+            r.execute("SELECT c_custkey FROM customer ORDER BY c_acctbal",
+                      query_id="own_victim")
+    info = next(q for q in TRACKER.list() if q.query_id == "own_victim")
+    assert info.stats["memory_kills"] == 1 == NODE_POOL.kills - kills
+    assert info.stats["device_oom_errors"] == 0
+    assert NODE_POOL.reserved == 0

@@ -93,6 +93,14 @@ CLUSTER_OUT_OF_MEMORY = ErrorCode("CLUSTER_OUT_OF_MEMORY", 131076,
                                   INSUFFICIENT_RESOURCES, retryable=True)
 EXCEEDED_LOCAL_MEMORY_LIMIT = ErrorCode(
     "EXCEEDED_LOCAL_MEMORY_LIMIT", 131079, INSUFFICIENT_RESOURCES)
+# the device itself refused an allocation (XLA's RESOURCE_EXHAUSTED: the
+# ledger of collected pages does not see a program's temporaries, so the
+# node pool can admit what the HBM cannot hold). Retryable like the
+# killer's verdict: the memory is free again once the competing queries
+# finish. An engine-own code, past the reference's last (131083)
+EXCEEDED_DEVICE_MEMORY_LIMIT = ErrorCode(
+    "EXCEEDED_DEVICE_MEMORY_LIMIT", 131172, INSUFFICIENT_RESOURCES,
+    retryable=True)
 # spill partition stores exhausted their host-RAM byte budget
 # (`spill_max_bytes`): NOT retryable — a re-run would spill the same
 # bytes again (the reference's ExceededSpillLimitException contract)
@@ -196,7 +204,19 @@ def classify(exc: BaseException) -> ErrorCode:
         return NOT_FOUND
     if isinstance(exc, ZeroDivisionError):
         return DIVISION_BY_ZERO
+    if _is_device_out_of_memory(exc):
+        return EXCEEDED_DEVICE_MEMORY_LIMIT
     return GENERIC_INTERNAL_ERROR
+
+
+def _is_device_out_of_memory(exc: BaseException) -> bool:
+    """An XLA runtime error whose status is RESOURCE_EXHAUSTED: the
+    device could not allocate a program's buffers. Told by the class's
+    name and the status that leads its message, so this module imports
+    no jaxlib."""
+    return any(c.__name__ in ("XlaRuntimeError", "JaxRuntimeError")
+               for c in type(exc).__mro__) \
+        and "RESOURCE_EXHAUSTED" in str(exc)[:200]
 
 
 def is_retryable(exc: BaseException) -> bool:

@@ -258,7 +258,8 @@ class LocalQueryRunner:
         execution paths). `queued_at`/`dequeued_at` are the server's
         `time.monotonic()` stamps of the submit and of the executor
         thread taking the query: the `queued` span of its stats."""
-        from trino_tpu.errors import (QueryCanceledError, classify,
+        from trino_tpu.errors import (EXCEEDED_DEVICE_MEMORY_LIMIT,
+                                      QueryCanceledError, classify,
                                       is_retryable)
         from trino_tpu.exec.deadline import QueryDeadline
         from trino_tpu.exec.faults import FaultInjector
@@ -377,6 +378,8 @@ class LocalQueryRunner:
                         result = self._execute_statement(stmt)
                     break
                 except Exception as e:
+                    if classify(e) is EXCEEDED_DEVICE_MEMORY_LIMIT:
+                        self._collector.device_oom_errors += 1
                     if self._sink is not None and self._sink.emitted:
                         # rows already left through the result stream: a
                         # re-run would duplicate them client-side (the
@@ -501,6 +504,8 @@ class LocalQueryRunner:
         info.retries = self._retries
         info.faults_injected = faults
         col = self._collector
+        if col is not None and self._memory is not None:
+            col.memory_kills = self._memory.kills
         if col is not None and self._slices is not None:
             col.slices_executed = self._slices.slices_executed
         if col is not None and self._ckpts is not None:

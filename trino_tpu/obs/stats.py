@@ -241,6 +241,12 @@ class QueryStatsCollector:
         self.join_recursions = 0
         self.heavy_key_splits = 0
         self.spill_fallbacks = 0
+        # memory pressure that ended an attempt of the query: times the
+        # node pool's low-memory killer chose it (exec/memory.py), and
+        # allocations the device itself refused (XLA RESOURCE_EXHAUSTED,
+        # errors.EXCEEDED_DEVICE_MEMORY_LIMIT)
+        self.memory_kills = 0
+        self.device_oom_errors = 0
 
     # ----------------------------------------------------------- spans
 
@@ -534,6 +540,8 @@ class QueryStatsCollector:
             "join_recursions": self.join_recursions,
             "heavy_key_splits": self.heavy_key_splits,
             "spill_fallbacks": self.spill_fallbacks,
+            "memory_kills": self.memory_kills,
+            "device_oom_errors": self.device_oom_errors,
         }
         if self.operators:
             snap["operators"] = self.operator_rows()
