@@ -1,6 +1,6 @@
 """Deferred compaction (PR 26): a fused chain of lane-wise steps that ends in
 the partial hash aggregate runs its filters as a selection mask — no
-`compact_slots`, no `compact_gather` — and must aggregate exactly the rows
+`compact_slots`, no `compact_shift` (until PR 35: `compact_gather`) — and must aggregate exactly the rows
 the compacting chain aggregates.
 
 Each case runs the chain three ways and compares row for row:
@@ -336,7 +336,8 @@ def test_deferred_chain_aggregates_the_rows_the_compacting_chain_does(name):
         assert deferred_partial.selection is None
         text = jit_cache._CACHE[chain_key][0].lower(page, groups).as_text(
             debug_info=True)
-        assert "compact_gather" not in text and "compact_slots" not in text
+        for tag in ("compact_gather", "compact_slots", "compact_shift"):
+            assert tag not in text, tag
         # a dictionary key's four slots reduce under slot masks (PR 29)
         assert ("aggregate__direct_masked_reduce" in text) \
             == (key_channels == (FLAG,))

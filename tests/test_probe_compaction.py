@@ -93,8 +93,10 @@ def test_compact_to_is_filter_then_shrink_column_for_column(kind, cap):
         assert len(_arrays(g)) == len(_arrays(w)) == len(_arrays(src))
         for ga, wa in zip(_arrays(g), _arrays(w)):
             assert ga.shape == wa.shape and ga.dtype == wa.dtype
-            # every lane, the padding behind num_rows too
-            np.testing.assert_array_equal(np.asarray(ga), np.asarray(wa))
+            # the contract: the kept prefix, in order (what lies behind
+            # num_rows is each form's own leftovers)
+            np.testing.assert_array_equal(np.asarray(ga)[:count],
+                                          np.asarray(wa)[:count])
     # and the kept rows are the input's, in input order
     keep = np.flatnonzero(np.asarray(mask)[:ROWS])
     np.testing.assert_array_equal(
@@ -125,9 +127,9 @@ def test_compact_to_never_defers():
 
 def test_tight_program_keeps_the_old_scopes_and_gathers_at_the_rung():
     """Under the probe compaction's program name the two phases are
-    `join__compact_slots` / `join__compact_gather`, as `filter`'s are, so a
-    device trace reads the new form under the old names; and no gather of
-    the program is as long as the page."""
+    `join__compact_slots` / `join__compact_gather`, as `filter`'s were
+    until PR 35, so a device trace reads the new form under the old names;
+    and no gather of the program is as long as the page."""
     import re
 
     from trino_tpu.exec import jit_cache
@@ -141,3 +143,21 @@ def test_tight_program_keeps_the_old_scopes_and_gathers_at_the_rung():
     sizes = [int(n) for n in re.findall(
         r'"stablehlo\.gather"\(.*?\) -> tensor<(\d+)x', text, flags=re.S)]
     assert sizes and set(sizes) == {1024}, sizes
+
+
+def test_full_program_shifts_and_gathers_nothing():
+    """The full-capacity case of `_compact_counted` is `Page.filter` under
+    the same program name: `join__compact_slots`, then the shift-and-select
+    rounds as `join__compact_shift` (PR 35) — no index, so no gather, no
+    scatter and no sort."""
+    from trino_tpu.exec import jit_cache
+    key = ("probe-compact",)
+    assert jit_cache.program_name(key) == "join__probe_compact"
+    program = jit_cache.named(lambda p, m: p.filter(m), key)
+    text = jax.jit(program).lower(_page(), _mask("dense")).as_text(
+        debug_info=True)
+    assert "jit(join__probe_compact)/join__compact_slots" in text
+    assert "jit(join__probe_compact)/join__compact_shift" in text
+    for op in ("compact_gather", "stablehlo.gather", "stablehlo.scatter",
+               "stablehlo.sort"):
+        assert op not in text, op

@@ -161,7 +161,8 @@ def test_chain_steps_and_tail_each_have_a_scope():
         assert f"/{scope}/" in text, scope
     # the partial aggregate takes the filter's mask as a selection: the
     # chain compacts nothing (PR 26)
-    assert "compact_gather" not in text and "compact_slots" not in text
+    for tag in ("compact_gather", "compact_slots", "compact_shift"):
+        assert tag not in text, tag
     # shared kernels take the family of the operator that called them
     assert "aggregate__agg_partial/aggregate__group_sort/" \
            "aggregate__radix_pass" in text
@@ -182,7 +183,11 @@ def test_chains_without_a_partial_aggregate_tail_still_compact(tail,
     text = _chain_text(tail)
     assert f"jit({program})/" in text
     assert "scan_filter__filter/scan_filter__compact_slots" in text
-    assert "scan_filter__filter/scan_filter__compact_gather" in text
+    assert "scan_filter__filter/scan_filter__compact_shift" in text
+    # `filter` moves rows by shift-and-select (PR 35): no index
+    assert "compact_gather" not in text
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.scatter" not in text and "stablehlo.sort" not in text
 
 
 def test_scopes_do_not_change_the_program():
@@ -261,7 +266,8 @@ def test_a_mesh_program_names_every_op(monkeypatch):
         assert any(scope in n.split("/") for n in body), scope
     # q3's three filters feed hash repartitions, which drop dead rows as
     # they bucket them: none compacts its page first
-    assert not any("scan_filter__compact_gather" in n for n in body)
+    for tag in ("compact_gather", "compact_slots", "compact_shift"):
+        assert not any(f"scan_filter__{tag}" in n for n in body), tag
     # (XLA keeps a few ops of inner jits — `cummin`'s windows — under
     # their primitive's bare name: too few to matter to a trace)
     bare = {n for n in body

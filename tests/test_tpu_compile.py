@@ -181,6 +181,38 @@ def test_probe_compaction_at_the_kept_rung(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
 
 
+@pytest.mark.parametrize("lanes, columns, nullable", [
+    pytest.param(SCAN_WIDTH, 4, False, id="q3-chain-4xbigint"),
+    pytest.param(SCAN_WIDTH, 4, True, id="q3-chain-one-nullable"),
+    pytest.param(1 << 25, 5, False, id="verified-probe-33M-5xbigint")])
+def test_filter_compacts_with_no_index(one_chip, lanes, columns, nullable):
+    """`Page.filter`'s shift-and-select compaction (PR 35) at q3's chain
+    filter shape (one scan page of four BIGINT columns) and at the widest
+    page any site filters inside a program (ops/join.py's verified probes:
+    33 554 432 lanes x 5 BIGINT): seconds to compile, and no gather, no
+    scatter and no sort in the compiled program — a row moves by selects
+    between an array and itself shifted by a static power of two. Every
+    array runs its rounds alone, one round at a time
+    (`optimization_barrier`), so the temporaries are two copies of a
+    column and of `d` and its bits, not of the page (all arrays a round,
+    overlapped: three copies, 3.36 GB at 33 M x 5): under half the
+    operand and half a GB, well inside ISSUE 35's twice the operand and
+    half a GB — `sf10-throughput-s3` runs at 16.0 of the chip's 16.9 GB
+    and a refused allocation there is a failed request."""
+    page = _page(one_chip, lanes, (T.BIGINT,) * columns)
+    mask = jax.ShapeDtypeStruct((lanes,), jnp.bool_, sharding=one_chip)
+    if nullable:
+        first = page.columns[0].with_valid(mask)
+        page = Page((first,) + page.columns[1:], page.num_rows)
+    compiled = _compile(lambda p, m: p.filter(m), page, mask, limit_s=60)
+    text = compiled.as_text()
+    for op in (" gather(", " scatter(", " sort("):
+        assert op not in text, op
+    operand = lanes * (8 * columns + nullable)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < operand // 2 + (1 << 29)
+
+
 def test_mesh_all_to_all_on_four_chips(topo):
     """One mesh program for the four described chips: the hash
     repartition exchange must stay a collective inside the program."""

@@ -381,6 +381,13 @@ def _running_count(mask: jnp.ndarray) -> jnp.ndarray:
     return (inner + (jnp.cumsum(totals) - totals)[:, None]).reshape(n)
 
 
+def _shift_left(x: jnp.ndarray, s: int) -> jnp.ndarray:
+    """Lane p takes lane p + s along axis 0 (s static); the tail takes
+    zeros."""
+    tail = jnp.zeros((s,) + x.shape[1:], x.dtype)
+    return jnp.concatenate([x[s:], tail], axis=0)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class Page:
@@ -466,24 +473,88 @@ class Page:
         the SF10 scan cell's device time (PERF.md, PR 25), are not
         emitted at all.
 
-        Compaction: a stable partition without a sort (`_partition_perm`),
-        then every column gathered through the whole permutation — one
-        index per lane of the page, kept or not, and the gather costs by
-        the index (PERF.md, PR 31). Carrying the columns as payload of
-        one `lax.sort` runs faster (1.4 ms against 8 ms for eight columns
-        of 65 536 rows on a v5e) but the TPU compiler builds a sort
+        Compaction: shift-and-select, with no index. A kept row moves
+        left by d = the number of dropped rows before it, and d never
+        falls from one kept row to the next. In round k (the low bit
+        first) every kept row whose d has bit k set moves left by
+        s = 2^k: each array of each column becomes
+        `where(take, x shifted by s, x)`, a lane-wise select between an
+        array and itself shifted by a static power of two. log2(capacity)
+        rounds; no permutation, no scatter, no sort, no gather.
+        Kept rows never collide: for kept i < j the lanes between them
+        hold j - i - 1 >= d_j - d_i - 1 other rows, so
+        j - i >= 1 + d_j - d_i; after the rounds below k row i sits at
+        i - (d_i mod 2^k), and (d_j mod 2^k) - (d_i mod 2^k) is
+        d_j - d_i or less, so j still sits to the right of i, and a row
+        that moves in round k lands on a lane whose own row moves too or
+        that no row holds. `d` rides along and reads 0 on a lane that
+        holds no kept row, so such a lane is never taken from. Only d
+        has to run the rounds to say who takes when; its answers are
+        kept as the bits of one int32 a lane, and every array of every
+        column (`values`, `valid`, `lengths`, `aux`; 2-D planes along
+        axis 0) then moves alone: a round reads the bits and reads and
+        writes the array and its shifted copy, 36 B a lane of an int64
+        column, 20 rounds at 1 048 576 lanes. One array at a time keeps
+        the temporaries at two copies of a column, not of the page
+        (0.54 GB for 33 554 432 lanes x 5 int64, where the permutation
+        and its gathers took 0.40). What lies behind the kept prefix is
+        whatever the rounds left there.
+        On a v5e, fenced (PERF.md, PR 35, step 0): one page of 1 048 576
+        lanes x 4 int64 at keep share 0.54 takes 2.0 ms (2.7 s to
+        compile) against 72.7 ms (1.8 s) for the permutation and its
+        gathers, 2.3 ms (5.7 s) against 83.0 ms with one validity mask,
+        whatever the keep share (no round reads the data it moves);
+        33 554 432 lanes x 5 int64 take 312 ms against 8 617 ms (all
+        arrays a round: 289 ms, at 2.96 GB of temporaries). Inside q3's
+        chains a page's rounds are 0.5 ms of device time.
+        Roads not taken: a permutation (a running count and an int32
+        scatter) with one gather per column costs by the index, 18-25 M
+        elements/s on a v5e whatever the operand (PERF.md, PR 31) — it
+        is `compact_to`'s form, where the host's count cuts the indices
+        to the kept rung. Carrying the columns as payload of one
+        `lax.sort` ran at 1.4 ms against the gather's 8 ms for eight
+        columns of 65 536 rows, but the TPU compiler builds a sort
         network per operand: 290 s of compile time for five int64
-        operands, paid in EVERY fused chain, against a second for this
-        form (PR 23).
+        operands, paid in EVERY fused chain (PR 23).
         """
         mask = mask & self.row_mask()
         if getattr(_THREAD, "defer", False):
             return self.with_selection(mask)
         if not self.columns:
             return Page((), jnp.sum(mask).astype(jnp.int32))
-        perm, count = self._partition_perm(mask)
-        with shared_scope("compact_gather"):
-            return Page(tuple(c.gather(perm) for c in self.columns), count)
+        capacity = self.capacity
+        with shared_scope("compact_slots"):
+            kept = _running_count(mask)
+            count = kept[-1]
+            lane = jnp.arange(1, capacity + 1, dtype=jnp.int32)
+            d = jnp.where(mask, lane - kept, 0)
+        with shared_scope("compact_shift"):
+            # d's own rounds say which lane takes in which round: bit k of
+            # `takes`. Then every array moves alone, a round at a time
+            # (the barriers): the scheduler holds two copies of one array
+            # and not of the page (tests/test_tpu_compile.py)
+            takes = jnp.zeros(capacity, dtype=jnp.int32)
+            s = 1
+            while s < capacity:
+                d_left = _shift_left(d, s)
+                take = (d_left & s) != 0
+                takes = takes | jnp.where(take, s, 0)
+                d = jnp.where(take, d_left, jnp.where((d & s) != 0, 0, d))
+                d, takes = jax.lax.optimization_barrier((d, takes))
+                s *= 2
+            arrays, tree = jax.tree_util.tree_flatten(self.columns)
+            moved = []
+            for a in arrays:
+                lanes = (capacity,) + (1,) * (a.ndim - 1)
+                s = 1
+                while s < capacity:
+                    take = ((takes & s) != 0).reshape(lanes)
+                    a = jnp.where(take, _shift_left(a, s), a)
+                    a, takes = jax.lax.optimization_barrier((a, takes))
+                    s *= 2
+                moved.append(a)
+            columns = jax.tree_util.tree_unflatten(tree, moved)
+        return Page(columns, count)
 
     def _partition_perm(self, mask: jnp.ndarray):
         """(permutation, kept count) of a stable partition by `mask`: a
