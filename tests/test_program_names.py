@@ -26,12 +26,13 @@ KNOWN_TAGS = {
                     "tpch-generate-pooled", "tpch-generate-oidx"],
     "aggregate": ["agg-partial", "agg-bypass", "agg-final",
                   "agg-intermediate", "agg-single", "agg-groupmax",
-                  "agg-spill-part"],
+                  "agg-spill-part", "agg-having"],
     "join": ["join", "join-prep", "join-spill-part", "uprobe", "uattach",
              "semijoin", "markjoin", "fulljoin", "cross-attach",
              "dense-table", "dense-table-rows", "dfbounds", "dfrange",
              "dfrange-mask", "probe-compact", "spill-prep", "spill-probe",
-             "spill-probe-dense"],
+             "spill-probe-dense", "semijoin-prep",
+             "semijoin-dense-table"],
     "sort": ["sort", "sort-spill-bounds", "sort-spill-part",
              "sort-spill-rank", "topn-masked", "topn", "merge-sort"],
     "window": ["window"],
@@ -287,3 +288,186 @@ def test_a_mesh_program_names_every_op(monkeypatch):
         if op.startswith(("sort", "scatter", "gather", "cumsum")):
             assert scopes[-1] not in ("exchange__all_to_all",
                                       "exchange__broadcast"), name
+
+
+# ---------------------------------------- semi and mark joins (PR 37)
+
+SEMI_SCOPES = ("join__semi_probe", "join__mark_probe", "join__semi_build")
+
+
+def _join_text(join_type, semi):
+    """Lowered text of one join over a prepared build, built from the
+    operators the planner builds it from (`_prepare_build` and
+    `_exec_semijoin_filter` / `_exec_SemiJoinNode` / `_exec_JoinNode`)."""
+    from trino_tpu.ops.join import hash_join, prepare_build
+
+    def run(probe, build):
+        prepared = prepare_build([0], semi)(build)
+        return hash_join([0], [0], join_type, prepared=True)(probe, prepared)
+    probe = Page.from_numpy([jnp.arange(64) % 7, jnp.arange(64)],
+                            [T.BIGINT, T.BIGINT])
+    build = Page.from_numpy([jnp.arange(16) % 5], [T.BIGINT])
+    key = ("semijoin" if semi else "join", join_type)
+    return jax.jit(jit_cache.named(run, key)).lower(probe, build) \
+        .as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("join_type, probe_scope", [
+    ("semi", "join__semi_probe"), ("anti", "join__semi_probe"),
+    ("mark", "join__mark_probe")])
+def test_semi_and_mark_joins_carry_scopes_of_their_own(join_type,
+                                                       probe_scope):
+    """A trace tells a semi, anti or mark join's lookup and build from an
+    inner join's: their scopes are their own, under a program of the
+    `join__semijoin` / `join__markjoin` names."""
+    text = _join_text(join_type, semi=True)
+    assert "jit(join__semijoin)/" in text
+    assert f"/{probe_scope}/" in text
+    assert "/join__semi_build/" in text
+    # the build's radix passes are the family's shared kernel, under it
+    assert "join__semi_build/join__radix_pass" in text
+    for scope in ("join__probe_lookup", "join__build_sort",
+                  "join__build_runs"):
+        assert scope not in text, scope
+    for scope in set(re.findall(r"(?<=/)[a-z_]+__[a-z0-9_]+(?=/)", text)):
+        assert jit_cache.NAME_GRAMMAR.match(scope), scope
+
+
+@pytest.mark.parametrize("join_type", ["inner", "left"])
+def test_no_inner_join_carries_a_semi_scope(join_type):
+    text = _join_text(join_type, semi=False)
+    assert "/join__probe_lookup/" in text and "/join__build_sort/" in text
+    for scope in SEMI_SCOPES:
+        assert scope not in text, scope
+
+
+def test_the_dense_table_of_a_semi_join_is_its_build():
+    from trino_tpu.ops.join import build_dense_table
+    args = (jnp.arange(16, dtype=jnp.uint64), jnp.int32(16), jnp.uint64(0))
+    semi = jax.jit(build_dense_table(32, True)).lower(*args) \
+        .as_text(debug_info=True)
+    inner = jax.jit(build_dense_table(32)).lower(*args) \
+        .as_text(debug_info=True)
+    assert "join__semi_build" in semi
+    assert "join__build_dense_table" not in semi
+    assert "join__build_dense_table" in inner
+    assert "join__semi_build" not in inner
+
+
+def test_having_is_a_step_of_the_aggregate_family():
+    """The filter over an aggregation's output (HAVING) is keyed and
+    scoped `agg-having`; any other filter stays `filter`."""
+    from trino_tpu.obs.stats import QueryStatsCollector
+    seen = set()
+    tpch = LocalQueryRunner.tpch("tiny")
+    orig_hit, orig_miss = (QueryStatsCollector.jit_hit,
+                           QueryStatsCollector.jit_miss)
+    try:
+        QueryStatsCollector.jit_hit = \
+            lambda self, key=None: seen.add(key) or orig_hit(self, key)
+        QueryStatsCollector.jit_miss = \
+            lambda self, key=None: seen.add(key) or orig_miss(self, key)
+        tpch.execute("SELECT l_orderkey FROM lineitem WHERE l_tax > 0.01 "
+                     "GROUP BY l_orderkey HAVING sum(l_quantity) > 250")
+    finally:
+        QueryStatsCollector.jit_hit = orig_hit
+        QueryStatsCollector.jit_miss = orig_miss
+    names = {jit_cache.program_name(k) for k in seen}
+    having = [n for n in names if "agg_having" in n]
+    assert having and all(n.startswith("aggregate__chain_agg_having")
+                          for n in having), sorted(names)
+    assert any(n.startswith("aggregate__chain_filter") for n in names)
+    assert jit_cache.program_name((("agg-having", "x"),)) \
+        == "aggregate__agg_having"
+
+
+@pytest.fixture(scope="module")
+def tiny_columns():
+    from trino_tpu.connector import tpch_gen as G
+    rows = G.row_count("lineitem", 0.01)
+    return {
+        "late": G.numeric_chunk("lineitem", 0.01, "l_commitdate", 0, rows)
+        < G.numeric_chunk("lineitem", 0.01, "l_receiptdate", 0, rows),
+        "l_orderkey": G.numeric_chunk("lineitem", 0.01, "l_orderkey", 0,
+                                      rows),
+        "l_quantity": G.numeric_chunk("lineitem", 0.01, "l_quantity", 0,
+                                      rows),
+        "o_orderdate": G.numeric_chunk("orders", 0.01, "o_orderdate", 0,
+                                       15000)}
+
+
+def test_q4_counts_its_semi_join_and_its_groups(tiny_columns):
+    """EXISTS builds on the late lineitems and probes the quarter's
+    orders; the final aggregate emits the five priorities."""
+    import numpy as np
+    tpch = LocalQueryRunner.tpch("tiny")
+    tpch.execute("""
+        SELECT o_orderpriority, count(*) FROM orders
+        WHERE o_orderdate >= DATE '1993-07-01'
+          AND o_orderdate < DATE '1993-07-01' + INTERVAL '3' MONTH
+          AND EXISTS (SELECT * FROM lineitem WHERE l_orderkey = o_orderkey
+                      AND l_commitdate < l_receiptdate)
+        GROUP BY o_orderpriority ORDER BY o_orderpriority""")
+    stats = tpch.last_query_stats
+    lo = int(np.datetime64("1993-07-01", "D").astype(np.int64))
+    hi = int(np.datetime64("1993-10-01", "D").astype(np.int64))
+    dates = tiny_columns["o_orderdate"]
+    assert stats["semi_join_build_rows"] == int(tiny_columns["late"].sum())
+    assert stats["semi_join_probe_rows"] \
+        == int(((dates >= lo) & (dates < hi)).sum())
+    assert stats["aggregate_groups_out"] == 5
+
+
+def test_q18_counts_its_inner_groups_and_the_orders_it_probes(
+        tiny_columns):
+    """Q18's inner GROUP BY emits every order (15 000 at `tiny`), its
+    HAVING keeps a few dozen, the IN probes every order against those,
+    and the outer GROUP BY emits one group for each."""
+    import numpy as np
+    tpch = LocalQueryRunner.tpch("tiny")
+    got = tpch.execute("""
+        SELECT c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice,
+               sum(l_quantity)
+        FROM customer, orders, lineitem
+        WHERE o_orderkey IN (SELECT l_orderkey FROM lineitem
+                             GROUP BY l_orderkey
+                             HAVING sum(l_quantity) > 250)
+          AND c_custkey = o_custkey AND o_orderkey = l_orderkey
+        GROUP BY c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice
+        ORDER BY o_totalprice DESC, o_orderdate LIMIT 100""")
+    stats = tpch.last_query_stats
+    sums = np.bincount(tiny_columns["l_orderkey"],
+                       weights=tiny_columns["l_quantity"])
+    kept = int((sums > 25000).sum())
+    assert 1 <= kept <= 100 and len(got.rows) == kept
+    assert stats["semi_join_build_rows"] == kept
+    assert stats["semi_join_probe_rows"] == 15000
+    assert stats["aggregate_groups_out"] == 15000 + kept
+
+
+def test_a_cached_kernels_first_call_lies_under_a_compile_span():
+    """A `cached_kernel` program compiles inside its first call: that
+    call is a `compile` span of the calling query and the next is not;
+    the counters of the AOT sites do not move."""
+    from trino_tpu.obs.stats import QueryStatsCollector
+    key = ("semijoin", "compile-span-test")
+    jit_cache._CACHE.pop(key, None)
+    col = QueryStatsCollector("q-compile-span")
+    jit_cache.set_observer(col)
+    try:
+        with col.phase("execution"):
+            fn = jit_cache.cached_kernel(key, lambda: lambda x: x * 2 + 1)
+            assert int(fn(jnp.int32(3))) == 7
+            assert int(fn(jnp.int32(4))) == 9
+            again = jit_cache.cached_kernel(key, lambda: None)
+            assert int(again(jnp.int32(5))) == 11
+    finally:
+        jit_cache.set_observer(None)
+        jit_cache._CACHE.pop(key, None)
+    spans = [s for s in col.request_spans() if s[0] == "compile"]
+    assert len(spans) == 1
+    execution = next(s for s in col.request_spans() if s[0] == "execution")
+    assert execution[1] <= spans[0][1] <= spans[0][2] <= execution[2]
+    snap = col.snapshot()
+    assert snap["jit_compiles"] == 0 and snap["compile_time_ms"] == 0.0
+    assert snap["jit_misses"] == 1 and snap["jit_hits"] == 1

@@ -339,3 +339,100 @@ def test_q3_mesh_program_at_the_sf30_shard_shape(topo):
     # what the program holds beside the 2.7 GB of resident shards
     assert memory.temp_size_in_bytes + memory.output_size_in_bytes \
         < (9 << 30)
+
+
+# ------------------------------------- Q18 and Q4 at SF10 (PR 37)
+#
+# The shapes are the ones the chip ran (step 0 of PR 37): lineitem is one
+# scan page of 60 030 976 lanes, Q18's partial states tighten to 2^24
+# lanes for its 15 000 000 groups, Q4's build keeps the scan page's lanes
+# for its 37 927 020 late lines.
+
+LINEITEM_LANES = 60_030_976
+GROUP_LANES = 1 << 24
+DEVICE_BUDGET = 12 << 30            # ISSUE 37: `peak_hbm_GB` under 12
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.temp_size_in_bytes + m.argument_size_in_bytes
+            + m.output_size_in_bytes)
+
+
+def test_q18_final_aggregate_at_15m_groups(one_chip):
+    """GROUP BY l_orderkey, final step: 2^24 lanes of (key, sum) states
+    through the sorted path (past `_DIRECT_MAX_GROUPS`): radix passes,
+    boundary scan, segment reduce."""
+    from trino_tpu.ops import AggSpec, Step, hash_aggregate
+    from trino_tpu.ops.aggregate import get_aggregate
+    spec = AggSpec("sum", 1, D12_2)
+    states = get_aggregate("sum", D12_2).state(D12_2)
+    page = _page(one_chip, GROUP_LANES,
+                 (T.BIGINT,) + tuple(s.type for s in states))
+    op = hash_aggregate([0], [spec], Step.FINAL,
+                        [list(range(1, 1 + len(states)))])
+    compiled = _compile(op, page, limit_s=300)
+    assert _device_bytes(compiled) < DEVICE_BUDGET
+
+
+def test_q18_partial_aggregate_over_the_lineitem_page(one_chip):
+    """The same GROUP BY's partial step over the whole scan page: 60 M
+    lanes in, one state row a group."""
+    from trino_tpu.ops import AggSpec, Step, hash_aggregate
+    page = _page(one_chip, LINEITEM_LANES, (T.BIGINT, D12_2))
+    op = hash_aggregate([0], [AggSpec("sum", 1, D12_2)], Step.PARTIAL)
+    compiled = _compile(op, page, limit_s=300)
+    assert _device_bytes(compiled) < DEVICE_BUDGET
+
+
+def test_q18_probe_of_60m_lanes_against_a_hundred_orders(one_chip):
+    """Q18's outer join: every lineitem lane looked up in a build of the
+    ~100 orders the HAVING kept, whose keys span the table — the router's
+    `search` side, with no prefilter to help."""
+    from trino_tpu.ops.join import prepare_build, unique_inner_probe
+    build = _page(one_chip, 1024, (T.BIGINT, T.BIGINT, T.VARCHAR,
+                                   D12_2, T.DATE))
+    prepared = jax.eval_shape(prepare_build([0]), build)
+    probe = _page(one_chip, LINEITEM_LANES, (T.BIGINT, D12_2))
+    compiled = _compile(unique_inner_probe([0], [0], lookup="search"),
+                        probe, prepared, limit_s=300)
+    assert _device_bytes(compiled) < DEVICE_BUDGET
+
+
+def test_q4_semi_join_build_at_the_lineitem_page(one_chip):
+    """EXISTS builds on the filtering source: the late lineitems' keys,
+    collected into one page of the scan's 60 M lanes and sorted once
+    (`join__semi_build`), then a direct-address table over the 15 M
+    order keys they span."""
+    from trino_tpu.ops.join import build_dense_table, prepare_build
+    build = _page(one_chip, LINEITEM_LANES, (T.BIGINT,))
+    compiled = _compile(prepare_build([0], semi=True), build, limit_s=420)
+    assert _device_bytes(compiled) < DEVICE_BUDGET
+    assert "join__semi_build" in compiled.as_text()
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    table = _compile(build_dense_table(GROUP_LANES, semi=True),
+                     spec((LINEITEM_LANES,), jnp.uint64),
+                     spec((), jnp.int32), spec((), jnp.uint64), limit_s=120)
+    assert _device_bytes(table) < DEVICE_BUDGET
+
+
+def test_q4_semi_join_probe_of_a_quarters_orders(one_chip):
+    """The quarter's ~570 000 orders (2^20 lanes after the date filter)
+    probe that table: one gather a lane, then `Page.filter`'s shift
+    rounds over the three columns (ops/join.py's SEMI site)."""
+    from trino_tpu.ops.join import (JoinType, build_dense_table, hash_join,
+                                    prepare_build)
+    build = _page(one_chip, LINEITEM_LANES, (T.BIGINT,))
+    prepared = jax.eval_shape(prepare_build([0], semi=True), build)
+    table = jax.eval_shape(build_dense_table(GROUP_LANES, semi=True),
+                           prepared[1], prepared[3], prepared[8])
+    probe = _page(one_chip, 1 << 20, (T.BIGINT, T.DATE, T.VARCHAR))
+    op = hash_join([0], [0], JoinType.SEMI, output_capacity=1 << 20,
+                   prepared=True, lookup="dense", null_aware=False)
+    compiled = _compile(op, probe, prepared + (table,), limit_s=120)
+    text = compiled.as_text()
+    assert "join__semi_probe" in text
+    assert " sort(" not in text
+    assert _device_bytes(compiled) < DEVICE_BUDGET

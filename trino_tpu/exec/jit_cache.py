@@ -56,7 +56,8 @@ from trino_tpu.page import family_context, trace_notes
 
 # key -> [jitted kernel, last-seen flattened param signature or None,
 #         {input signature -> AOT compiled executable} (profiled path),
-#         {input signature -> what the program's trace noted} (`named`)]
+#         {input signature -> what the program's trace noted} (`named`),
+#         whether the kernel was called yet (`cached_kernel`'s first call)]
 _CACHE: "collections.OrderedDict[Hashable, list]" = \
     collections.OrderedDict()
 # concurrent queries (the server's executor pool) share this cache; the
@@ -222,7 +223,7 @@ def _lookup(key: Hashable, build: Callable[[], Callable],
             while len(_CACHE) >= _MAX_KERNELS:
                 _CACHE.popitem(last=False)
                 _STATS["evictions"] += 1
-            entry = _CACHE[key] = [fn, sig, {}, program.noted]
+            entry = _CACHE[key] = [fn, sig, {}, program.noted, False]
             _STATS["misses"] += 1
             miss = True
         else:
@@ -252,7 +253,8 @@ def cached_kernel(key: Hashable, build: Callable[[], Callable],
     to the kernel — used ONLY for hit attribution (param-hit vs plain hit),
     never for keying: the whole point is that the key excludes it.
     """
-    fn = _lookup(key, build, params)[0]
+    entry = _lookup(key, build, params)
+    fn = entry[0] if entry[4] else _first_call(entry)
     fenced = _fencing_observer()
     if fenced is None:
         return fn                   # the plain path: the jitted callable
@@ -260,6 +262,29 @@ def cached_kernel(key: Hashable, build: Callable[[], Callable],
     def dispatch(*args):
         return _timed(fn, args, fenced)
     return dispatch
+
+
+def _first_call(entry: list) -> Callable:
+    """A kernel nobody called yet: its first call traces and compiles
+    inside `jax.jit`, so that call lies under a `compile` span of the
+    calling query (obs/stats.compile_span) — the device's idle time in it
+    is a cold program's, not dispatch. Every later call, and every later
+    lookup, is the jitted callable's own."""
+    fn = entry[0]
+
+    def call(*args):
+        if entry[4]:
+            return fn(*args)
+        entry[4] = True
+        span = getattr(get_observer(), "compile_span", None)
+        if span is None:
+            return fn(*args)
+        t0 = time.monotonic()
+        try:
+            return fn(*args)
+        finally:
+            span(t0, time.monotonic())
+    return call
 
 
 def _fencing_observer():
@@ -326,7 +351,7 @@ def profiled_kernel(key: Hashable, build: Callable[[], Callable],
     hidden inside jax.jit's first call. Same key space, same hit/miss/
     param-hit counters as cached_kernel — a key warmed by one path is
     warm for the other."""
-    fn, _, aot, noted = _lookup(key, build, params)
+    fn, _, aot, noted, _ = _lookup(key, build, params)
     fenced = _fencing_observer()
     from trino_tpu.obs import profiler
 

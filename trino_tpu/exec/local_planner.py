@@ -142,9 +142,12 @@ def chain_params(pending) -> Tuple:
     return tuple(tuple(e[2]) for e in pending)
 
 
+# a filter step by either of its tags: `agg-having` is the filter over an
+# aggregation's output, named for the family whose groups it judges
+_FILTER_STEPS = frozenset({"filter", "agg-having"})
 # chain steps that touch each lane on its own (no row moves, no row is
 # read by position): the only steps a deferred filter may sit among
-_LANE_WISE_STEPS = frozenset({"filter", "project", "select"})
+_LANE_WISE_STEPS = _FILTER_STEPS | {"project", "select"}
 
 
 def chain_defers_compaction(key) -> bool:
@@ -236,7 +239,7 @@ def compose_chain(pending, tail_key=None, tail_builder=None,
             return page
         return run
     kernel = profiled_kernel(key, build, params=param_groups)
-    if any(key_tag(k) == "filter" for k in key[1:]):
+    if any(key_tag(k) in _FILTER_STEPS for k in key[1:]):
         deferred = chain_defers_compaction(key)
         dispatch = kernel
 
@@ -431,6 +434,18 @@ class LocalExecutionPlanner:
         col = self.collector
         if col is not None:
             setattr(col, name, getattr(col, name) + n)
+
+    def _count_rows(self, name: str, num_rows) -> None:
+        """Add a page's row count to the query's counter `name`; a count
+        still on the device is read at the query's end, not here."""
+        if self.collector is not None:
+            self.collector.count_rows(name, num_rows)
+
+    def _counted(self, stream: "PageStream", name: str) -> Iterator[Page]:
+        """`stream`'s pages, their rows counted under `name`."""
+        for page in stream.iter_pages():
+            self._count_rows(name, page.num_rows)
+            yield page
 
     def _adaptive_span(self, name: str, **attrs) -> None:
         """Emit an instantaneous strategy-switch trace span: every
@@ -831,9 +846,11 @@ class LocalExecutionPlanner:
         src = self.execute(node.source)
         lay, typ = _layout(src.symbols)
         pred, prm = self._hoist(lower_expr(node.predicate, lay, typ))
+        tag = "agg-having" if isinstance(node.source, AggregationNode) \
+            else "filter"
         return PageStream(
             src.pages, src.symbols,
-            src.pending + ((("filter", pred),
+            src.pending + (((tag, pred),
                             lambda: lambda p, g, f=compile_filter(pred):
                             p.filter(f(p, g)), prm),))
 
@@ -1031,7 +1048,16 @@ class LocalExecutionPlanner:
 
     def _merge_buf(self, buf: List[Page], rows: int) -> Page:
         page = buf[0] if len(buf) == 1 else self._device_concat(buf)
-        return self._tight(page, rows)
+        page = self._tight(page, rows)
+        if isinstance(page.num_rows, int):
+            # a scan page counts its rows in a Python int (a weak int64
+            # to a trace), a concatenation in an int32: whether a table
+            # arrives as one page or as two depends on the slice budget,
+            # which the clock retunes (exec/sliced/scheduler.py), so a
+            # blocking operator would compile twice for one shape — TPC-H
+            # Q18's customer build did, 37 s into its second execution
+            page = Page(page.columns, np.int32(page.num_rows))
+        return page
 
     def _free_collected(self, page: Optional[Page]) -> None:
         """Release a _collect reservation at operator scope (the reference
@@ -1142,10 +1168,15 @@ class LocalExecutionPlanner:
             state_channels.append(list(range(ch, ch + k)))
             ch += k
         final_keys = list(range(nkeys))
-        final_op = cached_kernel(
+        final_kernel = cached_kernel(
             ("agg-final", nkeys, specs_t),
             lambda: hash_aggregate(final_keys, specs, Step.FINAL,
                                    state_channels))
+
+        def final_op(page: Page) -> Page:
+            out = final_kernel(page)
+            self._count_rows("aggregate_groups_out", out.num_rows)
+            return out
 
         intermediate_op = cached_kernel(
             ("agg-intermediate", nkeys, specs_t),
@@ -2548,18 +2579,20 @@ class LocalExecutionPlanner:
                 yield Page(tuple(cols), page.num_rows) if changed else page
         return PageStream(gen(), probe_stream.symbols)
 
-    def _prepare_build(self, build_keys, build_page):
+    def _prepare_build(self, build_keys, build_page, semi: bool = False):
         """Sort the build side ONCE per join (LookupSourceFactory analog) —
-        probe-page kernels consume the prepared tuple without re-sorting."""
-        prep = cached_kernel(("join-prep", tuple(build_keys)),
-                             lambda: prepare_build(build_keys))
+        probe-page kernels consume the prepared tuple without re-sorting.
+        `semi`: a semi, anti or mark join's, a program of its own name."""
+        prep = cached_kernel(
+            ("semijoin-prep" if semi else "join-prep", tuple(build_keys)),
+            lambda: prepare_build(build_keys, semi))
         return prep(build_page)
 
     # direct-address tables: pow2 sizes bound compile-shape diversity; the
     # slot cap bounds HBM (64M slots = 256MB int32 for in-memory builds)
     _DENSE_MAX_SLOTS = 1 << 26
 
-    def _prepare_probe(self, build_keys, build_page):
+    def _prepare_probe(self, build_keys, build_page, semi: bool = False):
         """prepare_build + the ONE probe-lookup decision of every join,
         in memory or spilled: fetch (max_run, kmin, kmax) in one round
         trip; when the live-key span is small (dense surrogate keys —
@@ -2569,7 +2602,7 @@ class LocalExecutionPlanner:
 
         Returns (prepared [+ table], max_run, lookup)."""
         from trino_tpu.ops.join import build_dense_table
-        prepared = self._prepare_build(build_keys, build_page)
+        prepared = self._prepare_build(build_keys, build_page, semi)
         max_run, kmin, kmax = (int(x) for x in jax.device_get(
             [prepared[7], prepared[8], prepared[9]]))
         span = kmax - kmin + 1 if kmax >= kmin else 0
@@ -2579,8 +2612,8 @@ class LocalExecutionPlanner:
             return prepared, max_run, "search"
         size = _next_pow2(span)
         table_op = cached_kernel(
-            ("dense-table", size),
-            lambda: build_dense_table(size))
+            ("semijoin-dense-table" if semi else "dense-table", size),
+            lambda: build_dense_table(size, semi))
         table = table_op(prepared[1], prepared[3], prepared[8])
         return prepared + (table,), max_run, "dense"
 
@@ -2787,10 +2820,12 @@ class LocalExecutionPlanner:
                     return
                 bp = self._null_build_page(semi.filtering_source.outputs)
             try:
+                self._count_rows("semi_join_build_rows", bp.num_rows)
                 prepared, _max_run, mode = self._prepare_probe(
-                    build_keys, bp)
+                    build_keys, bp, semi=True)
                 yield from _run_with_overflow(
-                    self._coalesce_stream(probe_stream), prepared,
+                    self._counted(self._coalesce_stream(probe_stream),
+                                  "semi_join_probe_rows"), prepared,
                     lambda cap: semi_op(cap, mode), self.page_capacity)
             finally:
                 self._free_collected(build_page)
@@ -2831,10 +2866,12 @@ class LocalExecutionPlanner:
                     yield no_match(page)
                 return
             try:
+                self._count_rows("semi_join_build_rows", bp.num_rows)
                 prepared, _max_run, mode = self._prepare_probe(
-                    build_keys, bp)
+                    build_keys, bp, semi=True)
                 yield from _run_with_overflow(
-                    self._coalesce_stream(probe_stream), prepared,
+                    self._counted(self._coalesce_stream(probe_stream),
+                                  "semi_join_probe_rows"), prepared,
                     lambda cap: mark_op(cap, mode), self.page_capacity)
             finally:
                 self._free_collected(build_page)

@@ -325,7 +325,11 @@ class TableCache(_GenerationGuard):
             cap * (c.values.dtype.itemsize * math.prod(c.values.shape[1:])
                    + any(p.columns[i].valid is not None for p, _ in live))
             for i, c in enumerate(first.columns))
-        if need > self.max_bytes:
+        with self._lock:
+            # what the entry would score once admitted: used once, now
+            room = need <= self.max_bytes and self._room_locked(
+                need, (1, time.monotonic()))
+        if not room:
             _count("admission_denied")
             return False
         for i, (name, ch) in enumerate(symbols_cols):
@@ -376,6 +380,9 @@ class TableCache(_GenerationGuard):
                 self._release_locked(old)
             # budget first, then the chip's pool: a declined pool
             # reservation (HBM pressure from live queries) wins
+            if not self._room_locked(entry.nbytes, entry.score()):
+                _count("admission_denied")
+                return False
             self._evict_to_budget_locked(incoming=entry.nbytes)
             if not NODE_POOL.reserve_cache(entry.nbytes, entry.device):
                 _count("admission_denied")
@@ -394,6 +401,19 @@ class TableCache(_GenerationGuard):
         from trino_tpu.exec.memory import NODE_POOL
         self.resident_bytes -= entry.nbytes
         NODE_POOL.free_cache(entry.nbytes, entry.device)
+
+    def _room_locked(self, incoming: int, score: Tuple[int, float]) -> bool:
+        """Whether `incoming` bytes fit once every entry that scores below
+        `score` is gone. An entry earns its place: a newcomer pushes out
+        what is colder than itself and nothing hotter, so a working set
+        the size of the whole budget (SF10: two lineitem columns at their
+        pow2 envelope are 1 GiB to the byte) is refused while the tables
+        every query reads hold it, instead of wiping them on its second
+        scan and being wiped by the next query's set in turn — each
+        round a full-length copy, and a new page shape to compile for."""
+        colder = sum(e.nbytes for e in self._entries.values()
+                     if e.score() < score)
+        return self.resident_bytes - colder + incoming <= self.max_bytes
 
     def _evict_to_budget_locked(self, incoming: int = 0) -> None:
         while (self.resident_bytes + incoming > self.max_bytes

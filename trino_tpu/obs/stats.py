@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import numbers
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -247,6 +248,13 @@ class QueryStatsCollector:
         # errors.EXCEEDED_DEVICE_MEMORY_LIMIT)
         self.memory_kills = 0
         self.device_oom_errors = 0
+        # rows a semi, anti or mark join collected as its build and sent
+        # through its probe, and groups the final aggregates emitted (a
+        # state of the data, not a score): `count_rows`
+        self.semi_join_build_rows = 0
+        self.semi_join_probe_rows = 0
+        self.aggregate_groups_out = 0
+        self._rows_on_device: List[Tuple[str, Any]] = []
 
     # ----------------------------------------------------------- spans
 
@@ -335,6 +343,16 @@ class QueryStatsCollector:
         self.estimated_flops += float(flops)
         self.estimated_bytes += float(nbytes)
 
+    def compile_span(self, start_s: float, end_s: float) -> None:
+        """The first call of a `jit_cache.cached_kernel` program, which
+        traces and compiles inside `jax.jit` (times on `time.monotonic()`).
+        A span and nothing else: `compile_time_ms` and `jit_compiles` stay
+        what the AOT sites measured, since this wall holds the trace and
+        the dispatch too."""
+        self._stack[-1].children.append(Span(
+            "compile", kind="phase", start_s=float(start_s),
+            end_s=float(end_s), attrs={"first_call": True}))
+
     def plan_cache_hit(self) -> None:
         self.plan_cache_hits += 1
 
@@ -386,6 +404,23 @@ class QueryStatsCollector:
             self.probe_compactions_full += 1
         self.probe_compaction_lanes_in += int(lanes_in)
         self.probe_compaction_lanes_gathered += int(lanes_gathered)
+
+    def count_rows(self, name: str, num_rows) -> None:
+        """Add a page's row count to the counter `name`. A count that is
+        still a device scalar is kept as it is and read with the others
+        when the snapshot is taken: no sync on the path that counts."""
+        if isinstance(num_rows, numbers.Integral):
+            setattr(self, name, getattr(self, name) + int(num_rows))
+        else:
+            self._rows_on_device.append((name, num_rows))
+
+    def _read_rows_on_device(self) -> None:
+        if self._rows_on_device:
+            import jax
+            pending, self._rows_on_device = self._rows_on_device, []
+            for (name, _), n in zip(pending, jax.device_get(
+                    [n for _, n in pending])):
+                setattr(self, name, getattr(self, name) + int(n))
 
     def count_program_notes(self, notes) -> None:
         """One dispatch of a program whose trace noted `notes`
@@ -471,6 +506,7 @@ class QueryStatsCollector:
         what QueryInfo.stats and event payloads carry, and what
         benchmark/ reads."""
         spans = self.request_spans()
+        self._read_rows_on_device()
         snap: Dict[str, Any] = {
             "query_id": self.query_id,
             "wall_s": round(self.root.wall_s, 6),
@@ -512,6 +548,9 @@ class QueryStatsCollector:
             "probe_compaction_lanes_in": self.probe_compaction_lanes_in,
             "probe_compaction_lanes_gathered":
                 self.probe_compaction_lanes_gathered,
+            "semi_join_build_rows": self.semi_join_build_rows,
+            "semi_join_probe_rows": self.semi_join_probe_rows,
+            "aggregate_groups_out": self.aggregate_groups_out,
             "direct_reduces_masked": self.direct_reduces_masked,
             "direct_reduces_scattered": self.direct_reduces_scattered,
             "files_pruned": self.files_pruned,

@@ -101,15 +101,19 @@ def _mark_page(probe: Page, matched: jnp.ndarray, pnull: jnp.ndarray,
     return Page(tuple(probe.columns) + (mark,), probe.num_rows)
 
 
-def prepare_build(build_keys: Sequence[int]):
+def prepare_build(build_keys: Sequence[int], semi: bool = False):
     """Build-phase kernel: sort the build side ONCE into a LookupSource-like
     pytree consumed by every probe-page call (reference:
     operator/join/LookupSourceFactory — the build runs once per join, not
-    once per probe page). Returns prep(build_page) -> prepared tuple."""
+    once per probe page). Returns prep(build_page) -> prepared tuple.
+    `semi`: the build of a semi, anti or mark join, whose phases a trace
+    reads as `join__semi_build`, not as an inner join's."""
     build_keys = tuple(build_keys)
+    sort_scope = "join__semi_build" if semi else "join__build_sort"
+    runs_scope = "join__semi_build" if semi else "join__build_runs"
 
     def prep(build: Page):
-        with op_scope("join__build_sort"):
+        with op_scope(sort_scope):
             bkey, bnull = _key_u64(build, build_keys)
             # dead/null build rows: mask their key to u64::MAX and sort by
             # (key, dead) — keeps the key array globally sorted for
@@ -118,7 +122,7 @@ def prepare_build(build_keys: Sequence[int]):
             u64max = jnp.uint64(0xFFFFFFFFFFFFFFFF)
             bkey_masked = jnp.where(b_dead, u64max, bkey)
             (bkey_s, b_dead_s), bperm = sort_by_keys([bkey_masked, b_dead])
-        with op_scope("join__build_runs"):
+        with op_scope(runs_scope):
             n_live_build = jnp.sum(~b_dead_s).astype(jnp.int32)
             live_b = build.row_mask()
             n_build_rows = jnp.sum(live_b).astype(jnp.int32)
@@ -157,10 +161,11 @@ def prepare_build(build_keys: Sequence[int]):
 _DENSE_SENTINEL = np.int32(0x7FFFFFFF)
 
 
-def _dense_scatter(size: int, bkey_s, n_live, kmin, payload):
+def _dense_scatter(size: int, bkey_s, n_live, kmin, payload,
+                   scope: str = "join__build_dense_table"):
     """Shared scatter for the direct-address builders: dead positions and
     out-of-span keys route to the dropped slot `size`."""
-    with op_scope("join__build_dense_table"):
+    with op_scope(scope):
         n = bkey_s.shape[0]
         idx = jnp.arange(n, dtype=jnp.int32)
         raw = (bkey_s - kmin).astype(jnp.int64)
@@ -170,7 +175,7 @@ def _dense_scatter(size: int, bkey_s, n_live, kmin, payload):
             .at[slot].min(payload, mode="drop")
 
 
-def build_dense_table(size: int):
+def build_dense_table(size: int, semi: bool = False):
     """Direct-address lookup table for a sorted build: table[key - kmin] =
     position of that key's FIRST sorted occurrence (so run_len[pos] still
     yields the duplicate count), sentinel INT32_MAX elsewhere.
@@ -183,8 +188,9 @@ def build_dense_table(size: int):
 
     def op(bkey_s, n_live, kmin):
         n = bkey_s.shape[0]
-        return _dense_scatter(size, bkey_s, n_live, kmin,
-                              jnp.arange(n, dtype=jnp.int32))
+        return _dense_scatter(
+            size, bkey_s, n_live, kmin, jnp.arange(n, dtype=jnp.int32),
+            "join__semi_build" if semi else "join__build_dense_table")
     return op
 
 
@@ -196,6 +202,12 @@ def _dense_lo(table: jnp.ndarray, kmin, pkey: jnp.ndarray) -> jnp.ndarray:
     inb = (raw >= 0) & (raw < size)
     lo = jnp.take(table, jnp.clip(raw, 0, size - 1), mode="clip")
     return jnp.where(inb, lo, _DENSE_SENTINEL)
+
+
+# the lookup of a semi, anti or mark join reads as its own in a trace
+_PROBE_SCOPE = {JoinType.SEMI: "join__semi_probe",
+                JoinType.ANTI: "join__semi_probe",
+                JoinType.MARK: "join__mark_probe"}
 
 
 def _check_lookup(lookup: str) -> None:
@@ -275,7 +287,7 @@ def hash_join(
                     "string join keys across distinct dictionaries; "
                     "re-encode to a shared dictionary first")
 
-        with op_scope("join__probe_lookup"):
+        with op_scope(_PROBE_SCOPE.get(join_type, "join__probe_lookup")):
             pkey, pnull = _key_u64(probe, probe_keys)
 
             p_dead = ~probe.row_mask() | pnull
