@@ -333,6 +333,148 @@ ORDER BY i_product_name, d1.d_year, cnt LIMIT 100"""
                 ordered=True)
 
 
+# ------------- the unique INNER lookup: one gather, a table of build rows
+
+def _unique_case(name):
+    """(probe Page, build Page, probe key values or None where NULL/dead,
+    build key -> row) of one shape the row table has to get right."""
+    import jax.numpy as jnp
+    import numpy as np
+    from trino_tpu import types as T
+    from trino_tpu.page import Dictionary, Page
+    rng = np.random.default_rng(38)
+    base = {"kmin-far-from-0": 9_000_000_000}.get(name, 5)
+    # 200 distinct keys in a span of 1 000: most slots hold the sentinel
+    bkeys = base + np.sort(rng.choice(1000, size=200, replace=False))
+    pkeys = base + rng.integers(0, 1000, size=500)
+    if name == "below-kmin-and-above-kmax":
+        pkeys[::3] = bkeys[0] - 1 - rng.integers(0, 50, size=len(pkeys[::3]))
+        pkeys[1::3] = bkeys[-1] + 1 + rng.integers(0, 50,
+                                                   size=len(pkeys[1::3]))
+    pvalid = None
+    if name == "null-probe-keys":
+        pvalid = rng.random(500) > 0.3
+    typ, dictionary = T.BIGINT, None
+    if name == "string-key-on-a-shared-dictionary":
+        dictionary, _ = Dictionary.build(
+            [f"k{i:04d}" for i in range(1005)])
+        typ = T.VARCHAR
+        bkeys, pkeys = bkeys.astype(np.int32), pkeys.astype(np.int32)
+    order = rng.permutation(len(bkeys))          # the build is not sorted
+    bvals = np.zeros(256, bkeys.dtype)
+    bvals[:200] = bkeys[order]
+    payload = np.arange(256, dtype=np.int64) * 7
+    build = Page.from_numpy([bvals, payload], [typ, T.BIGINT],
+                            dictionaries=[dictionary, None])
+    n_build = 150 if name == "dead-lanes-on-both-sides" else 200
+    build = Page(build.columns, jnp.asarray(n_build, jnp.int32))
+    blive = np.arange(256) < n_build
+    if name == "live-build-rows-not-a-prefix":
+        keep = rng.random(256) > 0.4
+        build = build.with_selection(jnp.asarray(keep))
+        blive &= keep
+    pvals = np.zeros(512, pkeys.dtype)
+    pvals[:500] = pkeys
+    probe = Page.from_numpy(
+        [pvals, np.arange(512, dtype=np.int64)], [typ, T.BIGINT],
+        valids=[None if pvalid is None else np.append(pvalid, [True] * 12),
+                None], dictionaries=[dictionary, None])
+    n_probe = 400 if name == "dead-lanes-on-both-sides" else 500
+    probe = Page(probe.columns, jnp.asarray(n_probe, jnp.int32))
+    plive = np.arange(512) < n_probe
+    if pvalid is not None:
+        plive[:500] &= pvalid
+    rows = {int(k): i for i, k in enumerate(bvals) if blive[i]}
+    return probe, build, [int(k) if ok else None
+                          for k, ok in zip(pvals, plive)], rows
+
+
+@pytest.mark.parametrize("case", [
+    "keys-missing-inside-the-span", "below-kmin-and-above-kmax",
+    "kmin-far-from-0", "null-probe-keys", "dead-lanes-on-both-sides",
+    "live-build-rows-not-a-prefix", "string-key-on-a-shared-dictionary"])
+def test_unique_dense_lookup_is_the_search_lookup_and_a_numpy_join(case):
+    """`unique_inner_probe(lookup="dense")` — one gather a lane against
+    the table of build rows — finds, for every probe lane, the build row
+    the `search` lookup finds and a dictionary of the build's keys gives,
+    and nothing where the key is absent, NULL or the lane dead."""
+    import jax.numpy as jnp
+    import numpy as np
+    from trino_tpu.ops.join import (_DENSE_SENTINEL, attach_build,
+                                    build_dense_table, prepare_build,
+                                    unique_inner_probe)
+    probe, build, pkeys, rows = _unique_case(case)
+    prepared = prepare_build([0])(build)
+    assert int(prepared[7]) == 1                 # max_run: a unique build
+    kmin, kmax = int(prepared[8]), int(prepared[9])
+    assert (kmin, kmax) == (min(rows), max(rows))
+    table = build_dense_table(1024)(prepared[1], prepared[3], prepared[8],
+                                    prepared[2])
+    held = np.asarray(table)
+    assert sorted(held[held != _DENSE_SENTINEL]) == sorted(rows.values())
+    want = np.array([rows.get(k, -1) if k is not None else -1
+                     for k in pkeys])
+    assert 0 < (want >= 0).sum() < len(want)
+    for lookup, prep in (("dense", prepared + (table,)),
+                         ("search", prepared)):
+        pre, found, count = unique_inner_probe([0], [0], lookup=lookup)(
+            probe, prep)
+        assert np.array_equal(np.asarray(found), want >= 0), lookup
+        assert int(count) == (want >= 0).sum()
+        brow = np.asarray(pre.columns[-1].values)
+        assert np.array_equal(brow, np.where(want >= 0, want, 0)), lookup
+        if lookup == "dense":
+            assert brow.dtype == np.int32 and pre.capacity == probe.capacity
+            # the build's payload column arrives at the matched lanes
+            out = attach_build(2)(pre, prep)
+            got = np.asarray(out.columns[3].values)
+            assert np.array_equal(got[want >= 0], want[want >= 0] * 7)
+
+
+@pytest.mark.parametrize("sql, counted, rows", [
+    ("SELECT count(*), sum(v) FROM memory.default.lp p, "
+     "memory.default.lu b WHERE p.k = b.k", "row_table", (2, 30)),
+    ("SELECT count(*), sum(v) FROM memory.default.lp p, "
+     "memory.default.ld b WHERE p.k = b.k", "position_table", (3, 51)),
+    ("SELECT count(*), sum(v) FROM memory.default.lp p LEFT JOIN "
+     "memory.default.lu b ON p.k = b.k", "position_table", (5, 30)),
+    ("SELECT count(*) FROM memory.default.lp p WHERE EXISTS (SELECT 1 "
+     "FROM memory.default.lu b WHERE b.k = p.k)", "position_table", (2,)),
+    ("SELECT count(*), sum(v) FROM memory.default.lp p, "
+     "memory.default.ls b WHERE p.k = b.k", "row_table", (1, 10)),
+    ("SELECT count(*), sum(v) FROM memory.default.lp p, "
+     "memory.default.lx b WHERE p.k = b.k", "search", (1, 10))],
+    ids=["unique-inner", "max-run-2", "left", "semi",
+         "unique-inner-past-the-fill-rule", "past-the-slot-cap"])
+def test_the_router_picks_the_tables_payload(sql, counted, rows):
+    """`_prepare_probe` gives the table of build rows to the unique INNER
+    probe alone: a build with a duplicate key, a LEFT join and a semi
+    join read run_len at the key's position and keep the position table.
+    The row table is bounded by the slot cap alone (2^26), not by the
+    position table's fill rule (4 slots a build lane, at least 2^20): a
+    gather costs the same whatever the table's fill (PERF.md, PR 38);
+    past the cap the build is searched. One decision a join, counted;
+    the probe's lanes counted from shapes."""
+    r = LocalQueryRunner.tpch("tiny")
+    r.execute("CREATE TABLE memory.default.lp (k BIGINT, u BIGINT)")
+    r.execute("INSERT INTO memory.default.lp VALUES (1, 1), (2, 1), "
+              "(3, 1), (9, 1), (NULL, 1)")
+    for name, values in (("lu", "(1, 10), (2, 20), (4, 40)"),
+                         ("ld", "(1, 10), (2, 20), (2, 21)"),
+                         ("ls", f"(1, 10), ({_LIMIT + 1}, 20)"),
+                         ("lx", f"(1, 10), ({(1 << 26) + 1}, 20)")):
+        r.execute(f"CREATE TABLE memory.default.{name} (k BIGINT, v BIGINT)")
+        r.execute(f"INSERT INTO memory.default.{name} VALUES {values}")
+    got = r.execute(sql)
+    stats = r.last_query_stats
+    lookups = {k: stats["probe_lookups_" + k]
+               for k in ("row_table", "position_table", "search")}
+    assert lookups == {k: int(k == counted) for k in lookups}
+    lanes = stats["probe_lookup_lanes"]
+    assert lanes >= 8 and lanes & (lanes - 1) == 0   # one buffer's capacity
+    assert got.rows == [rows]
+
+
 # ------------------------------------------------- the one router
 
 # the span limit of a small build: min(max(4 * capacity, 2^20), 2^26)
@@ -352,8 +494,9 @@ def test_router_at_the_span_limit(monkeypatch, caller, span, lookup):
     seen = []
     route = LocalExecutionPlanner._prepare_probe
 
-    def spy(self, build_keys, build_page):
-        prepared, max_run, mode = route(self, build_keys, build_page)
+    def spy(self, build_keys, build_page, **kind):
+        prepared, max_run, mode = route(self, build_keys, build_page,
+                                        **kind)
         assert 4 * build_page.capacity <= _LIMIT
         kmin, kmax = (int(x) for x in jax.device_get(
             [prepared[8], prepared[9]]))
@@ -401,7 +544,8 @@ def test_a_lookup_is_search_or_dense(probe):
 def test_prepare_fetches_three_scalars_and_two_kernels(monkeypatch):
     """What a join pays before its first probe page: the build's sort
     (`join-prep`), ONE fetch of (max_run, kmin, kmax), and the
-    direct-address table (`dense-table`) — every join of q3 at `tiny`."""
+    direct-address table — of build rows (`dense-table-rows`), both of
+    q3's builds being unique and INNER — every join of q3 at `tiny`."""
     from trino_tpu.exec import local_planner
     from trino_tpu.exec.local_planner import LocalExecutionPlanner
     import chip_smoke
@@ -410,7 +554,7 @@ def test_prepare_fetches_three_scalars_and_two_kernels(monkeypatch):
     lookup_kernel = local_planner.cached_kernel
     device_get = jax.device_get
 
-    def spy(self, build_keys, build_page):
+    def spy(self, build_keys, build_page, **kind):
         def kernel(key, *a, **kw):
             kernels[-1].append(key[0])
             return lookup_kernel(key, *a, **kw)
@@ -423,11 +567,11 @@ def test_prepare_fetches_three_scalars_and_two_kernels(monkeypatch):
         with monkeypatch.context() as m:
             m.setattr(local_planner, "cached_kernel", kernel)
             m.setattr(local_planner.jax, "device_get", get)
-            return route(self, build_keys, build_page)
+            return route(self, build_keys, build_page, **kind)
     monkeypatch.setattr(LocalExecutionPlanner, "_prepare_probe", spy)
     r = LocalQueryRunner.tpch("tiny")
     assert len(r.execute(chip_smoke.Q3).rows) == 10
-    assert kernels == [["join-prep", "dense-table"]] * 2
+    assert kernels == [["join-prep", "dense-table-rows"]] * 2
     assert fetches == [[3]] * 2
 
 

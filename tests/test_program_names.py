@@ -396,18 +396,30 @@ def tiny_columns():
                                        15000)}
 
 
+_Q4 = """
+    SELECT o_orderpriority, count(*) FROM orders
+    WHERE o_orderdate >= DATE '1993-07-01'
+      AND o_orderdate < DATE '1993-07-01' + INTERVAL '3' MONTH
+      AND EXISTS (SELECT * FROM lineitem WHERE l_orderkey = o_orderkey
+                  AND l_commitdate < l_receiptdate)
+    GROUP BY o_orderpriority ORDER BY o_orderpriority"""
+_Q18 = """
+    SELECT c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice,
+           sum(l_quantity)
+    FROM customer, orders, lineitem
+    WHERE o_orderkey IN (SELECT l_orderkey FROM lineitem
+                         GROUP BY l_orderkey HAVING sum(l_quantity) > 250)
+      AND c_custkey = o_custkey AND o_orderkey = l_orderkey
+    GROUP BY c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice
+    ORDER BY o_totalprice DESC, o_orderdate LIMIT 100"""
+
+
 def test_q4_counts_its_semi_join_and_its_groups(tiny_columns):
     """EXISTS builds on the late lineitems and probes the quarter's
     orders; the final aggregate emits the five priorities."""
     import numpy as np
     tpch = LocalQueryRunner.tpch("tiny")
-    tpch.execute("""
-        SELECT o_orderpriority, count(*) FROM orders
-        WHERE o_orderdate >= DATE '1993-07-01'
-          AND o_orderdate < DATE '1993-07-01' + INTERVAL '3' MONTH
-          AND EXISTS (SELECT * FROM lineitem WHERE l_orderkey = o_orderkey
-                      AND l_commitdate < l_receiptdate)
-        GROUP BY o_orderpriority ORDER BY o_orderpriority""")
+    tpch.execute(_Q4)
     stats = tpch.last_query_stats
     lo = int(np.datetime64("1993-07-01", "D").astype(np.int64))
     hi = int(np.datetime64("1993-10-01", "D").astype(np.int64))
@@ -425,16 +437,7 @@ def test_q18_counts_its_inner_groups_and_the_orders_it_probes(
     and the outer GROUP BY emits one group for each."""
     import numpy as np
     tpch = LocalQueryRunner.tpch("tiny")
-    got = tpch.execute("""
-        SELECT c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice,
-               sum(l_quantity)
-        FROM customer, orders, lineitem
-        WHERE o_orderkey IN (SELECT l_orderkey FROM lineitem
-                             GROUP BY l_orderkey
-                             HAVING sum(l_quantity) > 250)
-          AND c_custkey = o_custkey AND o_orderkey = l_orderkey
-        GROUP BY c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice
-        ORDER BY o_totalprice DESC, o_orderdate LIMIT 100""")
+    got = tpch.execute(_Q18)
     stats = tpch.last_query_stats
     sums = np.bincount(tiny_columns["l_orderkey"],
                        weights=tiny_columns["l_quantity"])
@@ -443,6 +446,39 @@ def test_q18_counts_its_inner_groups_and_the_orders_it_probes(
     assert stats["semi_join_build_rows"] == kept
     assert stats["semi_join_probe_rows"] == 15000
     assert stats["aggregate_groups_out"] == 15000 + kept
+
+
+@pytest.mark.parametrize("sql, row_table, position_table", [
+    pytest.param(chip_smoke.Q3, 2, 0, id="q3"),
+    pytest.param(_Q4, 0, 1, id="q4"),
+    pytest.param(_Q18, 2, 1, id="q18")])
+def test_joins_count_their_lookups_and_the_lanes_they_ran_over(
+        monkeypatch, sql, row_table, position_table):
+    """One `_prepare_probe` decision a join, counted by what the table
+    holds (PR 38). q3: both builds are unique, INNER and dense — two
+    tables of build rows. Q4's `EXISTS` is a semi join: a table of
+    positions. Q18 at `tiny`: the customer join and the outer join with
+    lineitem (unique, INNER: the ~50 orders the HAVING kept span the
+    order keys, inside the row table's slot cap) read row tables, the
+    `IN` a position table; nothing is searched. `probe_lookup_lanes` is
+    the capacities of the buffers those lookups ran over."""
+    from trino_tpu.exec.local_planner import LocalExecutionPlanner
+    lanes = []
+    counted = LocalExecutionPlanner._lookup_lanes
+
+    def spy(self, pages):
+        for page in counted(self, pages):
+            lanes.append(page.capacity)
+            yield page
+    monkeypatch.setattr(LocalExecutionPlanner, "_lookup_lanes", spy)
+    tpch = LocalQueryRunner.tpch("tiny")
+    tpch.execute(sql)
+    stats = tpch.last_query_stats
+    assert (stats["probe_lookups_row_table"],
+            stats["probe_lookups_position_table"],
+            stats["probe_lookups_search"]) == (row_table, position_table, 0)
+    assert len(lanes) >= row_table + position_table
+    assert stats["probe_lookup_lanes"] == sum(lanes) > 0
 
 
 def test_a_cached_kernels_first_call_lies_under_a_compile_span():

@@ -181,6 +181,39 @@ def test_probe_compaction_at_the_kept_rung(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
 
 
+def test_unique_dense_lookup_is_one_gather(one_chip):
+    """`join__uprobe` against the table of build rows (PR 38) at q3's
+    larger SF10 shape — a 33 554 432-lane lineitem buffer, the orders
+    build's 15 M keys in a table of 16 777 216 slots: exactly ONE gather
+    under `join__probe_lookup`, of 32-bit indices into the int32 table;
+    no sort (the `search` lookup's four, with its two scatters and three
+    gathers, are what this replaces) and no second gather through the
+    sort permutation. The row channel leaves as int32."""
+    from trino_tpu.ops.join import (build_dense_table, prepare_build,
+                                    unique_inner_probe)
+    lanes, slots = 1 << 25, 1 << 24
+    build = _page(one_chip, 1 << 21, (T.BIGINT, T.DATE, T.INTEGER))
+    prepared = jax.eval_shape(prepare_build([0]), build)
+    table = jax.eval_shape(build_dense_table(slots), prepared[1],
+                           prepared[3], prepared[8], prepared[2])
+    assert table.shape == (slots,) and table.dtype == jnp.int32
+    probe = _page(one_chip, lanes, (T.BIGINT, D12_2, D12_2, T.DATE))
+    op = unique_inner_probe([0], [0], lookup="dense")
+    compiled = _compile(op, probe, prepared + (table,), limit_s=60)
+    text = compiled.as_text()
+    gathers = [line for line in text.splitlines() if " gather(" in line]
+    assert len(gathers) == 1, gathers
+    assert "join__probe_lookup" in gathers[0]
+    assert gathers[0].split(" gather(")[0].split(" = ")[1] \
+        .startswith(f"s32[{lanes}]"), gathers[0]
+    assert " sort(" not in text and " scatter(" not in text
+    pre, found, count = jax.eval_shape(op, probe, prepared + (table,))
+    assert pre.columns[-1].values.dtype == jnp.int32
+    assert found.shape == (lanes,) and count.dtype == jnp.int64
+    # the index, the gathered rows and the mask: no copy of the buffer
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 29)
+
+
 @pytest.mark.parametrize("lanes, columns, nullable", [
     pytest.param(SCAN_WIDTH, 4, False, id="q3-chain-4xbigint"),
     pytest.param(SCAN_WIDTH, 4, True, id="q3-chain-one-nullable"),

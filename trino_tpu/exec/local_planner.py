@@ -441,6 +441,21 @@ class LocalExecutionPlanner:
         if self.collector is not None:
             self.collector.count_rows(name, num_rows)
 
+    def _count_lookup(self, table: str) -> None:
+        """One `_prepare_probe` decision: `row_table`, `position_table`
+        or `search`."""
+        if self.collector is not None:
+            self.collector.count_probe_lookup(table)
+
+    def _lookup_lanes(self, pages) -> Iterator[Page]:
+        """The probe buffers a prepared lookup runs over, their lanes
+        (capacities: shapes, no sync) summed as `probe_lookup_lanes`."""
+        for page in (pages.iter_pages() if hasattr(pages, "iter_pages")
+                     else pages):
+            if self.collector is not None:
+                self.collector.probe_lookup_lanes += page.capacity
+            yield page
+
     def _counted(self, stream: "PageStream", name: str) -> Iterator[Page]:
         """`stream`'s pages, their rows counted under `name`."""
         for page in stream.iter_pages():
@@ -1828,7 +1843,7 @@ class LocalExecutionPlanner:
                 return
             try:
                 prepared, max_run, mode = self._prepare_probe(
-                    build_keys, bp)
+                    build_keys, bp, inner=join_kind == JoinType.INNER)
                 prefilter = None
                 if join_kind == JoinType.INNER and \
                         self.session.get("enable_dynamic_filtering") and \
@@ -1860,11 +1875,13 @@ class LocalExecutionPlanner:
                         self.register_dynamic_domain(
                             scan_node, col_name, col_type,
                             lo_h.item(), hi_h.item())
-                probe_in = self._coalesce_stream(aligned,
-                                                 prefilter=prefilter)
+                probe_in = self._lookup_lanes(self._coalesce_stream(
+                    aligned, prefilter=prefilter))
                 if join_kind == JoinType.INNER and max_run <= 1:
                     # unique build side (primary/dimension key): the
-                    # no-expansion probe + live-size build attach
+                    # no-expansion probe (against the table of build rows
+                    # `_prepare_probe` made for it, where the keys are
+                    # dense) + live-size build attach
                     probe_op, attach_op = unique_ops(mode)
                     yield from self._run_unique_inner(
                         probe_in, prepared, probe_op, attach_op)
@@ -1899,7 +1916,7 @@ class LocalExecutionPlanner:
         or wrong."""
         from trino_tpu.exec.memory import page_bytes
         from trino_tpu.ops.join import (attach_build_host,
-                                        build_dense_table_rows,
+                                        build_dense_table,
                                         prepare_build_spilled,
                                         spilled_dense_probe,
                                         spilled_unique_probe)
@@ -1946,8 +1963,8 @@ class LocalExecutionPlanner:
                 prepared, _max_run, mode = self._prepare_probe(
                     build_keys, build_page)
                 yield from _run_with_overflow(
-                    self._coalesce_stream(probe_stream), prepared,
-                    lambda cap: fallback_join_op(cap, mode),
+                    self._lookup_lanes(self._coalesce_stream(probe_stream)),
+                    prepared, lambda cap: fallback_join_op(cap, mode),
                     self.page_capacity)
             finally:
                 self._free_collected(build_page)
@@ -1996,8 +2013,8 @@ class LocalExecutionPlanner:
         if spill_dense:
             size = _next_pow2(span)
             tab_op = cached_kernel(("dense-table-rows", size),
-                                   lambda: build_dense_table_rows(size))
-            table = tab_op(bkey_s, bperm, n_live, kmin)
+                                   lambda: build_dense_table(size))
+            table = tab_op(bkey_s, n_live, kmin, bperm)
             kmin_dev = jnp.uint64(kmin)
             bkey_s = bperm = None   # free sorted keys + permutation
             held_bytes = int(table.nbytes)
@@ -2022,7 +2039,9 @@ class LocalExecutionPlanner:
         try:
             it2 = probe_stream if isinstance(probe_stream, Iterator) \
                 else self._coalesce_stream(probe_stream).iter_pages()
-            for batch in _byte_bounded_batches(it2, 1 << 29):
+            self._count_lookup("row_table" if spill_dense else "search")
+            for batch in _byte_bounded_batches(self._lookup_lanes(it2),
+                                               1 << 29):
                 if spill_dense:
                     results = [probe_op(p, table, kmin_dev) for p in batch]
                 else:
@@ -2342,8 +2361,8 @@ class LocalExecutionPlanner:
             prepared, _max_run, mode = self._prepare_probe(
                 list(bkeys), bpage)
             yield from _run_with_overflow(
-                pstore.drain_partition_chunks(
-                    p, pstore.chunk_rows_for(p, threshold)),
+                self._lookup_lanes(pstore.drain_partition_chunks(
+                    p, pstore.chunk_rows_for(p, threshold))),
                 prepared,
                 lambda cap: join_op(cap, mode),
                 self.page_capacity)
@@ -2374,7 +2393,8 @@ class LocalExecutionPlanner:
                 prepared, _mr, mode = self._prepare_probe(
                     list(bkeys), bchunk)
                 yield from _run_with_overflow(
-                    pstore.iter_partition_chunks(p, pchunk_rows),
+                    self._lookup_lanes(
+                        pstore.iter_partition_chunks(p, pchunk_rows)),
                     prepared,
                     lambda cap, m=mode: join_op(cap, m),
                     self.page_capacity)
@@ -2422,7 +2442,9 @@ class LocalExecutionPlanner:
     def _run_unique_inner(self, probe_stream, prepared, probe_op,
                           attach_op) -> Iterator[Page]:
         """Drive the unique-build INNER fast path in two steps with the
-        host's counts between them: the gather-probe kernel per page
+        host's counts between them: the probe kernel per page (ONE gather
+        a lane against `prepared[10]`, the table of build rows, where
+        `_prepare_probe` routed `dense`; a searchsorted where it did not)
         yields the match mask and count and moves nothing; one batched
         fetch of (matched, live) per batch; then `_compact_counted` per
         buffer (skip / matched prefix at its pow2 rung / full) and the
@@ -2592,29 +2614,54 @@ class LocalExecutionPlanner:
     # slot cap bounds HBM (64M slots = 256MB int32 for in-memory builds)
     _DENSE_MAX_SLOTS = 1 << 26
 
-    def _prepare_probe(self, build_keys, build_page, semi: bool = False):
+    def _prepare_probe(self, build_keys, build_page, semi: bool = False,
+                       inner: bool = False):
         """prepare_build + the ONE probe-lookup decision of every join,
         in memory or spilled: fetch (max_run, kmin, kmax) in one round
         trip; when the live-key span is small (dense surrogate keys —
         every TPC-H/DS join), append a direct-address lookup table so
-        probe kernels cost one gather ('dense') instead of a sort-engine
-        searchsorted pass per buffer ('search').
+        probe kernels cost one gather a lane ('dense') instead of a
+        sort-engine searchsorted pass per buffer ('search': four sorts,
+        two scatters and three gathers on the v5e).
+
+        What a slot of the table holds follows from what was just read
+        and from the join's kind, nothing to set. `inner` says the caller
+        takes the no-expansion probe when the build turns out unique
+        (INNER, `max_run <= 1`: unique_inner_probe): the table then
+        holds the build ROW of each key and the probe is that one gather
+        and nothing else. Every other consumer (hash_join over
+        duplicates, LEFT/FULL, SEMI/ANTI/MARK) reads run_len at the
+        key's sorted POSITION and keeps the position table. Counted on
+        the query's collector as `probe_lookups_row_table` /
+        `_position_table` / `_search`.
 
         Returns (prepared [+ table], max_run, lookup)."""
         from trino_tpu.ops.join import build_dense_table
         prepared = self._prepare_build(build_keys, build_page, semi)
         max_run, kmin, kmax = (int(x) for x in jax.device_get(
             [prepared[7], prepared[8], prepared[9]]))
+        rows = inner and max_run <= 1
         span = kmax - kmin + 1 if kmax >= kmin else 0
-        limit = min(max(4 * build_page.capacity, 1 << 20),
-                    self._DENSE_MAX_SLOTS)
+        # the row table is worth its slots at any fill: one gather runs
+        # at 115-137 M lanes/s into a table of 2^24 slots holding 1.5 M
+        # keys or a hundred, the search at 15-20 M, and the table's own
+        # scatter is 1-21 ms (chip, PERF.md PR 38) — so only the slot cap
+        # bounds it. The position table keeps the fill rule it had
+        # (at most 4 slots a build lane): no chip run has timed it
+        limit = self._DENSE_MAX_SLOTS if rows else \
+            min(max(4 * build_page.capacity, 1 << 20),
+                self._DENSE_MAX_SLOTS)
         if not 0 < span <= limit:
+            self._count_lookup("search")
             return prepared, max_run, "search"
         size = _next_pow2(span)
+        tag = "semijoin-dense-table" if semi else \
+            "dense-table-rows" if rows else "dense-table"
         table_op = cached_kernel(
-            ("semijoin-dense-table" if semi else "dense-table", size),
-            lambda: build_dense_table(size, semi))
-        table = table_op(prepared[1], prepared[3], prepared[8])
+            (tag, size), lambda: build_dense_table(size, semi))
+        table = table_op(prepared[1], prepared[3], prepared[8],
+                         prepared[2] if rows else None)
+        self._count_lookup("row_table" if rows else "position_table")
         return prepared + (table,), max_run, "dense"
 
     def _exec_right_join(self, node: JoinNode) -> PageStream:
@@ -2824,8 +2871,9 @@ class LocalExecutionPlanner:
                 prepared, _max_run, mode = self._prepare_probe(
                     build_keys, bp, semi=True)
                 yield from _run_with_overflow(
-                    self._counted(self._coalesce_stream(probe_stream),
-                                  "semi_join_probe_rows"), prepared,
+                    self._lookup_lanes(self._counted(
+                        self._coalesce_stream(probe_stream),
+                        "semi_join_probe_rows")), prepared,
                     lambda cap: semi_op(cap, mode), self.page_capacity)
             finally:
                 self._free_collected(build_page)
@@ -2870,8 +2918,9 @@ class LocalExecutionPlanner:
                 prepared, _max_run, mode = self._prepare_probe(
                     build_keys, bp, semi=True)
                 yield from _run_with_overflow(
-                    self._counted(self._coalesce_stream(probe_stream),
-                                  "semi_join_probe_rows"), prepared,
+                    self._lookup_lanes(self._counted(
+                        self._coalesce_stream(probe_stream),
+                        "semi_join_probe_rows")), prepared,
                     lambda cap: mark_op(cap, mode), self.page_capacity)
             finally:
                 self._free_collected(build_page)

@@ -175,6 +175,17 @@ class QueryStatsCollector:
         self.probe_compactions_skipped = 0
         self.probe_compaction_lanes_in = 0
         self.probe_compaction_lanes_gathered = 0
+        # a join's lookup (local_planner._prepare_probe, one decision a
+        # build): `row_table` — a unique INNER build under the span limit,
+        # one gather a probe lane against a table of build rows;
+        # `position_table` — any other build under it, one gather against
+        # a table of sorted positions, then run_len there; `search` — a
+        # searchsorted a buffer. `probe_lookup_lanes` sums the capacities
+        # of the probe buffers those lookups ran over
+        self.probe_lookups_row_table = 0
+        self.probe_lookups_position_table = 0
+        self.probe_lookups_search = 0
+        self.probe_lookup_lanes = 0
         # dispatches of a chain or mesh program (jit_cache.
         # profiled_kernel) whose direct GROUP BY (ops/aggregate.
         # _direct_aggregate) reduced its slot table lane-wise under slot
@@ -405,6 +416,12 @@ class QueryStatsCollector:
         self.probe_compaction_lanes_in += int(lanes_in)
         self.probe_compaction_lanes_gathered += int(lanes_gathered)
 
+    def count_probe_lookup(self, table: str) -> None:
+        """One join's lookup decision: `row_table`, `position_table` or
+        `search`."""
+        name = "probe_lookups_" + table
+        setattr(self, name, getattr(self, name) + 1)
+
     def count_rows(self, name: str, num_rows) -> None:
         """Add a page's row count to the counter `name`. A count that is
         still a device scalar is kept as it is and read with the others
@@ -548,6 +565,11 @@ class QueryStatsCollector:
             "probe_compaction_lanes_in": self.probe_compaction_lanes_in,
             "probe_compaction_lanes_gathered":
                 self.probe_compaction_lanes_gathered,
+            "probe_lookups_row_table": self.probe_lookups_row_table,
+            "probe_lookups_position_table":
+                self.probe_lookups_position_table,
+            "probe_lookups_search": self.probe_lookups_search,
+            "probe_lookup_lanes": self.probe_lookup_lanes,
             "semi_join_build_rows": self.semi_join_build_rows,
             "semi_join_probe_rows": self.semi_join_probe_rows,
             "aggregate_groups_out": self.aggregate_groups_out,

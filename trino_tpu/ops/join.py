@@ -148,8 +148,11 @@ def prepare_build(build_keys: Sequence[int], semi: bool = False):
                                              < n_live_build, run_len, 0))
             # live-key min/max (u64 space): the executor fetches these with
             # max_run and, when the span is small (dense surrogate keys — every
-            # TPC-H/DS key), builds a direct-address lookup table so probes
-            # cost ONE gather instead of a sort-engine searchsorted pass
+            # TPC-H/DS key), builds a direct-address lookup table
+            # (build_dense_table) so a probe lane costs ONE gather instead
+            # of a sort-engine searchsorted pass and three gathers: the
+            # table holds the build row itself for a unique INNER build,
+            # the sorted position (for run_len) for every other consumer
             live_key = ~b_dead
             kmin = jnp.min(jnp.where(live_key, bkey, u64max))
             kmax = jnp.max(jnp.where(live_key, bkey, jnp.uint64(0)))
@@ -161,47 +164,67 @@ def prepare_build(build_keys: Sequence[int], semi: bool = False):
 _DENSE_SENTINEL = np.int32(0x7FFFFFFF)
 
 
-def _dense_scatter(size: int, bkey_s, n_live, kmin, payload,
-                   scope: str = "join__build_dense_table"):
-    """Shared scatter for the direct-address builders: dead positions and
-    out-of-span keys route to the dropped slot `size`."""
-    with op_scope(scope):
-        n = bkey_s.shape[0]
-        idx = jnp.arange(n, dtype=jnp.int32)
-        raw = (bkey_s - kmin).astype(jnp.int64)
-        oob = (idx >= n_live) | (raw < 0) | (raw >= size)
-        slot = jnp.where(oob, size, raw)
-        return jnp.full(size, _DENSE_SENTINEL, jnp.int32) \
-            .at[slot].min(payload, mode="drop")
-
-
 def build_dense_table(size: int, semi: bool = False):
-    """Direct-address lookup table for a sorted build: table[key - kmin] =
-    position of that key's FIRST sorted occurrence (so run_len[pos] still
-    yields the duplicate count), sentinel INT32_MAX elsewhere.
+    """Direct-address lookup table for a sorted build, one int32 slot per
+    key of the live span: op(bkey_s, n_live, kmin, payload=None) ->
+    table[key - kmin], sentinel INT32_MAX where no live key falls. Dead
+    positions and out-of-span keys route to the dropped slot `size`.
+
+    `payload` is what a slot holds, indexed by sorted position:
+      None   — the position of the key's FIRST sorted occurrence, so that
+               run_len[pos] still yields the duplicate count: what
+               hash_join reads (duplicates, LEFT/FULL, SEMI/ANTI/MARK);
+      bperm  — the ORIGINAL build row of that (unique) key: what the
+               unique INNER probe reads, in memory (unique_inner_probe)
+               or spilled (spilled_dense_probe, which then needs no
+               sorted keys and no permutation on the device: 4 B a slot
+               instead of 12 B a row).
 
     The TPU analog of the reference's array-based lookup source for dense
     bigint keys (operator/join/... ArrayBasedLookupSource idea): one
     scatter at build time buys gather-only probes. Every TPC-H/DS join key
     is a dense surrogate (orderkey/partkey/.._sk), so this path carries
-    the hot joins; sparse/hashed keys fall back to searchsorted."""
+    the hot joins; sparse/hashed keys fall back to searchsorted.
+    `semi`: a semi, anti or mark join's, read as `join__semi_build`."""
+    scope = "join__semi_build" if semi else "join__build_dense_table"
 
-    def op(bkey_s, n_live, kmin):
-        n = bkey_s.shape[0]
-        return _dense_scatter(
-            size, bkey_s, n_live, kmin, jnp.arange(n, dtype=jnp.int32),
-            "join__semi_build" if semi else "join__build_dense_table")
+    def op(bkey_s, n_live, kmin, payload=None):
+        with op_scope(scope):
+            n = bkey_s.shape[0]
+            idx = jnp.arange(n, dtype=jnp.int32)
+            raw = (bkey_s - kmin).astype(jnp.int64)
+            oob = (idx >= n_live) | (raw < 0) | (raw >= size)
+            slot = jnp.where(oob, size, raw)
+            return jnp.full(size, _DENSE_SENTINEL, jnp.int32) \
+                .at[slot].min(idx if payload is None else payload,
+                              mode="drop")
     return op
 
 
 def _dense_lo(table: jnp.ndarray, kmin, pkey: jnp.ndarray) -> jnp.ndarray:
-    """lower-bound analog via the dense table: position of pkey's first
-    sorted occurrence, or a huge sentinel (>= any n_live) when absent."""
-    size = table.shape[0]
-    raw = (pkey - kmin).astype(jnp.int64)
-    inb = (raw >= 0) & (raw < size)
-    lo = jnp.take(table, jnp.clip(raw, 0, size - 1), mode="clip")
-    return jnp.where(inb, lo, _DENSE_SENTINEL)
+    """table[pkey - kmin] in ONE gather, or the sentinel (>= any n_live,
+    never a build row) for a key outside the table's span. The range test
+    runs on the u64 difference — a key below kmin wraps past any size —
+    and the index is narrowed to int32 before the gather: the TPU has no
+    64-bit lanes, and a table has under 2^31 slots."""
+    raw = pkey - kmin
+    inb = raw < jnp.uint64(table.shape[0])
+    slot = jnp.where(inb, raw, 0).astype(jnp.int32)
+    return jnp.where(inb, jnp.take(table, slot, mode="clip"),
+                     _DENSE_SENTINEL)
+
+
+def _dense_row_lookup(table: jnp.ndarray, kmin, pkey: jnp.ndarray,
+                      p_dead: jnp.ndarray):
+    """The lookup of a UNIQUE build through its row table
+    (build_dense_table with bperm as payload): one gather a probe lane,
+    no second one through the sort permutation — a position matters only
+    for run_len, and a unique build has no runs. Returns (brow, found):
+    the build row as int32 (the sentinel where the slot is empty) and the
+    match mask; slot identity is key equality, NULL and dead lanes never
+    match."""
+    brow = _dense_lo(table, kmin, pkey)
+    return brow, (brow != _DENSE_SENTINEL) & ~p_dead
 
 
 # the lookup of a semi, anti or mark join reads as its own in a trace
@@ -239,8 +262,12 @@ def hash_join(
 
     `lookup` picks the probe strategy (exec/local_planner._prepare_probe
     routes by the build's live key span): 'search' = sort-engine
-    searchsorted, 'dense' = one gather against a direct-address table
-    (prepared[10]). The mesh shard_map bodies prep inline
+    searchsorted, 'dense' = one gather against the direct-address table
+    of sorted POSITIONS (prepared[10], build_dense_table with no
+    payload), then run_len at the position — this kernel serves
+    duplicate builds and the LEFT/FULL/SEMI/ANTI/MARK kinds, which need
+    the run; a unique INNER build probes its table of build ROWS through
+    unique_inner_probe instead. The mesh shard_map bodies prep inline
     (prepared=False), have no table and probe by 'search'.
 
     null_aware governs SEMI/ANTI/MARK null semantics (reference:
@@ -492,39 +519,36 @@ def prepare_build_spilled(build_keys: Sequence[int]):
     return prep
 
 
-def build_dense_table_rows(size: int):
-    """Spilled-dense build finisher: table[key - kmin] = ORIGINAL build row
-    of that (unique) key, sentinel elsewhere. The probe then needs ONLY
-    this table on device — no sorted keys, no permutation (4B/slot instead
-    of 12B/row of HBM for a >threshold build)."""
-
-    def op(bkey_s, bperm, n_live, kmin):
-        return _dense_scatter(size, bkey_s, n_live, kmin, bperm)
-    return op
-
-
 def spilled_dense_probe(probe_keys: Sequence[int],
                         probe_out: Optional[Sequence[int]] = None):
-    """Probe a spilled build through its dense row table: one gather per
-    probe row. Returns (pre_page, found_mask, match_count) — compaction is
-    deferred to the executor, which skips it entirely when every live
-    probe row matched (the common fact-to-dimension case)."""
+    """Probe a spilled build through its dense row table — the lookup of
+    unique_inner_probe(lookup='dense') with the table alone on the
+    device: one gather per probe row. Returns (pre_page, found_mask,
+    match_count) — compaction is deferred to the executor, which skips it
+    entirely when every live probe row matched (the common
+    fact-to-dimension case)."""
     probe_keys = tuple(probe_keys)
 
     def op(probe: Page, table, kmin):
         with op_scope("join__probe_lookup"):
             pkey, pnull = _key_u64(probe, probe_keys)
-            p_dead = ~probe.row_mask() | pnull
-            brow = _dense_lo(table, kmin, pkey)
-            found = (brow != _DENSE_SENTINEL) & ~p_dead
-        brow_col = Column(jnp.where(found, brow, 0).astype(jnp.int64),
-                          None, T.BIGINT, None)
-        p_idx = range(probe.num_columns) if probe_out is None else probe_out
-        pre = Page(tuple(probe.columns[i] for i in p_idx) + (brow_col,),
-                   probe.num_rows)
-        return pre, found, jnp.sum(found).astype(jnp.int64)
+            brow, found = _dense_row_lookup(
+                table, kmin, pkey, ~probe.row_mask() | pnull)
+            brow = jnp.where(found, brow, 0)
+        return _pre_page(probe, probe_out, brow), found, \
+            jnp.sum(found).astype(jnp.int64)
 
     return op
+
+
+def _pre_page(probe: Page, probe_out: Optional[Sequence[int]],
+              brow: jnp.ndarray) -> Page:
+    """The unique probes' output: the emitted probe channels ++ the build
+    row of each lane (`brow`), at PROBE order, nothing moved yet."""
+    p_idx = range(probe.num_columns) if probe_out is None else probe_out
+    typ = T.INTEGER if brow.dtype == jnp.int32 else T.BIGINT
+    return Page(tuple(probe.columns[i] for i in p_idx)
+                + (Column(brow, None, typ, None),), probe.num_rows)
 
 
 _ANCHOR_LOG2 = 10
@@ -575,11 +599,8 @@ def spilled_unique_probe(probe_keys: Sequence[int],
             found = (jnp.take(bkey_s, lo_c, mode="clip") == pkey) & \
                 (lo < n_live) & ~p_dead
             brow = jnp.take(bperm, lo_c, mode="clip").astype(jnp.int64)
-        brow_col = Column(brow, None, T.BIGINT, None)
-        p_idx = range(probe.num_columns) if probe_out is None else probe_out
-        pre = Page(tuple(probe.columns[i] for i in p_idx) + (brow_col,),
-                   probe.num_rows)
-        return pre, found, jnp.sum(found).astype(jnp.int64)
+        return _pre_page(probe, probe_out, brow), found, \
+            jnp.sum(found).astype(jnp.int64)
 
     return op
 
@@ -654,11 +675,15 @@ def unique_inner_probe(
     join. No cumsum expansion, no output-slot searchsorted, no
     capacity-sized gathers (round-4 profiling: those cost ~0.7s per
     MILLION probe rows in the general kernel). With lookup='dense' the
-    searchsorted collapses to one gather against the direct-address table
-    (prepared[10]).
+    searchsorted, the key gather that verifies it and the gather through
+    the sort permutation collapse to ONE gather a probe lane: prepared[10]
+    is then the table of build ROWS (build_dense_table with bperm as
+    payload; exec/local_planner._prepare_probe builds it for this
+    consumer and no other), the lookup spilled_dense_probe shares.
 
     Returns (pre_page, found_mask, match_count): pre_page is probe columns
-    ++ a BIGINT `brow` channel at PROBE order, nothing moved yet. The
+    ++ a `brow` channel (INTEGER from the table, BIGINT from the search)
+    at PROBE order, nothing moved yet. The
     executor fetches the count and then compacts in a second program
     (exec/local_planner._compact_counted): not at all when every live row
     matched (count == num_rows; the common fact-to-dim case), the matched
@@ -672,7 +697,6 @@ def unique_inner_probe(
     _check_lookup(lookup)
 
     def op(probe: Page, prepared):
-        aux_table = prepared[10] if lookup == "dense" else None
         (build, bkey_s, bperm, n_live_build, n_build_rows,
          build_has_null, run_len, _max_run, kmin, _kmax) = prepared[:10]
         n_build = build.capacity
@@ -689,27 +713,23 @@ def unique_inner_probe(
         with op_scope("join__probe_lookup"):
             pkey, pnull = _key_u64(probe, probe_keys)
             p_dead = ~probe.row_mask() | pnull
-            n_build_m1 = jnp.maximum(n_build - 1, 0)
-            if lookup == "dense" and aux_table is not None:
-                lo = _dense_lo(aux_table, kmin, pkey)
-                lo_c = jnp.minimum(lo, n_build_m1)
-                found = (lo < n_live_build) & ~p_dead
+            if lookup == "dense":
+                brow, found = _dense_row_lookup(prepared[10], kmin, pkey,
+                                                p_dead)
             else:
                 lo = jnp.searchsorted(bkey_s, pkey, side="left", method="sort")
-                lo_c = jnp.minimum(lo, n_build_m1)
+                lo_c = jnp.minimum(lo, jnp.maximum(n_build - 1, 0))
                 found = (jnp.take(bkey_s, lo_c, mode="clip") == pkey) & \
                     (lo < n_live_build) & ~p_dead
-            brow = jnp.take(bperm, lo_c, mode="clip").astype(jnp.int64)
+                brow = jnp.take(bperm, lo_c, mode="clip").astype(jnp.int64)
             if composite and verify_composite:
                 # unique build: at most one candidate — verify it directly
                 for pk, bk in zip(probe_keys, build_keys):
                     bv = jnp.take(build.column(bk).values, brow, mode="clip")
                     found = found & (probe.column(pk).values == bv)
-            brow_col = Column(jnp.where(found, brow, 0), None, T.BIGINT, None)
-        p_idx = range(probe.num_columns) if probe_out is None else probe_out
-        pre = Page(tuple(probe.columns[i] for i in p_idx) + (brow_col,),
-                   probe.num_rows)
-        return pre, found, jnp.sum(found).astype(jnp.int64)
+            brow = jnp.where(found, brow, 0)
+        return _pre_page(probe, probe_out, brow), found, \
+            jnp.sum(found).astype(jnp.int64)
 
     return op
 
