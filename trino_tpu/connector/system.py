@@ -33,7 +33,12 @@ TABLES: Dict[str, tuple] = {
         ("pool_reserved_bytes", T.BIGINT), ("pool_peak_bytes", T.BIGINT),
         ("memory_kills", T.BIGINT), ("leaked_bytes", T.BIGINT),
         ("spilled_bytes", T.BIGINT),
-        ("device_time_ms", T.DOUBLE), ("compile_time_ms", T.DOUBLE)),
+        ("device_time_ms", T.DOUBLE), ("compile_time_ms", T.DOUBLE),
+        # the executor's host timeline (obs/stats.activity): programs the
+        # query enqueued, times its thread stopped to read a device
+        # value, executables XLA compiled for it
+        ("kernel_calls", T.BIGINT), ("host_reads", T.BIGINT),
+        ("backend_compiles", T.BIGINT)),
     # the query-history ring (obs/history.py): terminal queries retained
     # past the live tracker's pruning bound, with the device/compile/host
     # time split and the full error taxonomy — the post-incident table
@@ -121,7 +126,10 @@ def _rows_for(table: str) -> List[tuple]:
                  q.leaked_bytes,
                  (q.stats or {}).get("spilled_bytes", 0),
                  float((q.stats or {}).get("device_time_ms", 0) or 0),
-                 float((q.stats or {}).get("compile_time_ms", 0) or 0))
+                 float((q.stats or {}).get("compile_time_ms", 0) or 0),
+                 (q.stats or {}).get("kernel_calls", 0),
+                 (q.stats or {}).get("host_reads", 0),
+                 (q.stats or {}).get("backend_compiles", 0))
                 for q in TRACKER.list()]
     if table == "completed_queries":
         from trino_tpu.obs.history import HISTORY

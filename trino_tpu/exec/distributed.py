@@ -39,7 +39,8 @@ from trino_tpu import types as T
 from trino_tpu.exec.local_planner import (
     ExecutionError, LocalExecutionPlanner, PageStream, _layout, _next_pow2,
     compose_chain)
-from trino_tpu.exec.jit_cache import cached_kernel
+from trino_tpu.exec.jit_cache import (cached_kernel, host_read,
+                                      observed_activity, pulled)
 from trino_tpu.exec.runner import LocalQueryRunner, MaterializedResult
 from trino_tpu.metadata import Metadata, Session
 from trino_tpu.ops import AggSpec, SortKey, Step, hash_aggregate, order_by
@@ -130,8 +131,9 @@ class ShardExecutionPlanner(LocalExecutionPlanner):
                 if isinstance(entry, ShardedTable):
                     my_page = entry.shard_page(names, self.shard)
                 else:
-                    my_page = build_shard_page(entry, names, self.shard,
-                                               self.n_shards)
+                    with observed_activity("eager_slice", "table_cache"):
+                        my_page = build_shard_page(entry, names, self.shard,
+                                                   self.n_shards)
 
                 def gen_resident(page=my_page):
                     if page is None:
@@ -179,8 +181,9 @@ class ShardExecutionPlanner(LocalExecutionPlanner):
                 for split in mine:
                     self._fault_site("scan",
                                      f"{node.table} part {split.part}")
-                    for page, moved in count_host_staging(
-                            conn.page_source.pages(split, columns, cap)):
+                    for page, moved in count_host_staging(pulled(
+                            conn.page_source.pages(split, columns, cap),
+                            "connector")):
                         self._checkpoint()
                         if col is not None:
                             col.add_scan_staging(page_bytes(page), moved)
@@ -224,8 +227,8 @@ class ShardExecutionPlanner(LocalExecutionPlanner):
         # promotion's device concat
         dev = jax.devices()[0]
         pages = [jax.device_put(p, dev) for p in pages]
-        counts = [int(c) for c in jax.device_get(
-            [p.num_rows for p in pages])]
+        counts = [int(c) for c in host_read(
+            [p.num_rows for p in pages], "promote_counts")]
         # the promoting shard is the LAST one drained (never shard 0);
         # the collector is shared across the attempt's shard executors
         self.table_cache.promote_from_pages(
@@ -315,7 +318,8 @@ class ShardExecutionPlanner(LocalExecutionPlanner):
 
         def gen():
             page = self._collect(src)
-            if page is None or int(page.num_rows) == 0:
+            if page is None \
+                    or int(host_read(page.num_rows, "agg_input_rows")) == 0:
                 if not node.group_by:
                     yield self._empty_global_agg(node, specs)
                 return
@@ -425,15 +429,17 @@ class DistributedQueryRunner(LocalQueryRunner):
         from trino_tpu.exec.memory import live_page_bytes
         for page in root_stream.iter_pages():
             self._check_deadline()      # page-batch cancellation point
-            n = int(page.num_rows)
+            n = int(host_read(page.num_rows, "result_rows"))
             if n == 0:
                 continue
             nbytes += live_page_bytes(page, n)
-            cols = page.to_host(n)
+            with observed_activity("to_host"):
+                cols = page.to_host(n)
             from trino_tpu.exec.runner import _to_python
-            for i in range(n):
-                rows.append(tuple(_to_python(cols[j][i], types[j])
-                                  for j in range(len(cols))))
+            with observed_activity("rows_to_python"):
+                for i in range(n):
+                    rows.append(tuple(_to_python(cols[j][i], types[j])
+                                      for j in range(len(cols))))
         if self._faults is not None:
             self._faults.site("fragment", "root")
         self._last_output_nbytes = nbytes
@@ -658,8 +664,8 @@ class DistributedQueryRunner(LocalQueryRunner):
             # everything else on this path follows)
             from trino_tpu.exec.memory import live_page_bytes
             live = [p for p in child_pages if p is not None]
-            counts = [int(c) for c in jax.device_get(
-                [p.num_rows for p in live])]
+            counts = [int(c) for c in host_read(
+                [p.num_rows for p in live], "exchange_counts")]
             rows = sum(counts)
             nbytes = sum(live_page_bytes(p, c)
                          for p, c in zip(live, counts))
@@ -680,7 +686,8 @@ class DistributedQueryRunner(LocalQueryRunner):
             while True:
                 out, overflow = self._exchange_jit(
                     "a2a", keys, bucket)(global_page)
-                if int(np.max(np.asarray(jax.device_get(overflow)))) == 0:
+                if int(np.max(np.asarray(host_read(
+                        overflow, "exchange_overflow")))) == 0:
                     break
                 bucket *= 2
                 if bucket > cap:
@@ -809,7 +816,7 @@ def _unstack_page(global_page: Page, n: int) -> List[Optional[Page]]:
             key=lambda s: (s.index[0].start or 0) if s.index else 0)
         if len(shards) != n:
             # replicated or single-device leaf: slice on host
-            data = jax.device_get(leaf)
+            data = host_read(leaf, "split_shards")
             for k in range(n):
                 per_shard[k].append(jnp.asarray(data[k]))
             continue

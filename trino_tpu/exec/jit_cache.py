@@ -32,6 +32,23 @@ call time (defensive — shardings or weak types drifting) falls back to
 the plain jitted callable rather than failing the query, counted as
 `aot_fallbacks`.
 
+The host's side of a dispatch (PR 39): every call of a program — the
+dispatch `profiled_kernel` hands out and the callable `cached_kernel`
+hands out — is the activity `kernel_call` of the calling query's
+collector (`obs/stats.activity`; detail: the program's name), every
+read of a device value by the executor thread goes through `host_read`
+here, the activity `host_read` (detail: the site), and a scan pulls its
+pages through `pulled`, the activity `page_pull`. Compiles are counted
+where XLA reports them: one `jax.monitoring` listener, registered when
+this module is imported, hears `backend_compile_duration` on the thread
+that compiled and tells that thread's observer (`backend_compile`) — so a
+cached kernel retraced for new avals, which compiles inside `jax.jit`
+where neither `_first_call` nor `_aot_compile` sees it and which
+`jit_misses` never counted, is a compile with a name. The event fires on
+a persistent-compilation-cache hit too (there with the retrieval's
+wall), after `/jax/compilation_cache/cache_hits` on the same thread: such
+a one is counted as a reload, apart.
+
 Interaction with the on-disk persistent XLA cache
 (trino_tpu.enable_persistent_cache): this
 LRU caches *loaded executables + traces in-process*; the persistent cache
@@ -44,20 +61,25 @@ disk entry serves every literal variant of a shape across processes.
 from __future__ import annotations
 
 import collections
+import contextlib
+import numbers
 import re
 import threading
 import time
 from typing import Any, Callable, Dict, Hashable, Optional, Tuple
 
 import jax
+import jax.monitoring
 import numpy as np
 
+from trino_tpu.obs.stats import NO_ACTIVITY
 from trino_tpu.page import family_context, trace_notes
 
 # key -> [jitted kernel, last-seen flattened param signature or None,
 #         {input signature -> AOT compiled executable} (profiled path),
 #         {input signature -> what the program's trace noted} (`named`),
-#         whether the kernel was called yet (`cached_kernel`'s first call)]
+#         whether the kernel was called yet (`cached_kernel`'s first call),
+#         program_name(key)]
 _CACHE: "collections.OrderedDict[Hashable, list]" = \
     collections.OrderedDict()
 # concurrent queries (the server's executor pool) share this cache; the
@@ -88,7 +110,8 @@ _TLS = threading.local()
 def set_observer(observer) -> None:
     """Install/clear (None) this thread's per-query jit observer — an
     object with jit_hit(key)/jit_miss(key) and optionally
-    jit_param_hit(key) / add_compile(wall_s, hlo_ops, flops, nbytes)."""
+    jit_param_hit(key) / add_compile(wall_s, hlo_ops, nbytes) /
+    activity(name, detail) / backend_compile(...)."""
     _TLS.observer = observer
 
 
@@ -96,6 +119,93 @@ def get_observer():
     """This thread's per-query observer (the executing query's
     QueryStatsCollector), or None outside runner.execute()."""
     return getattr(_TLS, "observer", None)
+
+
+def observed_activity(name: str, detail: Optional[str] = None,
+                      collector=None):
+    """`collector.activity(name, detail)` (default: this thread's
+    observer's, which on an executor thread is the query's collector), or
+    a no-op where there is none to tell: the executor's one door to
+    `obs/stats.activity`."""
+    activity = getattr(collector if collector is not None
+                       else get_observer(), "activity", None)
+    return NO_ACTIVITY if activity is None else activity(name, detail)
+
+
+def host_read(tree, site: str, collector=None):
+    """`jax.device_get(tree)` as the activity `host_read` of `collector`
+    (default: this thread's observer): the one door through which an
+    executor thread reads a device value — a row count, a key range, an
+    overflow flag — and so the one place where it stops to wait for the
+    device. `site` names the call site (a bounded set). A Python or NumPy
+    integer is on the host already: it passes through untimed and
+    uncounted."""
+    if isinstance(tree, numbers.Integral):
+        return tree
+    with observed_activity("host_read", site, collector):
+        return jax.device_get(tree)
+
+
+_NO_PAGE = object()
+
+
+def pulled(pages, source: str):
+    """`pages`, each pull of the next one the activity `page_pull` —
+    `source` says where the page comes from: `connector`, `scan_cache` or
+    `table_cache`. The activity closes before the page is handed on: it
+    never spans a `yield`."""
+    it = iter(pages)
+    while True:
+        with observed_activity("page_pull", source):
+            page = next(it, _NO_PAGE)
+        if page is _NO_PAGE:
+            return
+        yield page
+
+
+# ---------------------------------------------------------------------------
+# compiles, as XLA reports them (the module docstring)
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_TRACE_LOWER = ("/jax/core/compile/jaxpr_trace_duration",
+                "/jax/core/compile/jaxpr_to_mlir_module_duration")
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+def _on_duration_event(event: str, duration_s: float, **kwargs) -> None:
+    if event == _BACKEND_COMPILE:
+        reloaded = getattr(_TLS, "cache_hit", False)
+        _TLS.cache_hit = False
+        report = getattr(get_observer(), "backend_compile", None)
+        if report is not None:
+            report(str(kwargs.get("fun_name", "?")), duration_s, reloaded,
+                   getattr(_TLS, "in_compile_span", False))
+    elif event in _TRACE_LOWER:
+        observer = get_observer()
+        if hasattr(observer, "trace_lower_s"):
+            observer.trace_lower_s += duration_s
+
+
+def _on_event(event: str, **kwargs) -> None:
+    if event == _CACHE_HIT:     # the next backend_compile here is a reload
+        _TLS.cache_hit = True
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration_event)
+jax.monitoring.register_event_listener(_on_event)
+
+
+@contextlib.contextmanager
+def _compiling(name: str):
+    """The activity `compile` around one of the jit cache's own compile
+    sites, which stamp their own `compile` span: a compile XLA reports
+    from inside is not given a second one."""
+    _TLS.in_compile_span = True
+    try:
+        with observed_activity("compile", name):
+            yield
+    finally:
+        _TLS.in_compile_span = False
 
 
 def _param_signature(params) -> Tuple:
@@ -223,7 +333,8 @@ def _lookup(key: Hashable, build: Callable[[], Callable],
             while len(_CACHE) >= _MAX_KERNELS:
                 _CACHE.popitem(last=False)
                 _STATS["evictions"] += 1
-            entry = _CACHE[key] = [fn, sig, {}, program.noted, False]
+            entry = _CACHE[key] = [fn, sig, {}, program.noted, False,
+                                   program.__name__]
             _STATS["misses"] += 1
             miss = True
         else:
@@ -255,12 +366,16 @@ def cached_kernel(key: Hashable, build: Callable[[], Callable],
     """
     entry = _lookup(key, build, params)
     fn = entry[0] if entry[4] else _first_call(entry)
-    fenced = _fencing_observer()
-    if fenced is None:
-        return fn                   # the plain path: the jitted callable
+    fenced, activity = _fencing_observer(), _observers_activity()
+    if activity is None and fenced is None:
+        return fn                   # nobody to tell: the jitted callable
+    activity, name = activity or _no_activity, entry[5]
 
     def dispatch(*args):
-        return _timed(fn, args, fenced)
+        with activity("kernel_call", name):
+            if fenced is None:
+                return fn(*args)
+            return _timed(fn, args, fenced)
     return dispatch
 
 
@@ -281,7 +396,8 @@ def _first_call(entry: list) -> Callable:
             return fn(*args)
         t0 = time.monotonic()
         try:
-            return fn(*args)
+            with _compiling(entry[5]):
+                return fn(*args)
         finally:
             span(t0, time.monotonic())
     return call
@@ -293,6 +409,17 @@ def _fencing_observer():
     is looked up — once per operator, not per dispatch."""
     observer = get_observer()
     return observer if getattr(observer, "fenced", False) else None
+
+
+def _observers_activity():
+    """This thread's observer's `activity`, or None. Asked when a kernel
+    is looked up, as whether to fence is: a dispatch then reads no
+    thread-local and tests no attribute."""
+    return getattr(get_observer(), "activity", None)
+
+
+def _no_activity(name, detail=None):
+    return NO_ACTIVITY
 
 
 def _timed(fn, args: tuple, observer):
@@ -309,11 +436,11 @@ def _timed(fn, args: tuple, observer):
     return out
 
 
-def _aot_compile(key: Hashable, fn, args: tuple, arg_sig, aot: dict):
+def _aot_compile(name: str, fn, args: tuple, arg_sig, aot: dict):
     """Lower + compile one executable for this input signature, timed:
     the explicit XLA-compile event behind compile_time_ms. Records the
-    wall, the HLO instruction count, and the cost-model flops/bytes on
-    the process ledger and the calling query's collector. Concurrent
+    wall and the HLO instruction count on the process ledger and, with
+    the cost model's bytes, on the calling query's collector. Concurrent
     losers of the publish race discard their duplicate and record
     NOTHING — the ledger counts real resident executables, not wasted
     work (full in-flight dedup would need a per-signature latch; the
@@ -321,8 +448,9 @@ def _aot_compile(key: Hashable, fn, args: tuple, arg_sig, aot: dict):
     not be)."""
     from trino_tpu.obs import profiler
     t0 = time.monotonic()       # the spans' clock: this wall is a span
-    lowered = fn.lower(*args)
-    compiled = lowered.compile()
+    with _compiling(name):
+        lowered = fn.lower(*args)
+        compiled = lowered.compile()
     t1 = time.monotonic()
     wall = t1 - t0
     ops = profiler.hlo_op_count(lowered)
@@ -338,7 +466,6 @@ def _aot_compile(key: Hashable, fn, args: tuple, arg_sig, aot: dict):
     observer = get_observer()
     if observer is not None and hasattr(observer, "add_compile"):
         observer.add_compile(wall, hlo_ops=ops,
-                             flops=cost.get("flops", 0.0),
                              nbytes=cost.get("bytes", 0.0), end_s=t1)
     return compiled
 
@@ -351,8 +478,9 @@ def profiled_kernel(key: Hashable, build: Callable[[], Callable],
     hidden inside jax.jit's first call. Same key space, same hit/miss/
     param-hit counters as cached_kernel — a key warmed by one path is
     warm for the other."""
-    fn, _, aot, noted, _ = _lookup(key, build, params)
+    fn, _, aot, noted, _, name = _lookup(key, build, params)
     fenced = _fencing_observer()
+    activity = _observers_activity() or _no_activity
     from trino_tpu.obs import profiler
 
     def _fallback(*args):
@@ -364,6 +492,10 @@ def profiled_kernel(key: Hashable, build: Callable[[], Callable],
         return fn(*args)
 
     def dispatch(*args):
+        with activity("kernel_call", name):
+            return enqueue(*args)
+
+    def enqueue(*args):
         # per-dispatch signature cost is ~10us of pytree flattening —
         # small against the >=100us python dispatch + kernel launch a
         # page already pays, and it is what detects the retrace
@@ -372,7 +504,7 @@ def profiled_kernel(key: Hashable, build: Callable[[], Callable],
             arg_sig = profiler.tree_signature(args)
             compiled = aot.get(arg_sig)
             if compiled is None:
-                compiled = _aot_compile(key, fn, args, arg_sig, aot)
+                compiled = _aot_compile(name, fn, args, arg_sig, aot)
         except Exception:
             return _fallback(*args)
         notes = noted.get(arg_sig)

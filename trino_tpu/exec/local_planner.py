@@ -29,8 +29,9 @@ import numpy as np
 from trino_tpu import types as T
 from trino_tpu.connector.spi import Split
 from trino_tpu.errors import GENERIC_INTERNAL_ERROR, TrinoError
-from trino_tpu.exec.jit_cache import (cached_kernel, get_observer, key_tag,
-                                      profiled_kernel, program_name)
+from trino_tpu.exec.jit_cache import (cached_kernel, get_observer,
+                                      host_read, key_tag, observed_activity,
+                                      profiled_kernel, program_name, pulled)
 from trino_tpu.expr.compiler import compile_expression, compile_filter
 from trino_tpu.expr.ir import (Call, InputRef, Literal, RowExpression,
                                SpecialForm, SpecialKind, SymbolRef)
@@ -307,7 +308,8 @@ def _attributed_chain_call(kernel, key, pending, param_groups, slots,
                 page, param_groups))
         shares = profiler.apportion(wall, weights_box[0])
         count_exit = isinstance(out, Page)
-        n = int(out.num_rows) if count_exit else 0
+        n = int(host_read(out.num_rows, "chain_exit_rows")) \
+            if count_exit else 0
         nbytes = live_page_bytes(out, n) if count_exit else 0
         for st, share in zip(slots, shares):
             if isinstance(st, DeviceShareSlot):
@@ -509,7 +511,10 @@ class LocalExecutionPlanner:
         method = getattr(self, f"_exec_{name}", None)
         if method is None:
             raise ExecutionError(f"no executor for {name}")
-        stream = method(node)
+        # lowering a node: its programs looked up, its streams built — and
+        # a join's build side collected, under its own activities
+        with observed_activity("lower_plan", name):
+            stream = method(node)
         if self.collector is None or not self.collector.operator_level:
             return stream
         return self._instrument(node, stream)
@@ -559,7 +564,7 @@ class LocalExecutionPlanner:
                     return
                 if fence:
                     jax.block_until_ready(page)
-                n = int(page.num_rows)
+                n = int(host_read(page.num_rows, "operator_rows"))
                 st.output_rows += n
                 st.wall_s += _time.perf_counter() - t0
                 st.pages += 1
@@ -592,10 +597,11 @@ class LocalExecutionPlanner:
                 if col is not None:
                     col.table_cache_hit()
                 from trino_tpu.exec.table_cache import build_pages
-                resident = build_pages(entry, col_names, cap)
+                with observed_activity("eager_slice", "table_cache"):
+                    resident = build_pages(entry, col_names, cap)
 
                 def gen_resident(pages=resident):
-                    for page in pages:
+                    for page in pulled(pages, "table_cache"):
                         self._checkpoint()
                         yield page
                 return PageStream(self._sliced(gen_resident()), symbols)
@@ -621,7 +627,7 @@ class LocalExecutionPlanner:
                 self._maybe_promote(tcache, tkey, node, staged, tgen)
 
                 def gen_hit(pages=staged):
-                    for page in pages:
+                    for page in pulled(pages, "scan_cache"):
                         self._checkpoint()
                         yield page
                 return PageStream(self._sliced(gen_hit()), symbols)
@@ -664,8 +670,9 @@ class LocalExecutionPlanner:
             try:
                 for split in splits:
                     self._fault_site("scan", str(node.table))
-                    for page, moved in count_host_staging(
-                            conn.page_source.pages(split, columns, cap)):
+                    for page, moved in count_host_staging(pulled(
+                            conn.page_source.pages(split, columns, cap),
+                            "connector")):
                         self._checkpoint()
                         if col is not None:
                             col.add_scan_staging(page_bytes(page), moved)
@@ -684,8 +691,8 @@ class LocalExecutionPlanner:
                 # constraint, so it must not publish at all.
                 cache.put(key, staging, gen=gen_seen)
             if promote and staging:
-                counts = [int(c) for c in jax.device_get(
-                    [p.num_rows for p in staging])]
+                counts = [int(c) for c in host_read(
+                    [p.num_rows for p in staging], "promote_counts")]
                 tcache.promote_from_pages(
                     tkey, [(c.name, c) for _, c in node.assignments],
                     staging, counts, device=self.mem_device,
@@ -744,8 +751,8 @@ class LocalExecutionPlanner:
             return
         if not tcache.should_promote(tkey, names):
             return
-        counts = [int(c) for c in jax.device_get(
-            [p.num_rows for p in pages])]
+        counts = [int(c) for c in host_read(
+            [p.num_rows for p in pages], "promote_counts")]
         tcache.promote_from_pages(
             tkey, [(c.name, c) for _, c in node.assignments], pages,
             counts, device=self.mem_device, collector=self.collector,
@@ -889,7 +896,7 @@ class LocalExecutionPlanner:
         def gen():
             remaining = node.count
             for page in src.iter_pages():
-                n = int(page.num_rows)
+                n = int(host_read(page.num_rows, "limit_rows"))
                 if n >= remaining:
                     yield Page(page.columns, remaining)
                     return
@@ -903,7 +910,7 @@ class LocalExecutionPlanner:
         def gen():
             to_skip = node.count
             for page in src.iter_pages():
-                n = int(page.num_rows)
+                n = int(host_read(page.num_rows, "offset_rows"))
                 if to_skip >= n:
                     to_skip -= n
                     continue
@@ -951,13 +958,15 @@ class LocalExecutionPlanner:
         every compaction boundary."""
         if not pages:
             return None, 0
-        counts = [int(c) for c in jax.device_get(
-            [p.num_rows for p in pages])]
-        total = sum(counts)
-        if total == 0:
-            return None, 0
-        live = [self._tight(p, c) for p, c in zip(pages, counts) if c > 0]
-        return self._merge_buf(live, total), total
+        with observed_activity("page_concat"):
+            counts = [int(c) for c in host_read(
+                [p.num_rows for p in pages], "merge_counts")]
+            total = sum(counts)
+            if total == 0:
+                return None, 0
+            live = [self._tight(p, c)
+                    for p, c in zip(pages, counts) if c > 0]
+            return self._merge_buf(live, total), total
 
     @staticmethod
     def _tight_capacity(page: Page, n: int) -> int:
@@ -969,9 +978,11 @@ class LocalExecutionPlanner:
 
     @classmethod
     def _tight(cls, page: Page, n: int) -> Page:
-        """Shrink a page to the pow2 envelope of its live count (free
-        device slice; downstream sorts/builds then run at live size)."""
-        return page.shrink_to(cls._tight_capacity(page, n))
+        """Shrink a page to the pow2 envelope of its live count (one
+        eager device slice a column; downstream sorts/builds then run at
+        live size)."""
+        with observed_activity("eager_slice", "shrink_to"):
+            return page.shrink_to(cls._tight_capacity(page, n))
 
     def _device_concat(self, pages: List[Page]) -> Page:
         """Jitted device-side page concatenation (page.device_concat) —
@@ -1025,9 +1036,9 @@ class LocalExecutionPlanner:
                 if use_df:
                     pf_op, pf_args = prefilter
                     masks = [pf_op(p, *pf_args) for p in window]
-                    fetched = jax.device_get(
+                    fetched = host_read(
                         ([p.num_rows for p in window],
-                         [k for _, k in masks]))
+                         [k for _, k in masks]), "coalesce_counts")
                     live, kept = ([int(c) for c in cs] for cs in fetched)
                     if not df_measured:
                         df_measured = True
@@ -1044,7 +1055,8 @@ class LocalExecutionPlanner:
                     else:
                         counts = live
                 else:
-                    counts = jax.device_get([p.num_rows for p in window])
+                    counts = host_read([p.num_rows for p in window],
+                                       "coalesce_counts")
                 for p, c in zip(window, counts):
                     n = int(c)
                     if n == 0:
@@ -1062,17 +1074,19 @@ class LocalExecutionPlanner:
         return PageStream(gen(), stream.symbols)
 
     def _merge_buf(self, buf: List[Page], rows: int) -> Page:
-        page = buf[0] if len(buf) == 1 else self._device_concat(buf)
-        page = self._tight(page, rows)
-        if isinstance(page.num_rows, int):
-            # a scan page counts its rows in a Python int (a weak int64
-            # to a trace), a concatenation in an int32: whether a table
-            # arrives as one page or as two depends on the slice budget,
-            # which the clock retunes (exec/sliced/scheduler.py), so a
-            # blocking operator would compile twice for one shape — TPC-H
-            # Q18's customer build did, 37 s into its second execution
-            page = Page(page.columns, np.int32(page.num_rows))
-        return page
+        with observed_activity("page_concat"):
+            page = buf[0] if len(buf) == 1 else self._device_concat(buf)
+            page = self._tight(page, rows)
+            if isinstance(page.num_rows, int):
+                # a scan page counts its rows in a Python int (a weak
+                # int64 to a trace), a concatenation in an int32: whether
+                # a table arrives as one page or as two depends on the
+                # slice budget, which the clock retunes (exec/sliced/
+                # scheduler.py), so a blocking operator would compile
+                # twice for one shape — TPC-H Q18's customer build did,
+                # 37 s into its second execution
+                page = Page(page.columns, np.int32(page.num_rows))
+            return page
 
     def _free_collected(self, page: Optional[Page]) -> None:
         """Release a _collect reservation at operator scope (the reference
@@ -1136,7 +1150,7 @@ class LocalExecutionPlanner:
                     szop = cached_kernel(
                         ("agg-groupmax", key_channels_t),
                         lambda: group_max_size(key_channels))
-                    got = max(int(jax.device_get(szop(page))), 1)
+                    got = max(int(host_read(szop(page), "group_max_size")), 1)
                     # small pow2 (not the 1024-floor page helper): the
                     # element plane is [capacity, L]
                     L = 1 << (got - 1).bit_length() if got > 1 else 1
@@ -1269,7 +1283,7 @@ class LocalExecutionPlanner:
                 if merged is None:
                     return None, rows_in, 0
                 out = intermediate_op(merged)
-                n = int(jax.device_get(out.num_rows))
+                n = int(host_read(out.num_rows, "agg_compact_rows"))
                 if n == 0:
                     return None, rows_in, 0
                 return self._tight(out, n), rows_in, n
@@ -1277,7 +1291,7 @@ class LocalExecutionPlanner:
             def raw_rows_in():
                 nonlocal raw_counts, raw_carry
                 total = raw_carry + sum(
-                    int(c) for c in jax.device_get(raw_counts)) \
+                    int(c) for c in host_read(raw_counts, "agg_raw_rows")) \
                     if raw_counts else raw_carry
                 raw_counts = []
                 return total
@@ -1303,7 +1317,7 @@ class LocalExecutionPlanner:
                     store = self._new_spill_store(npart)
                 sorted_pg, counts = part_op_for(0)(combined)
                 store.spill_partitioned(sorted_pg,
-                                        jax.device_get(counts))
+                                        host_read(counts, "spill_counts"))
 
             try:
                 for page in src.pages:
@@ -1466,7 +1480,7 @@ class LocalExecutionPlanner:
                     self._checkpoint()
                     sorted_pg, counts = op(chunk)
                     child.spill_partitioned(sorted_pg,
-                                            jax.device_get(counts))
+                                            host_read(counts, "spill_counts"))
                 store.drop(p)
                 yield from self._finalize_agg_spill(
                     child, depth + 1, final_op, intermediate_op,
@@ -1488,7 +1502,7 @@ class LocalExecutionPlanner:
             merged = chunk if state is None \
                 else self._device_concat([state, chunk])
             out = intermediate_op(merged)
-            n = int(jax.device_get(out.num_rows))
+            n = int(host_read(out.num_rows, "agg_compact_rows"))
             state = self._tight(out, n) if n else None
         store.drop(p)
         if state is not None:
@@ -1585,7 +1599,8 @@ class LocalExecutionPlanner:
                     bounds = bounds_op(rank_op(merged), merged.row_mask(),
                                        merged.num_rows)
                 sorted_pg, counts = part_op(merged, bounds)
-                store.spill_partitioned(sorted_pg, jax.device_get(counts))
+                store.spill_partitioned(
+                    sorted_pg, host_read(counts, "spill_counts"))
 
             try:
                 for page in src.iter_pages():
@@ -1652,9 +1667,12 @@ class LocalExecutionPlanner:
             partials = [partial_topn(page) for page in src.pages]
             if not partials:
                 return
-            merged = concat_pages(partials) if len(partials) > 1 \
-                else partials[0]
-            if int(merged.num_rows) == 0:
+            merged = partials[0]
+            if len(partials) > 1:
+                # its two reads (the counts, the live prefixes) are in it
+                with observed_activity("page_concat"):
+                    merged = concat_pages(partials)
+            if int(host_read(merged.num_rows, "topn_rows")) == 0:
                 return
             yield merge_kernel(merged, count)
         return PageStream(gen(), src.symbols)
@@ -1871,7 +1889,7 @@ class LocalExecutionPlanner:
                         probe_stream.symbols[probe_keys[0]].name)
                     if target is not None:
                         scan_node, col_name, col_type = target
-                        lo_h, hi_h = jax.device_get(prefilter[1])
+                        lo_h, hi_h = host_read(prefilter[1], "build_key_range")
                         self.register_dynamic_domain(
                             scan_node, col_name, col_type,
                             lo_h.item(), hi_h.item())
@@ -1944,8 +1962,8 @@ class LocalExecutionPlanner:
                  kmin_d, kmax_d) = prep(build_page)
                 # ONE batched fetch for all four scalars (each fetch is a
                 # device sync)
-                uq, nr, km, kx = jax.device_get(
-                    [is_unique_d, n_rows_d, kmin_d, kmax_d])
+                uq, nr, km, kx = host_read(
+                    [is_unique_d, n_rows_d, kmin_d, kmax_d], "build_key_stats")
                 is_unique, n_rows, kmin, kmax = \
                     bool(uq), int(nr), int(km), int(kx)
             except Exception:
@@ -2047,8 +2065,9 @@ class LocalExecutionPlanner:
                 else:
                     results = [probe_op(p, bkey_s, bperm, n_live)
                                for p in batch]
-                fetched = jax.device_get(
-                    [(t, pre.num_rows) for pre, _, t in results])
+                fetched = host_read(
+                    [(t, pre.num_rows) for pre, _, t in results],
+                    "probe_totals")
                 for (pre, found, _), (total, live) in zip(results, fetched):
                     total, live = int(total), int(live)
                     if total == 0:
@@ -2091,11 +2110,11 @@ class LocalExecutionPlanner:
                                 device=self.mem_device)
             try:
                 self._checkpoint()
-                vals[off:hi] = np.asarray(jax.device_get(
-                    c.values[off:hi]))
+                vals[off:hi] = np.asarray(host_read(
+                    c.values[off:hi], "stage_column"))
                 if valid is not None:
-                    valid[off:hi] = np.asarray(jax.device_get(
-                        c.valid[off:hi]))
+                    valid[off:hi] = np.asarray(host_read(
+                        c.valid[off:hi], "stage_column"))
             finally:
                 self.memory.free(held, "spill-stage",
                                  device=self.mem_device)
@@ -2219,7 +2238,7 @@ class LocalExecutionPlanner:
                 try:
                     sorted_pg, counts = bop(build_source)
                     bstore.spill_partitioned(sorted_pg,
-                                             jax.device_get(counts))
+                                             host_read(counts, "spill_counts"))
                 finally:
                     self._free_collected(build_source)
             else:
@@ -2230,7 +2249,7 @@ class LocalExecutionPlanner:
                     self._checkpoint()
                     sorted_pg, counts = bop(bpage)
                     bstore.spill_partitioned(sorted_pg,
-                                             jax.device_get(counts))
+                                             host_read(counts, "spill_counts"))
                 self._record_spill(bstore.bytes)
             it = probe_stream if isinstance(probe_stream, Iterator) \
                 else self._coalesce_stream(probe_stream).iter_pages()
@@ -2239,7 +2258,7 @@ class LocalExecutionPlanner:
                 self._checkpoint()
                 sorted_pg, counts = pop(page)
                 pstore.spill_partitioned(sorted_pg,
-                                         jax.device_get(counts))
+                                         host_read(counts, "spill_counts"))
             self._record_spill(pstore.bytes)
             yield from self._join_partitions(
                 bstore, pstore, 0, bkeys_t, pkeys_t, join_op, part_op,
@@ -2329,14 +2348,16 @@ class LocalExecutionPlanner:
                         p, bstore.chunk_rows_for(p, threshold)):
                     self._checkpoint()
                     spg, cnt = bop(chunk)
-                    childb.spill_partitioned(spg, jax.device_get(cnt))
+                    childb.spill_partitioned(
+                        spg, host_read(cnt, "spill_counts"))
                 bstore.drop(p)
                 pop = part_op(pkeys, depth + 1)
                 for chunk in pstore.drain_partition_chunks(
                         p, pstore.chunk_rows_for(p, threshold)):
                     self._checkpoint()
                     spg, cnt = pop(chunk)
-                    childp.spill_partitioned(spg, jax.device_get(cnt))
+                    childp.spill_partitioned(
+                        spg, host_read(cnt, "spill_counts"))
                 pstore.drop(p)
                 yield from self._join_partitions(
                     childb, childp, depth + 1, bkeys, pkeys, join_op,
@@ -2455,8 +2476,8 @@ class LocalExecutionPlanner:
             else probe_stream.iter_pages()
         for batch in _byte_bounded_batches(it, 1 << 29):
             results = [probe_op(page, prepared) for page in batch]
-            fetched = jax.device_get(
-                [(t, pre.num_rows) for pre, _, t in results])
+            fetched = host_read(
+                [(t, pre.num_rows) for pre, _, t in results], "probe_totals")
             for (pre, found, _), (total, live) in zip(results, fetched):
                 total, live = int(total), int(live)
                 if total == 0:
@@ -2507,7 +2528,7 @@ class LocalExecutionPlanner:
                 sorted_pg, counts = compact(page)
                 before = len(stage.pieces[0])
                 stage.spill_partitioned(sorted_pg,
-                                        jax.device_get(counts))
+                                        host_read(counts, "spill_counts"))
                 if len(stage.pieces[0]) > before:
                     # dictionaries per APPENDED piece (all-pad pages
                     # append nothing) — stage.meta only remembers the
@@ -2638,8 +2659,8 @@ class LocalExecutionPlanner:
         Returns (prepared [+ table], max_run, lookup)."""
         from trino_tpu.ops.join import build_dense_table
         prepared = self._prepare_build(build_keys, build_page, semi)
-        max_run, kmin, kmax = (int(x) for x in jax.device_get(
-            [prepared[7], prepared[8], prepared[9]]))
+        max_run, kmin, kmax = (int(x) for x in host_read(
+            [prepared[7], prepared[8], prepared[9]], "build_key_stats"))
         rows = inner and max_run <= 1
         span = kmax - kmin + 1 if kmax >= kmin else 0
         # the row table is worth its slots at any fill: one gather runs
@@ -2719,17 +2740,18 @@ class LocalExecutionPlanner:
                         (c.type, c.dictionary) for c in page.columns)
                     cap = max(self.page_capacity, page.capacity)
                     results.append((cap, full_op(cap)(page, prepared)))
-                totals = jax.device_get([t for _, (_, t, _) in results])
+                totals = host_read([t for _, (_, t, _) in results],
+                                   "probe_totals")
                 for page, (cap, (out, _, bm)), total in zip(
                         batch, results, totals):
                     total = int(total)
                     while total > cap:
                         cap = _next_pow2(total)
                         out, t, bm = full_op(cap)(page, prepared)
-                        total = int(t)
+                        total = int(host_read(t, "probe_totals"))
                     matched = matched | bm
                     yield out
-            if int(bp.num_rows) == 0:
+            if int(host_read(bp.num_rows, "build_rows")) == 0:
                 return
             # once-per-query finisher: executed eagerly (its dictionaries
             # are per-query objects — caching on them would pin string
@@ -2753,7 +2775,7 @@ class LocalExecutionPlanner:
         def gen():
             if build_page is None:
                 return
-            nb = int(build_page.num_rows)
+            nb = int(host_read(build_page.num_rows, "build_rows"))
             if nb == 1:
                 # scalar-subquery path: broadcast the single build row
                 def build():
@@ -2774,7 +2796,7 @@ class LocalExecutionPlanner:
                 return
             # general cross join: bounded expansion
             for page in probe_stream.iter_pages():
-                np_rows = int(page.num_rows)
+                np_rows = int(host_read(page.num_rows, "cross_probe_rows"))
                 if np_rows == 0:
                     continue
                 total = np_rows * nb
@@ -2992,7 +3014,7 @@ class LocalExecutionPlanner:
 
         def gen():
             for page in src.iter_pages():
-                total = int(jax.device_get(count_op(page)))
+                total = int(host_read(count_op(page), "unnest_total"))
                 if total == 0:
                     continue
                 yield expand_op(_next_pow2(total))(page)
@@ -3036,7 +3058,7 @@ class LocalExecutionPlanner:
                 # zero rows -> one all-null row (EnforceSingleRowOperator)
                 yield Page(self._null_build_page(node.outputs).columns, 1)
                 return
-            n = int(page.num_rows)
+            n = int(host_read(page.num_rows, "single_row_rows"))
             if n > 1:
                 raise ExecutionError(
                     "Scalar sub-query has returned multiple rows")
@@ -3063,7 +3085,7 @@ class LocalExecutionPlanner:
             remaps = _union_dictionary_remaps(node.symbols, children)
             for it, first, order in children:
                 for page in _chain_first(first, it):
-                    if int(page.num_rows) == 0:
+                    if int(host_read(page.num_rows, "union_rows")) == 0:
                         continue
                     cols = []
                     for i, ch in enumerate(order):
@@ -3199,7 +3221,7 @@ class LocalExecutionPlanner:
             try:
                 for page in src.iter_pages():
                     self._checkpoint()
-                    n = int(page.num_rows)
+                    n = int(host_read(page.num_rows, "writer_rows"))
                     if n == 0:
                         continue
                     out = Page(tuple(page.column(c) for c in order), n)
@@ -3270,19 +3292,20 @@ def _run_with_overflow(probe_stream: PageStream, build_page: Page,
         for page in probe_pages:
             cap = max(page_capacity, page.capacity)
             results.append((cap, make_op(cap)(page, build_page)))
-        totals = jax.device_get([t for _, (_, t) in results])
+        totals = host_read([t for _, (_, t) in results], "probe_totals")
         for page, (cap, (out, _)), total in zip(probe_pages, results,
                                                 totals):
             total = int(total)
             while total > cap:
                 cap = _next_pow2(total)
                 out, t = make_op(cap)(page, build_page)
-                total = int(t)
+                total = int(host_read(t, "probe_totals"))
             # join outputs inherit probe capacity; shrink heavily padded
             # ones so downstream sorts run at live size
             tight = _next_pow2(max(total, 1))
             if cap > 2 * tight:
-                out = out.shrink_to(tight)
+                with observed_activity("eager_slice", "shrink_to"):
+                    out = out.shrink_to(tight)
             yield out
 
 

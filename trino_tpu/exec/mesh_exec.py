@@ -64,7 +64,7 @@ import numpy as np
 
 from trino_tpu import types as T
 from trino_tpu.errors import GENERIC_INTERNAL_ERROR, TrinoError
-from trino_tpu.exec.jit_cache import cached_kernel
+from trino_tpu.exec.jit_cache import cached_kernel, host_read, pulled
 from trino_tpu.exec.local_planner import _layout, _next_pow2, lower_expr
 from trino_tpu.expr.compiler import compile_expression, compile_filter
 from trino_tpu.expr.ir import BoundParam, Call, Literal, SpecialForm
@@ -842,8 +842,9 @@ def read_shard_pages(mesh, conn, splits, columns, cap: int,
         with jax.default_device(device):
             pages: List[Page] = []
             for split in (s for s in splits if s.part % n == shard):
-                for page, moved in count_host_staging(
-                        conn.page_source.pages(split, columns, cap)):
+                for page, moved in count_host_staging(pulled(
+                        conn.page_source.pages(split, columns, cap),
+                        "connector")):
                     if on_page is not None:
                         on_page(page, moved)
                     pages.append(page)
@@ -888,7 +889,7 @@ def _place(mesh, per_shard: List[Optional[Page]], what
 def admit_shards(cache, tkey, names: Sequence[str], page: Page,
                  collector=None, gen: Optional[int] = None) -> bool:
     """Keep a staged global Page as the table's resident shards."""
-    rows = int(np.sum(jax.device_get(page.num_rows)))
+    rows = int(np.sum(host_read(page.num_rows, "shard_rows", collector)))
     return rows > 0 and cache.admit_sharded(tkey, names, page, rows,
                                             collector=collector, gen=gen)
 
@@ -1065,7 +1066,7 @@ def _co_schedule(runner, frag: PlanFragment, remote: RemoteSourceNode,
                 # out) and added it to the query's device time. The
                 # CONVERGED round's wall is what the operators share.
                 round_wall = col.device_time_s - pre_device
-            host_aux = jax.device_get(aux)
+            host_aux = host_read(aux, "mesh_program_aux", col)
             bumps = _ladder_bumps(lowerer, host_aux)
             if not bumps:
                 if stats_on:

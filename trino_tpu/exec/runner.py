@@ -1143,6 +1143,7 @@ class LocalQueryRunner:
         collecting = sink is None or collect_cap is not None
         total = 0
         nbytes = 0
+        from trino_tpu.exec.jit_cache import host_read, observed_activity
         from trino_tpu.exec.memory import live_page_bytes
         with contextlib.ExitStack() as fetching:
             # ONE `result_fetch` span per attempt, opened when the first
@@ -1153,17 +1154,19 @@ class LocalQueryRunner:
             # that produce the later pages.
             for page in stream.iter_pages():
                 self._check_deadline()      # page-batch cancellation point
-                n = int(page.num_rows)
+                n = int(host_read(page.num_rows, "result_rows"))
                 if n == 0:
                     continue
                 if self._collector is not None and not total:
                     fetching.enter_context(self._collector.span(
                         "result_fetch", kind="phase"))
                 nbytes += live_page_bytes(page, n)
-                cols = page.to_host(n)
-                chunk = [tuple(_to_python(cols[j][i], types[j])
-                               for j in range(len(cols)))
-                         for i in range(n)]
+                with observed_activity("to_host"):
+                    cols = page.to_host(n)
+                with observed_activity("rows_to_python"):
+                    chunk = [tuple(_to_python(cols[j][i], types[j])
+                                   for j in range(len(cols)))
+                             for i in range(n)]
                 total += n
                 if sink is not None:
                     sink.put(chunk, checkpoint=self._check_deadline)
@@ -1324,6 +1327,7 @@ class LocalQueryRunner:
         bytes, and wall time (operator/ExplainAnalyzeOperator.java +
         OperatorStats.java via obs/stats.py)."""
         import time
+        from trino_tpu.exec.jit_cache import host_read
         from trino_tpu.obs.stats import (QueryStatsCollector, maybe_phase,
                                          render_analyzed_plan)
         col = self._collector
@@ -1351,7 +1355,7 @@ class LocalQueryRunner:
         with maybe_phase(col, "execution"):
             for page in executor.execute(plan).iter_pages():
                 self._check_deadline()
-                n_out += int(page.num_rows)
+                n_out += int(host_read(page.num_rows, "result_rows", col))
         total = time.perf_counter() - t0
         text = render_analyzed_plan(plan, col, n_out, total)
         return MaterializedResult(["Query Plan"], [T.VARCHAR], [(text,)])

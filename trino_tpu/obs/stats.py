@@ -34,15 +34,35 @@ device - compile is what is left. Unfenced, device work runs
 asynchronously and neither can be told from the execution wall: both
 are null.
 
-The request's life (round 25): five spans of the query's tree, stamped
-on `time.monotonic()` — `queued` (submit -> an executor thread took it),
-`planning`, `execution`, and under execution `compile` (one per AOT
-compile) and `result_fetch` (first result page on the host -> last);
-on a mesh runner also `mesh_stage` (a co-scheduled program's leaf scans
-being staged: nothing of it once the shards are resident).
-`execution`'s self time, its wall minus those children, is dispatch.
-snapshot() carries them as absolute [name, start, end] triples, so
-spans of concurrent queries and a device trace lay on one axis.
+The request's life (round 25): the spans of REQUEST_SPANS in the query's
+tree, stamped on `time.monotonic()` — `queued` (submit -> an executor
+thread took it), `planning`, `execution`, and under execution `compile`
+(one per AOT compile, per first call of a `cached_kernel`, and per
+compile XLA reports outside both: `backend_compile`) and `result_fetch`
+(first result page on the host -> last); on a mesh runner also
+`mesh_stage` (a co-scheduled program's leaf scans being staged: nothing
+of it once the shards are resident). snapshot() carries them as absolute
+[name, start, end] triples, so spans of concurrent queries and a device
+trace lay on one axis.
+
+The executor's host timeline (PR 39): what the executor thread does
+inside `execution` is named where it happens by `activity(name, detail)`
+— `kernel_call`, `host_read`, `page_pull`, `page_concat`, `to_host`,
+`rows_to_python`, `compile`, `lower_plan`, `eager_slice` (ACTIVITIES;
+README lists the sites). An activity is two clock reads into
+`host_s`/`host_n` (self time: an activity nested in another is taken out
+of the outer one) and, under a profiler session, a
+`jax.profiler.TraceAnnotation("host__<name>[:<detail>]")`; a phase enters
+`request__<name>` the same way. With no session the annotation is not
+made and its name not built (`TraceAnnotation.is_enabled()`, the flag the
+annotation itself would test); with one the interval lies in the xplane's
+`/host:` plane on the executor thread's own line, on the device trace's
+clock. What is
+left of `execution` under no activity is the interpreter's: generators,
+`Page` construction, this collector's bookkeeping. It is computed
+(`benchmark/host_timeline.py`), never stamped. An activity never spans a
+`yield`: a generator suspended inside one would put its consumer's work
+under it.
 
 Threading contract: one collector belongs to one query, mutated by that
 query's executor thread only (distributed shards dispatch sequentially
@@ -52,11 +72,14 @@ query end.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import numbers
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from trino_tpu.obs.spans import Span
 
@@ -88,6 +111,56 @@ class OperatorStats:
 
 REQUEST_SPANS = ("queued", "planning", "execution", "compile",
                  "result_fetch", "mesh_stage")
+# the named host activities of an executor thread (the module docstring)
+ACTIVITIES = ("kernel_call", "host_read", "page_pull", "page_concat",
+              "to_host", "rows_to_python", "compile", "lower_plan",
+              "eager_slice")
+# names of the programs XLA compiled for a query that `backend_compiled`
+# keeps: the last few
+_BACKEND_COMPILED_KEPT = 8
+# what stands in where there is nobody to tell (reusable: it holds nothing)
+NO_ACTIVITY = contextlib.nullcontext()
+# whether a profiler session would keep an annotation made now: the flag a
+# TraceAnnotation tests itself, asked before the object and its name exist
+_profiling = TraceAnnotation.is_enabled
+
+
+class _Activity:
+    """One entry of `QueryStatsCollector.activity`. A class with slots and
+    not a `@contextmanager`: the generator form costs a microsecond more
+    an entry, and a chain dispatches a page through several."""
+
+    __slots__ = ("_col", "_name", "_detail", "_annotation", "_closed_s",
+                 "_t0")
+
+    def __init__(self, col: "QueryStatsCollector", name: str,
+                 detail: Optional[str]):
+        self._col = col
+        self._name = name
+        self._detail = detail
+
+    def __enter__(self):
+        col = self._col
+        # seconds of the activities that closed inside the one open now:
+        # the outer one's so far is kept here, mine start at nothing
+        self._closed_s = col._closed_s
+        col._closed_s = 0.0
+        self._annotation = TraceAnnotation(
+            f"host__{self._name}" if self._detail is None
+            else f"host__{self._name}:{self._detail}") \
+            if _profiling() else None
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        wall = time.monotonic() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        col, name = self._col, self._name
+        col.host_s[name] += wall - col._closed_s
+        col.host_n[name] += 1
+        col._closed_s = self._closed_s + wall
+        return False
 
 
 class QueryStatsCollector:
@@ -127,7 +200,6 @@ class QueryStatsCollector:
         self.compile_time_s = 0.0
         self.jit_compiles = 0
         self.compiled_hlo_ops = 0
-        self.estimated_flops = 0.0
         self.estimated_bytes = 0.0
         # hits on a canonical key whose literal parameter values differ
         # from that key's previous call — kernel sharing that per-literal
@@ -266,19 +338,51 @@ class QueryStatsCollector:
         self.semi_join_probe_rows = 0
         self.aggregate_groups_out = 0
         self._rows_on_device: List[Tuple[str, Any]] = []
+        # the executor thread's named host activities (`activity`): self
+        # seconds and entries by name; seconds of those that closed
+        # inside the one open now
+        self.host_s: Dict[str, float] = collections.defaultdict(float)
+        self.host_n: Dict[str, int] = collections.defaultdict(int)
+        self._closed_s = 0.0
+        # compiles as XLA reports them (jit_cache's monitoring listener,
+        # on the thread that compiled): programs it compiled for this
+        # query and their wall, whatever called for them — an AOT site,
+        # a `cached_kernel`'s first call, a retrace of a cached kernel
+        # for new avals, an eager jnp op; executables it loaded from the
+        # persistent compilation cache instead (a reload, not a
+        # compile); and the seconds of tracing and lowering around both
+        # (as reported: a trace nested in another counts in both)
+        self.backend_compiles = 0
+        self.backend_compile_s = 0.0
+        self.backend_cache_loads = 0
+        self.backend_compiled: List[str] = []
+        self.trace_lower_s = 0.0
 
     # ----------------------------------------------------------- spans
 
     @contextlib.contextmanager
     def span(self, name: str, kind: str = "internal", **attrs):
+        """A span of the query's tree. A phase (`planning`, `execution`,
+        `result_fetch`, `mesh_stage`) is also the profiler annotation
+        `request__<name>`, on the same thread line as its activities."""
         s = Span(name, kind=kind, attrs=attrs)
         self._stack[-1].children.append(s)
         self._stack.append(s)
+        annotation = TraceAnnotation(f"request__{name}") \
+            if kind == "phase" and _profiling() else NO_ACTIVITY
         try:
-            yield s
+            with annotation:
+                yield s
         finally:
             s.finish()
             self._stack.pop()
+
+    def activity(self, name: str, detail: Optional[str] = None
+                 ) -> _Activity:
+        """`with collector.activity("host_read", "merge_counts"):` — one
+        named piece of the executor thread's host time (ACTIVITIES).
+        `detail` only from bounded sets: a program name, a site name."""
+        return _Activity(self, name, detail)
 
     @contextlib.contextmanager
     def phase(self, name: str):
@@ -340,7 +444,7 @@ class QueryStatsCollector:
         self.device_time_s += float(wall_s)
 
     def add_compile(self, wall_s: float, hlo_ops: int = 0,
-                    flops: float = 0.0, nbytes: float = 0.0,
+                    nbytes: float = 0.0,
                     end_s: Optional[float] = None) -> None:
         """One XLA compile this query triggered (jit-cache AOT site);
         `end_s` is `time.monotonic()` when it ended: the `compile` span."""
@@ -351,7 +455,6 @@ class QueryStatsCollector:
         self.compile_time_s += float(wall_s)
         self.jit_compiles += 1
         self.compiled_hlo_ops += int(hlo_ops)
-        self.estimated_flops += float(flops)
         self.estimated_bytes += float(nbytes)
 
     def compile_span(self, start_s: float, end_s: float) -> None:
@@ -363,6 +466,31 @@ class QueryStatsCollector:
         self._stack[-1].children.append(Span(
             "compile", kind="phase", start_s=float(start_s),
             end_s=float(end_s), attrs={"first_call": True}))
+
+    def backend_compile(self, fun_name: str, wall_s: float,
+                        reloaded: bool, in_compile_span: bool) -> None:
+        """XLA's own report of one executable made on this query's
+        thread, `wall_s` ago to now: compiled, or `reloaded` from the
+        persistent compilation cache. Outside the jit cache's own
+        `compile` spans (an AOT compile, a `cached_kernel`'s first call)
+        it gets one, so the stall is nobody's dispatch."""
+        if reloaded:
+            self.backend_cache_loads += 1
+        else:
+            self.backend_compiles += 1
+            self.backend_compile_s += float(wall_s)
+            self.backend_compiled.append(fun_name)
+            del self.backend_compiled[:-_BACKEND_COMPILED_KEPT]
+        if not in_compile_span:
+            end = time.monotonic()
+            self._stack[-1].children.append(Span(
+                "compile", kind="phase", start_s=end - float(wall_s),
+                end_s=end, attrs={"backend_compile": fun_name}))
+            # and, after the fact, the activity `compile` closed inside
+            # whatever is open: a jit call that retraced, an eager op
+            self._closed_s += float(wall_s)
+            self.host_s["compile"] += float(wall_s)
+            self.host_n["compile"] += 1
 
     def plan_cache_hit(self) -> None:
         self.plan_cache_hits += 1
@@ -433,10 +561,10 @@ class QueryStatsCollector:
 
     def _read_rows_on_device(self) -> None:
         if self._rows_on_device:
-            import jax
+            from trino_tpu.exec.jit_cache import host_read
             pending, self._rows_on_device = self._rows_on_device, []
-            for (name, _), n in zip(pending, jax.device_get(
-                    [n for _, n in pending])):
+            for (name, _), n in zip(pending, host_read(
+                    [n for _, n in pending], "rows_on_device", self)):
                 setattr(self, name, getattr(self, name) + int(n))
 
     def count_program_notes(self, notes) -> None:
@@ -545,8 +673,18 @@ class QueryStatsCollector:
             if self.fenced else None,
             "jit_compiles": self.jit_compiles,
             "compiled_hlo_ops": self.compiled_hlo_ops,
-            "estimated_flops": self.estimated_flops,
             "estimated_bytes": self.estimated_bytes,
+            "backend_compiles": self.backend_compiles,
+            "backend_compile_ms": round(self.backend_compile_s * 1000, 3),
+            "backend_cache_loads": self.backend_cache_loads,
+            "backend_compiled": list(self.backend_compiled),
+            "trace_lower_ms": round(self.trace_lower_s * 1000, 3),
+            "host_ms": {name: round(s * 1000, 3)
+                        for name, s in self.host_s.items()},
+            "host_calls": dict(self.host_n),
+            "kernel_calls": self.host_n.get("kernel_call", 0),
+            "host_reads": self.host_n.get("host_read", 0),
+            "page_pulls": self.host_n.get("page_pull", 0),
             "plan_cache_hits": self.plan_cache_hits,
             "plan_cache_misses": self.plan_cache_misses,
             "result_cache_hits": self.result_cache_hits,
@@ -646,6 +784,14 @@ def maybe_phase(collector: Optional[QueryStatsCollector], name: str):
     return collector.phase(name)
 
 
+def maybe_activity(collector: Optional[QueryStatsCollector], name: str,
+                   detail: Optional[str] = None):
+    """`collector.activity(...)`, or a no-op without a collector."""
+    if collector is None:
+        return NO_ACTIVITY
+    return _Activity(collector, name, detail)
+
+
 def render_analyzed_plan(plan, collector: QueryStatsCollector,
                          total_rows: int, total_wall_s: float,
                          label: str = "single device") -> str:
@@ -704,6 +850,13 @@ def render_analyzed_plan(plan, collector: QueryStatsCollector,
              f"{collector.plan_cache_misses} misses")
     if collector.spilled_bytes:
         text += f", spilled {_fmt_bytes(collector.spilled_bytes)}"
+    calls, named_s = collector.host_n, collector.host_s
+    text += (f"\nhost: {calls.get('kernel_call', 0)} calls / "
+             f"{calls.get('host_read', 0)} reads / "
+             f"{calls.get('page_pull', 0)} pulls, "
+             + " / ".join(f"{name} {named_s[name] * 1000:.2f}ms"
+                          for name in ACTIVITIES if name in named_s)
+             + f", {collector.backend_compiles} backend compiles")
     if (collector.agg_mode_downgrades or collector.agg_mode_upgrades
             or collector.agg_recursions or collector.join_recursions
             or collector.heavy_key_splits or collector.spill_fallbacks):
