@@ -459,9 +459,11 @@ def test_direct_reduce_forms_and_the_sorted_path_give_the_same_rows(
                 rows[form] = _agg_rows(_run_steps(page, keys, specs, steps),
                                        len(keys))
             notes[form] = set(said)
+    said_sorted = notes.pop("sorted")
     assert notes == {"masked": {"direct_reduce_masked"},
-                     "scatter": {"direct_reduce_scattered"},
-                     "sorted": set()}
+                     "scatter": {"direct_reduce_scattered"}}
+    assert said_sorted and all(
+        fact.startswith("sorted_reduce_scan:") for fact in said_sorted)
     if selection == "empty":
         assert rows["masked"] == {}
     elif selection == "rows":
@@ -520,3 +522,235 @@ def test_slot_count_at_the_crossover_and_one_past_it(monkeypatch, past,
     want = _agg_rows(jax.jit(hash_aggregate([0], specs, Step.SINGLE))(
         page), 1)
     assert got == want and len(got) > 1000
+
+
+# ------------------------------------------------------------------
+# The sorted GROUP BY's reduce (PR 40): a segmented scan over the sorted
+# lanes and one compaction, against NumPy's reduceat over the same rows.
+
+_SORTED_LAYOUTS = ("mixed", "selection", "one_group", "own_groups",
+                   "long_groups", "ends_on_last_lane", "empty")
+_SORTED_POOL = ["AIR", "FOB", "MAIL", "RAIL", "SHIP", "TRUCK"]   # sorted
+
+
+def _sorted_columns(layout, cap):
+    """NumPy columns of one input page: (key, key_valid, live, inputs)
+    with inputs = [(values, valid)] for BIGINT, DECIMAL(12,2), DOUBLE, a
+    dictionary string's codes and the BOOLEAN of a FILTER."""
+    import numpy as np
+    rng = np.random.default_rng(40 + cap + _SORTED_LAYOUTS.index(layout))
+    rows = {"mixed": cap * 4 // 5, "selection": cap * 9 // 10,
+            "one_group": cap - 3, "own_groups": cap, "long_groups": cap - 1,
+            "ends_on_last_lane": cap, "empty": 0}[layout]
+    key = rng.integers(0, max(cap // 8, 2), cap)
+    key_valid = np.ones(cap, dtype=bool)
+    if layout in ("mixed", "selection"):
+        key_valid = rng.random(cap) > 0.05          # NULL is one more group
+    elif layout == "one_group":
+        key[:] = 7                 # longer than 2^k + 1 lanes for every k
+    elif layout == "own_groups":
+        key = rng.permutation(cap)
+    elif layout == "long_groups":
+        # groups of 2^k + 2 rows, k = 0, 1, 2, ..., in shuffled row order
+        lengths, k = [], 0
+        while sum(lengths) + (1 << k) + 2 <= rows:
+            lengths.append((1 << k) + 2)
+            k += 1
+        key[:] = len(lengths)      # what is left: one more group
+        key[:sum(lengths)] = np.repeat(np.arange(len(lengths)), lengths)
+        key[:rows] = rng.permutation(key[:rows])
+    elif layout == "ends_on_last_lane":
+        key[rng.choice(cap, 5, replace=False)] = cap   # sorts last: 5 rows
+    live = np.arange(cap) < rows
+    if layout == "selection":
+        live &= rng.random(cap) > 0.3
+    inputs = [
+        (rng.integers(-2**40, 2**40, cap), rng.random(cap) > 0.2),
+        (rng.integers(-10**7, 10**7, cap), rng.random(cap) > 0.2),
+        (rng.uniform(1.0, 1e6, cap), rng.random(cap) > 0.2),
+        (rng.integers(0, len(_SORTED_POOL), cap).astype(np.int32),
+         rng.random(cap) > 0.2),
+        (rng.random(cap) > 0.4, rng.random(cap) > 0.1)]
+    return key, key_valid, live, inputs
+
+
+def _sorted_specs(cap=1024):
+    """(spec, input column of `inputs` or None, FILTER). Every kind of
+    state (dtype x reducer) is one more pair of round sets to compile, so
+    the large page carries Q18's kinds and one of each other type, the
+    small one all of them."""
+    from trino_tpu import types as T
+    from trino_tpu.ops import AggSpec
+    dec = T.DecimalType(12, 2)
+    specs = [(AggSpec("sum", 2, dec), 1, False),
+             (AggSpec("count", None, None), None, False),
+             (AggSpec("min", 4, T.VARCHAR), 3, False),
+             (AggSpec("sum", 2, dec, 5), 1, True)]
+    if cap > 1024:
+        return specs
+    return specs + [(AggSpec("avg", 3, T.DOUBLE), 2, False),
+                    (AggSpec("sum", 1, T.BIGINT), 0, False),
+                    (AggSpec("min", 1, T.BIGINT), 0, False),
+                    (AggSpec("max", 1, T.BIGINT), 0, False),
+                    (AggSpec("count", 1, T.BIGINT), 0, False),
+                    (AggSpec("avg", 1, T.BIGINT), 0, False),
+                    (AggSpec("avg", 2, dec), 1, False),
+                    (AggSpec("min", 2, dec), 1, False),
+                    (AggSpec("sum", 3, T.DOUBLE), 2, False),
+                    (AggSpec("max", 3, T.DOUBLE), 2, False),
+                    (AggSpec("max", 4, T.VARCHAR), 3, False),
+                    (AggSpec("count", None, None, 5), None, True)]
+
+
+def _sorted_reference(specs, key, key_valid, live, inputs):
+    """-> (group keys with NULL as None's stand-in -1 last, [(values,
+    valid)] an aggregate), by NumPy's reduceat over the stably sorted live
+    rows."""
+    import numpy as np
+
+    from trino_tpu import types as T
+    idx = np.flatnonzero(live)
+    k = np.where(key_valid, key, 0)[idx]
+    order = idx[np.lexsort((k, ~key_valid[idx]))]   # NULL keys last
+    ks, kv = np.where(key_valid, key, 0)[order], key_valid[order]
+    if len(order) == 0:
+        return ks, kv, None
+    first = np.ones(len(order), dtype=bool)
+    first[1:] = (ks[1:] != ks[:-1]) | (kv[1:] != kv[:-1])
+    starts = np.flatnonzero(first)
+    out = []
+    filt = inputs[4][0] & inputs[4][1]
+    for spec, src, filtered in specs:
+        mask = np.ones(len(order), dtype=bool)
+        vals = np.zeros(len(order), dtype=np.int64)
+        if src is not None:
+            vals, mask = inputs[src][0][order], inputs[src][1][order]
+        if filtered:
+            mask = mask & filt[order]
+        cnt = np.add.reduceat(mask.astype(np.int64), starts)
+        if spec.name == "count":
+            out.append((cnt, np.ones(len(starts), dtype=bool)))
+            continue
+        if spec.name in ("sum", "avg"):
+            if spec.name == "avg" \
+                    and not isinstance(spec.input_type, T.DecimalType):
+                vals = vals.astype(np.float64)      # avg(BIGINT) is DOUBLE
+            tot = np.add.reduceat(np.where(mask, vals, 0), starts)
+            if spec.name == "avg":
+                den = np.maximum(cnt, 1)
+                if tot.dtype == np.float64:
+                    tot = tot / den
+                else:       # decimal: HALF_UP at the column's scale
+                    half = den // 2
+                    adj = np.where(tot >= 0, tot + half, tot - half)
+                    tot = np.sign(adj) * (np.abs(adj) // den)
+            out.append((tot, cnt > 0))
+            continue
+        red = np.minimum if spec.name == "min" else np.maximum
+        if vals.dtype == np.float64:
+            ident = np.inf if spec.name == "min" else -np.inf
+        else:
+            info = np.iinfo(vals.dtype)
+            ident = info.max if spec.name == "min" else info.min
+        out.append((red.reduceat(np.where(mask, vals, ident), starts),
+                    cnt > 0))
+    return ks[starts], kv[starts], out
+
+
+def _sorted_page(layout, cap, extra_selection=None):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from trino_tpu import types as T
+    from trino_tpu.page import Column, Dictionary, Page
+    key, key_valid, live, inputs = _sorted_columns(layout, cap)
+    types = (T.BIGINT, T.DecimalType(12, 2), T.DOUBLE, T.VARCHAR, T.BOOLEAN)
+    pool = Dictionary(np.array(_SORTED_POOL, dtype=object))
+    cols = [Column(jnp.asarray(key), jnp.asarray(key_valid), T.BIGINT, None)]
+    for (vals, valid), t in zip(inputs, types):
+        cols.append(Column(jnp.asarray(vals), jnp.asarray(valid), t,
+                           pool if t is T.VARCHAR else None))
+    rows = int(live.sum()) if layout != "selection" \
+        else int(np.flatnonzero(live)[-1]) + 1
+    page = Page(tuple(cols), jnp.asarray(rows, dtype=jnp.int32))
+    selection = None
+    if layout == "selection":
+        selection = live
+    if extra_selection is not None:
+        selection = extra_selection if selection is None \
+            else selection & extra_selection
+    return page if selection is None else \
+        page.with_selection(jnp.asarray(selection))
+
+
+_SORTED_OPS: dict = {}
+
+
+def _sorted_op(step, cap):
+    """One jitted operator a (step, capacity), for every layout."""
+    import jax
+
+    from trino_tpu.ops import Step, hash_aggregate
+    if (step, cap) not in _SORTED_OPS:
+        specs = [s for s, _, _ in _sorted_specs(cap)]
+        chans = None if step in (Step.SINGLE, Step.PARTIAL) \
+            else _state_channels(1, specs)
+        _SORTED_OPS[step, cap] = jax.jit(
+            hash_aggregate([0], specs, step, chans))
+    return _SORTED_OPS[step, cap]
+
+
+@pytest.mark.parametrize("cap", [1024, 131072])
+@pytest.mark.parametrize("steps", ["single", "partial_final",
+                                   "partial_intermediate_final"])
+@pytest.mark.parametrize("layout", _SORTED_LAYOUTS)
+def test_sorted_path_reduces_like_numpy(layout, steps, cap):
+    """sum / min / max / count / avg over BIGINT, DECIMAL(12,2), DOUBLE and
+    a dictionary string, with NULL inputs, a FILTER mask, dead rows and a
+    deferred selection, through the sorted path's scan (a BIGINT key has
+    no slot table), on every step. The merge steps see each group's state
+    twice: the page's even and odd rows go through PARTIAL apart."""
+    import numpy as np
+
+    from trino_tpu.ops import Step
+    from trino_tpu.page import concat_pages, trace_notes
+    with trace_notes():         # the ops are traced once a module: keep
+        if steps == "single":   # the notes out of whoever listens
+            # (every page carries a selection, so one program serves all)
+            out = _sorted_op(Step.SINGLE, cap)(
+                _sorted_page(layout, cap, np.ones(cap, dtype=bool)))
+        else:
+            even = np.arange(cap) % 2 == 0
+            halves = [_sorted_op(Step.PARTIAL, cap)(
+                _sorted_page(layout, cap, m)) for m in (even, ~even)]
+            for half in halves:
+                assert half.selection is None
+            # each half holds at most cap / 2 groups
+            out = concat_pages(halves).pad_to(cap)
+            if steps == "partial_intermediate_final":
+                out = _sorted_op(Step.INTERMEDIATE, cap)(out)
+            out = _sorted_op(Step.FINAL, cap)(out)
+    assert out.selection is None
+    ks, kv, want = _sorted_reference(_sorted_specs(cap),
+                                     *_sorted_columns(layout, cap))
+    n = int(out.num_rows)
+    assert n == len(ks)
+    if n == 0:
+        return
+    kcol = out.column(0)
+    got_kv = np.asarray(kcol.valid_mask())[:n]
+    got_k = np.where(got_kv, np.asarray(kcol.values)[:n], 0)
+    order = np.lexsort((got_k, ~got_kv))
+    assert np.array_equal(got_k[order], ks)
+    assert np.array_equal(got_kv[order], kv)
+    for ci, ((spec, _, _), (vals, valid)) in enumerate(
+            zip(_sorted_specs(cap), want), start=1):
+        col = out.column(ci)
+        got_valid = np.asarray(col.valid_mask())[:n][order]
+        got = np.asarray(col.values)[:n][order]
+        assert np.array_equal(got_valid, valid), (spec, "NULLs")
+        if got.dtype == np.float64:
+            assert np.allclose(got[valid], vals[valid], rtol=1e-9,
+                               atol=0.0), spec
+        else:       # integers, decimals and dictionary codes: bit for bit
+            assert np.array_equal(got[valid], vals[valid]), spec

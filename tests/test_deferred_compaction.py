@@ -336,8 +336,13 @@ def test_deferred_chain_aggregates_the_rows_the_compacting_chain_does(name):
         assert deferred_partial.selection is None
         text = jit_cache._CACHE[chain_key][0].lower(page, groups).as_text(
             debug_info=True)
+        # the filters move no row; what is compacted is the sorted
+        # reduce's own states, a lane a group (PR 40)
         for tag in ("compact_gather", "compact_slots", "compact_shift"):
-            assert tag not in text, tag
+            assert text.count(tag) == text.count(
+                f"aggregate__segment_reduce/aggregate__{tag}"), tag
+        assert ("aggregate__compact_shift" in text) \
+            == (key_channels not in ((), (FLAG,)))   # the sorted path
         # a dictionary key's four slots reduce under slot masks (PR 29)
         assert ("aggregate__direct_masked_reduce" in text) \
             == (key_channels == (FLAG,))

@@ -161,9 +161,12 @@ def test_chain_steps_and_tail_each_have_a_scope():
                   "aggregate__agg_partial"):
         assert f"/{scope}/" in text, scope
     # the partial aggregate takes the filter's mask as a selection: the
-    # chain compacts nothing (PR 26)
+    # chain compacts nothing (PR 26); the sorted reduce moves its own
+    # states to a lane a group (PR 40)
     for tag in ("compact_gather", "compact_slots", "compact_shift"):
-        assert tag not in text, tag
+        assert text.count(tag) == text.count(
+            f"aggregate__segment_reduce/aggregate__{tag}"), tag
+    assert "compact_gather" not in text
     # shared kernels take the family of the operator that called them
     assert "aggregate__agg_partial/aggregate__group_sort/" \
            "aggregate__radix_pass" in text
@@ -479,6 +482,61 @@ def test_joins_count_their_lookups_and_the_lanes_they_ran_over(
             stats["probe_lookups_search"]) == (row_table, position_table, 0)
     assert len(lanes) >= row_table + position_table
     assert stats["probe_lookup_lanes"] == sum(lanes) > 0
+
+
+@pytest.mark.parametrize("sql, sorted_dispatches", [
+    pytest.param(Q6, 0, id="q6"),
+    pytest.param(chip_smoke.Q1, 0, id="q1"),
+    pytest.param(_Q4, 0, id="q4"),
+    pytest.param(_Q18, 2, id="q18")])
+def test_the_sorted_reduce_is_counted_where_it_runs(sql, sorted_dispatches):
+    """`sorted_reduces_scanned` counts the dispatches of a program whose
+    sorted GROUP BY reduced by the segmented scan (PR 40),
+    `sorted_reduce_lanes` the capacities of the pages they ran over. q6
+    has no GROUP BY, q1 and Q4 group by pooled values (the direct path);
+    Q18's inner GROUP BY runs the PARTIAL chain a page and the FINAL
+    kernel, its outer one the same again."""
+    tpch = LocalQueryRunner.tpch("tiny")
+    tpch.execute(sql)
+    stats = tpch.last_query_stats
+    if sorted_dispatches == 0:
+        assert stats["sorted_reduces_scanned"] == 0
+        assert stats["sorted_reduce_lanes"] == 0
+    else:
+        assert stats["sorted_reduces_scanned"] >= sorted_dispatches
+        # a power of two a page, at least a lane a dispatch
+        assert stats["sorted_reduce_lanes"] \
+            >= 1024 * stats["sorted_reduces_scanned"]
+
+
+@pytest.mark.parametrize("step", ["partial", "final", "intermediate",
+                                  "single"])
+def test_the_sorted_group_by_scatters_no_state_column(step):
+    """The sorted path reduces its state columns (here int64 and float64)
+    by a scan over the sorted lanes and moves them by shifts (PR 40): the
+    lowered program's only scatters are the boundary flag's first lane
+    and `aggregate__key_gather`'s int32 row index of each group's first
+    lane, and what it says while it is traced is the counter's fact."""
+    from trino_tpu.ops import AggSpec, hash_aggregate
+    from trino_tpu.page import trace_notes
+    specs = [AggSpec("sum", 1, T.BIGINT), AggSpec("avg", 2, T.DOUBLE),
+             AggSpec("min", 1, T.BIGINT)]
+    page = Page.from_numpy(
+        [jnp.arange(64) % 7, jnp.arange(64), jnp.arange(64) * 0.5],
+        [T.BIGINT, T.BIGINT, T.DOUBLE])
+    chans = None
+    if step in ("final", "intermediate"):
+        page = jax.eval_shape(hash_aggregate([0], specs, "partial"), page)
+        chans = [[1, 2], [3, 4], [5, 6]]
+    with trace_notes() as said:
+        text = jax.jit(hash_aggregate([0], specs, step, chans)) \
+            .lower(page).as_text(dialect="hlo")
+    assert said == {"sorted_reduce_scan:64"}
+    scatters = [line.split(" = ")[1].split(" scatter(")[0]
+                for line in text.splitlines() if " scatter(" in line]
+    assert sorted(t.split("[")[0] for t in scatters) == ["pred", "s32"], \
+        scatters
+    assert "s64[64]" in text and "f64[64]" in text
 
 
 def test_a_cached_kernels_first_call_lies_under_a_compile_span():

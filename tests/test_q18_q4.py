@@ -192,6 +192,21 @@ def test_the_second_cycle_compiles_nothing(served, i):
     assert r["stats"]["spill_fallbacks"] == 0
 
 
+@pytest.mark.parametrize("i", range(2 * CYCLES))
+def test_the_sorted_reduce_runs_in_q18_and_not_in_q4(served, i):
+    """Q18's GROUP BYs (15 000 groups, then the few dozen the HAVING
+    kept) take the sorted path, whose reduce is the segmented scan
+    (PR 40): a PARTIAL chain a page and a FINAL kernel each. Q4 groups by
+    five pooled values: the direct path, no sorted reduce."""
+    stats = served[i]["stats"]
+    if served[i]["shape"] == "q18":
+        assert stats["sorted_reduces_scanned"] >= 2
+        assert stats["sorted_reduce_lanes"] > 0
+    else:
+        assert stats["sorted_reduces_scanned"] == 0
+        assert stats["sorted_reduce_lanes"] == 0
+
+
 def test_q18s_in_is_planned_under_the_join_on_orders():
     """The IN's semi join runs on orders, below both joins (optimizer
     rule PushSemiJoinThroughJoin): the joins see the orders the HAVING

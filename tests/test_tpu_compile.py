@@ -392,30 +392,49 @@ def _device_bytes(compiled) -> int:
             + m.output_size_in_bytes)
 
 
-def test_q18_final_aggregate_at_15m_groups(one_chip):
-    """GROUP BY l_orderkey, final step: 2^24 lanes of (key, sum) states
-    through the sorted path (past `_DIRECT_MAX_GROUPS`): radix passes,
-    boundary scan, segment reduce."""
-    from trino_tpu.ops import AggSpec, Step, hash_aggregate
+@pytest.mark.parametrize("step", ["final", "intermediate"])
+def test_q18_final_aggregate_at_15m_groups(one_chip, step):
+    """GROUP BY l_orderkey, the merge steps: 2^24 lanes of (key, sum,
+    count) states through the sorted path (past `_DIRECT_MAX_GROUPS`):
+    radix passes, boundary scan, then the reduce as a segmented scan over
+    the sorted lanes and one shift compaction (PR 40) — no scatter as
+    long as the page but `aggregate__key_gather`'s int32 row index.
+    INTERMEDIATE is the same merge emitting states (the executor's
+    over-budget compaction)."""
+    from trino_tpu.ops import AggSpec, hash_aggregate
     from trino_tpu.ops.aggregate import get_aggregate
     spec = AggSpec("sum", 1, D12_2)
     states = get_aggregate("sum", D12_2).state(D12_2)
     page = _page(one_chip, GROUP_LANES,
                  (T.BIGINT,) + tuple(s.type for s in states))
-    op = hash_aggregate([0], [spec], Step.FINAL,
+    op = hash_aggregate([0], [spec], step,
                         [list(range(1, 1 + len(states)))])
     compiled = _compile(op, page, limit_s=300)
     assert _device_bytes(compiled) < DEVICE_BUDGET
+    _assert_no_state_scatter(compiled, GROUP_LANES)
+
+
+def _assert_no_state_scatter(compiled, lanes):
+    """No scatter over the page's lanes moves anything 64 bits wide: the
+    state columns are scanned and shifted, and what is still scattered is
+    the 32-bit row index of each group's first lane."""
+    for line in compiled.as_text().splitlines():
+        if " scatter(" in line:
+            result = line.split(" = ")[1].split(" scatter(")[0]
+            assert not (f"[{lanes}]" in result
+                        and result.startswith(("s64", "u64", "f64"))), line
 
 
 def test_q18_partial_aggregate_over_the_lineitem_page(one_chip):
     """The same GROUP BY's partial step over the whole scan page: 60 M
-    lanes in, one state row a group."""
+    lanes in, one state row a group, the states reduced by the segmented
+    scan (26 rounds at this capacity) and moved by shifts."""
     from trino_tpu.ops import AggSpec, Step, hash_aggregate
     page = _page(one_chip, LINEITEM_LANES, (T.BIGINT, D12_2))
     op = hash_aggregate([0], [AggSpec("sum", 1, D12_2)], Step.PARTIAL)
     compiled = _compile(op, page, limit_s=300)
     assert _device_bytes(compiled) < DEVICE_BUDGET
+    _assert_no_state_scatter(compiled, LINEITEM_LANES)
 
 
 def test_q18_probe_of_60m_lanes_against_a_hundred_orders(one_chip):

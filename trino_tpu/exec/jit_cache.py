@@ -369,13 +369,17 @@ def cached_kernel(key: Hashable, build: Callable[[], Callable],
     fenced, activity = _fencing_observer(), _observers_activity()
     if activity is None and fenced is None:
         return fn                   # nobody to tell: the jitted callable
-    activity, name = activity or _no_activity, entry[5]
+    activity, name, noted = activity or _no_activity, entry[5], entry[3]
 
     def dispatch(*args):
         with activity("kernel_call", name):
-            if fenced is None:
-                return fn(*args)
-            return _timed(fn, args, fenced)
+            out = fn(*args) if fenced is None else _timed(fn, args, fenced)
+        if noted:
+            # a program whose trace said something (page.note_trace; the
+            # first call has traced by now): few do, and only they pay
+            # the signature
+            _count_notes(noted.get(_aval_signature(args)), chain=False)
+        return out
     return dispatch
 
 
@@ -401,6 +405,15 @@ def _first_call(entry: list) -> Callable:
         finally:
             span(t0, time.monotonic())
     return call
+
+
+def _count_notes(notes, chain: bool = True) -> None:
+    """One dispatch of a program whose trace noted `notes`, told to the
+    thread's observer (obs/stats.count_program_notes)."""
+    if notes:
+        count = getattr(get_observer(), "count_program_notes", None)
+        if count is not None:
+            count(notes, chain)
 
 
 def _fencing_observer():
@@ -514,10 +527,7 @@ def profiled_kernel(key: Hashable, build: Callable[[], Callable],
             # dispatch already has in hand
             notes = noted[arg_sig] = noted.get(_aval_signature(args),
                                                frozenset())
-        if notes:
-            count = getattr(get_observer(), "count_program_notes", None)
-            if count is not None:
-                count(notes)
+        _count_notes(notes)
         try:
             if fenced is None:
                 return compiled(*args)

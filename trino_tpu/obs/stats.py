@@ -264,6 +264,13 @@ class QueryStatsCollector:
         # masks / with scatter-adds; a program holding both counts in both
         self.direct_reduces_masked = 0
         self.direct_reduces_scattered = 0
+        # dispatches of a program (chain, mesh or `cached_kernel`: the
+        # PARTIAL chain, the FINAL / INTERMEDIATE / SINGLE kernels) whose
+        # sorted GROUP BY reduced its state columns by the segmented scan
+        # (ops/aggregate._scan_reduce), and the capacities of the pages
+        # those reduces ran over (shapes, no sync)
+        self.sorted_reduces_scanned = 0
+        self.sorted_reduce_lanes = 0
         # lake connector pruning (connector/lake/): whole data files
         # and row groups skipped via partition values + min/max zone
         # maps evaluated against the scan's TupleDomain (static
@@ -567,11 +574,20 @@ class QueryStatsCollector:
                     [n for _, n in pending], "rows_on_device", self)):
                 setattr(self, name, getattr(self, name) + int(n))
 
-    def count_program_notes(self, notes) -> None:
+    def count_program_notes(self, notes, chain: bool = True) -> None:
         """One dispatch of a program whose trace noted `notes`
-        (page.note_trace)."""
-        self.direct_reduces_masked += "direct_reduce_masked" in notes
-        self.direct_reduces_scattered += "direct_reduce_scattered" in notes
+        (page.note_trace). `chain`: a chain or mesh program
+        (jit_cache.profiled_kernel), whose direct reduces PR 29 counts; a
+        `cached_kernel` program — the local merge steps — counts its
+        sorted reduces alone."""
+        if chain:
+            self.direct_reduces_masked += "direct_reduce_masked" in notes
+            self.direct_reduces_scattered += \
+                "direct_reduce_scattered" in notes
+        lanes = [int(fact.partition(":")[2]) for fact in notes
+                 if fact.startswith("sorted_reduce_scan:")]
+        self.sorted_reduces_scanned += bool(lanes)
+        self.sorted_reduce_lanes += sum(lanes)
 
     def add_pruned(self, files: int = 0, row_groups: int = 0) -> None:
         self.files_pruned += int(files)
@@ -713,6 +729,8 @@ class QueryStatsCollector:
             "aggregate_groups_out": self.aggregate_groups_out,
             "direct_reduces_masked": self.direct_reduces_masked,
             "direct_reduces_scattered": self.direct_reduces_scattered,
+            "sorted_reduces_scanned": self.sorted_reduces_scanned,
+            "sorted_reduce_lanes": self.sorted_reduce_lanes,
             "files_pruned": self.files_pruned,
             "row_groups_pruned": self.row_groups_pruned,
             "streamed_chunks": self.streamed_chunks,

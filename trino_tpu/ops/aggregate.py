@@ -29,7 +29,8 @@ import jax.numpy as jnp
 
 from trino_tpu import types as T
 from trino_tpu.ops.radix import sort_by_keys
-from trino_tpu.page import Column, Page, note_trace, op_scope
+from trino_tpu.page import (Column, Page, note_trace, op_scope, shift_left,
+                            shift_move, shift_takes)
 
 
 class Step:
@@ -607,7 +608,7 @@ def hash_aggregate(
         with op_scope("aggregate__segment_reduce"):
             agg_cols = _accumulate(page, aggs, resolved, step,
                                    partial_state_channels, perm_sorted, seg,
-                                   n, key_channels, list_len)
+                                   boundary, n, key_channels, list_len)
         out_cols.extend(agg_cols)
         return Page(tuple(out_cols), num_groups)
 
@@ -910,11 +911,117 @@ def _distinct_first_mask(page: Page, key_channels: Sequence[int],
     return jnp.zeros(n, dtype=jnp.bool_).at[perm_s].set(first)
 
 
+_SCAN_OPS = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+
+
+def _scan_reduce(contribs, boundary, live_sorted):
+    """Per-group reduction of every `(contribution, reducer)` of `contribs`
+    over SORTED lanes: `boundary` flags a group's first lane, the live
+    lanes are a prefix and a dead lane contributes its reducer's identity.
+    Group g's state lands on lane g — `jax.ops.segment_*(contrib, seg,
+    num_segments=n)`'s layout, beside the keys `aggregate__key_gather`
+    places — with no index: no scatter, no gather, no sort.
+
+    1. A segmented scan from the right. Lane i is `open` in round k while
+       no group ends in [i, i + 2^k): it then holds the reduction of
+       exactly those lanes, and takes `op(own, lane i + 2^k)`, which holds
+       the next 2^k lanes or as many as the group has left. log2(n) rounds
+       of a select between an array and itself shifted by a static power
+       of two; afterwards a group's FIRST lane holds its state. Which lane
+       is open when depends on the flags alone, so their rounds run once
+       and are kept as the bits of one int32 a lane; every kind of state
+       (dtype x reducer, its states end to end in one array) then scans
+       alone, a round at a time (the barriers, as in `Page.filter`: the
+       scheduler holds two copies of one array, not of every state).
+    2. One order-preserving compaction of the scanned arrays under
+       `boundary` by `Page.filter`'s shift-and-select rounds
+       (`page.shift_takes`, `page.shift_move`): who takes when is worked
+       out once, and every state of every aggregate moves under it.
+
+    Lanes at and past the group count are set to what the scatter left
+    there (0 for a sum, the identity for min/max), so every consumer —
+    `fn.final`, `_agg_out_column`, the INTERMEDIATE step, the pass-through
+    partial's mixing buffer, the HAVING chain step — reads what it read.
+    Integer and decimal sums are the same int64 additions in another
+    order (exact, wrapping alike), min/max are order-free, a double sum
+    runs in a fixed tree order where the scatter's was undefined.
+
+    On a v5e (PERF.md section 6, PR 40, step 0; Q18's inner GROUP BY, two
+    int64 states, fenced): 1 048 576 lanes take 1.98 ms (1.14 in a chain;
+    6.6 s to compile, 26.5 MB of temporaries) against 143.8 ms for the two
+    `segment_sum`s (0.3 s, none); 16 777 216 lanes 127.1 ms (8.2 s,
+    746 MB) against 4 143 ms (0.3 s, 134 MB). Without the rounds'
+    barriers 2.0 and 124.3 ms, and 1 288 MB against 762 in the whole
+    FINAL program. Roads not taken: running sums and one gather at the
+    groups' last lanes (integers only) 87.7 and 1 553 ms — the gather and
+    the scatter of the last lanes' positions cost by the index;
+    `jax.lax.associative_scan` 8.07 ms at 1 048 576 lanes after 75 s of
+    compile, and not compiled in 85 minutes at 16 777 216. The whole
+    sorted aggregate: a PARTIAL page 266.6 -> 125.0 ms, FINAL at 2^24
+    6 481 -> 2 531 ms; what is left is gathers through the sort
+    permutation (the keys', the inputs', the radix passes').
+    """
+    n = boundary.shape[0]
+    # every input's gather through the sort permutation is done before a
+    # round starts: the TPU compiler gives a gather one of two forms by
+    # where its table lives, and with the rounds' arrays wanting the same
+    # fast memory at the same time the tables were moved out and every
+    # gather of the program took three times as long (a Q18 page 201.8 ms
+    # against 125.0 with this barrier, 2^24 lanes 3 914 against 2 531)
+    gathered, boundary, live_sorted = jax.lax.optimization_barrier(
+        ([v for v, _ in contribs], boundary, live_sorted))
+    contribs = [(v, reducer)
+                for v, (_, reducer) in zip(gathered, contribs)]
+    # lane i + 1 continues lane i's group; the last lane's group ends there
+    open_ = shift_left(~boundary & live_sorted, 1)
+    opens = jnp.zeros(n, dtype=jnp.int32)
+    s = 1
+    while s < n:
+        opens = opens | jnp.where(open_, s, 0)
+        open_ = open_ & shift_left(open_, s)
+        open_, opens = jax.lax.optimization_barrier((open_, opens))
+        s *= 2
+    takes, num_groups = shift_takes(boundary)
+    is_group = jnp.arange(n, dtype=jnp.int32) < num_groups
+
+    # states that reduce alike ride ONE array, end to end, each under the
+    # same flags: the program's size (and its compile time, which grew
+    # faster than the states) follows the kinds of state, not their number.
+    # No group runs from one state's lanes into the next's: lane n - 1 is
+    # never open, and no lane takes from beyond lane n - 1
+    alike: dict = {}
+    for i, (v, reducer) in enumerate(contribs):
+        alike.setdefault((v.dtype, reducer), []).append(i)
+    out: list = [None] * len(contribs)
+    for (dtype, reducer), members in alike.items():
+        k = len(members)
+        v = jnp.concatenate([contribs[i][0] for i in members])
+        flags = jnp.tile(opens, k)
+        op = _SCAN_OPS[reducer]
+        s = 1
+        while s < n:
+            # an open lane's partner lies inside its array: what the shift
+            # fills in is never read
+            v = jnp.where((flags & s) != 0, op(v, shift_left(v, s)), v)
+            v, flags = jax.lax.optimization_barrier((v, flags))
+            s *= 2
+        v, _ = shift_move(v, jnp.tile(takes, k), n)
+        rest = jnp.zeros((), dtype) if reducer == "sum" \
+            else _ident_for(dtype, reducer == "min")
+        for j, i in enumerate(members):
+            out[i] = jnp.where(is_group, v[j * n:(j + 1) * n], rest)
+    return out
+
+
 def _accumulate(page, aggs, resolved, step, partial_state_channels,
-                perm_sorted, seg, n, key_channels=(),
+                perm_sorted, seg, boundary, n, key_channels=(),
                 list_len=None) -> List[Column]:
-    """Per-agg state accumulation + (for FINAL/SINGLE) final projection."""
-    out: List[Column] = []
+    """Per-agg state accumulation + (for FINAL/SINGLE) final projection.
+    Every state column of every aggregate that reduces with sum/min/max
+    goes through ONE `_scan_reduce` (its docstring has the chip's timings
+    of this form and of the scatter it replaced); the single-step
+    aggregates keep their own evaluation over `seg`."""
+    live_sorted = seg < n
     dmask_cache: dict = {}
 
     def distinct_mask(spec):
@@ -926,57 +1033,64 @@ def _accumulate(page, aggs, resolved, step, partial_state_channels,
                 mode="clip")
         return dmask_cache[key]
 
+    # first every aggregate's contributions (or, single-step, its finished
+    # column), then one reduce for all of them, then the projections
+    entries: list = []
     for ai, (spec, fn) in enumerate(zip(aggs, resolved)):
         if step in (Step.FINAL, Step.INTERMEDIATE):
             # inputs are partial state columns; merge with each state's
             # reducer (dead rows contribute the reducer identity)
             chans = partial_state_channels[ai]
             states = fn.state(spec.input_type)
-            merged = [
-                _segment_reduce(contrib, seg, n, reducer)
-                for contrib, reducer in _final_state_contribs(
-                    page, states, chans, seg < n, gather=perm_sorted)]
-            if step == Step.INTERMEDIATE:
-                d = page.column(chans[0]).dictionary
-                for sc, arr in zip(states, merged):
-                    sd = d if T.is_string(sc.type) else None
-                    out.append(Column(arr.astype(sc.type.dtype), None,
-                                      sc.type, sd))
-                continue
-            values, valid = fn.final(merged, None)
-            out.append(_agg_out_column(fn, spec, values, valid,
-                                       page.column(chans[0]).dictionary))
+            entries.append((states, page.column(chans[0]).dictionary,
+                            _final_state_contribs(page, states, chans,
+                                                  live_sorted,
+                                                  gather=perm_sorted)))
         elif spec.name in COLLECT_AGGREGATES:
-            out.append(_collect_grouped(page, spec, fn, perm_sorted, seg,
-                                        n, list_len))
+            entries.append(_collect_grouped(page, spec, fn, perm_sorted, seg,
+                                            n, list_len))
         elif spec.name == "approx_distinct":
-            out.append(_hll_grouped(page, spec, key_channels))
+            entries.append(_hll_grouped(page, spec, key_channels))
         elif spec.name == "approx_percentile":
-            out.append(_percentile_grouped(page, spec, key_channels))
+            entries.append(_percentile_grouped(page, spec, key_channels))
         elif spec.name in POSITIONAL_AGGREGATES:
-            out.append(_positional_grouped(page, spec, perm_sorted, seg, n))
+            entries.append(_positional_grouped(page, spec, perm_sorted, seg,
+                                               n))
         elif spec.name in CENTERED_AGGREGATES:
             extra = distinct_mask(spec) if spec.distinct else None
-            out.append(_centered_grouped(page, spec, perm_sorted, seg, n,
-                                         extra))
+            entries.append(_centered_grouped(page, spec, perm_sorted, seg, n,
+                                             extra))
         else:
             states = fn.state(spec.input_type)
-            vals, mask, dictionary = _agg_inputs(page, spec, fn, seg < n,
+            vals, mask, dictionary = _agg_inputs(page, spec, fn, live_sorted,
                                                  gather=perm_sorted)
             if spec.distinct:
                 mask = mask & distinct_mask(spec)
-            state_arrays = []
-            for sc in states:
-                contrib = sc.contrib(vals, mask)
-                state_arrays.append(_segment_reduce(contrib, seg, n, sc.reducer))
-            if step == Step.PARTIAL:
-                for sc, arr in zip(states, state_arrays):
-                    d = dictionary if T.is_string(sc.type) else None
-                    out.append(Column(arr.astype(sc.type.dtype), None, sc.type,
-                                      d))
-            else:  # SINGLE
-                values, valid = fn.final(state_arrays, None)
-                out.append(_agg_out_column(fn, spec, values, valid, dictionary))
+            entries.append((states, dictionary,
+                            [(sc.contrib(vals, mask), sc.reducer)
+                             for sc in states]))
+
+    contribs = [c for e in entries if isinstance(e, tuple) for c in e[2]]
+    reduced = iter(())
+    if contribs:
+        note_trace(f"sorted_reduce_scan:{n}")
+        reduced = iter(_scan_reduce(contribs, boundary, live_sorted))
+
+    out: List[Column] = []
+    for (spec, fn), entry in zip(zip(aggs, resolved), entries):
+        if isinstance(entry, Column):
+            out.append(entry)
+            continue
+        states, dictionary, _ = entry
+        state_arrays = [next(reduced) for _ in states]
+        if step in (Step.PARTIAL, Step.INTERMEDIATE):
+            for sc, arr in zip(states, state_arrays):
+                d = dictionary if T.is_string(sc.type) else None
+                out.append(Column(arr.astype(sc.type.dtype), None, sc.type,
+                                  d))
+        else:   # FINAL, SINGLE
+            values, valid = fn.final(state_arrays, None)
+            out.append(_agg_out_column(fn, spec, values, valid, dictionary))
     return out
 
 

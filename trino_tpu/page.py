@@ -381,11 +381,56 @@ def _running_count(mask: jnp.ndarray) -> jnp.ndarray:
     return (inner + (jnp.cumsum(totals) - totals)[:, None]).reshape(n)
 
 
-def _shift_left(x: jnp.ndarray, s: int) -> jnp.ndarray:
+def shift_left(x: jnp.ndarray, s: int) -> jnp.ndarray:
     """Lane p takes lane p + s along axis 0 (s static); the tail takes
     zeros."""
     tail = jnp.zeros((s,) + x.shape[1:], x.dtype)
     return jnp.concatenate([x[s:], tail], axis=0)
+
+
+def shift_takes(mask: jnp.ndarray):
+    """Who moves when, for `Page.filter`'s compaction under `mask` (its
+    docstring has the argument): -> (takes, kept count), bit k of
+    `takes[p]` set where lane p takes lane p + 2^k in round k. A kept row
+    moves left by d = the dropped rows before it; only d has to run the
+    rounds to say so."""
+    capacity = mask.shape[0]
+    with shared_scope("compact_slots"):
+        kept = _running_count(mask)
+        count = kept[-1]
+        lane = jnp.arange(1, capacity + 1, dtype=jnp.int32)
+        d = jnp.where(mask, lane - kept, 0)
+    with shared_scope("compact_shift"):
+        takes = jnp.zeros(capacity, dtype=jnp.int32)
+        s = 1
+        while s < capacity:
+            d_left = shift_left(d, s)
+            take = (d_left & s) != 0
+            takes = takes | jnp.where(take, s, 0)
+            d = jnp.where(take, d_left, jnp.where((d & s) != 0, 0, d))
+            d, takes = jax.lax.optimization_barrier((d, takes))
+            s *= 2
+    return takes, count
+
+
+def shift_move(a: jnp.ndarray, takes: jnp.ndarray, capacity: int):
+    """One array (1-D, or planes along axis 0) through the rounds that
+    `takes` spells, a round at a time (the barriers): the scheduler holds
+    two copies of one array and not of the page
+    (tests/test_tpu_compile.py). -> (moved array, takes): the next array
+    takes `takes` from here, so it starts when this one is done.
+    `capacity` is the mask's; `a` and `takes` may hold several arrays of
+    that many lanes end to end, each with the mask's `takes`: no lane
+    takes from beyond its own array's end."""
+    with shared_scope("compact_shift"):
+        lanes = (a.shape[0],) + (1,) * (a.ndim - 1)
+        s = 1
+        while s < capacity:
+            take = ((takes & s) != 0).reshape(lanes)
+            a = jnp.where(take, shift_left(a, s), a)
+            a, takes = jax.lax.optimization_barrier((a, takes))
+            s *= 2
+    return a, takes
 
 
 @jax.tree_util.register_pytree_node_class
@@ -522,38 +567,13 @@ class Page:
             return self.with_selection(mask)
         if not self.columns:
             return Page((), jnp.sum(mask).astype(jnp.int32))
-        capacity = self.capacity
-        with shared_scope("compact_slots"):
-            kept = _running_count(mask)
-            count = kept[-1]
-            lane = jnp.arange(1, capacity + 1, dtype=jnp.int32)
-            d = jnp.where(mask, lane - kept, 0)
-        with shared_scope("compact_shift"):
-            # d's own rounds say which lane takes in which round: bit k of
-            # `takes`. Then every array moves alone, a round at a time
-            # (the barriers): the scheduler holds two copies of one array
-            # and not of the page (tests/test_tpu_compile.py)
-            takes = jnp.zeros(capacity, dtype=jnp.int32)
-            s = 1
-            while s < capacity:
-                d_left = _shift_left(d, s)
-                take = (d_left & s) != 0
-                takes = takes | jnp.where(take, s, 0)
-                d = jnp.where(take, d_left, jnp.where((d & s) != 0, 0, d))
-                d, takes = jax.lax.optimization_barrier((d, takes))
-                s *= 2
-            arrays, tree = jax.tree_util.tree_flatten(self.columns)
-            moved = []
-            for a in arrays:
-                lanes = (capacity,) + (1,) * (a.ndim - 1)
-                s = 1
-                while s < capacity:
-                    take = ((takes & s) != 0).reshape(lanes)
-                    a = jnp.where(take, _shift_left(a, s), a)
-                    a, takes = jax.lax.optimization_barrier((a, takes))
-                    s *= 2
-                moved.append(a)
-            columns = jax.tree_util.tree_unflatten(tree, moved)
+        takes, count = shift_takes(mask)
+        arrays, tree = jax.tree_util.tree_flatten(self.columns)
+        moved = []
+        for a in arrays:
+            a, takes = shift_move(a, takes, self.capacity)
+            moved.append(a)
+        columns = jax.tree_util.tree_unflatten(tree, moved)
         return Page(columns, count)
 
     def _partition_perm(self, mask: jnp.ndarray):
