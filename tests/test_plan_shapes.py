@@ -191,13 +191,70 @@ def test_distinct_agg_not_split(runner):
 
 # ------------------------------------------------------------ join ordering
 
-def test_q9_join_order_starts_from_part(runner):
-    """Greedy reorder keeps the selective part-filter side early; regression
-    guard for the q9 ordering that round 2 fixed."""
-    p = plan(runner, QUERIES["q9"][0])
-    joins = p.find("Join")
-    assert len(joins) >= 5
+SCHEMAS = ("tiny", "sf1", "sf10", "sf30", "sf100")
+_RUNNERS = {}
+
+
+def runner_at(schema: str) -> LocalQueryRunner:
+    """A runner over a TPC-H schema of any scale: plans only, no data is
+    generated until a query runs."""
+    if schema not in _RUNNERS:
+        _RUNNERS[schema] = LocalQueryRunner.tpch(schema)
+    return _RUNNERS[schema]
+
+
+@pytest.mark.parametrize("schema", SCHEMAS)
+def test_q9_join_order_starts_from_part(schema):
+    """At every scale factor Q9's innermost join is lineitem against the
+    LIKE-filtered part, and its six tables meet in five joins with no
+    cross join among them. (Until PR 42 the guard ran at `tiny` alone and
+    asked only for five joins and no cross; at `sf10` the plan was
+    supplier x part, 4.3e9 rows: a constant cross-join penalty against
+    costs that grow with the scale, and a two-column key damped like two
+    independent edges.)"""
+    p = plan(runner_at(schema), QUERIES["q9"][0])
+    assert len(p.find("Join")) == 5
     assert not p.has("Join", "cross")
+    first = p.parent_of("Filter", "like(p_name")
+    assert first is not None and first[1] == "Join", p.text
+    assert "l_partkey" in first[2] and "p_partkey" in first[2]
+    assert " AND " not in first[2]
+    at = p.nodes.index(first)
+    kids = [node for _, node in p.children_of(at)]
+    assert [op for _, op, _ in kids] == ["TableScan", "Filter"], p.text
+    assert kids[0][2].endswith(".lineitem")
+    # nothing sits below it but the two scans: it is where the plan starts
+    deepest = max(d for d, op, _ in p.nodes if op == "Join")
+    assert first[0] == deepest
+    # the two-column key joins partsupp to those lines, not to lineitem
+    composite = p.find("Join", " AND ")
+    assert len(composite) == 1 and composite[0][0] == deepest - 1
+
+
+@pytest.mark.parametrize("schema", ["sf10", "sf100"])
+@pytest.mark.parametrize("name", sorted(QUERIES))
+def test_connected_joins_never_cross(schema, name):
+    """`joins_connected_never_cross` (server/app.CAPABILITIES):
+    `reorder_joins` compares plans by their cross joins first and by
+    cost second, so none of the 22 queries plans one at the scales users
+    run (a scalar subquery's single row is attached by a cross join: not
+    one of these)."""
+    p = plan(runner_at(schema), QUERIES[name][0])
+    assert not p.real_cross_joins(), f"{name} at {schema}:\n{p.text}"
+
+
+@pytest.mark.parametrize("name", ["q3", "q4", "q18"])
+def test_the_benchmarks_sf10_plans_print_as_on_the_parent(name):
+    """q3's, Q18's and Q4's SF10 plans, as `EXPLAIN` printed them before
+    PR 42 changed the join reordering (tests/golden_plans_sf10.json)."""
+    import json
+    import os
+    with open(os.path.join(os.path.dirname(__file__),
+                           "golden_plans_sf10.json")) as f:
+        want = json.load(f)[name]
+    got = "\n".join(row[0] for row in runner_at("sf10").execute(
+        "EXPLAIN " + QUERIES[name][0]).rows)
+    assert got == want
 
 
 def test_q21_exists_and_not_exists_shape(runner):

@@ -32,7 +32,8 @@ KNOWN_TAGS = {
              "dense-table", "dense-table-rows", "dfbounds", "dfrange",
              "dfrange-mask", "probe-compact", "spill-prep", "spill-probe",
              "spill-probe-dense", "semijoin-prep",
-             "semijoin-dense-table"],
+             "semijoin-dense-table", "join-composite", "uprobe-composite",
+             "join-prep-composite"],
     "sort": ["sort", "sort-spill-bounds", "sort-spill-part",
              "sort-spill-rank", "topn-masked", "topn", "merge-sort"],
     "window": ["window"],
@@ -334,6 +335,76 @@ def test_semi_and_mark_joins_carry_scopes_of_their_own(join_type,
         assert scope not in text, scope
     for scope in set(re.findall(r"(?<=/)[a-z_]+__[a-z0-9_]+(?=/)", text)):
         assert jit_cache.NAME_GRAMMAR.match(scope), scope
+
+
+@pytest.mark.parametrize("join_type", ["inner", "left", "semi"])
+def test_a_join_on_two_columns_carries_names_of_its_own(join_type):
+    """A key of more than one column (Q9's (partkey, suppkey), PR 42) is
+    mix-hashed, searched and verified: the executor names its programs
+    `join__join_composite` / `join__uprobe_composite` /
+    `join__join_prep_composite` (`local_planner._composite`), and the
+    verification of every candidate has the scope
+    `join__composite_verify`, beside the expansion's `join__probe_expand`."""
+    from trino_tpu.exec.local_planner import _composite
+    from trino_tpu.ops.join import hash_join, prepare_build
+    assert _composite("join", (0, 1)) == "join-composite"
+    assert _composite("uprobe", (3,)) == "uprobe"
+    assert jit_cache.program_name((_composite("join-prep", (0, 1)), (0, 1))) \
+        == "join__join_prep_composite"
+
+    def run(probe, build):
+        prepared = prepare_build([0, 1])(build)
+        return hash_join([0, 1], [0, 1], join_type, prepared=True)(
+            probe, prepared)
+    probe = Page.from_numpy([jnp.arange(64) % 7, jnp.arange(64) % 3],
+                            [T.BIGINT, T.BIGINT])
+    build = Page.from_numpy([jnp.arange(16) % 5, jnp.arange(16) % 3],
+                            [T.BIGINT, T.BIGINT])
+    key = (_composite("join", (0, 1)), join_type)
+    text = jax.jit(jit_cache.named(run, key)).lower(probe, build) \
+        .as_text(debug_info=True)
+    assert "jit(join__join_composite)/" in text
+    assert "/join__composite_verify/" in text
+    assert "/join__probe_expand/" in text
+    for scope in set(re.findall(r"(?<=/)[a-z_]+__[a-z0-9_]+(?=/)", text)):
+        assert jit_cache.NAME_GRAMMAR.match(scope), scope
+    # one key column: hashing is the identity, nothing to verify
+    assert "join__composite_verify" not in _join_text(
+        "inner" if join_type == "semi" else join_type, semi=False)
+
+
+def test_unique_composite_probe_verifies_under_the_scope():
+    from trino_tpu.ops.join import prepare_build, unique_inner_probe
+
+    def run(probe, build):
+        return unique_inner_probe([0, 1], [0, 1])(
+            probe, prepare_build([0, 1])(build))
+    probe = Page.from_numpy([jnp.arange(64) % 7, jnp.arange(64) % 3],
+                            [T.BIGINT, T.BIGINT])
+    build = Page.from_numpy([jnp.arange(16), jnp.arange(16) % 3],
+                            [T.BIGINT, T.BIGINT])
+    text = jax.jit(jit_cache.named(run, ("uprobe-composite",))) \
+        .lower(probe, build).as_text(debug_info=True)
+    assert "jit(join__uprobe_composite)/" in text
+    assert "join__probe_lookup/join__composite_verify" in text
+
+
+def test_like_table_is_an_activity_with_an_annotation():
+    """The host's build of a LIKE table is the activity `like_table`: a
+    name of ACTIVITIES, `host__like_table` under a profiler session, and
+    the counter `like_tables_built` in the snapshot."""
+    from trino_tpu.obs.stats import ACTIVITIES, QueryStatsCollector
+    assert "like_table" in ACTIVITIES
+    tpch = LocalQueryRunner.tpch("tiny")
+    tpch.execute("SELECT count(*) FROM part WHERE p_name LIKE '%green%'")
+    stats = tpch.last_query_stats
+    assert stats["like_tables_built"] == 1
+    assert stats["host_calls"]["like_table"] == 1
+    assert stats["cross_joins"] == 0
+    assert stats["probe_lookup_lanes_search"] == 0
+    for key in ("like_tables_built", "cross_joins",
+                "probe_lookup_lanes_search"):
+        assert QueryStatsCollector().snapshot()[key] == 0
 
 
 @pytest.mark.parametrize("join_type", ["inner", "left"])

@@ -15,11 +15,13 @@ from trino_tpu.metadata import (SERVER_PROPERTY_DOCS,
                                 SESSION_PROPERTY_DOCS)
 
 # constructor parameters that inject collaborators rather than
-# configure behavior — not operator-facing properties
+# configure behavior — not operator-facing properties. `requires` names
+# what a deployment depends on (server/app.CAPABILITIES): the
+# constructor's check reads it and nothing else does
 _WIRING = {
     "self", "runner", "resource_groups", "result_cache", "scan_cache",
     "table_cache", "warmup_manifest", "worker_env", "engine_env",
-    "engine_kwargs",
+    "engine_kwargs", "requires",
 }
 
 

@@ -437,3 +437,59 @@ def test_bad_session_value_fails_unknown_name_tolerated(server):
     info = next(q for q in TRACKER.list() if q.query_id == payload["id"])
     assert info.state == "FAILED"
     assert info.error_name == "INVALID_SESSION_PROPERTY"
+
+
+# ------------------------------------------------- requires (the handshake)
+
+def test_a_server_starts_with_what_it_has():
+    from trino_tpu.server.app import CAPABILITIES
+    assert {"joins_connected_never_cross", "like_pattern_operand"} \
+        <= CAPABILITIES
+    srv = TrinoServer(LocalQueryRunner.tpch("tiny"),
+                      requires=["joins_connected_never_cross",
+                                "like_pattern_operand"]).start()
+    try:
+        assert run_query(srv, "SELECT 7")[2] == [[7]]
+    finally:
+        srv.stop()
+
+
+def test_a_server_that_lacks_a_requirement_does_not_start():
+    """Refused in the constructor: before a session property is set, the
+    warm-up manifest is touched or a port is bound."""
+    class Untouched:
+        def __getattr__(self, name):
+            raise AssertionError(f"the runner was touched: {name}")
+
+    class Manifest:
+        def __getattr__(self, name):
+            raise AssertionError(f"the manifest was touched: {name}")
+
+    with pytest.raises(ValueError, match=r"this engine lacks: "
+                       r"composite_key_table, zz_unknown$"):
+        TrinoServer(Untouched(), warmup_manifest=Manifest(),
+                    requires=["zz_unknown", "like_pattern_operand",
+                              "composite_key_table"])
+
+
+def test_every_capability_is_cited_by_a_test():
+    """A name in CAPABILITIES is a fact a test proves: some test file
+    other than this one names it, and the comment beside the name in
+    server/app.py names a test that exists."""
+    import os
+    import re
+    import trino_tpu.server.app as app
+    here = os.path.dirname(__file__)
+    sources = {f: open(os.path.join(here, f)).read()
+               for f in os.listdir(here)
+               if f.startswith("test_") and f.endswith(".py")
+               and f != os.path.basename(__file__)}
+    with open(app.__file__) as f:
+        block = re.search(r"CAPABILITIES = frozenset\(\{(.*?)\}\)",
+                          f.read(), re.DOTALL).group(1)
+    cited = re.findall(
+        r'# proved by tests/(test_\w+\.py)::(test_\w+)\n\s*"(\w+)"', block)
+    assert {name for _, _, name in cited} == set(app.CAPABILITIES)
+    for file, test, name in cited:
+        assert f"def {test}(" in sources[file], (file, test)
+        assert name in sources[file], (file, name)

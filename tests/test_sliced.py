@@ -97,6 +97,23 @@ def test_scheduler_capacity_cap():
     assert s.capacity_cap(floor=1 << 16) == 1 << 16
 
 
+def test_the_clock_retunes_the_budget_and_never_a_scan_pages_capacity():
+    """A page's capacity is a compiled program's shape: it follows the
+    session's static `slice_target_rows`, whatever the wall-clock EWMA
+    makes of the row budget between checkpoints (ROADMAP D14; TPC-H Q9's
+    six scans took two sets of shapes in turn until PR 42)."""
+    s = SliceScheduler(target_rows=1 << 20, target_ms=250)
+    before = s.capacity_cap(floor=1 << 16)
+    assert before == 1 << 20
+    s.observe(1 << 20, 0.001)           # a cached scan: the budget grows
+    assert s.target_rows == s.max_rows
+    assert s.capacity_cap(floor=1 << 16) == before
+    for _ in range(60):
+        s.observe(1 << 12, 5.0)         # a cold one: it shrinks
+    assert s.target_rows == s.min_rows
+    assert s.capacity_cap(floor=1 << 16) == before
+
+
 def test_scheduler_session_pin():
     from trino_tpu.metadata import Session
     sess = Session()

@@ -488,3 +488,76 @@ def test_q4_semi_join_probe_of_a_quarters_orders(one_chip):
     assert "join__semi_probe" in text
     assert " sort(" not in text
     assert _device_bytes(compiled) < DEVICE_BUDGET
+
+
+# ---- Q9 at SF10 (PR 42): six lineitem columns through part's row table,
+# their compaction, and the join on (partkey, suppkey) -------------------
+
+Q9_PROBE_LANES = 1 << 24        # a coalesced lineitem buffer: 2^29 B / 48 B
+Q9_LINES = 1 << 21              # the ~1.3 M lines of a COLOR's parts
+LINEITEM_Q9 = (T.BIGINT, T.BIGINT, T.BIGINT, D12_2, D12_2, D12_2)
+
+
+def test_q9_probe_of_a_lineitem_buffer_through_parts_row_table(one_chip):
+    """Q9's first join: a buffer of 16 777 216 lineitem lanes carrying the
+    six columns the query reads, looked up by `l_partkey` in the table of
+    build rows over part's 2 000 000 keys (2^21 slots; the build the ~43 K
+    parts a COLOR keeps): one gather, no sort, no scatter, as q3's."""
+    from trino_tpu.ops.join import (build_dense_table, prepare_build,
+                                    unique_inner_probe)
+    build = _page(one_chip, 1 << 16, (T.BIGINT,))
+    prepared = jax.eval_shape(prepare_build([0]), build)
+    table = jax.eval_shape(build_dense_table(1 << 21), prepared[1],
+                           prepared[3], prepared[8], prepared[2])
+    probe = _page(one_chip, Q9_PROBE_LANES, LINEITEM_Q9)
+    op = unique_inner_probe([1], [0], lookup="dense")
+    compiled = _compile(op, probe, prepared + (table,), limit_s=120)
+    text = compiled.as_text()
+    assert len([ln for ln in text.splitlines() if " gather(" in ln]) == 1
+    assert " sort(" not in text and " scatter(" not in text
+    assert _device_bytes(compiled) < DEVICE_BUDGET
+
+
+def test_q9_compaction_carries_six_columns_to_the_kept_rung(one_chip):
+    """The 2.16 % of that buffer's lanes whose part matched, six columns
+    and the build row, gathered to the 524 288-lane rung
+    (`join__probe_compact`): no gather as wide as the buffer."""
+    rung = 1 << 19
+    page = _page(one_chip, Q9_PROBE_LANES, LINEITEM_Q9 + (T.INTEGER,))
+    mask = jax.ShapeDtypeStruct((Q9_PROBE_LANES,), jnp.bool_,
+                                sharding=one_chip)
+    compiled = _compile(lambda p, m: p.compact_to(m, rung), page, mask,
+                        limit_s=120)
+    for line in compiled.as_text().splitlines():
+        if " gather(" in line:
+            assert f"[{Q9_PROBE_LANES}]" not in line.split(" gather(")[0]
+    assert _device_bytes(compiled) < DEVICE_BUDGET
+
+
+@pytest.mark.slow
+def test_q9_composite_search_and_verify_at_partsupps_lanes(one_chip):
+    """The join on (ps_partkey, ps_suppkey) = (l_partkey, l_suppkey) as
+    the fixed plan gives it: partsupp's 8 000 000 rows in one buffer of
+    8 388 608 lanes probe a build of the matching lines (2^21 lanes, six
+    columns, ~7 lines a pair: duplicates, so the expanding `hash_join`).
+    The key is mix-hashed to 64 bits: `search`, the expansion, and
+    `join__composite_verify` over every candidate, each in the program."""
+    from trino_tpu.ops.join import JoinType, hash_join, prepare_build
+    lanes = 1 << 23
+    build = _page(one_chip, Q9_LINES, LINEITEM_Q9)
+    prepared = jax.eval_shape(prepare_build([1, 2]), build)
+    probe = _page(one_chip, lanes, (T.BIGINT, T.BIGINT, D12_2))
+    op = hash_join([0, 1], [1, 2], JoinType.INNER, output_capacity=lanes,
+                   prepared=True, lookup="search", probe_out=(2,),
+                   build_out=(0, 2, 3, 4, 5))
+    compiled = _compile(op, probe, prepared, limit_s=420)
+    text = compiled.as_text()
+    for scope in ("join__probe_lookup", "join__probe_expand",
+                  "join__composite_verify", "join__output_gather"):
+        assert scope in text, scope
+    # marked slow: 143 s to compile for the v5e alone, 244 s beside
+    # tier-1's other compiles, where it pushed Q18's 60 M-lane probe past
+    # its own 300 s limit (here, PR 42); 1.16 GB on the device;
+    # the build's own program — `prepare_build([1, 2])` at these lanes,
+    # 34 s and 0.28 GB — is q3's build sort with the hash mixed in
+    assert _device_bytes(compiled) < DEVICE_BUDGET

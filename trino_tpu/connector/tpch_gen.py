@@ -223,8 +223,33 @@ def row_count(table: str, sf: float) -> int:
     if table == "partsupp":
         return max(1, int(200_000 * sf)) * 4
     if table == "lineitem":
-        return int(_line_index(sf)[1][-1])
+        return _lineitem_rows(sf)
     return _n(table, sf)
+
+
+_LINEITEM_ROWS_CACHE: Dict[int, int] = {}
+
+
+def _lineitem_rows(sf: float) -> int:
+    """Lineitem's row count: the line index's last offset where a scan
+    has built the index, else the per-order line counts summed a few
+    million orders at a time — a plan needs the count alone, and the
+    index of SF100's 150 M orders is 1.35 GB and 40 s to build (6 GB at
+    its peak): `EXPLAIN` at `sf100` no longer pays it (PR 42's plan tests
+    run there)."""
+    key = round(sf * 1000)
+    got = _LINE_INDEX_CACHE.get(key)
+    if got is not None:
+        return int(got[1][-1])
+    rows = _LINEITEM_ROWS_CACHE.get(key)
+    if rows is None:
+        norders, step, rows = _n("orders", sf), 1 << 22, 0
+        for lo in range(0, norders, step):
+            oidx = np.arange(lo, min(lo + step, norders), dtype=np.uint64)
+            rows += int((1 + _u64("lineitem", "l_count", sf, oidx)
+                         % np.uint64(7)).sum())
+        _LINEITEM_ROWS_CACHE[key] = rows
+    return rows
 
 
 # ------------------------------------------------------- column streams

@@ -72,6 +72,7 @@ import jax
 import jax.monitoring
 import numpy as np
 
+from trino_tpu.expr.hoist import LikeOperand
 from trino_tpu.obs.stats import NO_ACTIVITY
 from trino_tpu.page import family_context, trace_notes
 
@@ -221,6 +222,9 @@ def _param_signature(params) -> Tuple:
         if isinstance(p, (tuple, list)):
             for x in p:
                 visit(x)
+        elif isinstance(p, LikeOperand):
+            # a LIKE table not built yet: its pattern is its value
+            out.append(("like", p.pattern, p.escape))
         else:
             a = np.asarray(p)
             out.append((a.dtype.str, a.shape, a.tobytes()))

@@ -19,7 +19,9 @@ executor doubles the capacity bucket and re-runs (hash_join contract).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import threading
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
@@ -133,6 +135,14 @@ class PageStream:
                 yield fn(p)
 
 
+def _composite(tag: str, keys) -> str:
+    """A join program's tag, `-composite` after it where the key has more
+    than one column: such a key is mix-hashed to 64 bits (ops/join._key_u64),
+    so the lookup is `search` and every candidate is verified — the trace
+    gives those programs names of their own."""
+    return f"{tag}-composite" if len(keys) > 1 else tag
+
+
 def chain_keys(pending) -> Tuple:
     return tuple(e[0] for e in pending)
 
@@ -240,6 +250,12 @@ def compose_chain(pending, tail_key=None, tail_builder=None,
             return page
         return run
     kernel = profiled_kernel(key, build, params=param_groups)
+    resolve = _like_tables(key, build, param_groups)
+    if resolve is not None:
+        unresolved = kernel
+
+        def kernel(page, groups):
+            return unresolved(page, resolve(page))
     if any(key_tag(k) in _FILTER_STEPS for k in key[1:]):
         deferred = chain_defers_compaction(key)
         dispatch = kernel
@@ -256,7 +272,64 @@ def compose_chain(pending, tail_key=None, tail_builder=None,
             return kernel(page, param_groups)
         return call
     return _attributed_chain_call(kernel, key, pending, param_groups,
-                                  slots, tail_builder, tail_slot)
+                                  slots, tail_builder, tail_slot, resolve)
+
+
+# (chain key, page structure) -> the dictionary behind each LikeOperand of
+# the chain, in the operands' order: what one abstract trace found
+_LIKE_DICTIONARIES: "collections.OrderedDict" = collections.OrderedDict()
+_LIKE_DICTIONARIES_KEPT = 512
+_LIKE_DICTIONARIES_LOCK = threading.Lock()
+
+
+def _like_tables(key, build, param_groups):
+    """None for a chain without a `LikeOperand` among its parameters
+    (expr/hoist.py), else `resolve(page)`: the chain's parameter groups
+    with every operand replaced by its boolean table over the dictionary
+    the column has in THIS page's program — built on the host, once per
+    (dictionary, pattern) for the `compose_chain` that asked, so once a
+    request; the activity `like_table`, the counter `like_tables_built`.
+
+    Which dictionary: the column a LIKE reads may be any expression at
+    any step of the chain, so its dictionary is known only to a trace.
+    One abstract trace (`jax.eval_shape`: nothing compiles, nothing runs)
+    per chain key and page structure — the structure holds every
+    dictionary, as jit's own cache key does — tells, and is kept."""
+    from trino_tpu.expr.hoist import LikeOperand, finding_dictionaries
+    slots = [(gi, pi) for gi, group in enumerate(param_groups)
+             for pi, p in enumerate(group) if isinstance(p, LikeOperand)]
+    if not slots:
+        return None
+    tables: dict = {}
+
+    def dictionaries(page):
+        at = (key, jax.tree_util.tree_structure(page))
+        with _LIKE_DICTIONARIES_LOCK:
+            found = _LIKE_DICTIONARIES.get(at)
+        if found is None:
+            with finding_dictionaries() as by_operand:
+                jax.eval_shape(lambda p: build()(p, param_groups), page)
+            found = tuple(by_operand[id(param_groups[gi][pi])]
+                          for gi, pi in slots)
+            with _LIKE_DICTIONARIES_LOCK:   # the server's pool shares it
+                while len(_LIKE_DICTIONARIES) >= _LIKE_DICTIONARIES_KEPT:
+                    _LIKE_DICTIONARIES.popitem(last=False)
+                _LIKE_DICTIONARIES[at] = found
+        return found
+
+    def resolve(page):
+        groups = [list(g) for g in param_groups]
+        for (gi, pi), d in zip(slots, dictionaries(page)):
+            table = tables.get((gi, pi, d))
+            if table is None:
+                observer = get_observer()
+                with observed_activity("like_table"):
+                    table = tables[gi, pi, d] = param_groups[gi][pi].table(d)
+                if hasattr(observer, "like_tables_built"):
+                    observer.like_tables_built += 1
+            groups[gi][pi] = table
+        return tuple(tuple(g) for g in groups)
+    return resolve
 
 
 class DeviceShareSlot:
@@ -271,7 +344,7 @@ class DeviceShareSlot:
 
 
 def _attributed_chain_call(kernel, key, pending, param_groups, slots,
-                           tail_builder, tail_slot):
+                           tail_builder, tail_slot, resolve=None):
     """The operator-attribution dispatch wrapper: take the chain
     dispatch's fenced device wall (the jit cache times every dispatch of
     a fenced query, compile wall excluded — `jit_cache._timed`) and split
@@ -305,7 +378,7 @@ def _attributed_chain_call(kernel, key, pending, param_groups, slots,
         if not weights_box:
             weights_box.append(profiler.chain_weights(
                 key, lambda: chain_steps(key, pending, tail_builder),
-                page, param_groups))
+                page, param_groups if resolve is None else resolve(page)))
         shares = profiler.apportion(wall, weights_box[0])
         count_exit = isinstance(out, Page)
         n = int(host_read(out.num_rows, "chain_exit_rows")) \
@@ -366,6 +439,9 @@ class LocalExecutionPlanner:
         # from this tuple, so one cached (value-free) plan re-executes
         # with fresh values through the same warm kernels
         self.exec_params: tuple = ()
+        # the newest `_prepare_probe` decision (`_count_lookup`): what
+        # `_lookup_lanes` counts the probe buffers under
+        self._lookup_decided: Optional[str] = None
         # preemptible sliced execution (exec/sliced/SliceScheduler),
         # installed by the owning runner: leaf page production runs as
         # bounded-work slices with the cooperative boundary (cancel /
@@ -446,17 +522,27 @@ class LocalExecutionPlanner:
     def _count_lookup(self, table: str) -> None:
         """One `_prepare_probe` decision: `row_table`, `position_table`
         or `search`."""
+        self._lookup_decided = table
         if self.collector is not None:
             self.collector.count_probe_lookup(table)
 
     def _lookup_lanes(self, pages) -> Iterator[Page]:
         """The probe buffers a prepared lookup runs over, their lanes
-        (capacities: shapes, no sync) summed as `probe_lookup_lanes`."""
-        for page in (pages.iter_pages() if hasattr(pages, "iter_pages")
-                     else pages):
-            if self.collector is not None:
-                self.collector.probe_lookup_lanes += page.capacity
-            yield page
+        (capacities: shapes, no sync) summed as `probe_lookup_lanes` —
+        and, where the lookup just decided (`_count_lookup`) is `search`,
+        as `probe_lookup_lanes_search` too."""
+        searched = self._lookup_decided == "search"
+
+        def counted():
+            for page in (pages.iter_pages() if hasattr(pages, "iter_pages")
+                         else pages):
+                if self.collector is not None:
+                    self.collector.probe_lookup_lanes += page.capacity
+                    if searched:
+                        self.collector.probe_lookup_lanes_search += \
+                            page.capacity
+                yield page
+        return counted()
 
     def _counted(self, stream: "PageStream", name: str) -> Iterator[Page]:
         """`stream`'s pages, their rows counted under `name`."""
@@ -483,7 +569,7 @@ class LocalExecutionPlanner:
 
     # ------------------------------------------------- literal hoisting
 
-    def _hoist(self, expr):
+    def _hoist(self, expr, chain: bool = False):
         """Canonicalize one lowered expression: (literal-free tree,
         runtime values tuple). When hoisting is disabled, statement
         parameters still bind — as baked-in Literals (per-value kernel
@@ -493,16 +579,18 @@ class LocalExecutionPlanner:
         from trino_tpu.expr.hoist import hoist_literals, materialize_bound
         if not self._hoist_on:
             return materialize_bound(expr, self.exec_params), ()
-        return hoist_literals(expr, bound=self.exec_params)
+        return hoist_literals(expr, bound=self.exec_params,
+                              like_operands=chain)
 
-    def _hoist_seq(self, exprs):
+    def _hoist_seq(self, exprs, chain: bool = False):
         """Canonicalize a projection list with one shared values tuple."""
         from trino_tpu.expr.hoist import hoist_literal_seq, \
             materialize_bound
         if not self._hoist_on:
             return tuple(materialize_bound(e, self.exec_params)
                          for e in exprs), ()
-        return hoist_literal_seq(exprs, bound=self.exec_params)
+        return hoist_literal_seq(exprs, bound=self.exec_params,
+                                 like_operands=chain)
 
     # ------------------------------------------------------------ dispatch
 
@@ -867,7 +955,8 @@ class LocalExecutionPlanner:
             return self._exec_semijoin_filter(node)
         src = self.execute(node.source)
         lay, typ = _layout(src.symbols)
-        pred, prm = self._hoist(lower_expr(node.predicate, lay, typ))
+        pred, prm = self._hoist(lower_expr(node.predicate, lay, typ),
+                                chain=True)
         tag = "agg-having" if isinstance(node.source, AggregationNode) \
             else "filter"
         return PageStream(
@@ -880,7 +969,8 @@ class LocalExecutionPlanner:
         src = self.execute(node.source)
         lay, typ = _layout(src.symbols)
         exprs, prm = self._hoist_seq(
-            tuple(lower_expr(e, lay, typ) for _, e in node.assignments))
+            tuple(lower_expr(e, lay, typ) for _, e in node.assignments),
+            chain=True)
 
         def builder():
             fns = [compile_expression(e) for e in exprs]
@@ -1754,7 +1844,8 @@ class LocalExecutionPlanner:
                     return out.filter(post_filter(out, g)), total
                 return run
             kernel = cached_kernel(
-                ("join", tuple(probe_keys), tuple(build_keys), join_kind,
+                (_composite("join", probe_keys), tuple(probe_keys),
+                 tuple(build_keys), join_kind,
                  cap, post_pred, mode, probe_keep, build_keep), build,
                 params=post_params)
             return lambda p, b: kernel(p, b, post_params)
@@ -1763,7 +1854,8 @@ class LocalExecutionPlanner:
 
         def unique_ops(mode: str):
             probe_op = cached_kernel(
-                ("uprobe", tuple(probe_keys), tuple(build_keys), mode,
+                (_composite("uprobe", probe_keys), tuple(probe_keys),
+                 tuple(build_keys), mode,
                  probe_keep),
                 lambda: unique_inner_probe(probe_keys, build_keys,
                                            lookup=mode,
@@ -2627,7 +2719,8 @@ class LocalExecutionPlanner:
         probe-page kernels consume the prepared tuple without re-sorting.
         `semi`: a semi, anti or mark join's, a program of its own name."""
         prep = cached_kernel(
-            ("semijoin-prep" if semi else "join-prep", tuple(build_keys)),
+            ("semijoin-prep" if semi
+             else _composite("join-prep", build_keys), tuple(build_keys)),
             lambda: prepare_build(build_keys, semi))
         return prep(build_page)
 
@@ -2767,6 +2860,7 @@ class LocalExecutionPlanner:
         return Page(tuple(cols), 0)
 
     def _exec_cross_join(self, node: JoinNode) -> PageStream:
+        self._adaptive_event("cross_joins")
         probe_stream = self.execute(node.left)
         build_stream = self.execute(node.right)
         build_page = self._collect(build_stream)

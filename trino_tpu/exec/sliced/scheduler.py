@@ -17,8 +17,8 @@ the row budget fills it runs the SLICE BOUNDARY protocol —
     the row budget is the mechanism, wall time is the contract
     (cancellation latency is bounded by ONE slice's wall).
 
-The budget also bounds SCAN PAGE CAPACITY (the local planner consults
-`capacity_cap`): without it a statistics-grown scan page is one
+The session's static budget also bounds SCAN PAGE CAPACITY (the local
+planner consults `capacity_cap`): without it a statistics-grown scan page is one
 multi-million-row kernel the engine cannot preempt, which is exactly
 the wedged-kernel problem this subsystem exists to remove. In-kernel
 preemption of a single mega-slice (a checkpointing kernel body) stays
@@ -48,6 +48,9 @@ class SliceScheduler:
                  min_rows: int = MIN_TARGET_ROWS,
                  max_rows: int = MAX_TARGET_ROWS):
         self.target_rows = max(int(target_rows), 1)
+        # the session's own budget, never retuned: what bounds a scan
+        # page (`capacity_cap`)
+        self.page_rows = self.target_rows
         self.target_ms = float(target_ms)
         self.min_rows = max(1, int(min_rows))
         self.max_rows = max(self.min_rows, int(max_rows))
@@ -73,8 +76,19 @@ class SliceScheduler:
         """Pow2 page-capacity bound for leaf scans: one scan page must
         never exceed a slice (a bigger page is one un-preemptible kernel
         launch). `floor` is the session page capacity — slicing never
-        shrinks pages below the engine's normal streaming grain."""
-        cap = 1 << (max(self.target_rows, 1) - 1).bit_length()
+        shrinks pages below the engine's normal streaming grain.
+
+        Read from the session's STATIC budget (`slice_target_rows`), not
+        from the budget the clock retunes: a page's capacity is a
+        compiled program's shape, and a plan lowers its scans as it
+        executes, so a cap that followed the EWMA gave a query's later
+        scans the shapes its earlier slices' wall time chose — TPC-H Q9 at
+        SF10 (six scans) alternated between two sets of shapes from one
+        request to the next, 559 page pulls then 291, each warming the
+        connector's column cache for the other's timing, and every shape
+        the warm-up had not met compiled inside the window (PR 42; ROADMAP
+        D14). The retune still sizes the row budget between checkpoints."""
+        cap = 1 << (max(self.page_rows, 1) - 1).bit_length()
         return max(cap, floor)
 
     def observe(self, rows: int, wall_s: float) -> None:

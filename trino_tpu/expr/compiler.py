@@ -15,6 +15,9 @@ Dictionary folding happens at trace time (dictionaries are static aux data):
   varchar_col < 'FOO'   -> codes < dict.lower_bound('FOO')
   varchar_col LIKE 'F%' -> gather of a host-computed boolean table by code
 so string predicates cost one int32 compare/gather per row on device.
+On the chain path the LIKE table is no constant of the trace but an
+operand (`$like_table`, expr/hoist.py, PR 42): the trace depends on the
+dictionary alone, and every pattern runs the one executable.
 """
 
 from __future__ import annotations
@@ -51,7 +54,9 @@ _COMPARISONS = {"eq", "ne", "lt", "le", "gt", "ge"}
 #   name -> frozenset of arg positions that must stay Literal, or "all"
 #   (skip the whole call — no hoisting anywhere beneath it).
 STATIC_LITERAL_ARGS = {
-    # _like: pattern + escape build a host like-table over the dictionary
+    # _like: pattern + escape build a host like-table over the dictionary.
+    # (The hoister takes a `like` of the chain path before it looks here:
+    # `$like_table` below gets that table as an operand.)
     "like": frozenset({1, 2}),
     # _date_unit_call: the unit string selects the arithmetic at trace time
     "date_trunc": frozenset({0}),
@@ -146,6 +151,8 @@ def _eval_call(expr: Call, page: Page, params=()) -> Column:
         return _format_datetime(expr, page, params)
     if name == "$in_padded":
         return _in_padded(expr, page, params)
+    if name == "$like_table":
+        return _like_table(expr, page, params)
     # --- generic null-propagating scalar ----------------------------------
     impl = F.lookup(name)
     args = [_eval(a, page, params) for a in expr.args]
@@ -236,7 +243,27 @@ def _like(expr: Call, page: Page, params=()) -> Column:
     if len(expr.args) > 2:
         escape = _literal_str(expr.args[2])
     table = F.like_table(col.dictionary, pattern, escape)
+    if not len(table):      # an empty dictionary: a row to clip to
+        table = np.zeros(1, dtype=np.bool_)
     vals = jnp.take(table, col.values, mode="clip")
+    return Column(vals, col.valid, expr.type, None)
+
+
+def _like_table(expr: Call, page: Page, params=()) -> Column:
+    """`$like_table(col, Param)`: LIKE with the boolean table over the
+    column's dictionary as an operand — `hoist.LikeOperand.table`, built
+    by the dispatcher on the host from the dictionary of the page in hand.
+    A Param that still holds the `LikeOperand` itself is the abstract
+    trace by which the dispatcher learns which dictionary that is."""
+    from trino_tpu.expr.hoist import LikeOperand, found_dictionary
+    col = _eval(expr.args[0], page, params)
+    if col.dictionary is None:
+        raise NotImplementedError("LIKE requires a dictionary")
+    table = params[expr.args[1].index]
+    if isinstance(table, LikeOperand):
+        found_dictionary(table, col.dictionary)
+        table = table.placeholder(col.dictionary)
+    vals = jnp.take(jnp.asarray(table), col.values, mode="clip")
     return Column(vals, col.valid, expr.type, None)
 
 

@@ -730,11 +730,16 @@ def like_pattern_to_regex(pattern: str, escape: Optional[str] = None) -> str:
     return "^" + "".join(out) + "$"
 
 
+def like_matcher(pattern: str, escape: Optional[str] = None):
+    """str -> whether it matches the LIKE pattern."""
+    rx = re.compile(like_pattern_to_regex(pattern, escape), re.DOTALL)
+    return lambda s: rx.match(s) is not None
+
+
 def like_table(d: Dictionary, pattern: str,
                escape: Optional[str] = None) -> jnp.ndarray:
-    rx = re.compile(like_pattern_to_regex(pattern, escape), re.DOTALL)
     return dictionary_table(d, ("like", pattern, escape),
-                            lambda s: rx.match(s) is not None)
+                            like_matcher(pattern, escape))
 
 
 def transform_dictionary_nullable(d: Dictionary, key, fn):

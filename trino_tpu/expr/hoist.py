@@ -33,10 +33,25 @@ padding slot's comparison duplicates a real member's comparison, so it
 can never change membership. The bucket width is baked into the
 canonical tree (it IS trace shape).
 
+LIKE patterns (PR 42): on the chain path (`like_operands=True`: the
+filter and project steps `exec/local_planner.compose_chain` runs) a
+`like(x, 'pattern'[, 'escape'])` rewrites to `$like_table(x, Param)` whose
+value is a `LikeOperand` — pattern and escape, no table yet: the boolean
+table over x's dictionary is built on the host at dispatch, once per
+(dictionary, pattern) a request, from the dictionary the page in hand
+carries (`compose_chain` finds which by one abstract trace per chain and
+page structure), and rides in as an operand like `$in_padded`'s vector. The
+kernel keys on the dictionary (static aux data of the page) and not on the
+pattern, so Q9's `p_name LIKE '%green%'` and `'%almond%'` dispatch one
+executable. Everywhere else — a join's residual filter, a mesh program,
+`hoist_literals = false` — the pattern stays a Literal and the table a
+constant of the trace, as before.
+
 What stays static (and why, per call site): see
-expr/compiler.py STATIC_LITERAL_ARGS — LIKE/regex patterns and every
-string-function literal feed host-side per-dictionary tables; date/format
-unit strings select the kernel at trace time. Globally static here:
+expr/compiler.py STATIC_LITERAL_ARGS — regex patterns, a LIKE pattern
+outside the chain path, and every string-function literal feed host-side
+per-dictionary tables; date/format unit strings select the kernel at
+trace time. Globally static here:
 string literals (comparisons fold against the column's dictionary codes
 at trace time), NULL literals (validity structure differs), and booleans
 (worthless to parameterize, often trace-shaping). String/boolean
@@ -49,6 +64,9 @@ leaves, and they size capacities or planes.
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
+import threading
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -61,6 +79,54 @@ from trino_tpu.expr.ir import (BoundParam, Call, Literal, Param,
 # (comparing 8 scalars costs the same fused op as comparing 3 on TPU),
 # so the common dashboard IN-lists all dispatch a single executable
 IN_PAD_MIN_WIDTH = 8
+
+
+@dataclasses.dataclass(frozen=True)
+class LikeOperand:
+    """The value of a `$like_table` Param until dispatch: what the table
+    is made of, not the table. `table(d)` is the operand itself — one
+    boolean per code of `d`, and one `False` for a dictionary with no
+    value at all (a gather needs a row to clip to)."""
+
+    pattern: str
+    escape: Optional[str] = None
+
+    def table(self, d) -> np.ndarray:
+        from trino_tpu.expr.functions import like_matcher
+        matches = like_matcher(self.pattern, self.escape)
+        out = self.placeholder(d)
+        out[:len(d.values)] = [matches(s) for s in d.values]
+        return out
+
+    @staticmethod
+    def placeholder(d) -> np.ndarray:
+        """All `False` at the operand's shape: what the abstract trace
+        that finds the dictionary runs on."""
+        return np.zeros(max(len(d.values), 1), dtype=np.bool_)
+
+
+_FOUND = threading.local()
+
+
+@contextlib.contextmanager
+def finding_dictionaries():
+    """While open on this thread, a trace that evaluates a `$like_table`
+    whose Param still holds its `LikeOperand` tells here which dictionary
+    the column had: {operand identity: Dictionary}."""
+    found = _FOUND.found = {}
+    try:
+        yield found
+    finally:
+        _FOUND.found = None
+
+
+def found_dictionary(operand: LikeOperand, d) -> None:
+    found = getattr(_FOUND, "found", None)
+    if found is None:
+        raise TypeError("a LIKE table reached a trace unresolved")
+    if found.setdefault(id(operand), d) != d:
+        raise NotImplementedError(
+            "one LIKE pattern over two dictionaries in one program")
 
 
 def hoistable(lit: Literal) -> bool:
@@ -89,7 +155,8 @@ def param_value(lit: Literal) -> np.ndarray:
     return np.asarray(value, dtype=lit.type.dtype)
 
 
-def hoist_literals(expr: RowExpression, bound: Tuple = ()
+def hoist_literals(expr: RowExpression, bound: Tuple = (),
+                   like_operands: bool = False
                    ) -> Tuple[RowExpression, Tuple[np.ndarray, ...]]:
     """Canonicalize one lowered expression: (literal-free tree, values).
 
@@ -97,21 +164,33 @@ def hoist_literals(expr: RowExpression, bound: Tuple = ()
     canonical tree of any two literal variants of one shape is identical
     and their values tuples align positionally. `bound` is the statement
     parameter values (EXECUTE ... USING) BoundParam leaves draw from.
+    `like_operands`: the caller resolves `LikeOperand` values at dispatch
+    (the chain path), so LIKE patterns hoist too.
     """
-    values: List[np.ndarray] = []
+    values: List[np.ndarray] = _Values(like_operands)
     out = _walk(expr, values, bound)
     return out, tuple(values)
 
 
-def hoist_literal_seq(exprs: Sequence[RowExpression], bound: Tuple = ()
+def hoist_literal_seq(exprs: Sequence[RowExpression], bound: Tuple = (),
+                      like_operands: bool = False
                       ) -> Tuple[Tuple[RowExpression, ...],
                                  Tuple[np.ndarray, ...]]:
     """Canonicalize a projection list with ONE shared params tuple:
     indices run on across expressions, so the whole operator passes a
     single values tuple to its compiled kernel."""
-    values: List[np.ndarray] = []
+    values: List[np.ndarray] = _Values(like_operands)
     outs = tuple(_walk(e, values, bound) for e in exprs)
     return outs, tuple(values)
+
+
+class _Values(list):
+    """The values list of one hoisting pass, and whether its caller takes
+    `LikeOperand`s among them."""
+
+    def __init__(self, like_operands: bool = False):
+        super().__init__()
+        self.like_operands = like_operands
 
 
 def hoist_into(expr: RowExpression, values: List[np.ndarray],
@@ -172,6 +251,14 @@ def _walk(e: RowExpression, values: List[np.ndarray],
         values.append(param_value(lit))
         return Param(len(values) - 1, e.type)
     if isinstance(e, Call):
+        if e.name == "like" and getattr(values, "like_operands", False):
+            operand = _like_operand(e, bound)
+            if operand is not None:
+                col = _walk(e.args[0], values, bound)
+                values.append(operand)
+                return Call("$like_table",
+                            (col, Param(len(values) - 1, T.BOOLEAN)),
+                            e.type)
         static = STATIC_LITERAL_ARGS.get(e.name)
         if static == "all":
             # the whole call (column subtree included) evaluates inside
@@ -192,6 +279,23 @@ def _walk(e: RowExpression, values: List[np.ndarray],
                            tuple(_walk(a, values, bound) for a in e.args),
                            e.type)
     return e   # InputRef / SymbolRef / already-canonical Param
+
+
+def _like_operand(e: Call, bound: Tuple) -> Optional[LikeOperand]:
+    """The operand of `like(x, pattern[, escape])` when pattern and escape
+    are non-null string literals (or bound parameters), else None: the
+    call stays as it is and `_like` decides."""
+    strings = []
+    for a in e.args[1:]:
+        if isinstance(a, BoundParam):
+            a = _bound_literal(a, bound)
+        if not isinstance(a, Literal) or not T.is_string(a.type) \
+                or a.value is None:
+            return None
+        strings.append(str(a.value))
+    if not 1 <= len(strings) <= 2:
+        return None
+    return LikeOperand(*strings)
 
 
 # ------------------------------------------------------- padded IN-lists

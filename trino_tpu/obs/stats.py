@@ -48,7 +48,8 @@ trace lay on one axis.
 The executor's host timeline (PR 39): what the executor thread does
 inside `execution` is named where it happens by `activity(name, detail)`
 — `kernel_call`, `host_read`, `page_pull`, `page_concat`, `to_host`,
-`rows_to_python`, `compile`, `lower_plan`, `eager_slice` (ACTIVITIES;
+`rows_to_python`, `compile`, `lower_plan`, `eager_slice`, `like_table`
+(ACTIVITIES;
 README lists the sites). An activity is two clock reads into
 `host_s`/`host_n` (self time: an activity nested in another is taken out
 of the outer one) and, under a profiler session, a
@@ -114,7 +115,7 @@ REQUEST_SPANS = ("queued", "planning", "execution", "compile",
 # the named host activities of an executor thread (the module docstring)
 ACTIVITIES = ("kernel_call", "host_read", "page_pull", "page_concat",
               "to_host", "rows_to_python", "compile", "lower_plan",
-              "eager_slice")
+              "eager_slice", "like_table")
 # names of the programs XLA compiled for a query that `backend_compiled`
 # keeps: the last few
 _BACKEND_COMPILED_KEPT = 8
@@ -258,6 +259,17 @@ class QueryStatsCollector:
         self.probe_lookups_position_table = 0
         self.probe_lookups_search = 0
         self.probe_lookup_lanes = 0
+        # ... and those of them that went through `search` (a key of
+        # several columns is mix-hashed to 64 bits, so its span fits no
+        # table: Q9's partsupp join)
+        self.probe_lookup_lanes_search = 0
+        # boolean LIKE tables built on the host for this query, one per
+        # (dictionary, pattern) a chain (local_planner._like_tables): the
+        # activity `like_table` times them
+        self.like_tables_built = 0
+        # CROSS JoinNodes in the plans this query executed (runner): a
+        # connected join graph plans none (optimizer.reorder_joins)
+        self.cross_joins = 0
         # dispatches of a chain or mesh program (jit_cache.
         # profiled_kernel) whose direct GROUP BY (ops/aggregate.
         # _direct_aggregate) reduced its slot table lane-wise under slot
@@ -724,6 +736,9 @@ class QueryStatsCollector:
                 self.probe_lookups_position_table,
             "probe_lookups_search": self.probe_lookups_search,
             "probe_lookup_lanes": self.probe_lookup_lanes,
+            "probe_lookup_lanes_search": self.probe_lookup_lanes_search,
+            "like_tables_built": self.like_tables_built,
+            "cross_joins": self.cross_joins,
             "semi_join_build_rows": self.semi_join_build_rows,
             "semi_join_probe_rows": self.semi_join_probe_rows,
             "aggregate_groups_out": self.aggregate_groups_out,

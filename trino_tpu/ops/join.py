@@ -14,7 +14,9 @@ returns the true match total so the executor can detect overflow and re-run
 at a larger capacity bucket (SURVEY §7 hard part 1).
 
 Composite keys collapse to one u64 via a mixing hash and every join type
-verifies the real key columns post-expansion: INNER/LEFT/FULL filter
+verifies the real key columns post-expansion (scope `join__composite_verify`;
+the executor names such a join's programs `join__join_composite`,
+`join__uprobe_composite`, `join__join_prep_composite`): INNER/LEFT/FULL filter
 collision slots exactly (LEFT/FULL additionally rescue probe rows whose
 every candidate was a collision as null-extension rows), and SEMI/ANTI/MARK
 re-check candidates and scatter the verdict back per probe row. SQL
@@ -401,13 +403,16 @@ def hash_join(
             # candidate, then scatter-or back to probe rows. Exact whenever the
             # hash-expansion fits in cap (else total > cap -> executor re-runs
             # at a bigger bucket, same contract as INNER).
-            keep = slot_live & matched
-            for pk, bk in zip(probe_keys, build_keys):
-                pv = jnp.take(probe.column(pk).values, prow_c, mode="clip")
-                bv = jnp.take(build.column(bk).values, brow, mode="clip")
-                keep = keep & (pv == bv)
-            verified = jnp.zeros(n_probe, dtype=jnp.bool_).at[prow_c].max(
-                keep, mode="drop")
+            with op_scope("join__composite_verify"):
+                keep = slot_live & matched
+                for pk, bk in zip(probe_keys, build_keys):
+                    pv = jnp.take(probe.column(pk).values, prow_c,
+                                  mode="clip")
+                    bv = jnp.take(build.column(bk).values, brow,
+                                  mode="clip")
+                    keep = keep & (pv == bv)
+                verified = jnp.zeros(n_probe, dtype=jnp.bool_) \
+                    .at[prow_c].max(keep, mode="drop")
             if join_type == JoinType.MARK:
                 rows = probe.num_rows.astype(jnp.int64)
                 return mark_page(verified), \
@@ -426,10 +431,13 @@ def hash_join(
         # hash collisions are filtered exactly (single-key u64 is injective)
         keep = jnp.ones(cap, dtype=jnp.bool_)
         if composite and verify_composite:
-            for pk, bk in zip(probe_keys, build_keys):
-                pv = jnp.take(probe.column(pk).values, prow_c, mode="clip")
-                bv = jnp.take(build.column(bk).values, brow, mode="clip")
-                keep = keep & (pv == bv)
+            with op_scope("join__composite_verify"):
+                for pk, bk in zip(probe_keys, build_keys):
+                    pv = jnp.take(probe.column(pk).values, prow_c,
+                                  mode="clip")
+                    bv = jnp.take(build.column(bk).values, brow,
+                                  mode="clip")
+                    keep = keep & (pv == bv)
         verified_slot = real_match & keep
 
         if join_type in (JoinType.LEFT, JoinType.FULL) and composite \
@@ -462,8 +470,9 @@ def hash_join(
         if composite and verify_composite:
             # drop collision slots (null-extension slots pass: matched=False
             # there so keep was never narrowed for them... they start True)
-            keep_final = jnp.where(real_match, keep, True)
-            out_page = out_page.filter(keep_final)
+            with op_scope("join__composite_verify"):
+                keep_final = jnp.where(real_match, keep, True)
+                out_page = out_page.filter(keep_final)
             # overflow contract: if every hash match fit in cap, the filtered
             # count is the exact total; else keep the (over)count so the
             # executor re-plans at a larger capacity
@@ -724,9 +733,11 @@ def unique_inner_probe(
                 brow = jnp.take(bperm, lo_c, mode="clip").astype(jnp.int64)
             if composite and verify_composite:
                 # unique build: at most one candidate — verify it directly
-                for pk, bk in zip(probe_keys, build_keys):
-                    bv = jnp.take(build.column(bk).values, brow, mode="clip")
-                    found = found & (probe.column(pk).values == bv)
+                with op_scope("join__composite_verify"):
+                    for pk, bk in zip(probe_keys, build_keys):
+                        bv = jnp.take(build.column(bk).values, brow,
+                                      mode="clip")
+                        found = found & (probe.column(pk).values == bv)
             brow = jnp.where(found, brow, 0)
         return _pre_page(probe, probe_out, brow), found, \
             jnp.sum(found).astype(jnp.int64)

@@ -20,6 +20,7 @@ the rules that carry TPC-H/DS (SURVEY.md §2.3):
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from trino_tpu import types as T
@@ -1218,6 +1219,24 @@ def _dp_join_tree(sources: List[PlanNode], located,
             # anchored on the edge's smaller endpoint
             d = max(min(rows[a], rows[b]), 1.0)
         edge_info.append((1 << a, 1 << b, 1.0 / d))
+    # a key of several columns between one pair of sources is ONE edge:
+    # the clauses' selectivities multiply, but a relation holds no more
+    # distinct keys than rows, so the pair's selectivity is never under
+    # 1 / its larger side. (Damped apart like independent edges, Q9's
+    # lineitem x partsupp on (partkey, suppkey) read 0.76 M rows at SF10
+    # where the FK gives 60 M, and the plan started there instead of at
+    # the filtered part — and only from SF10 up: the damping does not
+    # scale.)
+    pairs: Dict[Tuple[int, int], List[float]] = {}
+    for ma, mb, sel in edge_info:
+        pairs.setdefault((min(ma, mb), max(ma, mb)), []).append(sel)
+    edge_info = []
+    for (ma, mb), sels in pairs.items():
+        sel = sels[0]
+        if len(sels) > 1:
+            a, b = ma.bit_length() - 1, mb.bit_length() - 1
+            sel = max(math.prod(sels), 1.0 / max(rows[a], rows[b], 1.0))
+        edge_info.append((ma, mb, sel))
 
     from functools import lru_cache
 
@@ -1237,9 +1256,16 @@ def _dp_join_tree(sources: List[PlanNode], located,
         return any(((ea & ma) and (eb & mb)) or ((eb & ma) and (ea & mb))
                    for ea, eb, _ in edge_info)
 
-    best: Dict[int, Tuple[float, Optional[Tuple[int, int]]]] = {}
+    # best[mask] = ((cross joins, cost), split): plans compare by how
+    # many cross joins they hold FIRST and by cost second, so a cross
+    # join survives only where the equality graph of `mask` is genuinely
+    # disconnected — at any scale factor (a constant penalty added to the
+    # cost stopped binding once the costs themselves passed it: Q9 at
+    # SF10 planned supplier x part, 4.3e9 rows; EliminateCrossJoins'
+    # contract)
+    best: Dict[int, Tuple[Tuple[int, float], Optional[Tuple[int, int]]]] = {}
     for i in range(n):
-        best[1 << i] = (0.0, None)
+        best[1 << i] = ((0, 0.0), None)
 
     full = (1 << n) - 1
     # iterate masks in popcount order so sub-results exist
@@ -1248,12 +1274,7 @@ def _dp_join_tree(sources: List[PlanNode], located,
         if mask in best:
             continue
         size = mask_rows(mask)
-        # a cross join (disconnected partition) carries a huge penalty so
-        # it survives ONLY when the equality graph is genuinely
-        # disconnected — parents then avoid any split whose subtree needs
-        # one (EliminateCrossJoins' contract)
-        CROSS_PENALTY = 1e12
-        picked: Optional[Tuple[float, Tuple[int, int]]] = None
+        picked: Optional[Tuple[Tuple[int, float], Tuple[int, int]]] = None
         # enumerate proper submask partitions (canonical: sub contains
         # lowest set bit, so each split is seen once)
         low = mask & (-mask)
@@ -1261,9 +1282,9 @@ def _dp_join_tree(sources: List[PlanNode], located,
         while sub:
             other = mask ^ sub
             if (sub & low) and sub in best and other in best:
-                cost = best[sub][0] + best[other][0] + size
-                if not connects(sub, other):
-                    cost += CROSS_PENALTY
+                (xa, ca), (xb, cb) = best[sub][0], best[other][0]
+                cost = (xa + xb + (not connects(sub, other)),
+                        ca + cb + size)
                 if picked is None or cost < picked[0]:
                     picked = (cost, (sub, other))
             sub = (sub - 1) & mask

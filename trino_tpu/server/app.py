@@ -73,7 +73,7 @@ import time
 import uuid
 import weakref
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 from trino_tpu.errors import QueryCanceledError
 from trino_tpu.exec.resource_groups import ResourceGroupManager
@@ -82,6 +82,17 @@ from trino_tpu.serve.streaming import ResultStream
 from trino_tpu.server import protocol
 
 PAGE_ROWS = 1000
+
+# What a deployment may depend on (`TrinoServer(requires=...)`): a
+# deployment's file names what it needs of the engine, and a server that
+# lacks a name does not start. A name is a fact of this engine that a test
+# proves, never a switch: nothing reads the set but the constructor's check.
+CAPABILITIES = frozenset({
+    # proved by tests/test_plan_shapes.py::test_connected_joins_never_cross
+    "joins_connected_never_cross",
+    # proved by tests/test_q9.py::test_a_new_color_compiles_nothing
+    "like_pattern_operand",
+})
 
 # live servers, for the /v1/metrics serving-tier gauges (weak: a stopped
 # server's registry entry disappears with it)
@@ -199,7 +210,14 @@ class TrinoServer:
                  history_max_entries: Optional[int] = None,
                  drain_timeout_s: float = 10.0,
                  drain_idle_grace_s: float = 1.0,
-                 listen_fd: Optional[int] = None):
+                 listen_fd: Optional[int] = None,
+                 requires: Sequence[str] = ()):
+        # the handshake: a deployment that depends on what this engine
+        # lacks is refused here, before a session property is set or a
+        # table is warmed (CAPABILITIES, above)
+        lacking = sorted(set(requires) - CAPABILITIES)
+        if lacking:
+            raise ValueError(f"this engine lacks: {', '.join(lacking)}")
         self.runner = runner
         # serving tier defaults: the server IS the production front door,
         # so result/scan caching default ON for server sessions (clones
