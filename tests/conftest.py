@@ -65,3 +65,33 @@ def node_pool_leak_gate():
     assert leaked == 0, (
         f"node memory pool leaked {leaked} bytes "
         f"(live contexts: {culprits})")
+
+
+# a worker's memory maps, and the count past which its compiled programs
+# are dropped. Every XLA:CPU executable a test compiles or reloads maps its
+# code and stays mapped while the jit caches hold it — an 8-device mesh
+# program maps thousands of regions — and at `vm.max_map_count` (65 530
+# here) the next one's `mmap` fails inside the compiler or the cache's
+# loader: a segmentation fault, the worker down, whichever test came next
+# failed (`tests/test_distributed_queries.py` run in one process reaches
+# it at its 26th test; under `--dist load` it is luck whether one worker
+# gets that many of them). Dropping the caches unmaps (14 391 -> 776 maps,
+# PR 43); the persistent cache keeps the recompiles short.
+_MAPS_HIGH_WATER = 50_000
+
+
+@pytest.fixture(autouse=True)
+def compiled_programs_stay_mappable():
+    yield
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            maps = sum(1 for _ in f)
+    except OSError:         # no procfs: nothing to count, nothing to fear
+        return
+    if maps > _MAPS_HIGH_WATER:
+        import gc
+
+        from trino_tpu.exec import jit_cache
+        jit_cache.clear()
+        jax.clear_caches()
+        gc.collect()

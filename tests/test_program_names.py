@@ -23,7 +23,8 @@ Q6 = chip_smoke.Q6.format(date="1994-01-01", disc="0.06", qty=24)
 KNOWN_TAGS = {
     "scan_filter": ["filter", "project", "select", "dconcat", "unnest",
                     "unnest-count", "assign-unique-id", "tpch-generate",
-                    "tpch-generate-pooled", "tpch-generate-oidx"],
+                    "tpch-generate-pooled", "tpch-generate-oidx",
+                    "page-cut", "page-cut-tail"],
     "aggregate": ["agg-partial", "agg-bypass", "agg-final",
                   "agg-intermediate", "agg-single", "agg-groupmax",
                   "agg-spill-part", "agg-having"],
@@ -174,6 +175,73 @@ def test_chain_steps_and_tail_each_have_a_scope():
     assert "sort__radix_pass" not in text
     for scope in set(re.findall(r"(?<=/)[a-z_]+__[a-z0-9_]+(?=/)", text)):
         assert jit_cache.NAME_GRAMMAR.match(scope), scope
+
+
+def test_the_walking_chain_keeps_the_chains_name_and_scopes():
+    """The program that walks its scan's pages inside (PR 43) is the chain
+    under the chain's name, each step under its scope inside the loop: a
+    device trace gives its seconds to the owners it gave a page's launch."""
+    from trino_tpu.exec.local_planner import (ColumnSpan, compose_chain,
+                                              compose_walk)
+    from trino_tpu.ops import AggSpec, Step, hash_aggregate
+    from trino_tpu.page import Column
+
+    def filt():
+        return lambda page, params: page.filter(
+            page.column(0).values < params[0])
+
+    def proj():
+        return lambda page, params: Page(
+            (Column(page.column(1).values * 2, None, T.BIGINT, None),),
+            page.num_rows)
+    specs = (AggSpec("sum", 0, T.BIGINT),)
+    pending = ((("filter", "x"), filt, (jnp.int64(5),)),
+               (("project", "y"), proj, ()))
+    tail_key = ("agg-partial", (), specs)
+
+    def tail():
+        return hash_aggregate((), specs, Step.PARTIAL)
+    whole = Page.from_numpy([jnp.arange(256) % 7, jnp.arange(256)],
+                            [T.BIGINT, T.BIGINT]).columns
+    span = ColumnSpan(whole, 200, 64, 0, 4)
+    walk = compose_walk(pending, tail_key, tail, span)
+    out = walk(span)
+    assert int(out.num_rows) == 4           # a state row a page
+    per_page = compose_chain(pending, tail_key, tail)
+    want = sum(int(per_page(Page(tuple(
+        Column(c.values[i * 64:(i + 1) * 64], None, c.type, None)
+        for c in whole), min(64, 200 - i * 64))).columns[0].values[0])
+        for i in range(4))
+    assert int(jnp.sum(out.columns[0].values[:4])) == want
+    key = ("chain", ("filter", "x"), ("project", "y"),
+           tail_key + (("walk", 64, 4),))
+    assert jit_cache.program_name(key) \
+        == "aggregate__chain_filter_project_agg_partial"
+    # the compiled program's `op_name`s: what the device trace reads. The
+    # loop's body is a function of its own in the lowered text, and its
+    # scopes join the program's path only when XLA inlines it
+    names = set(re.findall(r'op_name="([^"]*)"', jit_cache._CACHE[key][0]
+                           .lower(whole, jnp.int32(0), jnp.int32(200),
+                                  ((jnp.int64(5),), ())).compile().as_text()))
+    root = "jit(aggregate__chain_filter_project_agg_partial)/"
+    assert any(n.startswith(f"{root}while/body/") for n in names), names
+    for scope in ("scan_filter__filter", "scan_filter__project",
+                  "aggregate__agg_partial/aggregate__global_reduce"):
+        assert any(n.startswith(f"{root}while/body/closed_call/{scope}/")
+                   for n in names), scope
+    # the steps compact nothing; what compacts is the pages' state rows
+    text = "\n".join(sorted(names))
+    assert "scan_filter__compact" not in text
+    assert f"{root}aggregate__compact_shift/" in text
+    for scope in set(re.findall(r"(?<=/)[a-z_]+__[a-z0-9_]+(?=/)", text)):
+        assert jit_cache.NAME_GRAMMAR.match(scope), scope
+    # a GROUP BY that sorts holds a state a lane: it does not walk
+    assert compose_walk((), ("agg-partial", (0,), specs),
+                        lambda: hash_aggregate((0,), specs, Step.PARTIAL),
+                        span) is None
+    # nor does a chain that does not end in the partial aggregate
+    assert compose_walk(pending, ("agg-bypass", (), specs), tail,
+                        span) is None
 
 
 @pytest.mark.parametrize("tail, program", [

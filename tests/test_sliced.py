@@ -144,7 +144,17 @@ def test_checkpoint_store_counters():
 # ------------------------------------------------------ sliced execution
 
 
-def test_sliced_scan_parity_and_counters():
+def _walks_a_slice_a_launch(monkeypatch, lanes=4096):
+    """A scan into a direct aggregate walks its pages inside one launch
+    of up to `local_planner._WALK_LANES` lanes (PR 43), the slice boundary
+    between launches: bound a launch to a slice, as a table of more than
+    2^26 lanes is bound, so that tiny's lineitem takes many."""
+    from trino_tpu.exec import local_planner
+    monkeypatch.setattr(local_planner, "_WALK_LANES", lanes)
+
+
+def test_sliced_scan_parity_and_counters(monkeypatch):
+    _walks_a_slice_a_launch(monkeypatch)
     r = _sliced_runner()
     got = r.execute(
         "SELECT count(*), sum(l_quantity) FROM lineitem")
@@ -156,11 +166,13 @@ def test_sliced_scan_parity_and_counters():
         "SELECT count(*), sum(l_quantity) FROM lineitem")
     assert got.rows == expect.rows
     assert base.last_query_stats["slices_executed"] == 0
+    assert stats["chain_walks"] == stats["slices_executed"] == 15
 
 
-def test_sliced_tpch_parity_q1():
+def test_sliced_tpch_parity_q1(monkeypatch):
     """A full aggregation query through many small slices matches the
     sqlite oracle (slice boundaries are invisible to semantics)."""
+    _walks_a_slice_a_launch(monkeypatch)
     oracle = load_tpch_sqlite(0.01)
     try:
         r = _sliced_runner()

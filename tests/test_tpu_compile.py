@@ -143,6 +143,67 @@ def test_q1_partial_aggregate_reduces_under_slot_masks(one_chip):
             assert str(SCAN_WIDTH) not in line.split(" scatter(")[1], line
 
 
+def test_q1_chain_walks_sf10s_lineitem_in_one_program(one_chip):
+    """q1's chain as the planner composes it — filter, project, the
+    direct GROUP BY under slot masks — walking SF10's lineitem inside one
+    program (PR 43): seven whole columns of 58 pages x 1 048 576 lanes, a
+    `while` over the pages whose body is the page's program. Nothing as
+    long as a column is materialised, nothing is gathered or sorted."""
+    from trino_tpu.exec import LocalQueryRunner, jit_cache, local_planner
+    import chip_smoke
+    seen = {}
+    real = local_planner.compose_walk
+
+    def spy(pending, tail_key, tail_builder, sample):
+        seen.update(pending=pending, tail_key=tail_key,
+                    tail_builder=tail_builder, columns=sample.columns)
+        return real(pending, tail_key, tail_builder, sample)
+    r = LocalQueryRunner.tpch("tiny")
+    r.session.set("page_capacity", 8192)
+    r.session.set("scan_page_capacity", 8192)
+    local_planner.compose_walk = spy
+    try:
+        r.execute(chip_smoke.Q1)
+    finally:
+        local_planner.compose_walk = real
+    assert r.last_query_stats["chain_walks"] == 1
+    pages, lanes = 58, SCAN_WIDTH
+    whole = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct((pages * lanes,) + x.shape[1:],
+                                       x.dtype, sharding=one_chip),
+        seen["columns"])
+    # the store keeps the four decimals as their low and high words: as
+    # int64 operands the chip would split them whole before the loop,
+    # 1.95 GB of temporaries
+    from trino_tpu.page import SplitColumn
+    assert len(whole) == 7
+    assert sum(isinstance(c, SplitColumn) for c in whole) == 4
+    span = local_planner.ColumnSpan(whole, 59_993_741, lanes, 0, pages)
+    assert local_planner.compose_walk(
+        seen["pending"], seen["tail_key"], seen["tail_builder"],
+        span) is not None
+    key = ("chain",) + local_planner.chain_keys(seen["pending"]) + (
+        seen["tail_key"] + (("walk", lanes, pages),),)
+    fn = jit_cache._CACHE[key][0]
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    groups = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x),
+                                       sharding=one_chip),
+        local_planner.chain_params(seen["pending"]))
+    t0 = time.perf_counter()
+    compiled = fn.lower(whole, scalar, scalar, groups).compile()
+    assert time.perf_counter() - t0 < 120
+    text = compiled.as_text()
+    assert " while(" in text
+    for op in (" gather(", " sort("):
+        assert op not in text, op
+    # what scatters is `compact()`, over the twelve slots of a page
+    for line in text.splitlines():
+        if " scatter(" in line:
+            assert str(lanes) not in line.split(" scatter(")[1], line
+    assert compiled.memory_analysis().temp_size_in_bytes < (64 << 20)
+
+
 def test_q3_join_build_sort(one_chip):
     """The sort-bearing build kernel of q3's joins: 64-bit keys ordered by
     passes of one 32-bit sort (ops/radix.py)."""

@@ -33,7 +33,7 @@ from trino_tpu.connector.spi import (
     ConnectorPageSource, ConnectorSplitManager, ConnectorTableHandle,
     ColumnStatistics, SchemaTableName, Split, TableMetadata, TableStatistics,
     pad_to_capacity, split_range)
-from trino_tpu.page import Column, Dictionary, Page
+from trino_tpu.page import Column, Dictionary, Page, SplitColumn
 
 _D12_2 = T.DecimalType(12, 2)
 
@@ -278,6 +278,11 @@ def _host_cached(key: tuple, build) -> np.ndarray:
     return arr
 
 
+# the device column store: (table, sf, column, split start, split end) ->
+# that split's WHOLE column, one device buffer. A column is resident once,
+# in this form alone: a chain that walks its pages inside its program
+# (exec/local_planner.compose_walk) is handed the buffers as they are, and
+# every other consumer gets pages cut from them (`_cut_pages`)
 _DEVICE_COL_CACHE: "collections.OrderedDict[tuple, Column]" = \
     collections.OrderedDict()
 # LRU byte budget for staged table columns (HBM residency is finite;
@@ -285,53 +290,48 @@ _DEVICE_COL_CACHE: "collections.OrderedDict[tuple, Column]" = \
 _DEVICE_COL_CACHE_BYTES = int(os.environ.get(
     "TRINO_TPU_SCAN_CACHE_BYTES", 4 << 30))
 _DEVICE_COL_CACHE_USED = 0
+# one cold build at a time: a column is hundreds of MB while its pages and
+# their join are both alive, and the server's pool scans concurrently
+_BUILD_LOCK = threading.Lock()
 
 
 def set_device_cache_budget(nbytes: int) -> None:
     """Adjust the staged-column LRU budget at runtime (bench shrinks it
     before SF100 rungs so join state owns the HBM, evicting as needed)."""
-    global _DEVICE_COL_CACHE_BYTES, _DEVICE_COL_CACHE_USED
+    global _DEVICE_COL_CACHE_BYTES
     with _CACHE_LOCK:
         _DEVICE_COL_CACHE_BYTES = int(nbytes)
-        while _DEVICE_COL_CACHE_USED > _DEVICE_COL_CACHE_BYTES \
-                and _DEVICE_COL_CACHE:
-            _, evicted = _DEVICE_COL_CACHE.popitem(last=False)
-            _DEVICE_COL_CACHE_USED -= evicted.nbytes
+        _evict_locked(0)
+
+
+def _evict_locked(incoming: int) -> None:
+    global _DEVICE_COL_CACHE_USED
+    while (_DEVICE_COL_CACHE_USED + incoming > _DEVICE_COL_CACHE_BYTES
+           and _DEVICE_COL_CACHE):
+        _, evicted = _DEVICE_COL_CACHE.popitem(last=False)
+        _DEVICE_COL_CACHE_USED -= evicted.nbytes
 
 
 def _staged_column(table: str, sf: float, name: str, typ: T.Type,
                    off: int, hi: int, page_capacity: int) -> Column:
-    """Generate + pad + stage one column slice to device, once per
-    (table, sf, column, slice, capacity), LRU-evicted under a byte budget.
+    """Generate + pad + stage one page of a column to the device: rows
+    [off, hi) in `page_capacity` lanes, a new array every call. The store
+    keeps what `_resident_columns` joins from these.
 
     The reference streams table data from storage per query; TPC-H data here
     is immutable generator output, so re-staging identical bytes to HBM on
     every execution would only re-measure PCIe. Real-table residency analog:
     Trino's memory connector / a warmed OS page cache."""
-    global _DEVICE_COL_CACHE_USED
-    import jax
-    # a caller that pins its pages to a chip (`jax.default_device`: a mesh
-    # scan makes shard i on chip i and keeps it there itself) gets fresh
-    # arrays and leaves none here: this LRU is the default device's
-    pinned = jax.config.jax_default_device is not None
-    key = (table, round(sf * 1000), name, off, hi, page_capacity)
-    with _CACHE_LOCK:
-        col = None if pinned else _DEVICE_COL_CACHE.get(key)
-        if col is not None:
-            _DEVICE_COL_CACHE.move_to_end(key)
-            return col
     hkey = (table, round(sf * 1000), name, off, hi)
     if _DEVICE_GEN and tpch_dev.supported(table, name):
         # generate ON the device: same hash-stream expressions jit'd via
         # jnp (tpch_dev docstring) — no host hashing, no column transfer
-        import jax.numpy as jnp
         values = tpch_dev.generate(table, sf, name, off, hi, page_capacity)
         if T.is_string(typ):
-            col = Column(values, None, typ,
-                         table_dictionary(table, sf, name))
-        else:
-            col = Column(values.astype(T.to_numpy_dtype(typ)), None, typ)
-    elif T.is_string(typ):
+            return Column(values, None, typ,
+                          table_dictionary(table, sf, name))
+        return Column(values.astype(T.to_numpy_dtype(typ)), None, typ)
+    if T.is_string(typ):
         d = table_dictionary(table, sf, name)
         if G.string_kind(table, name) == "pooled":
             codes = _host_cached(
@@ -340,31 +340,162 @@ def _staged_column(table: str, sf: float, name: str, typ: T.Type,
             codes = _host_cached(
                 hkey, lambda: d.encode(
                     G.object_chunk(table, sf, name, off, hi)))
-        col = Column.from_numpy(pad_to_capacity(codes, page_capacity, 0),
-                                typ, dictionary=d)
-    else:
-        arr = pad_to_capacity(
-            _host_cached(hkey, lambda: np.asarray(
-                G.numeric_chunk(table, sf, name, off, hi),
-                T.to_numpy_dtype(typ))), page_capacity, 0)
-        col = Column.from_numpy(arr, typ)
-    nbytes = col.nbytes
-    with _CACHE_LOCK:
-        if pinned or nbytes > _DEVICE_COL_CACHE_BYTES:
-            return col   # the caller's, or larger than the whole budget
-        if key not in _DEVICE_COL_CACHE:
-            while (_DEVICE_COL_CACHE_USED + nbytes
-                   > _DEVICE_COL_CACHE_BYTES and _DEVICE_COL_CACHE):
-                _, evicted = _DEVICE_COL_CACHE.popitem(last=False)
-                _DEVICE_COL_CACHE_USED -= evicted.nbytes
-            _DEVICE_COL_CACHE[key] = col
-            _DEVICE_COL_CACHE_USED += nbytes
-    return col
+        return Column.from_numpy(pad_to_capacity(codes, page_capacity, 0),
+                                 typ, dictionary=d)
+    arr = pad_to_capacity(
+        _host_cached(hkey, lambda: np.asarray(
+            G.numeric_chunk(table, sf, name, off, hi),
+            T.to_numpy_dtype(typ))), page_capacity, 0)
+    return Column.from_numpy(arr, typ)
+
+
+def _joined(pieces: List[Column]):
+    """One column's pages end to end, as one buffer — a 64-bit column of
+    several pages as its two words' (`page.SplitColumn`: a program that
+    walks the pages would split the whole of it on every launch).
+    `pieces` is emptied as it is read: each page is split on its own and
+    dropped, the words joined one plane at a time, so what is alive
+    beside the result is half a column."""
+    import jax.numpy as jnp
+    first = pieces[0]
+    if len(pieces) == 1:
+        return pieces.pop()
+    valid = None
+    if any(c.valid is not None for c in pieces):
+        valid = jnp.concatenate([c.valid_mask() for c in pieces])
+    if not SplitColumn.splits(first):
+        values = jnp.concatenate([c.values for c in pieces])
+        pieces.clear()
+        return Column(values, valid, first.type, first.dictionary)
+    words = []
+    while pieces:
+        words.append(SplitColumn.of(pieces.pop(0)))
+    low = jnp.concatenate([w.low for w in words])
+    for w in words:
+        w.low = None
+    return SplitColumn(low, jnp.concatenate([w.high for w in words]), valid,
+                       first.type, first.values.dtype, first.dictionary)
+
+
+def _page_of(col, at, lanes: int) -> Column:
+    """Lanes [at, at + lanes) of a stored column, inside a program. `at`
+    traced: a `dynamic_slice` (the caller knows the lanes are there); a
+    Python int: a slice, padded where the buffer ends first."""
+    import jax
+    import jax.numpy as jnp
+
+    def cut(x):
+        if not isinstance(at, int):
+            return jax.lax.dynamic_slice_in_dim(x, at, lanes)
+        piece = x[at:at + lanes]
+        return jnp.pad(piece, [(0, lanes - piece.shape[0])]
+                       + [(0, 0)] * (x.ndim - 1))
+    cut_col = jax.tree_util.tree_map(cut, col)
+    return cut_col.column() if isinstance(cut_col, SplitColumn) else cut_col
+
+
+def _resident_columns(table: str, sf: float, columns: Sequence[ColumnHandle],
+                      start: int, end: int, page_capacity: int
+                      ) -> Optional[List[Column]]:
+    """Rows [start, end) of each column as ONE device buffer, made once
+    per (table, sf, column, range) and LRU-evicted under the byte budget;
+    None where the store keeps nothing (a pinned caller, a column larger
+    than the whole budget).
+
+    A missing column is generated a page of `page_capacity` lanes at a
+    time — the generators' own shapes, all missing columns of a page
+    together (they share its order index) — and its pages are joined
+    column by column, each column's pages dropped as it joins: the
+    transient is one column. The buffer is as long as those pages, so
+    its length is a multiple of the capacity it was first asked at and
+    is padded once, here. Whatever capacity a later scan asks for reads
+    the same buffer."""
+    global _DEVICE_COL_CACHE_USED
+    import jax
+    # a caller that pins its pages to a chip (`jax.default_device`: a mesh
+    # scan makes shard i on chip i and keeps it there itself) gets fresh
+    # arrays and leaves none here: this LRU is the default device's
+    if jax.config.jax_default_device is not None or end <= start:
+        return None
+    keys = [(table, round(sf * 1000), ch.name, start, end) for ch in columns]
+
+    def lookup():
+        with _CACHE_LOCK:
+            found = [_DEVICE_COL_CACHE.get(k) for k in keys]
+            for k, col in zip(keys, found):
+                if col is not None:
+                    _DEVICE_COL_CACHE.move_to_end(k)
+        return found
+    found = lookup()
+    lanes = -(-(end - start) // page_capacity) * page_capacity
+    if lanes > page_capacity:
+        # kept whole from when a page held it, now asked for in several:
+        # the form follows (`_joined`), once
+        for i, col in enumerate(found):
+            if isinstance(col, Column) and SplitColumn.splits(col):
+                with _CACHE_LOCK:
+                    if _DEVICE_COL_CACHE.get(keys[i]) is col:
+                        _DEVICE_COL_CACHE[keys[i]] = SplitColumn.of(col)
+                    found[i] = _DEVICE_COL_CACHE.get(keys[i], col)
+    if all(col is not None for col in found):
+        return found
+    if any(lanes * (4 if T.is_string(ch.type)
+                    else np.dtype(T.to_numpy_dtype(ch.type)).itemsize)
+           > _DEVICE_COL_CACHE_BYTES for ch in columns):
+        return None
+    with _BUILD_LOCK:
+        found = lookup()    # another thread's build may have landed
+        missing = [i for i, col in enumerate(found) if col is None]
+        pieces: Dict[int, List[Column]] = {i: [] for i in missing}
+        for off in range(start, end, page_capacity):
+            hi = min(off + page_capacity, end)
+            for i in missing:
+                pieces[i].append(_staged_column(
+                    table, sf, columns[i].name, columns[i].type, off, hi,
+                    page_capacity))
+        for i in missing:
+            col = found[i] = _joined(pieces.pop(i))
+            with _CACHE_LOCK:
+                if keys[i] not in _DEVICE_COL_CACHE:
+                    _evict_locked(col.nbytes)
+                    _DEVICE_COL_CACHE[keys[i]] = col
+                    _DEVICE_COL_CACHE_USED += col.nbytes
+    return found
+
+
+def _cut_pages(whole: Sequence[Column], rows: int, page_capacity: int
+               ) -> Iterator[Page]:
+    """`rows` rows of whole columns as pages of `page_capacity` lanes:
+    the buffers themselves where one page holds them, else one launch a
+    page of a kernel that cuts every column (`lax.dynamic_slice` by a
+    traced offset: one executable for all pages; a copy, dropped with the
+    page; a split column's words joined on the way). A page that reaches
+    past a buffer's end — the buffer was first asked for at another
+    capacity — is cut at a static offset and padded."""
+    from trino_tpu.exec.jit_cache import cached_kernel
+    shortest = min(c.capacity for c in whole)
+    if rows <= page_capacity and all(
+            isinstance(c, Column) and c.capacity == page_capacity
+            for c in whole):
+        yield Page(tuple(whole), rows)
+        return
+    cut = cached_kernel(
+        ("page-cut", page_capacity), lambda: lambda cols, at: tuple(
+            _page_of(c, at, page_capacity) for c in cols))
+    whole = tuple(whole)
+    for at in range(0, rows, page_capacity):
+        if at + page_capacity <= shortest:
+            cols = cut(whole, np.int32(at))
+        else:
+            cols = cached_kernel(
+                ("page-cut-tail", page_capacity, at),
+                lambda at=at: lambda cols: tuple(
+                    _page_of(c, at, page_capacity) for c in cols))(whole)
+        yield Page(cols, min(page_capacity, rows - at))
 
 
 class TpchPageSource(ConnectorPageSource):
-    def pages(self, split: Split, columns: Sequence[ColumnHandle],
-              page_capacity: int) -> Iterator[Page]:
+    def _range(self, split: Split):
         handle = split.table
         table = handle.name.table
         sf = SCHEMAS[handle.name.schema]
@@ -372,12 +503,29 @@ class TpchPageSource(ConnectorPageSource):
         start, end = split_range(total, split.part, split.total_parts)
         if handle.limit is not None:
             end = min(end, start + handle.limit)
+        return table, sf, start, end
+
+    def resident_columns(self, split: Split,
+                         columns: Sequence[ColumnHandle],
+                         page_capacity: int):
+        table, sf, start, end = self._range(split)
+        whole = _resident_columns(table, sf, columns, start, end,
+                                  page_capacity)
+        return None if whole is None else (tuple(whole), end - start)
+
+    def pages(self, split: Split, columns: Sequence[ColumnHandle],
+              page_capacity: int) -> Iterator[Page]:
+        table, sf, start, end = self._range(split)
+        whole = _resident_columns(table, sf, columns, start, end,
+                                  page_capacity)
+        if whole is not None:
+            yield from _cut_pages(whole, end - start, page_capacity)
+            return
         for off in range(start, end, page_capacity):
             hi = min(off + page_capacity, end)
-            n = hi - off
             cols = [_staged_column(table, sf, ch.name, ch.type, off, hi,
                                    page_capacity) for ch in columns]
-            yield Page(tuple(cols), n)
+            yield Page(tuple(cols), hi - off)
 
 
 def create_connector() -> Connector:

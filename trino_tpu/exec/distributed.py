@@ -189,6 +189,9 @@ class ShardExecutionPlanner(LocalExecutionPlanner):
                             col.add_scan_staging(page_bytes(page), moved)
                         if self.device is not None:
                             page = jax.device_put(page, self.device)
+                        if staged == [] and not tcache.admits(
+                                page, max(self._table_rows(conn, node), 1)):
+                            staged = None   # refused by shapes: gather none
                         if staged is not None:
                             staged.append(page)
                         yield page

@@ -244,7 +244,7 @@ NAME_GRAMMAR = re.compile(
     r"__[a-z0-9]+(_[a-z0-9]+)*$")
 _FAMILY_PREFIXES = (
     ("scan_filter", ("filter", "project", "select", "dconcat", "unnest",
-                     "assign-unique-id", "tpch-generate")),
+                     "assign-unique-id", "tpch-generate", "page-cut")),
     ("aggregate", ("agg",)),
     ("join", ("join", "uprobe", "uattach", "semijoin", "markjoin",
               "fulljoin", "cross-attach", "dense-table", "dfbounds",

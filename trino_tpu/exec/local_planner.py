@@ -22,7 +22,8 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, Iterator, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -42,8 +43,9 @@ from trino_tpu.ops import (AggSpec, JoinType, SortKey, Step, hash_aggregate,
                            hash_join, order_by, prepare_build, top_n,
                            top_n_masked)
 from trino_tpu.ops.join import unique_inner_probe
-from trino_tpu.page import (Column, Page, concat_pages,
-                            count_host_staging, defer_compaction, op_scope)
+from trino_tpu.page import (Column, Page, SplitColumn, concat_pages,
+                            count_host_staging, defer_compaction,
+                            host_staged_bytes, in_chunks, op_scope)
 from trino_tpu.planner.nodes import (
     AggregationNode, DistinctLimitNode, EnforceSingleRowNode,
     ExchangeNode, FilterNode, GroupIdNode, JoinClause, JoinKind, JoinNode,
@@ -90,6 +92,20 @@ def _next_pow2(n: int) -> int:
     return out
 
 
+class WholeColumns(NamedTuple):
+    """One split of a scan as whole resident columns (`PageStream.whole`):
+    `rows` live rows in buffers that hold at least their pages of
+    `capacity` lanes. `staged` / `moved`: the bytes the scan counts as
+    pulled from its connector, and as moved host -> device for it, if
+    these columns are what it reads (a table-cache entry counts none)."""
+
+    columns: tuple
+    rows: int
+    capacity: int
+    staged: int = 0
+    moved: int = 0
+
+
 @dataclasses.dataclass
 class PageStream:
     """Stream of pages + a lazy chain of per-page device transforms.
@@ -121,6 +137,13 @@ class PageStream:
     pages: Iterator[Page]
     symbols: Tuple[Symbol, ...]
     pending: Tuple[tuple, ...] = ()
+    # a scan of resident columns offers them whole as well: `whole()` is
+    # None or a `WholeColumns` a split — the same rows as `pages`, each
+    # column ONE device buffer — for a consumer that walks the pages
+    # inside its program (`compose_walk`). Only lane-wise steps
+    # (`_LANE_WISE_STEPS`) carry it on; whoever takes it leaves `pages`
+    # unpulled
+    whole: Optional[Callable[[], Optional[list]]] = None
 
     def with_op(self, key, builder, params=()) -> "PageStream":
         return PageStream(self.pages, self.symbols,
@@ -210,6 +233,24 @@ def chain_steps(key, pending, tail_builder=None):
     return steps, tail
 
 
+def _chain_program(key, pending, tail_builder):
+    """The builder of the chain program `key`: `run(page, groups)`, every
+    step and then the tail over one page."""
+    def build():
+        steps, tail = chain_steps(key, pending, tail_builder)
+
+        def run(page, groups):
+            for step, g in zip(steps, groups):
+                page = step(page, g)
+            if tail is not None:
+                page = tail(page)
+            # trace time: a page with a selection never leaves its program
+            assert getattr(page, "selection", None) is None, key
+            return page
+        return run
+    return build
+
+
 def compose_chain(pending, tail_key=None, tail_builder=None,
                   tail_slot=None):
     """One cached jitted kernel running every pending transform (+ optional
@@ -236,19 +277,7 @@ def compose_chain(pending, tail_key=None, tail_builder=None,
     key = ("chain",) + chain_keys(pending) + \
         ((tail_key,) if tail_key is not None else ())
     param_groups = chain_params(pending)
-
-    def build():
-        steps, tail = chain_steps(key, pending, tail_builder)
-
-        def run(page, groups):
-            for step, g in zip(steps, groups):
-                page = step(page, g)
-            if tail is not None:
-                page = tail(page)
-            # trace time: a page with a selection never leaves its program
-            assert getattr(page, "selection", None) is None, key
-            return page
-        return run
+    build = _chain_program(key, pending, tail_builder)
     kernel = profiled_kernel(key, build, params=param_groups)
     resolve = _like_tables(key, build, param_groups)
     if resolve is not None:
@@ -273,6 +302,152 @@ def compose_chain(pending, tail_key=None, tail_builder=None,
         return call
     return _attributed_chain_call(kernel, key, pending, param_groups,
                                   slots, tail_builder, tail_slot, resolve)
+
+
+# lanes one launch of a walking chain covers at most. A launch cannot be
+# preempted — `SliceScheduler.capacity_cap` bounds a scan page for that
+# reason — and a lane-wise chain into a direct reduce is 0.24 ms a
+# 1 048 576-lane page on a v5e (PERF.md section 6, PR 43): 2^26 lanes are
+# 15-25 ms against `slice_target_ms` 250. A larger table takes several
+# launches, the cooperative boundary between them
+_WALK_LANES = 1 << 26
+
+
+@dataclasses.dataclass(frozen=True)
+class ColumnSpan:
+    """Pages [first, first + pages) of a scan's resident columns: what one
+    launch of a walking chain covers. `columns` are the WHOLE buffers and
+    `rows` their live rows; a page of the span that lies past them is
+    walked and counts nothing."""
+
+    columns: Tuple[Column, ...]
+    rows: int
+    capacity: int       # lanes of a page
+    first: int
+    pages: int
+
+    @property
+    def live_pages(self) -> int:
+        return max(0, min(self.pages,
+                          -(-self.rows // self.capacity) - self.first))
+
+    @property
+    def num_rows(self) -> int:
+        """Live rows of the span: what the slice budget counts."""
+        return max(0, min(self.rows - self.first * self.capacity,
+                          self.pages * self.capacity))
+
+
+def column_spans(columns, rows: int, capacity: int) -> Iterator[ColumnSpan]:
+    """The launches that walk `rows` rows of whole columns a page of
+    `capacity` lanes at a time: equal spans of at most `_WALK_LANES`
+    lanes, so one executable serves them all."""
+    pages = -(-rows // capacity)
+    span = min(pages, max(_WALK_LANES // capacity, 1))
+    for first in range(0, pages, span):
+        yield ColumnSpan(tuple(columns), rows, capacity, first, span)
+
+
+# (walking chain key, the columns' avals) -> whether the chain's partial
+# aggregate keeps few states there: what one abstract trace found
+_WALKS: "collections.OrderedDict" = collections.OrderedDict()
+
+
+def compose_walk(pending, tail_key, tail_builder, sample: ColumnSpan):
+    """`compose_chain`'s program walking its pages INSIDE one launch, or
+    None where this chain must take a page a launch.
+
+    A chain walks when it runs deferred into the partial aggregate
+    (`chain_defers_compaction`: every step lane-wise) and that aggregate
+    keeps few states a page — global, or direct-address slots — which one
+    abstract trace of the steps over a page of `sample`'s columns tells
+    (`jax.eval_shape`, kept per chain and column shapes; never a table's
+    or a query's name). Q18's inner GROUP BY sorts, holds a state a lane
+    and is flushed between pages: it does not walk.
+
+    `call(span)` hands the program each column as ONE buffer (a
+    `SplitColumn` as its two); it takes
+    `span.pages` pages of `span.capacity` lanes from page `span.first`
+    (`page.in_chunks`: the same steps under the same scopes, a page's
+    arithmetic unchanged, literals and LIKE tables passed once) and
+    returns the pages' partial states as one small page, which the
+    caller's buffer, merge and FINAL take as they take a page's. One key
+    — one executable, one program name, the per-page chain's — for every
+    span of these shapes. Counts `chain_walks` / `chain_walk_pages` on
+    the query's collector, and the pages as `compactions_deferred`."""
+    key = ("chain",) + chain_keys(pending) + (
+        tail_key + (("walk", sample.capacity, sample.pages),),)
+    if not chain_defers_compaction(key):
+        return None
+    param_groups = chain_params(pending)
+    per_page = _chain_program(key, pending, tail_builder)
+    resolve = _like_tables(key, per_page, param_groups)
+    capacity, pages = sample.capacity, sample.pages
+
+    def page_of(columns):
+        """A page of the walk as the steps see it, by shape."""
+        def lanes(x, dtype=None):
+            return jax.ShapeDtypeStruct((capacity,) + x.shape[1:],
+                                        dtype or x.dtype)
+        return Page(tuple(
+            c.like(lanes(c.low, c.dtype)).with_valid(
+                None if c.valid is None else lanes(c.valid))
+            if isinstance(c, SplitColumn)
+            else jax.tree_util.tree_map(lanes, c) for c in columns),
+            jax.ShapeDtypeStruct((), jnp.int32))
+
+    def groups_for(columns):
+        return param_groups if resolve is None \
+            else resolve(page_of(columns))
+
+    at = (key, jax.tree_util.tree_structure(sample.columns)) + tuple(
+        (x.dtype.str, x.shape[1:])
+        for x in jax.tree_util.tree_leaves(sample.columns))
+    with _LIKE_DICTIONARIES_LOCK:
+        few = _WALKS.get(at)
+    if few is None:
+        from trino_tpu.ops.aggregate import partial_states_are_few
+        steps, _ = chain_steps(key, pending)
+        groups = groups_for(sample.columns)
+
+        def fed(page):
+            for step, g in zip(steps, groups):
+                page = step(page, g)
+            return page
+        _, key_channels, specs = tail_key[:3]
+        few = partial_states_are_few(
+            jax.eval_shape(fed, page_of(sample.columns)), key_channels,
+            specs)
+        with _LIKE_DICTIONARIES_LOCK:
+            while len(_WALKS) >= _LIKE_DICTIONARIES_KEPT:
+                _WALKS.popitem(last=False)
+            _WALKS[at] = few
+    if not few:
+        return None
+
+    def build():
+        run = per_page()
+
+        def walk(columns, first, rows, groups):
+            def a_page(page):
+                # a split column's words joined, a page of them
+                return run(Page(tuple(
+                    c.column() if isinstance(c, SplitColumn) else c
+                    for c in page.columns), page.num_rows), groups)
+            return in_chunks(Page(columns, rows), a_page, capacity,
+                             (first, pages))
+        return walk
+    kernel = profiled_kernel(key, build, params=param_groups)
+    filters = any(key_tag(k) in _FILTER_STEPS for k in key[1:])
+
+    def call(span: ColumnSpan) -> Page:
+        assert (span.capacity, span.pages) == (capacity, pages), key
+        count = getattr(get_observer(), "count_walk", None)
+        if count is not None:
+            count(span.live_pages, filters)
+        return kernel(span.columns, np.int32(span.first),
+                      np.int32(span.rows), groups_for(span.columns))
+    return call
 
 
 # (chain key, page structure) -> the dictionary behind each LikeOperand of
@@ -684,18 +859,31 @@ class LocalExecutionPlanner:
             if entry is not None:
                 if col is not None:
                     col.table_cache_hit()
-                from trino_tpu.exec.table_cache import build_pages
-                with observed_activity("eager_slice", "table_cache"):
-                    resident = build_pages(entry, col_names, cap)
 
-                def gen_resident(pages=resident):
-                    for page in pulled(pages, "table_cache"):
+                def gen_resident():
+                    # cut when the first page is asked for: a consumer
+                    # that walks the entry's columns whole never asks
+                    from trino_tpu.exec.table_cache import build_pages
+                    with observed_activity("eager_slice", "table_cache"):
+                        resident = build_pages(entry, col_names, cap)
+                    for page in pulled(resident, "table_cache"):
                         self._checkpoint()
                         yield page
-                return PageStream(self._sliced(gen_resident()), symbols)
+
+                def whole_resident():
+                    return [WholeColumns(
+                        tuple(entry.columns[n] for n in col_names),
+                        entry.rows, cap)]
+                return PageStream(self._sliced(gen_resident()), symbols,
+                                  whole=whole_resident)
             if col is not None:
                 col.table_cache_miss()
-        cache = self.scan_cache
+        # a page source that keeps its columns resident is its own cache,
+        # and the pages it cuts from them are copies: staging those in
+        # the scan cache would hold every column twice
+        resident_source = getattr(conn.page_source, "resident_columns",
+                                  None)
+        cache = None if resident_source is not None else self.scan_cache
         key = None
         if cache is not None and not system:
             # system.runtime tables materialize live engine state at
@@ -764,6 +952,13 @@ class LocalExecutionPlanner:
                         self._checkpoint()
                         if col is not None:
                             col.add_scan_staging(page_bytes(page), moved)
+                        if promote and not staging and key is None \
+                                and not tcache.admits(
+                                    page, max(self._table_rows(conn, node),
+                                              1)):
+                            # by shapes alone it cannot be admitted (SF10
+                            # lineitem against 1 GiB): gather nothing
+                            promote, staging = False, None
                         if staging is not None:
                             staging.append(page)
                         yield page
@@ -785,7 +980,27 @@ class LocalExecutionPlanner:
                     tkey, [(c.name, c) for _, c in node.assignments],
                     staging, counts, device=self.mem_device,
                     collector=col, gen=tgen)
-        return PageStream(self._sliced(gen()), symbols)
+
+        def whole_connector():
+            if resident_source is None:
+                return None
+            handle, dyn_applied = self._effective_handle(conn, node)
+            if dyn_applied:
+                return None
+            parts = []
+            for split in conn.split_manager.get_splits(handle,
+                                                       target_splits=1):
+                mark = host_staged_bytes()
+                with observed_activity("page_pull", "connector"):
+                    got = resident_source(split, columns, cap)
+                if got is None:
+                    return None
+                parts.append(WholeColumns(
+                    got[0], got[1], cap, sum(c.nbytes for c in got[0]),
+                    host_staged_bytes() - mark))
+            return parts
+        return PageStream(self._sliced(gen()), symbols,
+                          whole=whole_connector)
 
     def _effective_handle(self, conn, node: TableScanNode):
         """(handle for split pruning, dynamic-filter-applied flag): the
@@ -890,6 +1105,15 @@ class LocalExecutionPlanner:
                 stack.extend(n.sources)
         return None
 
+    @staticmethod
+    def _table_rows(conn, node: TableScanNode) -> int:
+        """The table's row count by the connector's statistics, else 0."""
+        try:
+            stats = conn.metadata.get_table_statistics(node.table)
+            return int(stats.row_count) if stats and stats.row_count else 0
+        except Exception:
+            return 0
+
     def _scan_capacity(self, conn, node: TableScanNode) -> int:
         """Size scan pages to the table: one big page per split keeps the
         steady state at a handful of device calls instead of a Python loop
@@ -897,11 +1121,7 @@ class LocalExecutionPlanner:
         point is amortizing per-page overhead; on TPU the analog is fewer,
         larger fused kernel launches)."""
         cap = self.page_capacity
-        try:
-            stats = conn.metadata.get_table_statistics(node.table)
-            rows = int(stats.row_count) if stats and stats.row_count else 0
-        except Exception:
-            rows = 0
+        rows = self._table_rows(conn, node)
         if rows > cap:
             max_cap = int(self.session.get("scan_page_capacity"))
             cap = min(_next_pow2(rows), max_cap)
@@ -963,7 +1183,7 @@ class LocalExecutionPlanner:
             src.pages, src.symbols,
             src.pending + (((tag, pred),
                             lambda: lambda p, g, f=compile_filter(pred):
-                            p.filter(f(p, g)), prm),))
+                            p.filter(f(p, g)), prm),), src.whole)
 
     def _exec_ProjectNode(self, node: ProjectNode) -> PageStream:
         src = self.execute(node.source)
@@ -978,7 +1198,7 @@ class LocalExecutionPlanner:
                                         page.num_rows)
         return PageStream(src.pages, tuple(s for s, _ in node.assignments),
                           src.pending + ((("project", exprs), builder,
-                                          prm),))
+                                          prm),), src.whole)
 
     def _exec_LimitNode(self, node: LimitNode) -> PageStream:
         src = self.execute(node.source)
@@ -1259,10 +1479,12 @@ class LocalExecutionPlanner:
         # aggregate reads liveness from row_mask() alone, so when every
         # fused step is lane-wise the filters compact nothing and hand it
         # a selection mask (chain_defers_compaction)
-        partial_op = compose_chain(
-            src.pending, ("agg-partial", key_channels_t, specs_t),
-            lambda: hash_aggregate(key_channels, specs, Step.PARTIAL),
-            tail_slot=self._slot(node))
+        partial_key = ("agg-partial", key_channels_t, specs_t)
+
+        def partial_builder():
+            return hash_aggregate(key_channels, specs, Step.PARTIAL)
+        partial_op = compose_chain(src.pending, partial_key, partial_builder,
+                                   tail_slot=self._slot(node))
         # the adaptive bypass kernel: same fused chain, but the tail maps
         # each row to a PARTIAL-layout state row with NO sort (O(n) — the
         # "Partial Partial Aggregates" bypass for effectively-high NDV);
@@ -1410,7 +1632,15 @@ class LocalExecutionPlanner:
                                         host_read(counts, "spill_counts"))
 
             try:
-                for page in src.pages:
+                # a scan of resident columns into a direct aggregate is
+                # one launch, its pages walked inside (`compose_walk`);
+                # the states come back as one small page
+                walked = None if ctl is not None and ctl.mode != AggMode.FULL \
+                    else self._walked(src, partial_key, partial_builder)
+                for pp in walked or ():
+                    any_pages = True
+                    buf.append(pp)
+                for page in src.pages if walked is None else ():
                     self._checkpoint()
                     any_pages = True
                     mode = ctl.mode if ctl is not None else AggMode.FULL
@@ -1477,6 +1707,43 @@ class LocalExecutionPlanner:
                 if store is not None:
                     store.close()
         return PageStream(gen(), node.outputs)
+
+    def _walked(self, src: PageStream, tail_key, tail_builder
+                ) -> Optional[Iterator[Page]]:
+        """The partial states of `src`'s chain under the aggregate
+        `tail_key`, one page a launch over a span of the scan's whole
+        columns (`compose_walk`) — or None where the chain takes a page a
+        launch: the scan offers no resident columns (or operator-level
+        collection stands between), they are one page, a buffer is
+        shorter than its pages, or the chain cannot walk. Between
+        launches: the slice boundary and the checkpoint, as between
+        pages."""
+        parts = src.whole() if src.whole is not None else None
+        if not parts:
+            return None
+        ops = []
+        for part in parts:
+            pages = -(-part.rows // part.capacity)
+            if pages < 2 or any(c.capacity < pages * part.capacity
+                                for c in part.columns):
+                return None
+            op = compose_walk(src.pending, tail_key, tail_builder,
+                              next(column_spans(*part[:3])))
+            if op is None:
+                return None
+            ops.append(op)
+
+        def launches():
+            for part, op in zip(parts, ops):
+                if part.staged:
+                    self._fault_site("scan", "whole columns")
+                    if self.collector is not None:
+                        self.collector.add_scan_staging(part.staged,
+                                                        part.moved)
+                for span in self._sliced(column_spans(*part[:3])):
+                    self._checkpoint()
+                    yield op(span)
+        return launches()
 
     def _finalize_agg_spill(self, store, depth: int, final_op,
                             intermediate_op, part_op_for, key_idxs,
@@ -3338,13 +3605,13 @@ def _reorder_stream(src: PageStream, symbols: Tuple[Symbol, ...]
     lay, _ = _layout(src.symbols)
     order = tuple(lay[s.name] for s in symbols)
     if order == tuple(range(len(src.symbols))):
-        return PageStream(src.pages, symbols, src.pending)
+        return PageStream(src.pages, symbols, src.pending, src.whole)
     return PageStream(
         src.pages, symbols,
         src.pending + ((("select", order),
                         lambda: lambda p, g: Page(
                             tuple(p.columns[c] for c in order),
-                            p.num_rows), ()),))
+                            p.num_rows), ()),), src.whole)
 
 
 

@@ -72,7 +72,7 @@ from trino_tpu.ops import (AggSpec, JoinType, SortKey, Step, hash_aggregate,
                            hash_join, order_by, top_n)
 from trino_tpu.ops.aggregate import (COLLECT_AGGREGATES, get_aggregate)
 from trino_tpu.page import (Column, Page, count_host_staging,
-                            defer_compaction, op_scope)
+                            defer_compaction, in_chunks, op_scope)
 from trino_tpu.parallel.exchange import (AXIS, all_to_all_by_key,
                                          all_to_all_replicate,
                                          broadcast_page, detect_heavy_keys)
@@ -102,7 +102,8 @@ _MAX_LADDER_ROUNDS = 10
 # stacks every state into one [lanes, states] operand, which the TPU tiles
 # to 128 columns: a whole 46 M-lane SF30 shard at once asked for 23.6 GB.
 # (q1 takes the masked form since PR 29 and stacks nothing; whether the
-# chunks are still needed is a question for a later PR)
+# chunks are still needed is a question for a later PR.) The walk is
+# `page.in_chunks`, the local executor's too (local_planner.compose_walk)
 _CHUNK_LANES = 1 << 20
 
 
@@ -128,29 +129,6 @@ def _join_output_guess(probe_capacity: int, join_kind) -> int:
     if join_kind != JoinType.INNER:
         return probe_capacity
     return _bucket_guess(probe_capacity, 8)
-
-
-def _in_chunks(page: Page, body: Callable[[Page], Page]) -> Page:
-    """`body` over `page` a chunk of lanes at a time (`lax.map`: one chunk's
-    temporaries live at once), the chunks' output rows compacted into one
-    page. For bodies whose outputs may be merged by concatenation: partial
-    aggregation states."""
-    cap = page.capacity
-    if cap <= _CHUNK_LANES or cap % _CHUNK_LANES:
-        return body(page)
-    k = cap // _CHUNK_LANES
-    cols = jax.tree_util.tree_map(
-        lambda x: x.reshape((k, _CHUNK_LANES) + x.shape[1:]), page.columns)
-    rows = jnp.clip(page.num_rows
-                    - jnp.arange(k, dtype=jnp.int32) * _CHUNK_LANES,
-                    0, _CHUNK_LANES).astype(jnp.int32)
-    outs = jax.lax.map(lambda xs: body(Page(xs[0], xs[1])), (cols, rows))
-    m = outs.columns[0].values.shape[1]
-    live = (jnp.arange(m, dtype=jnp.int32)[None, :]
-            < outs.num_rows[:, None]).reshape(k * m)
-    flat = jax.tree_util.tree_map(
-        lambda x: x.reshape((k * m,) + x.shape[2:]), outs.columns)
-    return Page(flat, jnp.int32(k * m)).filter(live)
 
 
 class _Env:
@@ -553,8 +531,8 @@ class MeshLowerer:
         planner fuses them. It reads liveness from row_mask() alone, so
         the chain's filters hand it a selection mask and move no row
         (`_defer_filters_under`: q1). Where its state is small the chain
-        runs a chunk of lanes at a time (`_in_chunks`)."""
-        from trino_tpu.ops.aggregate import _direct_key_sizes
+        runs a chunk of lanes at a time (`page.in_chunks`)."""
+        from trino_tpu.ops.aggregate import partial_states_are_few
         below = self._defer_filters_under(node.source)
         base = self.lower_node(below, frag)
         fed: List[Page] = []        # the chain's input, while it is traced
@@ -568,7 +546,6 @@ class MeshLowerer:
         specs = self._agg_specs(node, lay)
         self._key("agg-partial", keys, specs, _CHUNK_LANES)
         op = hash_aggregate(list(keys), list(specs), Step.PARTIAL)
-        collects = any(a.name in COLLECT_AGGREGATES for a in specs)
 
         def fn(env: _Env) -> Page:
             def run_chain(page: Page) -> Page:
@@ -587,11 +564,13 @@ class MeshLowerer:
             # its state is small hangs on the key columns' dictionaries,
             # which are the chain's to make
             fed_shape = jax.eval_shape(run_chain, page)
-            if collects or (keys and _direct_key_sizes(
-                    fed_shape, keys, specs) is None):
+            if not partial_states_are_few(fed_shape, keys, specs):
                 return body(page)
             with op_scope("aggregate__partial_merge"):
-                return _in_chunks(page, body)
+                cap = page.capacity
+                if cap <= _CHUNK_LANES or cap % _CHUNK_LANES:
+                    return body(page)
+                return in_chunks(page, body, _CHUNK_LANES)
         return fn
 
     # -------------------------------------------------------------- joins

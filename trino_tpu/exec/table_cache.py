@@ -320,16 +320,10 @@ class TableCache(_GenerationGuard):
         # what the entry would hold, from shapes alone: a table that
         # cannot be admitted (SF10 lineitem against 1 GiB) must not pay
         # a full-length copy of every column on every scan first
-        first = live[0][0]
-        need = sum(
-            cap * (c.values.dtype.itemsize * math.prod(c.values.shape[1:])
-                   + any(p.columns[i].valid is not None for p, _ in live))
-            for i, c in enumerate(first.columns))
-        with self._lock:
-            # what the entry would score once admitted: used once, now
-            room = need <= self.max_bytes and self._room_locked(
-                need, (1, time.monotonic()))
-        if not room:
+        if not self._fits(_entry_bytes(
+                live[0][0], rows, [any(p.columns[i].valid is not None
+                                       for p, _ in live)
+                                   for i in range(len(names))])):
             _count("admission_denied")
             return False
         for i, (name, ch) in enumerate(symbols_cols):
@@ -361,6 +355,22 @@ class TableCache(_GenerationGuard):
                                          device, freq=1,
                                          last_used=time.monotonic()),
                            frozenset(names), collector, gen)
+
+    def _fits(self, need: int) -> bool:
+        with self._lock:
+            # what the entry would score once admitted: used once, now
+            return need <= self.max_bytes and self._room_locked(
+                need, (1, time.monotonic()))
+
+    def admits(self, page, rows: int) -> bool:
+        """Whether `rows` rows of columns shaped like `page`'s could be
+        admitted now, from shapes alone: asked on a scan's first page,
+        before its pages are gathered for `promote_from_pages` — a page a
+        connector cut from its own resident columns is a copy, and a
+        table's worth of them kept for a promotion that will be refused
+        is the table twice."""
+        return rows > 0 and self._fits(_entry_bytes(
+            page, rows, [c.valid is not None for c in page.columns]))
 
     def _admit(self, entry: ResidentTable, colset: frozenset,
                collector=None, gen: Optional[int] = None) -> bool:
@@ -468,6 +478,15 @@ class TableCache(_GenerationGuard):
                 pass
         except Exception:
             pass
+
+
+def _entry_bytes(page, rows: int, has_valid: Sequence[bool]) -> int:
+    """Bytes of a full-length entry of `rows` rows of `page`'s columns."""
+    cap = _next_pow2(rows)
+    return sum(
+        cap * (c.values.dtype.itemsize * math.prod(c.values.shape[1:])
+               + bool(valid))
+        for c, valid in zip(page.columns, has_valid))
 
 
 def build_pages(entry: ResidentTable, column_names: Sequence[str],

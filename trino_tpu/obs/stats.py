@@ -236,6 +236,11 @@ class QueryStatsCollector:
         # `run` one whose filters compact the page
         self.compactions_deferred = 0
         self.compactions_run = 0
+        # launches of a chain that walks its scan's pages inside its
+        # program (a scan of resident columns into a direct aggregate),
+        # and the pages walked in them: shapes, no sync
+        self.chain_walks = 0
+        self.chain_walk_pages = 0
         # compactions on the join's probe path, made by the host with
         # the kept count in hand (local_planner._compact_counted): `tight`
         # gathered the kept prefix alone at the count's pow2 capacity,
@@ -548,6 +553,15 @@ class QueryStatsCollector:
         else:
             self.compactions_run += 1
 
+    def count_walk(self, pages: int, filters: bool) -> None:
+        """One launch of a walking chain (local_planner.compose_walk) over
+        `pages` live pages; where the chain filters, each is a deferred
+        compaction as it was when it was a launch of its own."""
+        self.chain_walks += 1
+        self.chain_walk_pages += pages
+        if filters:
+            self.compactions_deferred += pages
+
     def count_probe_compaction(self, lanes_in: int, lanes_gathered: int
                                ) -> None:
         """One page of the probe path compacted from `lanes_in` lanes
@@ -725,6 +739,8 @@ class QueryStatsCollector:
             "scan_host_staging_bytes": self.scan_host_staging_bytes,
             "compactions_deferred": self.compactions_deferred,
             "compactions_run": self.compactions_run,
+            "chain_walks": self.chain_walks,
+            "chain_walk_pages": self.chain_walk_pages,
             "probe_compactions_tight": self.probe_compactions_tight,
             "probe_compactions_full": self.probe_compactions_full,
             "probe_compactions_skipped": self.probe_compactions_skipped,

@@ -692,6 +692,20 @@ def _direct_key_sizes(page: Page, key_channels, aggs):
     return tuple(sizes)
 
 
+def partial_states_are_few(page: Page, key_channels, aggs) -> bool:
+    """Whether the PARTIAL aggregate of a page shaped like `page` is a
+    small static state — one row (no GROUP BY), or direct-address slots
+    (`_direct_key_sizes`) — so that many pages' states may be stacked in
+    one program and merged by concatenation. From shapes and dictionaries
+    alone: `page` may be what `jax.eval_shape` gives for the chain that
+    feeds the aggregate. The executors that walk a scan inside its program
+    ask (exec/mesh_exec, exec/local_planner.compose_walk)."""
+    if any(a.name in COLLECT_AGGREGATES for a in aggs):
+        return False
+    return not key_channels or \
+        _direct_key_sizes(page, key_channels, aggs) is not None
+
+
 def _direct_reduces_masked(sizes, aggs, resolved) -> bool:
     """Which form the direct path's reduce takes, from what a trace can
     see alone: the slot count and the number of state columns."""

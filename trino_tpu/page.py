@@ -25,7 +25,7 @@ import contextlib
 import dataclasses
 import itertools
 import threading
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -361,6 +361,80 @@ class Column:
             out = out.copy()
             out[mask] = None
         return out
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class SplitColumn:
+    """A 64-bit integer column as its low and high 32-bit words, two
+    buffers: the form in which a store keeps a column whose pages a
+    program walks (`in_chunks`). The TPU has no 64-bit lanes; a program
+    given an int64 operand first splits the WHOLE of it into these two
+    arrays (`X64SplitLow` / `X64SplitHigh`), which fuses into the reader
+    where the reader takes the operand whole and is a copy as long as the
+    column where a loop cuts pages from it: 1.95 GB of temporaries for
+    q1's four decimals at SF10 (PR 43, compiled for the described chip).
+    Split once, when stored, a page's words are cut by the loop and
+    joined in registers."""
+
+    low: jnp.ndarray            # uint32 [capacity]
+    high: jnp.ndarray           # uint32 [capacity]
+    valid: Optional[jnp.ndarray]
+    type: T.Type
+    dtype: Any                  # the column's: int64 or uint64 (static)
+    dictionary: Optional[Dictionary] = None
+
+    def tree_flatten(self):
+        children = (self.low, self.high) + (
+            () if self.valid is None else (self.valid,))
+        return children, (self.type, self.dtype, self.dictionary)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        typ, dtype, dictionary = aux
+        low, high, *valid = children
+        return cls(low, high, valid[0] if valid else None, typ, dtype,
+                   dictionary)
+
+    @staticmethod
+    def splits(column: Column) -> bool:
+        """Whether `column` is one this form is for."""
+        v = column.values
+        return v.ndim == 1 and v.dtype.itemsize == 8 \
+            and v.dtype.kind in "iu" and column.lengths is None
+
+    @classmethod
+    def of(cls, column: Column) -> "SplitColumn":
+        low, high = _split_words(column.values)
+        return cls(low, high, column.valid, column.type,
+                   column.values.dtype, column.dictionary)
+
+    @property
+    def capacity(self) -> int:
+        return self.low.shape[0]
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(getattr(a, "nbytes", 0) or 0)
+                   for a in (self.low, self.high, self.valid)
+                   if a is not None)
+
+    def like(self, values) -> Column:
+        """The Column of these words' type over `values`."""
+        return Column(values, self.valid, self.type, self.dictionary)
+
+    def column(self) -> Column:
+        """The words joined: inside a program, over a page of them, two
+        converts that fuse into whatever reads the column."""
+        wide = self.low.astype(jnp.uint64) \
+            | (self.high.astype(jnp.uint64) << 32)
+        return self.like(wide.astype(self.dtype))
+
+
+@jax.jit
+def _split_words(values):
+    return (values.astype(jnp.uint32),
+            (values >> 32).astype(jnp.uint32))
 
 
 def _running_count(mask: jnp.ndarray) -> jnp.ndarray:
@@ -868,3 +942,50 @@ def device_concat(pages: Sequence[Page]) -> Page:
                     valid, p.column(ci).valid_mask(), (o,))
         cols.append(Column(out, valid, ref.type, ref.dictionary))
     return Page(tuple(cols), total.astype(jnp.int32))
+
+
+def in_chunks(page: Page, body: Callable[[Page], Page], lanes: int,
+              span=None) -> Page:
+    """`body` over `page` a chunk of `lanes` lanes at a time (`lax.map`:
+    one chunk's temporaries live at once), the chunks' output rows
+    compacted into one page. For bodies whose outputs may be merged by
+    concatenation: partial aggregation states. Live rows of each chunk
+    come from the page's row count, clipped to the chunk.
+
+    Without `span` every chunk of the page is walked, read from the
+    columns reshaped to [chunks, lanes] (the capacity is a multiple of
+    `lanes`): the mesh lowering's form, kept as it was — on one chip,
+    over whole int64 columns, it read 6-15 x the time of the other and
+    gigabytes of temporaries (PERF.md section 6, PR 43). With `span` =
+    (first chunk, chunks) — the first may be traced, the number is
+    static — that many chunks from there, each cut
+    from the flat columns by a `dynamic_slice` (no copy of the span; the
+    capacity need not be a multiple): one executable walks any span of any
+    page of these shapes, and a chunk past the live rows is read clamped
+    and counts none of its lanes."""
+    if span is None:
+        k = page.capacity // lanes
+        starts = jnp.arange(k, dtype=jnp.int32) * lanes
+        cols = jax.tree_util.tree_map(
+            lambda x: x.reshape((k, lanes) + x.shape[1:]), page.columns)
+
+        def chunk(xs):
+            return xs
+    else:
+        first, k = span
+        starts = (first + jnp.arange(k, dtype=jnp.int32)) * lanes
+        cols = starts
+
+        def chunk(at):
+            return jax.tree_util.tree_map(
+                lambda x: jax.lax.dynamic_slice_in_dim(x, at, lanes),
+                page.columns)
+    rows = jnp.clip(page.num_rows - starts, 0, lanes).astype(jnp.int32)
+    outs = jax.lax.map(lambda xs: body(Page(chunk(xs[0]), xs[1])),
+                       (cols, rows))
+    m = outs.columns[0].values.shape[1]
+    live = (jnp.arange(m, dtype=jnp.int32)[None, :]
+            < outs.num_rows[:, None]).reshape(k * m)
+    flat = jax.tree_util.tree_map(
+        lambda x: x.reshape((k * m,) + x.shape[2:]), outs.columns)
+    return Page(flat, jnp.int32(k * m)).filter(live)

@@ -161,14 +161,24 @@ def preload_table(runner, table: str,
     cap = 1 << 16
     while cap < rows and cap < (1 << 22):
         cap *= 2
+    cache.configure(int(runner.session.get("table_cache_max_bytes")),
+                    int(runner.session.get("table_cache_min_scans")))
     pages = []
     for split in conn.split_manager.get_splits(handle, target_splits=1):
-        pages.extend(conn.page_source.pages(split, handles, cap))
+        for page in conn.page_source.pages(split, handles, cap):
+            if not pages and rows and not cache.admits(page, rows):
+                # by shapes alone the cache cannot take it (SF10's
+                # lineitem against 1 GiB): the first page has warmed what
+                # the connector keeps on the device (the tpch connector
+                # builds its whole columns then); every further page
+                # would be a copy held for a promotion that is refused
+                _drop_scan_stats(conn)
+                return {"table": str(qname), "columns": len(handles),
+                        "rows": rows, "resident": False}
+            pages.append(page)
     _drop_scan_stats(conn)
     counts = [int(c) for c in jax.device_get(
         [p.num_rows for p in pages])] if pages else []
-    cache.configure(int(runner.session.get("table_cache_max_bytes")),
-                    int(runner.session.get("table_cache_min_scans")))
     cache.note_scan(tkey, names)
     resident = cache.promote_from_pages(
         tkey, [(c.name, c) for c in handles], pages, counts, gen=gen)
