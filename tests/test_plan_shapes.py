@@ -257,6 +257,59 @@ def test_the_benchmarks_sf10_plans_print_as_on_the_parent(name):
     assert got == want
 
 
+def _q13_sql(word1: str = "special", word2: str = "packages") -> str:
+    """The benchmark's own Q13 text (benchmark/queries/q13.py)."""
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "queries", "q13.py")
+    with open(path) as f:
+        text = f.read()
+    sql = text.split('SQL = """', 1)[1].split('"""', 1)[0]
+    return sql.format(word1=word1, word2=word2)
+
+
+@pytest.mark.parametrize("schema", SCHEMAS)
+def test_q13_not_like_is_pushed_below_the_outer_join(schema):
+    """Q13's `o_comment NOT LIKE '%WORD1%WORD2%'` is written in the LEFT
+    join's ON clause, on the null-supplying side: at every scale the
+    optimizer pushes it onto the orders scan, where it is a chain filter
+    whose LIKE table is an operand (`like_pattern_operand`) — never the
+    join's residual filter, which the executor refuses on an outer join
+    and where a pattern is still static (ROADMAP M4). Customer, the
+    preserved side, is the probe; orders the build."""
+    p = plan(runner_at(schema), _q13_sql())
+    joins = p.find("Join")
+    assert len(joins) == 1 and joins[0][2].startswith("left;"), p.text
+    assert "like" not in joins[0][2] and "o_comment" not in joins[0][2]
+    assert "c_custkey" in joins[0][2] and "o_custkey" in joins[0][2]
+    kids = [node for _, node in p.children_of(p.nodes.index(joins[0]))]
+    assert [op for _, op, _ in kids] == ["TableScan", "Filter"], p.text
+    assert kids[0][2].endswith(".customer")
+    assert kids[1][2].startswith("not(like(o_comment")
+    assert "'%special%packages%'" in kids[1][2]
+    under = p.children_of(p.nodes.index(kids[1]))
+    assert [n[1] for _, n in under] == ["TableScan"]
+    assert under[0][1][2].endswith(".orders")
+    # an aggregate over an aggregate: count(o_orderkey) by customer, then
+    # the customers by that count
+    aggs = p.find("Aggregation")
+    assert any("count(o_orderkey" in det for _, _, det in aggs), p.text
+    assert any("keys=(count" in det for _, _, det in aggs), p.text
+
+
+def test_q13_sf10_plan_prints_as_pinned():
+    """Q13's SF10 plan as `EXPLAIN` prints it (PR 44: the cell
+    `sf10-power-q13` runs this plan; tests/golden_plans_sf10.json)."""
+    import json
+    import os
+    with open(os.path.join(os.path.dirname(__file__),
+                           "golden_plans_sf10.json")) as f:
+        want = json.load(f)["q13"]
+    got = "\n".join(row[0] for row in runner_at("sf10").execute(
+        "EXPLAIN " + _q13_sql()).rows)
+    assert got == want
+
+
 def test_q21_exists_and_not_exists_shape(runner):
     p = plan(runner, QUERIES["q21"][0])
     # EXISTS -> semi/mark machinery without cross joins

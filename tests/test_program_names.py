@@ -29,12 +29,14 @@ KNOWN_TAGS = {
                   "agg-intermediate", "agg-single", "agg-groupmax",
                   "agg-spill-part", "agg-having"],
     "join": ["join", "join-prep", "join-spill-part", "uprobe", "uattach",
-             "semijoin", "markjoin", "fulljoin", "cross-attach",
+             "semijoin", "markjoin", "cross-attach",
              "dense-table", "dense-table-rows", "dfbounds", "dfrange",
              "dfrange-mask", "probe-compact", "spill-prep", "spill-probe",
              "spill-probe-dense", "semijoin-prep",
              "semijoin-dense-table", "join-composite", "uprobe-composite",
-             "join-prep-composite"],
+             "join-prep-composite", "join-outer", "join-prep-outer",
+             "join-outer-composite", "join-prep-outer-composite",
+             "join-full-outer", "dense-table-outer"],
     "sort": ["sort", "sort-spill-bounds", "sort-spill-part",
              "sort-spill-rank", "topn-masked", "topn", "merge-sort"],
     "window": ["window"],
@@ -367,10 +369,12 @@ def test_a_mesh_program_names_every_op(monkeypatch):
 SEMI_SCOPES = ("join__semi_probe", "join__mark_probe", "join__semi_build")
 
 
-def _join_text(join_type, semi):
+def _join_text(join_type, semi, key=None):
     """Lowered text of one join over a prepared build, built from the
     operators the planner builds it from (`_prepare_build` and
-    `_exec_semijoin_filter` / `_exec_SemiJoinNode` / `_exec_JoinNode`)."""
+    `_exec_semijoin_filter` / `_exec_SemiJoinNode` / `_exec_JoinNode`),
+    under the program name `key` gives (default: an INNER or semi
+    join's)."""
     from trino_tpu.ops.join import hash_join, prepare_build
 
     def run(probe, build):
@@ -379,7 +383,7 @@ def _join_text(join_type, semi):
     probe = Page.from_numpy([jnp.arange(64) % 7, jnp.arange(64)],
                             [T.BIGINT, T.BIGINT])
     build = Page.from_numpy([jnp.arange(16) % 5], [T.BIGINT])
-    key = ("semijoin" if semi else "join", join_type)
+    key = key or ("semijoin" if semi else "join", join_type)
     return jax.jit(jit_cache.named(run, key)).lower(probe, build) \
         .as_text(debug_info=True)
 
@@ -439,6 +443,78 @@ def test_a_join_on_two_columns_carries_names_of_its_own(join_type):
     # one key column: hashing is the identity, nothing to verify
     assert "join__composite_verify" not in _join_text(
         "inner" if join_type == "semi" else join_type, semi=False)
+
+
+@pytest.mark.parametrize("join_type", ["left", "full"])
+def test_an_outer_join_carries_names_of_its_own(join_type):
+    """A LEFT or FULL join (Q13's customer LEFT JOIN orders, PR 44): the
+    executor names its programs `join__join_outer` / `join__join_prep_outer`
+    / `join__dense_table_outer` / `join__join_full_outer`
+    (`local_planner._composite(..., outer=True)`), and what makes it outer
+    — the one slot an unmatched probe row emits, the null-extension of the
+    build columns — has the scope `join__outer_fill`, inside the
+    expansion and the output gather."""
+    from trino_tpu.exec.local_planner import _composite
+    assert _composite("join", (0,), outer=True) == "join-outer"
+    assert _composite("join", (0, 1), outer=True) == "join-outer-composite"
+    assert _composite("join", (0,)) == "join"
+    for tag, name in (("join", "join__join_outer"),
+                      ("join-prep", "join__join_prep_outer"),
+                      ("join-full", "join__join_full_outer")):
+        assert jit_cache.program_name(
+            (_composite(tag, (0,), outer=True), (0,))) == name
+    assert jit_cache.program_name(("dense-table-outer", 1024)) \
+        == "join__dense_table_outer"
+    key = (_composite("join", (0,), outer=True), join_type)
+    text = _join_text(join_type, semi=False, key=key)
+    assert "jit(join__join_outer)/" in text
+    assert "/join__probe_expand/join__outer_fill/" in text
+    assert "/join__output_gather/join__outer_fill/" in text
+    for scope in set(re.findall(r"(?<=/)[a-z_]+__[a-z0-9_]+(?=/)", text)):
+        assert jit_cache.NAME_GRAMMAR.match(scope), scope
+
+
+def test_an_inner_join_keeps_its_names():
+    """The ledger's `breakdown` of the cells that were there reads as
+    before: an INNER join's program is `join__join`, and nothing in it is
+    under `join__outer_fill`."""
+    text = _join_text("inner", semi=False)
+    assert "jit(join__join)/" in text
+    assert "outer" not in text
+
+
+def test_an_outer_join_through_the_executor_is_named(runner):
+    """LEFT and FULL joins as the executor runs them: every program of
+    theirs has `outer` in its name, and the inner join beside them does
+    not."""
+    from trino_tpu.obs.stats import QueryStatsCollector
+    seen = set()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(QueryStatsCollector, "jit_hit",
+                   lambda self, key=None: seen.add(key))
+        mp.setattr(QueryStatsCollector, "jit_miss",
+                   lambda self, key=None: seen.add(key))
+        tpch = LocalQueryRunner.tpch("tiny")
+        tpch.execute("SELECT count(o_orderkey), count(*) FROM customer "
+                     "LEFT JOIN orders ON c_custkey = o_custkey")
+        left = {jit_cache.program_name(k) for k in seen}
+        seen.clear()
+        tpch.execute("SELECT count(o_orderkey), count(c_custkey) FROM "
+                     "customer FULL JOIN orders ON c_custkey = o_custkey")
+        full = {jit_cache.program_name(k) for k in seen}
+        seen.clear()
+        tpch.execute("SELECT count(*) FROM customer JOIN orders "
+                     "ON c_custkey = o_custkey")
+        inner = {jit_cache.program_name(k) for k in seen}
+    assert {"join__join_outer", "join__join_prep_outer",
+            "join__dense_table_outer"} <= left, sorted(left)
+    assert {"join__join_full_outer", "join__join_prep_outer"} <= full, \
+        sorted(full)
+    for names in (left, full):
+        joins = {n for n in names if n.startswith("join__")}
+        assert joins and all("outer" in n for n in joins), sorted(joins)
+    assert {n for n in inner if "outer" in n} == set(), sorted(inner)
+    assert "join__join_prep" in inner
 
 
 def test_unique_composite_probe_verifies_under_the_scope():

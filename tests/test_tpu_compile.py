@@ -622,3 +622,79 @@ def test_q9_composite_search_and_verify_at_partsupps_lanes(one_chip):
     # the build's own program — `prepare_build([1, 2])` at these lanes,
     # 34 s and 0.28 GB — is q3's build sort with the hash mixed in
     assert _device_bytes(compiled) < DEVICE_BUDGET
+
+
+# ---- Q13 at SF10 (PR 44): 1.5 M customers LEFT JOIN their 15 M orders —
+# the outer join's build, its position table, the expanding probe with its
+# null-extension, and the GROUP BY over the 2^24 joined lanes ------------
+
+Q13_CUSTOMER_LANES = 1 << 21    # customer's 1 500 000 rows, one probe page
+Q13_ORDER_LANES = 1 << 24       # the 14.9 M kept orders: the build, and
+#                                 the 15.4 M joined rows of the output
+ORDERS_Q13 = (T.BIGINT, T.BIGINT, T.VARCHAR)    # orderkey, custkey, comment
+
+
+def test_q13_outer_join_build_and_its_position_table(one_chip):
+    """The LEFT join's build: the kept orders in one page of 2^24 lanes
+    (the comment's codes ride along), sorted once on `o_custkey` — ten
+    rows a key — and a direct-address table of sorted POSITIONS over the
+    1.5 M customer keys they span (2^21 slots: `_prepare_probe` decides
+    `dense`, the fill rule holds at a tenth of a slot a lane)."""
+    from trino_tpu.ops.join import build_dense_table, prepare_build
+    build = _page(one_chip, Q13_ORDER_LANES, ORDERS_Q13)
+    compiled = _compile(prepare_build([1]), build, limit_s=300)
+    assert _device_bytes(compiled) < DEVICE_BUDGET
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    table = _compile(build_dense_table(Q13_CUSTOMER_LANES),
+                     spec((Q13_ORDER_LANES,), jnp.uint64),
+                     spec((), jnp.int32), spec((), jnp.uint64), limit_s=120)
+    assert _device_bytes(table) < DEVICE_BUDGET
+
+
+@pytest.mark.parametrize("capacity", [
+    Q13_CUSTOMER_LANES,
+    # 128 s to compile for the v5e beside tier-1's other compiles
+    pytest.param(Q13_ORDER_LANES, marks=pytest.mark.slow)],
+    ids=["first-capacity", "rerun"])
+def test_q13_left_join_expands_customers_to_their_orders(one_chip,
+                                                         capacity):
+    """The customer page probes that table (one gather a lane, no sort for
+    the lookup), expands ten-fold and null-extends a third of its rows:
+    first at the probe's own capacity, where the total overflows, then at
+    2^24 (`_run_with_overflow`: `probe_overflow_reruns` 1). The
+    null-extension reads as `join__outer_fill`."""
+    from trino_tpu.ops.join import (JoinType, build_dense_table, hash_join,
+                                    prepare_build)
+    build = _page(one_chip, Q13_ORDER_LANES, ORDERS_Q13)
+    prepared = jax.eval_shape(prepare_build([1]), build)
+    table = jax.eval_shape(build_dense_table(Q13_CUSTOMER_LANES),
+                           prepared[1], prepared[3], prepared[8])
+    probe = _page(one_chip, Q13_CUSTOMER_LANES, (T.BIGINT,))
+    op = hash_join([0], [1], JoinType.LEFT, output_capacity=capacity,
+                   prepared=True, lookup="dense", probe_out=(0,),
+                   build_out=(0,))
+    compiled = _compile(op, probe, prepared + (table,), limit_s=300)
+    text = compiled.as_text()
+    for scope in ("join__probe_lookup", "join__probe_expand",
+                  "join__outer_fill", "join__output_gather"):
+        assert scope in text, scope
+    assert _device_bytes(compiled) < DEVICE_BUDGET
+
+
+def test_q13_count_by_customer_over_the_joined_lanes(one_chip):
+    """`count(o_orderkey) GROUP BY c_custkey` over the join's 2^24 output
+    lanes, the order key NULL on the null-extended ones: the sorted GROUP
+    BY (1.5 M groups), its states reduced by the segmented scan."""
+    from trino_tpu.ops import AggSpec, Step, hash_aggregate
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    lanes = Q13_ORDER_LANES
+    page = Page((Column(spec((lanes,), jnp.int64), None, T.BIGINT, None),
+                 Column(spec((lanes,), jnp.int64), spec((lanes,), jnp.bool_),
+                        T.BIGINT, None)), spec((), jnp.int32))
+    op = hash_aggregate([0], [AggSpec("count", 1, T.BIGINT)], Step.PARTIAL)
+    compiled = _compile(op, page, limit_s=300)
+    assert _device_bytes(compiled) < DEVICE_BUDGET
+    _assert_no_state_scatter(compiled, lanes)

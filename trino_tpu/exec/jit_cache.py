@@ -247,7 +247,7 @@ _FAMILY_PREFIXES = (
                      "assign-unique-id", "tpch-generate", "page-cut")),
     ("aggregate", ("agg",)),
     ("join", ("join", "uprobe", "uattach", "semijoin", "markjoin",
-              "fulljoin", "cross-attach", "dense-table", "dfbounds",
+              "cross-attach", "dense-table", "dfbounds",
               "dfrange", "probe-compact", "spill-prep", "spill-probe")),
     ("sort", ("sort", "topn", "merge-sort")),
     ("window", ("window",)),

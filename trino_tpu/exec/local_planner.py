@@ -158,11 +158,16 @@ class PageStream:
                 yield fn(p)
 
 
-def _composite(tag: str, keys) -> str:
-    """A join program's tag, `-composite` after it where the key has more
-    than one column: such a key is mix-hashed to 64 bits (ops/join._key_u64),
-    so the lookup is `search` and every candidate is verified — the trace
-    gives those programs names of their own."""
+def _composite(tag: str, keys, outer: bool = False) -> str:
+    """A join program's tag: `-outer` after it where the join preserves a
+    side (LEFT, FULL; a RIGHT join runs as a LEFT one) — its unmatched probe
+    rows come out null-extended (ops/join's scope `join__outer_fill`) —,
+    then `-composite` where the key has more than one column: such a key is
+    mix-hashed to 64 bits (ops/join._key_u64), so the lookup is `search`
+    and every candidate is verified. The trace gives those programs names
+    of their own; an INNER join on one column keeps the bare tag."""
+    if outer:
+        tag = f"{tag}-outer"
     return f"{tag}-composite" if len(keys) > 1 else tag
 
 
@@ -2081,6 +2086,7 @@ class LocalExecutionPlanner:
                            if s.name in out_names)
         join_kind = JoinType.INNER if node.kind == JoinKind.INNER \
             else JoinType.LEFT
+        outer = join_kind != JoinType.INNER
 
         # residual non-equi filter evaluated over joined layout — valid for
         # INNER only (LEFT would wrongly drop null-extended rows; planner
@@ -2111,7 +2117,7 @@ class LocalExecutionPlanner:
                     return out.filter(post_filter(out, g)), total
                 return run
             kernel = cached_kernel(
-                (_composite("join", probe_keys), tuple(probe_keys),
+                (_composite("join", probe_keys, outer), tuple(probe_keys),
                  tuple(build_keys), join_kind,
                  cap, post_pred, mode, probe_keep, build_keep), build,
                 params=post_params)
@@ -2220,7 +2226,7 @@ class LocalExecutionPlanner:
                 return
             try:
                 prepared, max_run, mode = self._prepare_probe(
-                    build_keys, bp, inner=join_kind == JoinType.INNER)
+                    build_keys, bp, inner=not outer, outer=outer)
                 prefilter = None
                 if join_kind == JoinType.INNER and \
                         self.session.get("enable_dynamic_filtering") and \
@@ -2981,13 +2987,16 @@ class LocalExecutionPlanner:
                 yield Page(tuple(cols), page.num_rows) if changed else page
         return PageStream(gen(), probe_stream.symbols)
 
-    def _prepare_build(self, build_keys, build_page, semi: bool = False):
+    def _prepare_build(self, build_keys, build_page, semi: bool = False,
+                       outer: bool = False):
         """Sort the build side ONCE per join (LookupSourceFactory analog) —
         probe-page kernels consume the prepared tuple without re-sorting.
-        `semi`: a semi, anti or mark join's, a program of its own name."""
+        `semi`: a semi, anti or mark join's; `outer`: a LEFT or FULL
+        join's — each a program of its own name."""
         prep = cached_kernel(
             ("semijoin-prep" if semi
-             else _composite("join-prep", build_keys), tuple(build_keys)),
+             else _composite("join-prep", build_keys, outer),
+             tuple(build_keys)),
             lambda: prepare_build(build_keys, semi))
         return prep(build_page)
 
@@ -2996,7 +3005,7 @@ class LocalExecutionPlanner:
     _DENSE_MAX_SLOTS = 1 << 26
 
     def _prepare_probe(self, build_keys, build_page, semi: bool = False,
-                       inner: bool = False):
+                       inner: bool = False, outer: bool = False):
         """prepare_build + the ONE probe-lookup decision of every join,
         in memory or spilled: fetch (max_run, kmin, kmax) in one round
         trip; when the live-key span is small (dense surrogate keys —
@@ -3014,11 +3023,12 @@ class LocalExecutionPlanner:
         duplicates, LEFT/FULL, SEMI/ANTI/MARK) reads run_len at the
         key's sorted POSITION and keeps the position table. Counted on
         the query's collector as `probe_lookups_row_table` /
-        `_position_table` / `_search`.
+        `_position_table` / `_search`. `outer` (a LEFT join's build) only
+        names the programs.
 
         Returns (prepared [+ table], max_run, lookup)."""
         from trino_tpu.ops.join import build_dense_table
-        prepared = self._prepare_build(build_keys, build_page, semi)
+        prepared = self._prepare_build(build_keys, build_page, semi, outer)
         max_run, kmin, kmax = (int(x) for x in host_read(
             [prepared[7], prepared[8], prepared[9]], "build_key_stats"))
         rows = inner and max_run <= 1
@@ -3037,7 +3047,8 @@ class LocalExecutionPlanner:
             return prepared, max_run, "search"
         size = _next_pow2(span)
         tag = "semijoin-dense-table" if semi else \
-            "dense-table-rows" if rows else "dense-table"
+            "dense-table-rows" if rows else \
+            "dense-table-outer" if outer else "dense-table"
         table_op = cached_kernel(
             (tag, size), lambda: build_dense_table(size, semi))
         table = table_op(prepared[1], prepared[3], prepared[8],
@@ -3074,7 +3085,8 @@ class LocalExecutionPlanner:
 
         def full_op(cap: int):
             return cached_kernel(
-                ("fulljoin", tuple(probe_keys), tuple(build_keys), cap),
+                (_composite("join-full", probe_keys, outer=True),
+                 tuple(probe_keys), tuple(build_keys), cap),
                 lambda: hash_join(probe_keys, build_keys, JoinType.FULL,
                                   output_capacity=cap, prepared=True))
 
@@ -3084,7 +3096,7 @@ class LocalExecutionPlanner:
             bp = build_page
             if bp is None:
                 bp = self._null_build_page(node.right.outputs)
-            prepared = self._prepare_build(build_keys, bp)
+            prepared = self._prepare_build(build_keys, bp, outer=True)
             matched = jnp.zeros(bp.capacity, dtype=jnp.bool_)
             it = self._coalesce_stream(probe_stream).iter_pages()
             while True:
@@ -3105,6 +3117,8 @@ class LocalExecutionPlanner:
                 for page, (cap, (out, _, bm)), total in zip(
                         batch, results, totals):
                     total = int(total)
+                    if total > cap:
+                        _count_overflow_rerun()
                     while total > cap:
                         cap = _next_pow2(total)
                         out, t, bm = full_op(cap)(page, prepared)
@@ -3636,6 +3650,14 @@ def _byte_bounded_batches(it: Iterator[Page], budget_bytes: int,
         yield batch
 
 
+def _count_overflow_rerun() -> None:
+    """One probe page whose join ran again at a larger output capacity:
+    `probe_overflow_reruns` on the query's collector."""
+    observer = get_observer()
+    if hasattr(observer, "probe_overflow_reruns"):
+        observer.probe_overflow_reruns += 1
+
+
 def _run_with_overflow(probe_stream: PageStream, build_page: Page,
                        make_op, page_capacity: int) -> Iterator[Page]:
     """Dispatch a capacity-laddered binary page op over probe pages in
@@ -3644,7 +3666,8 @@ def _run_with_overflow(probe_stream: PageStream, build_page: Page,
     remote TPUs, but dispatching the whole stream before the first sync
     would pin every intermediate output in HBM simultaneously); only pages
     that actually overflowed re-run at the next capacity bucket (SURVEY §7
-    contract). Accepts a PageStream or a bare page iterator (the
+    contract), each counted once as `probe_overflow_reruns` from the total
+    read anyway. Accepts a PageStream or a bare page iterator (the
     partitioned join streams restaged probe chunks directly)."""
     it = probe_stream.iter_pages() \
         if hasattr(probe_stream, "iter_pages") else iter(probe_stream)
@@ -3657,6 +3680,8 @@ def _run_with_overflow(probe_stream: PageStream, build_page: Page,
         for page, (cap, (out, _)), total in zip(probe_pages, results,
                                                 totals):
             total = int(total)
+            if total > cap:
+                _count_overflow_rerun()
             while total > cap:
                 cap = _next_pow2(total)
                 out, t = make_op(cap)(page, build_page)

@@ -272,6 +272,12 @@ class QueryStatsCollector:
         # (dictionary, pattern) a chain (local_planner._like_tables): the
         # activity `like_table` times them
         self.like_tables_built = 0
+        # probe pages whose join ran again at a larger output capacity
+        # (local_planner._run_with_overflow and the FULL join's loop): the
+        # true total exceeded `max(page_capacity, page.capacity)`, so the
+        # first run's lookup and expansion were thrown away. Counted on
+        # the host from the totals it reads anyway
+        self.probe_overflow_reruns = 0
         # CROSS JoinNodes in the plans this query executed (runner): a
         # connected join graph plans none (optimizer.reorder_joins)
         self.cross_joins = 0
@@ -754,6 +760,7 @@ class QueryStatsCollector:
             "probe_lookup_lanes": self.probe_lookup_lanes,
             "probe_lookup_lanes_search": self.probe_lookup_lanes_search,
             "like_tables_built": self.like_tables_built,
+            "probe_overflow_reruns": self.probe_overflow_reruns,
             "cross_joins": self.cross_joins,
             "semi_join_build_rows": self.semi_join_build_rows,
             "semi_join_probe_rows": self.semi_join_probe_rows,

@@ -26,6 +26,7 @@ rows without matches emit once with the other side NULL.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Callable, Optional, Sequence, Tuple
 
@@ -235,6 +236,15 @@ _PROBE_SCOPE = {JoinType.SEMI: "join__semi_probe",
                 JoinType.MARK: "join__mark_probe"}
 
 
+def _outer_fill(outer: bool):
+    """The scope of what makes a LEFT or FULL join outer — an unmatched
+    probe row's one emitted slot, the slots that are null-extensions, the
+    build columns' validity there — so a trace reads it as its own; an
+    INNER join's ops stay where they were."""
+    return op_scope("join__outer_fill") if outer \
+        else contextlib.nullcontext()
+
+
 def _check_lookup(lookup: str) -> None:
     if lookup not in ("search", "dense"):
         raise ValueError(
@@ -288,6 +298,7 @@ def hash_join(
     probe_keys = tuple(probe_keys)
     build_keys = tuple(build_keys)
     composite = len(probe_keys) > 1
+    outer = join_type in (JoinType.LEFT, JoinType.FULL)
     _check_lookup(lookup)
 
     def op(probe: Page, build) -> Tuple[Page, jnp.ndarray]:
@@ -377,11 +388,13 @@ def hash_join(
 
         with op_scope("join__probe_expand"):
             emit = counts
-            if join_type in (JoinType.LEFT, JoinType.FULL):
-                # unmatched live probe rows (incl. null keys) emit one null-extended row
-                live_probe = probe.row_mask()
-                emit = jnp.where(live_probe & (counts == 0), 1, counts)
-                emit = jnp.where(live_probe, emit, 0)
+            if outer:
+                # unmatched live probe rows (incl. null keys) emit one
+                # null-extended row
+                with _outer_fill(True):
+                    live_probe = probe.row_mask()
+                    emit = jnp.where(live_probe & (counts == 0), 1, counts)
+                    emit = jnp.where(live_probe, emit, 0)
             offsets = jnp.cumsum(emit)
             total = offsets[-1]
             starts = offsets - emit  # exclusive prefix
@@ -425,7 +438,8 @@ def hash_join(
             return out, jnp.where(total <= cap, rows, total)
 
         real_match = slot_live & matched      # slot is a real hash candidate
-        build_is_null = slot_live & ~matched  # LEFT/FULL null-extension rows
+        with _outer_fill(outer):
+            build_is_null = slot_live & ~matched  # null-extension rows
 
         # composite keys: re-check real key equality per candidate slot so
         # hash collisions are filtered exactly (single-key u64 is injective)
@@ -440,8 +454,7 @@ def hash_join(
                     keep = keep & (pv == bv)
         verified_slot = real_match & keep
 
-        if join_type in (JoinType.LEFT, JoinType.FULL) and composite \
-                and verify_composite:
+        if outer and composite and verify_composite:
             # a probe row whose EVERY candidate was a hash collision must
             # still emit one null-extended row: rescue its first candidate
             # slot as the null-extension carrier
@@ -462,7 +475,8 @@ def hash_join(
             for i in b_idx:
                 c = build.columns[i]
                 g = c.gather(brow)
-                valid = g.valid_mask() & ~build_is_null
+                with _outer_fill(outer):
+                    valid = g.valid_mask() & ~build_is_null
                 bcols.append(Column(g.values, valid, c.type, c.dictionary))
             out_rows = jnp.minimum(total, cap).astype(jnp.int32)
             out_page = Page(pcols + tuple(bcols), out_rows)

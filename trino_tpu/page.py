@@ -751,15 +751,21 @@ class Page:
         List (ARRAY/MAP) columns decode to python lists / dicts per row."""
         self._require_compact("to_host")
         n = int(self.num_rows) if num_rows is None else num_rows
-        fetch = []
-        for c in self.columns:
-            fetch.append((c.values[:n],
-                          c.valid[:n] if c.valid is not None else None,
-                          c.lengths[:n] if c.lengths is not None else None,
-                          c.aux[:n] if c.aux is not None else None))
+        # the eager `x[:k]` is an executable a length k, and an answer's
+        # length moves with its parameters (Q13: 45 or 46 rows): cut on
+        # the device at the power of two above n — one executable a rung —
+        # and to n on the host
+        k = min(self.capacity, 1 << max(n - 1, 0).bit_length())
+
+        def head(x):
+            return None if x is None else x[:k]
+        fetch = [(head(c.values), head(c.valid), head(c.lengths),
+                  head(c.aux)) for c in self.columns]
         host = jax.device_get(fetch)
         out = []
-        for c, (vals, valid, lengths, aux) in zip(self.columns, host):
+        for c, cut in zip(self.columns, host):
+            vals, valid, lengths, aux = (
+                None if x is None else x[:n] for x in cut)
             if lengths is not None:
                 rows = np.empty(n, dtype=object)
                 for i in range(n):
