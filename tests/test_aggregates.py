@@ -754,3 +754,209 @@ def test_sorted_path_reduces_like_numpy(layout, steps, cap):
                                atol=0.0), spec
         else:       # integers, decimals and dictionary codes: bit for bit
             assert np.array_equal(got[valid], vals[valid]), spec
+
+
+# ------------------------------------------------------------------
+# The sorted GROUP BY over lanes that arrive in key order (PR 45): the
+# device tests the order and, in order, neither sorts nor gathers. Against
+# the form it replaced — sort always, every input and the keys gathered
+# through the permutation — kept here as the oracle, array for array.
+
+def _permuted_group_by(page, key_channels, specs, step, state_channels):
+    """The sorted path of `hash_aggregate` as PR 44 left it."""
+    import jax.numpy as jnp
+
+    from trino_tpu.ops import Step, aggregate as A
+    from trino_tpu.ops.radix import sort_by_keys
+    from trino_tpu.page import Column, Page, shift_takes
+    n = page.capacity
+    sorted_keys, perm = sort_by_keys(A._sort_key_arrays(page, key_channels))
+    live = ~sorted_keys[0]
+    boundary = A._boundary_scan(sorted_keys[1:], n) & live
+    group_of_sorted = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+    num_groups = jnp.sum(boundary).astype(jnp.int32)
+    first_idx = jnp.zeros(n, dtype=jnp.int32).at[
+        jnp.where(boundary, group_of_sorted, n)].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop")
+    key_row = jnp.take(perm, first_idx, mode="clip")
+    out = [page.column(ch).gather(key_row) for ch in key_channels]
+    entries = []
+    for ai, spec in enumerate(specs):
+        fn = A.get_aggregate(spec.name, spec.input_type)
+        states = fn.state(spec.input_type)
+        if step in (Step.FINAL, Step.INTERMEDIATE):
+            contribs = []
+            for sc, ch in zip(states, state_channels[ai]):
+                vals = jnp.take(page.column(ch).values, perm, mode="clip")
+                ident = jnp.zeros((), vals.dtype) if sc.reducer == "sum" \
+                    else A._ident_for(vals.dtype, sc.reducer == "min")
+                contribs.append((jnp.where(live, vals, ident), sc.reducer))
+            dictionary = page.column(state_channels[ai][0]).dictionary
+        else:
+            vals, mask, dictionary = A._agg_inputs(page, spec, fn, live,
+                                                   gather=perm)
+            contribs = [(sc.contrib(vals, mask), sc.reducer)
+                        for sc in states]
+        entries.append((spec, fn, states, dictionary, contribs))
+    takes, count = shift_takes(boundary)
+    reduced = iter(A._scan_reduce(
+        [c for e in entries for c in e[4]], boundary, live, takes,
+        jnp.arange(n, dtype=jnp.int32) < count))
+    for spec, fn, states, dictionary, _ in entries:
+        arrays = [next(reduced) for _ in states]
+        if step in (Step.PARTIAL, Step.INTERMEDIATE):
+            out.extend(Column(a.astype(sc.type.dtype), None, sc.type, None)
+                       for sc, a in zip(states, arrays))
+        else:
+            values, valid = fn.final(arrays, None)
+            out.append(A._agg_out_column(fn, spec, values, valid,
+                                         dictionary))
+    return Page(tuple(out), num_groups)
+
+
+_ORDER_KEYS = ("bigint", "nullable", "two_keys", "double", "string")
+_ORDER_CASES = ("in_order", "dead_lane_inside", "inversion_at_the_last_lane",
+                "reversed", "all_dead", "one_group")
+_ORDER_CAP, _ORDER_ROWS = 32, 24
+_ORDER_POOL = ["AIR", "FOB", "MAIL", "RAIL", "SHIP", "TRUCK"]   # sorted
+
+
+def _order_key_rows(kind):
+    """`_ORDER_ROWS` key rows in the sort's ascending order, with ties:
+    a row is one (value, is NULL) a key column."""
+    import numpy as np
+    if kind == "bigint":
+        vals = [-(2**62), -5, -5, 0, 0, 0, 1, 2**33, 2**33, 2**62]
+        return [((v, False),) for v in np.repeat(vals, 3)[:_ORDER_ROWS]]
+    if kind == "nullable":      # NULLs group after the values, as one key
+        vals = [(-9, False), (3, False), (3, False), (2**40, False),
+                (77, True), (-1, True), (5, True)]
+        return [(v,) for v in vals for _ in range(4)][:_ORDER_ROWS]
+    if kind == "two_keys":
+        return [((a, False), (b, False)) for a in (-3, 4, 2**35)
+                for b in (-7, -7, 0, 0, 1, 9, 9, 2**20)]
+    if kind == "double":        # -0 is +0, NaN is one value and the last
+        vals = [-np.inf, -1.5, -1.5, -0.0, 0.0, -0.0, 2.5, 2.5, np.inf,
+                np.nan, np.nan, np.nan]
+        return [((v, False),) for v in np.repeat(vals, 2)]
+    codes = np.repeat(np.arange(len(_ORDER_POOL)), 4)
+    return [((int(c), False),) for c in codes]
+
+
+def _order_page(kind, case, step):
+    """-> (page, key channels, specs, state channels, lanes in order)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from trino_tpu import types as T
+    from trino_tpu.ops import AggSpec, Step
+    from trino_tpu.ops.aggregate import get_aggregate
+    from trino_tpu.page import Column, Dictionary, Page
+    cap = _ORDER_CAP
+    rng = np.random.default_rng(_ORDER_KEYS.index(kind) * 16
+                                + _ORDER_CASES.index(case))
+    rows = _order_key_rows(kind)
+    assert len(rows) == _ORDER_ROWS
+    live = _ORDER_ROWS
+    selection = None
+    if case == "dead_lane_inside":
+        selection = np.ones(cap, dtype=bool)
+        selection[5] = False
+    elif case == "inversion_at_the_last_lane":
+        rows = rows[:-1] + [rows[0]]
+    elif case == "reversed":
+        rows = rows[::-1]
+    elif case == "all_dead":
+        live = 0
+    elif case == "one_group":
+        rows = [rows[7]] * len(rows)
+    # what lies behind the live rows is whatever was there: not in order
+    rows = rows + [rows[i] for i in rng.integers(0, 8, cap - len(rows))]
+    key_types = {"bigint": (T.BIGINT,), "nullable": (T.BIGINT,),
+                 "two_keys": (T.BIGINT, T.INTEGER), "double": (T.DOUBLE,),
+                 "string": (T.VARCHAR,)}[kind]
+    cols = []
+    for k, typ in enumerate(key_types):
+        values = np.array([r[k][0] for r in rows],
+                          dtype=T.to_numpy_dtype(typ))
+        null = np.array([r[k][1] for r in rows])
+        cols.append(Column(
+            jnp.asarray(values), jnp.asarray(~null) if kind == "nullable"
+            else None, typ, Dictionary(np.array(_ORDER_POOL, dtype=object))
+            if kind == "string" else None))
+    nkeys = len(cols)
+    specs = [AggSpec("sum", nkeys, T.BIGINT),
+             AggSpec("count", None, None),
+             AggSpec("max", nkeys + 1, T.DOUBLE),
+             AggSpec("avg", nkeys + 1, T.DOUBLE, mask_channel=nkeys + 2)]
+    state_channels = None
+    if step in (Step.PARTIAL, Step.SINGLE):
+        cols += [
+            Column(jnp.asarray(rng.integers(-2**40, 2**40, cap)),
+                   jnp.asarray(rng.random(cap) > 0.2), T.BIGINT, None),
+            Column(jnp.asarray(rng.normal(size=cap) * 1e3), None, T.DOUBLE,
+                   None),
+            Column(jnp.asarray(rng.random(cap) > 0.3),
+                   jnp.asarray(rng.random(cap) > 0.1), T.BOOLEAN, None)]
+    else:       # the merge steps read state columns: keys first, then them
+        state_channels, ch = [], nkeys
+        for spec in specs:
+            states = get_aggregate(spec.name, spec.input_type).state(
+                spec.input_type)
+            state_channels.append(list(range(ch, ch + len(states))))
+            ch += len(states)
+            for sc in states:
+                dtype = T.to_numpy_dtype(sc.type)
+                values = rng.normal(size=cap) * 1e3 \
+                    if np.issubdtype(dtype, np.floating) \
+                    else rng.integers(0, 2**40, cap)
+                cols.append(Column(jnp.asarray(values.astype(dtype)), None,
+                                   sc.type, None))
+    page = Page(tuple(cols), jnp.asarray(live, jnp.int32),
+                None if selection is None else jnp.asarray(selection))
+    in_order = case in ("in_order", "all_dead", "one_group")
+    return (page, list(range(nkeys)), specs, state_channels,
+            cap if in_order else 0)
+
+
+@pytest.mark.parametrize("case", _ORDER_CASES)
+@pytest.mark.parametrize("kind", _ORDER_KEYS)
+@pytest.mark.parametrize("step", ["PARTIAL", "FINAL", "INTERMEDIATE",
+                                  "SINGLE"])
+def test_lanes_in_key_order_are_neither_sorted_nor_gathered(
+        monkeypatch, step, kind, case):
+    """Whatever order the lanes arrive in, the operator's page is the
+    sort-always form's, array for array — keys, states, NULL masks, the
+    lanes past the groups — and what it says of the lanes' order is what
+    the case is."""
+    import jax
+    import numpy as np
+
+    from trino_tpu.ops import aggregate, hash_aggregate
+    from trino_tpu.page import device_notes
+    # a dictionary key's few values would take the direct path
+    monkeypatch.setattr(aggregate, "_DIRECT_MAX_GROUPS", 0)
+    page, keys, specs, state_channels, lanes_in_order = _order_page(
+        kind, case, step)
+    with device_notes() as said:
+        got = hash_aggregate(keys, specs, step, state_channels)(page)
+    said = {name: int(value) for name, value in said}
+    assert said == {"group_by_lanes_in_order": lanes_in_order,
+                    "group_by_lanes_sorted": page.capacity - lanes_in_order}
+    want = _permuted_group_by(page, keys, specs, step, state_channels)
+    assert int(got.num_rows) == int(want.num_rows)
+    if case == "all_dead":
+        assert int(got.num_rows) == 0
+    elif case == "one_group":
+        assert int(got.num_rows) == 1
+    else:
+        assert int(got.num_rows) > 3
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    for ci, (g, w) in enumerate(zip(got.columns, want.columns)):
+        assert g.type == w.type and g.dictionary == w.dictionary, ci
+        for a, b in zip(jax.tree_util.tree_leaves(g),
+                        jax.tree_util.tree_leaves(w)):
+            a, b = np.asarray(a), np.asarray(b)
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), \
+                (ci, a, b)

@@ -337,10 +337,12 @@ def test_deferred_chain_aggregates_the_rows_the_compacting_chain_does(name):
         text = jit_cache._CACHE[chain_key][0].lower(page, groups).as_text(
             debug_info=True)
         # the filters move no row; what is compacted is the sorted
-        # reduce's own states, a lane a group (PR 40)
+        # reduce's own states, a lane a group (PR 40: who moves where is
+        # worked out once, under `group_bounds`, since PR 45)
         for tag in ("compact_gather", "compact_slots", "compact_shift"):
-            assert text.count(tag) == text.count(
-                f"aggregate__segment_reduce/aggregate__{tag}"), tag
+            assert text.count(tag) == sum(text.count(
+                f"aggregate__{under}/aggregate__{tag}")
+                for under in ("group_bounds", "segment_reduce")), tag
         assert ("aggregate__compact_shift" in text) \
             == (key_channels not in ((), (FLAG,)))   # the sorted path
         # a dictionary key's four slots reduce under slot masks (PR 29)

@@ -51,8 +51,11 @@ def dispatches(monkeypatch):
             return call
         return stub
     for name in ("cached_kernel", "profiled_kernel"):
-        monkeypatch.setattr(local_planner, name,
-                            counting(getattr(jit_cache, name)))
+        stub = counting(getattr(jit_cache, name))
+        monkeypatch.setattr(local_planner, name, stub)
+        # the tpch connector looks `cached_kernel` up when it cuts pages
+        # from a buffer another test's scan left at another capacity
+        monkeypatch.setattr(jit_cache, name, stub)
     return seen
 
 
@@ -194,7 +197,10 @@ def test_the_profiler_sees_the_activities_on_the_threads_own_line(
     (_, lo, hi), = [e for e in lines[0] if e[0] == "request__execution"]
     calls = [e for e in lines[0] if e[0].startswith("host__kernel_call:")]
     assert calls and all(lo <= a and b <= hi for _, a, b in calls)
-    assert {name.partition(":")[2] for name, _, _ in calls} == {
+    # (and the connector's page cut, where another test's scan left
+    # lineitem's buffers at another capacity: no walk over those)
+    assert {name.partition(":")[2] for name, _, _ in calls} \
+        - {"scan_filter__page_cut"} == {
         "aggregate__chain_filter_project_agg_partial",
         "aggregate__agg_final"}
     reads = [e for e in lines[0] if e[0].startswith("host__host_read:")]

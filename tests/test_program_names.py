@@ -165,15 +165,24 @@ def test_chain_steps_and_tail_each_have_a_scope():
                   "aggregate__agg_partial"):
         assert f"/{scope}/" in text, scope
     # the partial aggregate takes the filter's mask as a selection: the
-    # chain compacts nothing (PR 26); the sorted reduce moves its own
-    # states to a lane a group (PR 40)
+    # chain compacts nothing (PR 26); the sorted reduce works out once who
+    # moves where and moves its own states to a lane a group (PR 40), and
+    # the keys with them (PR 45)
     for tag in ("compact_gather", "compact_slots", "compact_shift"):
-        assert text.count(tag) == text.count(
-            f"aggregate__segment_reduce/aggregate__{tag}"), tag
+        assert text.count(tag) == sum(text.count(
+            f"aggregate__{under}/aggregate__{tag}")
+            for under in ("group_bounds", "segment_reduce")), tag
     assert "compact_gather" not in text
-    # shared kernels take the family of the operator that called them
+    assert "aggregate__key_move/aggregate__key_move" in text
+    assert "aggregate__key_gather" not in text
+    # shared kernels take the family of the operator that called them;
+    # the sort is one branch of the order test's `cond` (PR 45)
     assert "aggregate__agg_partial/aggregate__group_sort/" \
-           "aggregate__radix_pass" in text
+           "aggregate__order_test" in text
+    assert re.search(r"aggregate__agg_partial/aggregate__group_sort/cond/"
+                     r"branch_\d_fun/aggregate__radix_pass", text)
+    assert re.search(r"aggregate__group_sort/cond/branch_\d_fun/"
+                     r"aggregate__radix_gather", text)
     assert "sort__radix_pass" not in text
     for scope in set(re.findall(r"(?<=/)[a-z_]+__[a-z0-9_]+(?=/)", text)):
         assert jit_cache.NAME_GRAMMAR.match(scope), scope
@@ -728,10 +737,10 @@ def test_the_sorted_reduce_is_counted_where_it_runs(sql, sorted_dispatches):
                                   "single"])
 def test_the_sorted_group_by_scatters_no_state_column(step):
     """The sorted path reduces its state columns (here int64 and float64)
-    by a scan over the sorted lanes and moves them by shifts (PR 40): the
-    lowered program's only scatters are the boundary flag's first lane
-    and `aggregate__key_gather`'s int32 row index of each group's first
-    lane, and what it says while it is traced is the counter's fact."""
+    by a scan over the sorted lanes and moves them by shifts (PR 40), and
+    the keys as the states (PR 45): the lowered program's only scatter is
+    the boundary flag's first lane, and what it says while it is traced is
+    the counter's fact."""
     from trino_tpu.ops import AggSpec, hash_aggregate
     from trino_tpu.page import trace_notes
     specs = [AggSpec("sum", 1, T.BIGINT), AggSpec("avg", 2, T.DOUBLE),
@@ -749,8 +758,7 @@ def test_the_sorted_group_by_scatters_no_state_column(step):
     assert said == {"sorted_reduce_scan:64"}
     scatters = [line.split(" = ")[1].split(" scatter(")[0]
                 for line in text.splitlines() if " scatter(" in line]
-    assert sorted(t.split("[")[0] for t in scatters) == ["pred", "s32"], \
-        scatters
+    assert [t.split("[")[0] for t in scatters] == ["pred"], scatters
     assert "s64[64]" in text and "f64[64]" in text
 
 

@@ -248,6 +248,21 @@ def test_the_counters_read(served, i):
     assert stats["sorted_reduces_scanned"] >= 2    # both GROUP BYs sorted
 
 
+@pytest.mark.parametrize("i", range(REQUESTS))
+def test_the_joined_lanes_arrive_in_customer_order_and_are_not_sorted(
+        served, i):
+    """The LEFT join emits its output in the probe's row order, customers
+    by `c_custkey`: the GROUP BY on it finds PARTIAL's and FINAL's lanes in
+    order on the device and neither sorts nor gathers them (PR 45). The
+    GROUP BY on `c_count` — 1 500 counts in customer order — sorts."""
+    stats = served[i]["stats"]
+    assert stats["group_by_lanes_in_order"] \
+        + stats["group_by_lanes_sorted"] == stats["sorted_reduce_lanes"]
+    assert stats["group_by_lanes_in_order"] >= 16384   # the joined rows
+    assert 0 < stats["group_by_lanes_sorted"] \
+        < stats["group_by_lanes_in_order"] / 5
+
+
 def test_q13_plans_the_not_like_under_the_join():
     q13 = reference.load_by_path("queries", "q13")
     runner = LocalQueryRunner.tpch("tiny")

@@ -207,6 +207,60 @@ def test_the_sorted_reduce_runs_in_q18_and_not_in_q4(served, i):
         assert stats["sorted_reduce_lanes"] == 0
 
 
+@pytest.mark.parametrize("i", range(2 * CYCLES))
+def test_q18s_lanes_arrive_in_key_order_and_are_not_sorted(served, i):
+    """lineitem is stored in `l_orderkey` order, and so are the partial
+    pages' states that FINAL merges: the inner GROUP BY's pages are found
+    in order on the device and run with no sort and no gather (PR 45);
+    the outer one's few dozen rows, in the join's order, sort. Every lane
+    a sorted reduce ran over is booked to the one or the other. Q4 has
+    no sorted GROUP BY."""
+    stats = served[i]["stats"]
+    assert stats["group_by_lanes_in_order"] \
+        + stats["group_by_lanes_sorted"] == stats["sorted_reduce_lanes"]
+    if served[i]["shape"] == "q18":
+        assert stats["group_by_lanes_in_order"] \
+            > 9 * stats["group_by_lanes_sorted"] > 0
+    else:
+        assert stats["group_by_lanes_in_order"] == 0
+        assert stats["group_by_lanes_sorted"] == 0
+
+
+def test_q18_over_a_shuffled_lineitem_answers_the_same_and_sorts(served):
+    """The same rows in another order (a copy of lineitem in the memory
+    connector, ordered by price): the order test fails on the scan's
+    page, the sorting branch runs, and the answer is the one in key
+    order. The table is ONE page at `tiny`, and a sorted GROUP BY's
+    output is in key order: FINAL, over that one partial page's states,
+    still finds its lanes in order — nothing else does."""
+    q18 = reference.load_by_path("queries", "q18")
+    runner = LocalQueryRunner.tpch("tiny")
+    runner.execute("DROP TABLE IF EXISTS memory.default.l18")
+    runner.execute(
+        "CREATE TABLE memory.default.l18 AS SELECT l_orderkey, l_quantity "
+        "FROM lineitem ORDER BY l_extendedprice, l_orderkey")
+    keys = [r[0] for r in runner.execute(
+        "SELECT l_orderkey FROM memory.default.l18").rows]
+    assert keys != sorted(keys) and len(keys) == C.lineitem_rows_before(
+        TINY, C.order_count(TINY))
+    first = served[0]
+    sql = q18.SQL.format(**first["params"])
+    assert sql.count(" lineitem") == 2
+    got = runner.execute(sql.replace(" lineitem", " memory.default.l18"))
+    stats = runner.last_query_stats
+    assert stats["group_by_lanes_sorted"] >= len(keys)      # the scan's page
+    assert stats["group_by_lanes_in_order"] <= 15000 * 2    # FINAL's alone
+    assert stats["group_by_lanes_in_order"] \
+        + stats["group_by_lanes_sorted"] == stats["sorted_reduce_lanes"]
+    # the same query over the table in key order, on this runner: the
+    # served answer, which the reference has checked
+    want = runner.execute(sql)
+    assert got.rows == want.rows and len(want.rows) == len(first["rows"])
+    assert [int(r[2]) for r in want.rows] == [r[2] for r in first["rows"]]
+    assert runner.last_query_stats["group_by_lanes_in_order"] \
+        == first["stats"]["group_by_lanes_in_order"] >= len(keys)
+
+
 def test_q18s_in_is_planned_under_the_join_on_orders():
     """The IN's semi join runs on orders, below both joins (optimizer
     rule PushSemiJoinThroughJoin): the joins see the orders the HAVING

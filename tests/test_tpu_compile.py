@@ -473,6 +473,55 @@ def test_q18_final_aggregate_at_15m_groups(one_chip, step):
     compiled = _compile(op, page, limit_s=300)
     assert _device_bytes(compiled) < DEVICE_BUDGET
     _assert_no_state_scatter(compiled, GROUP_LANES)
+    _assert_orders_only_where_it_must(compiled)
+
+
+def _assert_orders_only_where_it_must(compiled):
+    """The sorted GROUP BY holds ONE `conditional` (PR 45): the branch for
+    lanes that arrive in key order computes nothing — no sort, no gather,
+    no scatter, no fusion: it hands its operands on —, the other holds the
+    radix passes and the gathers, and the rounds — the reduce's, the
+    compaction's, the keys' move — are compiled once, outside both (the
+    compiler may sink an elementwise op of what follows into them: the
+    `not` of the dead flags is)."""
+    import re
+    text = compiled.as_text()
+    computations, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split(" ")[0 if line[0] == "%" else 1].lstrip("%")
+            computations[name] = []
+        elif name is not None:
+            computations[name].append(line)
+
+    def reached(root):
+        seen, todo = set(), [root]
+        while todo:
+            at = todo.pop()
+            if at in seen or at not in computations:
+                continue
+            seen.add(at)
+            for line in computations[at]:
+                todo += re.findall(
+                    r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line)
+        return [line for at in seen for line in computations[at]]
+    conds = [line for line in text.splitlines() if " conditional(" in line]
+    assert len(conds) == 1, len(conds)
+    branches = [reached(b.strip().lstrip("%")) for b in re.search(
+        r"branch_computations=\{([^}]*)\}", conds[0]).group(1).split(",")]
+    assert len(branches) == 2
+    sorting = [b for b in branches if any(" sort(" in line for line in b)]
+    as_they_are = [b for b in branches if b not in sorting]
+    assert len(sorting) == 1 and len(as_they_are) == 1
+    for word in (" sort(", " gather(", " scatter(", " fusion(", " select("):
+        assert not any(word in line for line in as_they_are[0]), word
+    assert any(" gather(" in line for line in sorting[0])
+    once = ("aggregate__segment_reduce", "aggregate__compact_shift",
+            "aggregate__key_move")
+    for scope in once:
+        assert scope in text, scope
+        for branch in branches:
+            assert not any(scope in line for line in branch), scope
 
 
 def _assert_no_state_scatter(compiled, lanes):
@@ -496,6 +545,7 @@ def test_q18_partial_aggregate_over_the_lineitem_page(one_chip):
     compiled = _compile(op, page, limit_s=300)
     assert _device_bytes(compiled) < DEVICE_BUDGET
     _assert_no_state_scatter(compiled, LINEITEM_LANES)
+    _assert_orders_only_where_it_must(compiled)
 
 
 def test_q18_probe_of_60m_lanes_against_a_hundred_orders(one_chip):
@@ -698,3 +748,4 @@ def test_q13_count_by_customer_over_the_joined_lanes(one_chip):
     compiled = _compile(op, page, limit_s=300)
     assert _device_bytes(compiled) < DEVICE_BUDGET
     _assert_no_state_scatter(compiled, lanes)
+    _assert_orders_only_where_it_must(compiled)

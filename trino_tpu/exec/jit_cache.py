@@ -74,7 +74,7 @@ import numpy as np
 
 from trino_tpu.expr.hoist import LikeOperand
 from trino_tpu.obs.stats import NO_ACTIVITY
-from trino_tpu.page import family_context, trace_notes
+from trino_tpu.page import device_notes, family_context, trace_notes
 
 # key -> [jitted kernel, last-seen flattened param signature or None,
 #         {input signature -> AOT compiled executable} (profiled path),
@@ -299,6 +299,51 @@ def _aval_signature(args) -> Tuple:
         for leaf in leaves)
 
 
+@jax.tree_util.register_pytree_node_class
+class _Said:
+    """What a program returns where its trace said numbers through
+    `page.note_device`: its own output and those scalars, by name."""
+
+    def __init__(self, out, names, values):
+        self.out, self.names, self.values = out, names, values
+
+    def tree_flatten(self):
+        return (self.out, self.values), self.names
+
+    @classmethod
+    def tree_unflatten(cls, names, children):
+        return cls(children[0], names, children[1])
+
+
+def _heard(out):
+    """A dispatch's output as its caller expects it: what the program
+    said beside it (`_Said`) goes to the thread's observer as a row count
+    does (obs/stats.count_rows: still on the device, read at the query's
+    end), or to nobody."""
+    if type(out) is not _Said:
+        return out
+    count = getattr(get_observer(), "count_rows", None)
+    if count is not None:
+        for name, value in zip(out.names, out.values):
+            count(name, value)
+    return out.out
+
+
+class _Program:
+    """A jitted program as the cache keeps it and hands it out: a call
+    takes what the program said beside its output off again (`_heard`);
+    `lower` is the jitted callable's, for the AOT path."""
+
+    __slots__ = ("jitted", "lower", "__name__")
+
+    def __init__(self, jitted):
+        self.jitted, self.lower = jitted, jitted.lower
+        self.__name__ = jitted.__name__
+
+    def __call__(self, *args):
+        return _heard(self.jitted(*args))
+
+
 def named(fn: Callable, key: Hashable) -> Callable:
     """`fn` under `program_name(key)`: jax.jit names the module, and the
     root of every op's scope path, after the function it is given. A
@@ -307,16 +352,23 @@ def named(fn: Callable, key: Hashable) -> Callable:
 
     `program.noted` keeps, per input signature, what the traced code said
     through `page.note_trace`: static facts of the program that
-    `profiled_kernel` counts on the query's collector at each dispatch."""
+    `profiled_kernel` counts on the query's collector at each dispatch.
+    What it said through `page.note_device` leaves the program beside its
+    output (`_Said`); `_heard` takes it off again at every dispatch — a
+    `_Program`'s call, or the AOT executable's in `profiled_kernel`."""
     name = program_name(key)
     family = name.partition("__")[0]
     noted: Dict[Tuple, frozenset] = {}
 
     def program(*args):
-        with family_context(family), trace_notes() as notes:
+        with family_context(family), trace_notes() as notes, \
+                device_notes() as said:
             out = fn(*args)
         if notes:
             noted[_aval_signature(args)] = frozenset(notes)
+        if said:
+            return _Said(out, tuple(name for name, _ in said),
+                         tuple(value for _, value in said))
         return out
     program.__name__ = program.__qualname__ = name
     program.noted = noted
@@ -333,7 +385,7 @@ def _lookup(key: Hashable, build: Callable[[], Callable],
         entry = _CACHE.get(key)
         if entry is None:
             program = named(build(), key)
-            fn = jax.jit(program)
+            fn = _Program(jax.jit(program))
             while len(_CACHE) >= _MAX_KERNELS:
                 _CACHE.popitem(last=False)
                 _STATS["evictions"] += 1
@@ -372,7 +424,7 @@ def cached_kernel(key: Hashable, build: Callable[[], Callable],
     fn = entry[0] if entry[4] else _first_call(entry)
     fenced, activity = _fencing_observer(), _observers_activity()
     if activity is None and fenced is None:
-        return fn                   # nobody to tell: the jitted callable
+        return fn                   # nobody to tell: the cached callable
     activity, name, noted = activity or _no_activity, entry[5], entry[3]
 
     def dispatch(*args):
@@ -510,7 +562,7 @@ def profiled_kernel(key: Hashable, build: Callable[[], Callable],
 
     def dispatch(*args):
         with activity("kernel_call", name):
-            return enqueue(*args)
+            return _heard(enqueue(*args))
 
     def enqueue(*args):
         # per-dispatch signature cost is ~10us of pytree flattening —

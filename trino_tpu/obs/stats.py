@@ -294,6 +294,14 @@ class QueryStatsCollector:
         # those reduces ran over (shapes, no sync)
         self.sorted_reduces_scanned = 0
         self.sorted_reduce_lanes = 0
+        # capacities of the pages a sorted GROUP BY (ops/aggregate.
+        # _sorted_aggregate) found already in key order, and ran with no
+        # sort and no gather, or had to sort: the device decides and says
+        # which beside the page it returns (page.note_device), read with
+        # the row counts at the snapshot (`count_rows`). A mesh program's
+        # are counted in neither
+        self.group_by_lanes_in_order = 0
+        self.group_by_lanes_sorted = 0
         # lake connector pruning (connector/lake/): whole data files
         # and row groups skipped via partition values + min/max zone
         # maps evaluated against the scan's TupleDomain (static
@@ -769,6 +777,8 @@ class QueryStatsCollector:
             "direct_reduces_scattered": self.direct_reduces_scattered,
             "sorted_reduces_scanned": self.sorted_reduces_scanned,
             "sorted_reduce_lanes": self.sorted_reduce_lanes,
+            "group_by_lanes_in_order": self.group_by_lanes_in_order,
+            "group_by_lanes_sorted": self.group_by_lanes_sorted,
             "files_pruned": self.files_pruned,
             "row_groups_pruned": self.row_groups_pruned,
             "streamed_chunks": self.streamed_chunks,

@@ -28,9 +28,9 @@ import jax
 import jax.numpy as jnp
 
 from trino_tpu import types as T
-from trino_tpu.ops.radix import sort_by_keys
-from trino_tpu.page import (Column, Page, note_trace, op_scope, shift_left,
-                            shift_move, shift_takes)
+from trino_tpu.ops.radix import in_order, sorted_by
+from trino_tpu.page import (Column, Page, note_device, note_trace, op_scope,
+                            shift_left, shift_move, shift_takes)
 
 
 class Step:
@@ -582,37 +582,132 @@ def hash_aggregate(
                 return _direct_aggregate(page, key_channels, aggs, resolved,
                                          step, partial_state_channels, sizes,
                                          masked)
-        with op_scope("aggregate__group_sort"):
-            sorted_keys, perm_sorted = sort_by_keys(
-                _sort_key_arrays(page, key_channels))
-        with op_scope("aggregate__group_bounds"):
-            # boundary detection on the *sorted* key operands (incl. null
-            # flags)
-            live_sorted = ~sorted_keys[0]
-            boundary = _boundary_scan(sorted_keys[1:], n) & live_sorted
-            group_of_sorted = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-            num_groups = jnp.sum(boundary).astype(jnp.int32)
-            # route dead rows to an out-of-range segment id so they drop out
-            seg = jnp.where(live_sorted, group_of_sorted, n)
-
-        out_cols: List[Column] = []
-        with op_scope("aggregate__key_gather"):
-            # group key output = first sorted row of each segment
-            first_idx = jnp.zeros(n, dtype=jnp.int32).at[
-                jnp.where(boundary, group_of_sorted, n)].set(
-                jnp.arange(n, dtype=jnp.int32), mode="drop")
-            key_row = jnp.take(perm_sorted, first_idx, mode="clip")
-            for ch in key_channels:
-                out_cols.append(page.column(ch).gather(key_row))
-
-        with op_scope("aggregate__segment_reduce"):
-            agg_cols = _accumulate(page, aggs, resolved, step,
-                                   partial_state_channels, perm_sorted, seg,
-                                   boundary, n, key_channels, list_len)
-        out_cols.extend(agg_cols)
-        return Page(tuple(out_cols), num_groups)
+        return _sorted_aggregate(page, key_channels, aggs, resolved, step,
+                                 partial_state_channels, list_len)
 
     return op
+
+
+def _group_sort_operands(page: Page, key_channels: Sequence[int]):
+    """`_sort_key_arrays` for the sorted GROUP BY: a dead lane's key lanes
+    hold whatever the producer left there (a concatenation's overwritten
+    tails, a join's clipped slots), so they are made ONE key — the dead
+    lanes then sort among themselves in the order they came, and lanes
+    whose live rows are a prefix in key order are in order whatever
+    lies behind them."""
+    operands = _sort_key_arrays(page, key_channels)
+    dead = operands[0]
+    return [dead] + [jnp.where(dead, jnp.zeros((), a.dtype), a)
+                     for a in operands[1:]]
+
+
+def _channels_read(key_channels, aggs, step, partial_state_channels):
+    """channel -> whether its validity is read, for every column the
+    sorted GROUP BY reads in key order: the keys, and each aggregate's
+    arguments and FILTER mask or (FINAL, INTERMEDIATE) its partial states,
+    whose values alone are read."""
+    read = {ch: True for ch in key_channels}
+    for ai, spec in enumerate(aggs):
+        if step in (Step.FINAL, Step.INTERMEDIATE):
+            for ch in partial_state_channels[ai]:
+                read.setdefault(ch, False)
+        else:
+            for ch in (spec.input, spec.input2, spec.mask_channel):
+                if ch is not None:
+                    read[ch] = True
+    return read
+
+
+def _in_key_order(operands, lanes):
+    """-> (in_order, `lanes` in the stable order of `operands`), `lanes`
+    any tree of arrays a lane a row. The device decides: where the rows
+    already lie in that order (`radix.in_order`: one compare pass) they
+    are handed on as they are — no sort, no permutation, no gather —
+    and where they do not, by the radix passes and one gather an array.
+    (The first branch traces no op: what the device does there is the
+    copies a `conditional` makes of what it returns, under the scope of
+    the `cond` itself.)"""
+    with op_scope("aggregate__order_test"):
+        ordered = in_order(operands)
+    return ordered, jax.lax.cond(
+        ordered, lambda lanes: lanes,
+        lambda lanes: sorted_by(operands, lanes)[0], lanes)
+
+
+def _sorted_aggregate(page: Page, key_channels, aggs, resolved, step,
+                      partial_state_channels, list_len) -> Page:
+    """The sort-based GROUP BY: the rows in key order, a boundary where the
+    key changes, every state reduced over its group's lanes by one
+    `_scan_reduce`, keys and states moved to a lane a group.
+
+    Whether the rows need ordering is decided from the data, inside the
+    program (`_in_key_order`): a scan of a table stored in key order, a
+    join's output in its probe's order and the concatenated outputs of
+    this very operator arrive sorted, and are then neither sorted nor
+    gathered. An aggregation that holds a single-step aggregate or a
+    DISTINCT reads its rows through the permutation, so it always sorts.
+    The lanes a dispatch ran over leave the program booked to
+    `group_by_lanes_in_order` or `group_by_lanes_sorted`."""
+    n = page.capacity
+    indexed = any(a.distinct or a.name in SINGLE_STEP_AGGREGATES
+                  for a in aggs)
+    operands = _group_sort_operands(page, key_channels)
+    read = {ch: page.column(ch) if valid
+            else page.column(ch).with_valid(None)
+            for ch, valid in _channels_read(
+                key_channels, aggs, step, partial_state_channels).items()}
+    perm_sorted = None
+    if indexed:
+        with op_scope("aggregate__group_sort"):
+            (dead_sorted, read), perm_sorted = sorted_by(
+                operands, (operands[0], read))
+        note_device("group_by_lanes_sorted", n)
+    else:
+        with op_scope("aggregate__group_sort"):
+            ordered, (dead_sorted, read) = _in_key_order(
+                operands, (operands[0], read))
+        lanes_in_order = jnp.where(ordered, n, 0).astype(jnp.int32)
+        note_device("group_by_lanes_in_order", lanes_in_order)
+        note_device("group_by_lanes_sorted", n - lanes_in_order)
+    # the page in key order: the columns nobody reads stay where they were
+    sorted_page = Page(tuple(read.get(ch, col)
+                             for ch, col in enumerate(page.columns)),
+                       page.num_rows)
+    with op_scope("aggregate__group_bounds"):
+        # boundary detection on the *sorted* key operands (incl. null
+        # flags)
+        live_sorted = ~dead_sorted
+        boundary = _boundary_scan(
+            _sort_key_arrays(sorted_page, key_channels, dead_sorted)[1:],
+            n) & live_sorted
+        # who moves where, once, for the keys and for every state
+        takes, num_groups = shift_takes(boundary)
+        is_group = jnp.arange(n, dtype=jnp.int32) < num_groups
+        seg = None
+        if indexed:
+            # route dead rows to an out-of-range segment id so they drop
+            # out
+            seg = jnp.where(live_sorted,
+                            jnp.cumsum(boundary.astype(jnp.int32)) - 1, n)
+
+    def to_group_lanes(a):
+        # a group's first lane to the group's lane, as the states go; the
+        # lanes past the groups read the first sorted row's
+        moved, _ = shift_move(a, takes, n, tag="key_move")
+        return jnp.where(is_group.reshape((n,) + (1,) * (a.ndim - 1)),
+                         moved, a[:1])
+    with op_scope("aggregate__key_move"):
+        out_cols: List[Column] = [
+            jax.tree_util.tree_map(to_group_lanes, sorted_page.column(ch))
+            for ch in key_channels]
+
+    with op_scope("aggregate__segment_reduce"):
+        agg_cols = _accumulate(page, sorted_page, aggs, resolved, step,
+                               partial_state_channels, perm_sorted, seg,
+                               boundary, live_sorted, takes, is_group,
+                               key_channels, list_len)
+    out_cols.extend(agg_cols)
+    return Page(tuple(out_cols), num_groups)
 
 
 def _agg_inputs(page: Page, spec: "AggSpec", fn, base_mask, gather=None):
@@ -642,15 +737,13 @@ def _agg_inputs(page: Page, spec: "AggSpec", fn, base_mask, gather=None):
     return vals, mask, dictionary
 
 
-def _final_state_contribs(page: Page, states, chans, live_mask, gather=None):
+def _final_state_contribs(page: Page, states, chans, live_mask):
     """FINAL-step per-state (contribution, reducer): partial state columns
     with dead rows replaced by each reducer's identity — shared by the
     sorted, global and direct paths."""
     out = []
     for sc, ch in zip(states, chans):
-        col = page.column(ch)
-        vals = col.values if gather is None else \
-            jnp.take(col.values, gather, mode="clip")
+        vals = page.column(ch).values
         if sc.reducer == "sum":
             ident = jnp.zeros((), dtype=vals.dtype)
         else:
@@ -928,7 +1021,7 @@ def _distinct_first_mask(page: Page, key_channels: Sequence[int],
 _SCAN_OPS = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 
 
-def _scan_reduce(contribs, boundary, live_sorted):
+def _scan_reduce(contribs, boundary, live_sorted, takes, is_group):
     """Per-group reduction of every `(contribution, reducer)` of `contribs`
     over SORTED lanes: `boundary` flags a group's first lane, the live
     lanes are a prefix and a dead lane contributes its reducer's identity.
@@ -952,6 +1045,9 @@ def _scan_reduce(contribs, boundary, live_sorted):
        (`page.shift_takes`, `page.shift_move`): who takes when is worked
        out once, and every state of every aggregate moves under it.
 
+    Who takes when in the compaction (`takes`, `is_group`) is the
+    caller's, which moves the keys under it too.
+
     Lanes at and past the group count are set to what the scatter left
     there (0 for a sum, the identity for min/max), so every consumer —
     `fn.final`, `_agg_out_column`, the INTERMEDIATE step, the pass-through
@@ -972,8 +1068,16 @@ def _scan_reduce(contribs, boundary, live_sorted):
     `jax.lax.associative_scan` 8.07 ms at 1 048 576 lanes after 75 s of
     compile, and not compiled in 85 minutes at 16 777 216. The whole
     sorted aggregate: a PARTIAL page 266.6 -> 125.0 ms, FINAL at 2^24
-    6 481 -> 2 531 ms; what is left is gathers through the sort
+    6 481 -> 2 531 ms; what was left was gathers through the sort
     permutation (the keys', the inputs', the radix passes').
+
+    Since PR 45 (PERF.md section 6, step 0; same chip, whole programs,
+    fenced) lanes that arrive in key order are handed to these rounds as
+    they are, and the keys are moved as the states are: the PARTIAL page
+    124.8 -> 2.45 ms (1.38 in a chain), FINAL at 2^24 2 530 -> 164.6 ms,
+    Q13's count over 2^24 joined lanes 2 528 -> 100.7 ms; the same lanes
+    shuffled, which take the sort and one gather an array, 123.1 -> 68.2,
+    2 276 -> 1 615 and 2 015 -> 1 164 ms.
     """
     n = boundary.shape[0]
     # every input's gather through the sort permutation is done before a
@@ -995,9 +1099,6 @@ def _scan_reduce(contribs, boundary, live_sorted):
         open_ = open_ & shift_left(open_, s)
         open_, opens = jax.lax.optimization_barrier((open_, opens))
         s *= 2
-    takes, num_groups = shift_takes(boundary)
-    is_group = jnp.arange(n, dtype=jnp.int32) < num_groups
-
     # states that reduce alike ride ONE array, end to end, each under the
     # same flags: the program's size (and its compile time, which grew
     # faster than the states) follows the kinds of state, not their number.
@@ -1027,15 +1128,17 @@ def _scan_reduce(contribs, boundary, live_sorted):
     return out
 
 
-def _accumulate(page, aggs, resolved, step, partial_state_channels,
-                perm_sorted, seg, boundary, n, key_channels=(),
+def _accumulate(page, sorted_page, aggs, resolved, step,
+                partial_state_channels, perm_sorted, seg, boundary,
+                live_sorted, takes, is_group, key_channels=(),
                 list_len=None) -> List[Column]:
     """Per-agg state accumulation + (for FINAL/SINGLE) final projection.
     Every state column of every aggregate that reduces with sum/min/max
-    goes through ONE `_scan_reduce` (its docstring has the chip's timings
-    of this form and of the scatter it replaced); the single-step
-    aggregates keep their own evaluation over `seg`."""
-    live_sorted = seg < n
+    reads its rows from `sorted_page`, in key order, and goes through ONE
+    `_scan_reduce` (its docstring has the chip's timings of this form and
+    of the scatter it replaced); the single-step aggregates keep their
+    own evaluation over `page`, `perm_sorted` and `seg`."""
+    n = page.capacity
     dmask_cache: dict = {}
 
     def distinct_mask(spec):
@@ -1057,9 +1160,8 @@ def _accumulate(page, aggs, resolved, step, partial_state_channels,
             chans = partial_state_channels[ai]
             states = fn.state(spec.input_type)
             entries.append((states, page.column(chans[0]).dictionary,
-                            _final_state_contribs(page, states, chans,
-                                                  live_sorted,
-                                                  gather=perm_sorted)))
+                            _final_state_contribs(sorted_page, states, chans,
+                                                  live_sorted)))
         elif spec.name in COLLECT_AGGREGATES:
             entries.append(_collect_grouped(page, spec, fn, perm_sorted, seg,
                                             n, list_len))
@@ -1076,8 +1178,8 @@ def _accumulate(page, aggs, resolved, step, partial_state_channels,
                                              extra))
         else:
             states = fn.state(spec.input_type)
-            vals, mask, dictionary = _agg_inputs(page, spec, fn, live_sorted,
-                                                 gather=perm_sorted)
+            vals, mask, dictionary = _agg_inputs(sorted_page, spec, fn,
+                                                 live_sorted)
             if spec.distinct:
                 mask = mask & distinct_mask(spec)
             entries.append((states, dictionary,
@@ -1088,7 +1190,8 @@ def _accumulate(page, aggs, resolved, step, partial_state_channels,
     reduced = iter(())
     if contribs:
         note_trace(f"sorted_reduce_scan:{n}")
-        reduced = iter(_scan_reduce(contribs, boundary, live_sorted))
+        reduced = iter(_scan_reduce(contribs, boundary, live_sorted, takes,
+                                    is_group))
 
     out: List[Column] = []
     for (spec, fn), entry in zip(zip(aggs, resolved), entries):

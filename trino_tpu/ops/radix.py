@@ -101,8 +101,29 @@ def stable_argsort(keys: Sequence[jnp.ndarray]) -> jnp.ndarray:
     return perm
 
 
-def sort_by_keys(keys: Sequence[jnp.ndarray]):
-    """-> (keys in sorted order, the permutation that sorted them)."""
+def in_order(keys: Sequence[jnp.ndarray]) -> jnp.ndarray:
+    """Scalar: no row sorts before the row ahead of it, so
+    `stable_argsort(keys)` is the identity. Read off the same digits the
+    passes sort by — the sort's order, NaN and -0 included — in one
+    elementwise pass and a reduce."""
+    out_of_order = tied = None
+    for digit in _all_digits(keys):
+        ahead = jnp.concatenate([digit[:1], digit[:-1]])
+        gt, eq = ahead > digit, ahead == digit
+        out_of_order = gt if tied is None else out_of_order | (tied & gt)
+        tied = eq if tied is None else tied & eq
+    return ~jnp.any(out_of_order)
+
+
+def sorted_by(keys: Sequence[jnp.ndarray], lanes):
+    """-> (`lanes` — any tree of arrays a lane a row — gathered through
+    the permutation that sorts `keys`, that permutation)."""
     perm = stable_argsort(keys)
     with shared_scope("radix_gather", "sort"):
-        return [jnp.take(k, perm, mode="clip") for k in keys], perm
+        return jax.tree_util.tree_map(
+            lambda a: jnp.take(a, perm, axis=0, mode="clip"), lanes), perm
+
+
+def sort_by_keys(keys: Sequence[jnp.ndarray]):
+    """-> (keys in sorted order, the permutation that sorted them)."""
+    return sorted_by(keys, list(keys))

@@ -76,6 +76,30 @@ def note_trace(fact: str) -> None:
         notes.add(fact)
 
 
+def note_device(name: str, value) -> None:
+    """Say a number the program computes — a scalar of its trace — to
+    whoever runs it: heard by `device_notes`, said to nobody outside one.
+    It leaves the program beside its outputs and is counted under `name`
+    where a page's row count is (jit_cache.named)."""
+    heard = getattr(_THREAD, "device_notes", None)
+    if heard is not None:
+        heard.append((name, value))
+
+
+@contextlib.contextmanager
+def device_notes():
+    """Collect what the code traced inside says through `note_device`:
+    [(name, scalar of that trace)]. A nested trace (a `shard_map`'s or a
+    `lax.map`'s body) opens one of its own and drops it: its scalars may
+    not leave it this way."""
+    prev = getattr(_THREAD, "device_notes", None)
+    heard = _THREAD.device_notes = []
+    try:
+        yield heard
+    finally:
+        _THREAD.device_notes = prev
+
+
 @contextlib.contextmanager
 def trace_notes():
     """Collect what the code traced inside says through `note_trace`
@@ -487,7 +511,8 @@ def shift_takes(mask: jnp.ndarray):
     return takes, count
 
 
-def shift_move(a: jnp.ndarray, takes: jnp.ndarray, capacity: int):
+def shift_move(a: jnp.ndarray, takes: jnp.ndarray, capacity: int,
+               tag: str = "compact_shift"):
     """One array (1-D, or planes along axis 0) through the rounds that
     `takes` spells, a round at a time (the barriers): the scheduler holds
     two copies of one array and not of the page
@@ -495,8 +520,8 @@ def shift_move(a: jnp.ndarray, takes: jnp.ndarray, capacity: int):
     takes `takes` from here, so it starts when this one is done.
     `capacity` is the mask's; `a` and `takes` may hold several arrays of
     that many lanes end to end, each with the mask's `takes`: no lane
-    takes from beyond its own array's end."""
-    with shared_scope("compact_shift"):
+    takes from beyond its own array's end. `tag` names the rounds' scope."""
+    with shared_scope(tag):
         lanes = (a.shape[0],) + (1,) * (a.ndim - 1)
         s = 1
         while s < capacity:
@@ -987,8 +1012,11 @@ def in_chunks(page: Page, body: Callable[[Page], Page], lanes: int,
                 lambda x: jax.lax.dynamic_slice_in_dim(x, at, lanes),
                 page.columns)
     rows = jnp.clip(page.num_rows - starts, 0, lanes).astype(jnp.int32)
-    outs = jax.lax.map(lambda xs: body(Page(chunk(xs[0]), xs[1])),
-                       (cols, rows))
+
+    def a_chunk(xs):
+        with device_notes():        # a scalar of the map's body stays there
+            return body(Page(chunk(xs[0]), xs[1]))
+    outs = jax.lax.map(a_chunk, (cols, rows))
     m = outs.columns[0].values.shape[1]
     live = (jnp.arange(m, dtype=jnp.int32)[None, :]
             < outs.num_rows[:, None]).reshape(k * m)

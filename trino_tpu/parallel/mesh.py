@@ -19,7 +19,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from trino_tpu.page import Column, Page, op_scope
+from trino_tpu.page import Column, Page, device_notes, op_scope
 
 
 class QueryMesh:
@@ -83,7 +83,8 @@ class QueryMesh:
             with op_scope("exchange__shard_view"):
                 squeezed = jax.tree_util.tree_map(
                     lambda x: jnp.squeeze(x, axis=0), args[replicated:])
-            out = fn(*args[:replicated], *squeezed)
+            with device_notes():    # a shard's scalars stay in its trace
+                out = fn(*args[:replicated], *squeezed)
             with op_scope("exchange__shard_view"):
                 return jax.tree_util.tree_map(
                     lambda x: jnp.expand_dims(x, axis=0), out)
