@@ -439,22 +439,51 @@ def test_unique_dense_lookup_is_the_search_lookup_and_a_numpy_join(case):
     ("SELECT count(*), sum(v) FROM memory.default.lp p LEFT JOIN "
      "memory.default.lu b ON p.k = b.k", "position_table", (5, 30)),
     ("SELECT count(*) FROM memory.default.lp p WHERE EXISTS (SELECT 1 "
-     "FROM memory.default.lu b WHERE b.k = p.k)", "position_table", (2,)),
+     "FROM memory.default.lu b WHERE b.k = p.k)", "set_table", (2,)),
     ("SELECT count(*), sum(v) FROM memory.default.lp p, "
      "memory.default.ls b WHERE p.k = b.k", "row_table", (1, 10)),
     ("SELECT count(*), sum(v) FROM memory.default.lp p, "
-     "memory.default.lx b WHERE p.k = b.k", "search", (1, 10))],
+     "memory.default.lx b WHERE p.k = b.k", "search", (1, 10)),
+    ("SELECT count(*) FROM memory.default.lp p WHERE p.k NOT IN (SELECT k "
+     "FROM memory.default.ld)", "set_table", (2,)),
+    ("SELECT count(*) FROM memory.default.lp p WHERE NOT EXISTS (SELECT 1 "
+     "FROM memory.default.ld b WHERE b.k = p.k)", "set_table", (3,)),
+    ("SELECT count(*) FROM (SELECT p.k IN (SELECT k FROM "
+     "memory.default.ld) AS f FROM memory.default.lp p) WHERE f IS NULL",
+     "set_table", (1,)),
+    ("SELECT count(*) FROM memory.default.lp p WHERE EXISTS (SELECT 1 "
+     "FROM memory.default.ls b WHERE b.k = p.k)", "search", (1,)),
+    ("SELECT count(*) FROM memory.default.lp p WHERE EXISTS (SELECT 1 "
+     "FROM memory.default.ld b WHERE b.k = p.k AND b.v = p.u * 10)",
+     "search", (1,))],
     ids=["unique-inner", "max-run-2", "left", "semi",
-         "unique-inner-past-the-fill-rule", "past-the-slot-cap"])
-def test_the_router_picks_the_tables_payload(sql, counted, rows):
+         "unique-inner-past-the-fill-rule", "past-the-slot-cap",
+         "not-in", "not-exists", "mark", "semi-past-the-fill-rule",
+         "semi-on-two-columns"])
+def test_the_router_picks_the_tables_payload(monkeypatch, sql, counted,
+                                             rows):
     """`_prepare_probe` gives the table of build rows to the unique INNER
-    probe alone: a build with a duplicate key, a LEFT join and a semi
-    join read run_len at the key's position and keep the position table.
-    The row table is bounded by the slot cap alone (2^26), not by the
-    position table's fill rule (4 slots a build lane, at least 2^20): a
-    gather costs the same whatever the table's fill (PERF.md, PR 38);
-    past the cap the build is searched. One decision a join, counted;
-    the probe's lanes counted from shapes."""
+    probe alone: a build with a duplicate key and a LEFT join read
+    run_len at the key's position and keep the position table. The row
+    table is bounded by the slot cap alone (2^26), not by the position
+    table's fill rule (4 slots a build lane, at least 2^20): a gather
+    costs the same whatever the table's fill (PERF.md, PR 38); past the
+    cap the build is searched. A SEMI, ANTI or MARK join on one column
+    asks whether a key is there, not where: under the fill rule it gets
+    the set table and its build is never sorted (PR 46) — `_prepare_build`
+    is not called, the build page's lanes are booked to
+    `semi_build_lanes_set`; past the rule, or on two columns (a hashed
+    key, verified through the permutation), it sorts and searches as it
+    did. One decision a join, counted; the probe's lanes counted from
+    shapes."""
+    from trino_tpu.exec.local_planner import LocalExecutionPlanner
+    sorted_builds = []
+    prepare = LocalExecutionPlanner._prepare_build
+
+    def spy(self, build_keys, build_page, semi=False, outer=False):
+        sorted_builds.append((semi, build_page.capacity))
+        return prepare(self, build_keys, build_page, semi, outer)
+    monkeypatch.setattr(LocalExecutionPlanner, "_prepare_build", spy)
     r = LocalQueryRunner.tpch("tiny")
     r.execute("CREATE TABLE memory.default.lp (k BIGINT, u BIGINT)")
     r.execute("INSERT INTO memory.default.lp VALUES (1, 1), (2, 1), "
@@ -468,8 +497,19 @@ def test_the_router_picks_the_tables_payload(sql, counted, rows):
     got = r.execute(sql)
     stats = r.last_query_stats
     lookups = {k: stats["probe_lookups_" + k]
-               for k in ("row_table", "position_table", "search")}
+               for k in ("row_table", "position_table", "set_table",
+                         "search")}
     assert lookups == {k: int(k == counted) for k in lookups}
+    semi_lanes = sum(cap for semi, cap in sorted_builds if semi)
+    if counted == "set_table":
+        assert sorted_builds == []
+        assert stats["semi_build_lanes_set"] >= 8
+        assert stats["semi_build_lanes_sorted"] == 0
+    else:
+        assert len(sorted_builds) == 1
+        assert stats["semi_build_lanes_set"] == 0
+        assert stats["semi_build_lanes_sorted"] == semi_lanes
+        assert (semi_lanes > 0) == (" EXISTS " in sql)
     lanes = stats["probe_lookup_lanes"]
     assert lanes >= 8 and lanes & (lanes - 1) == 0   # one buffer's capacity
     assert got.rows == [rows]

@@ -322,3 +322,141 @@ def test_mark_join_no_build_nulls_definite_false():
     op = hash_join([0], [0], JoinType.MARK)
     out, _ = jax.jit(op)(probe, build)
     assert [r[-1] for r in out.to_pylist()] == [True, False]
+
+
+# ---------------------------------------------------------------------------
+# the set table of a single-key SEMI, ANTI or MARK join (PR 46)
+
+def _with_rows(page, n):
+    """The same lanes, the first `n` of them live."""
+    return Page(page.columns, jnp.asarray(n, dtype=jnp.int32))
+
+
+def _varchar_pages(probe_words, build_words, build_valid=None):
+    """Two one-column VARCHAR pages over ONE dictionary."""
+    from trino_tpu.page import Column, Dictionary
+    d, codes = Dictionary.build(np.asarray(probe_words + build_words,
+                                           dtype=object))
+    n = len(probe_words)
+    probe = Page((Column.from_numpy(codes[:n], T.VARCHAR, None, d),),
+                 jnp.asarray(n, dtype=jnp.int32))
+    valid = None if build_valid is None else np.asarray(build_valid, bool)
+    build = Page((Column.from_numpy(codes[n:], T.VARCHAR, valid, d),),
+                 jnp.asarray(len(build_words), dtype=jnp.int32))
+    return probe, build
+
+
+def _set_table_case(name):
+    """(probe_page, build_page) of one case; the key is channel 0."""
+    rng = np.random.default_rng(46)
+    if name == "duplicates":
+        return (page_of(([1, 2, 3, 4, 4], T.BIGINT), ([10, 20, 30, 40, 50],
+                                                      T.BIGINT)),
+                page_of(([2, 4, 4, 4, 2], T.BIGINT)))
+    if name == "dead_build_lanes":
+        # lanes past num_rows hold keys the probe asks for: never there
+        return (page_of(([2, 4, 9, 7], T.BIGINT)),
+                _with_rows(page_of(([2, 4, 9, 9, 7, 0], T.BIGINT)), 2))
+    if name == "null_build_keys":
+        return (page_of(([1, 2, 3, 4], T.BIGINT)),
+                page_of(([2, 3, 4, 1], T.BIGINT, [1, 0, 1, 0])))
+    if name == "null_probe_keys":
+        return (page_of(([1, 2, 3, 4], T.BIGINT, [1, 0, 0, 1])),
+                page_of(([2, 4, 1], T.BIGINT)))
+    if name == "null_keys_on_both_sides":
+        return (page_of(([1, 2, 3, 4], T.BIGINT, [1, 0, 1, 0])),
+                page_of(([2, 4, 1, 5], T.BIGINT, [1, 1, 0, 1])))
+    if name == "empty_build":
+        return (page_of(([1, 2, 3], T.BIGINT, [1, 0, 1])),
+                _with_rows(page_of(([1, 2, 3, 3], T.BIGINT)), 0))
+    if name == "probe_outside_the_span":
+        return (page_of(([-7, 0, 99, 100, 105, 106, 2 ** 40], T.BIGINT)),
+                page_of(([100, 105, 103], T.BIGINT)))
+    if name == "negative_keys":
+        return (page_of(([-6, -5, -4, -3, -2, 1], T.BIGINT)),
+                page_of(([-3, -5, -3], T.BIGINT)))
+    if name == "shuffled_build":
+        keys = rng.permutation(np.repeat(np.arange(1000, 1400, 3), 2))
+        probe = rng.integers(990, 1410, 512)
+        return (page_of((probe, T.BIGINT), (np.arange(512), T.BIGINT)),
+                _with_rows(page_of((keys, T.BIGINT),
+                                   (np.arange(len(keys)), T.BIGINT)), 200))
+    if name == "dictionary_key":
+        return _varchar_pages(["b", "zz", "a", "q", "b"],
+                              ["q", "b", "b", "m"], [1, 1, 1, 0])
+    if name == "integer_key":
+        return (page_of((np.asarray([5, -1, 7, 9], np.int32), T.INTEGER)),
+                page_of((np.asarray([9, 5, 5], np.int32), T.INTEGER)))
+    raise AssertionError(name)
+
+
+def _set_join(probe, build, join_type, null_aware):
+    """The join the way `_prepare_probe` runs it for lookup 'set': a pass
+    of reductions, one scatter from the unsorted lanes, one gather."""
+    from trino_tpu.ops.join import (build_set_table, semi_build_stats,
+                                    set_semi_join)
+    kmin, kmax, n_rows, has_null = jax.jit(semi_build_stats([0]))(build)
+    span = max(int(kmax) - int(kmin) + 1, 1)     # an empty build: 1 slot
+    size = 1 << (span - 1).bit_length()
+    table, key_cols = jax.jit(build_set_table([0], size))(build, kmin)
+    assert table.shape == (size,) and table.dtype == jnp.int32
+    assert all(c.values.shape == (0,) for c in key_cols)
+    prepared = (table, kmin, n_rows, has_null, key_cols)
+    return jax.jit(set_semi_join([0], join_type, null_aware))(
+        probe, prepared), (kmin, kmax, n_rows, has_null), size
+
+
+@pytest.mark.parametrize("case", [
+    "duplicates", "dead_build_lanes", "null_build_keys", "null_probe_keys",
+    "null_keys_on_both_sides", "empty_build", "probe_outside_the_span",
+    "negative_keys", "shuffled_build", "dictionary_key", "integer_key"])
+@pytest.mark.parametrize("null_aware", [True, False],
+                         ids=["in", "exists"])
+@pytest.mark.parametrize("join_type", [JoinType.SEMI, JoinType.ANTI,
+                                       JoinType.MARK])
+def test_the_set_table_answers_as_the_sorted_build_does(join_type,
+                                                        null_aware, case):
+    """The set build and set probe (no sort) against hash_join over a
+    sorted build, by search and through the position table: the same
+    page, row for row, NULL for NULL, and the same total — and the stats
+    pass reads what prepare_build read."""
+    from trino_tpu.ops.join import build_dense_table, prepare_build
+    probe, build = _set_table_case(case)
+    (got, got_total), stats, size = _set_join(probe, build, join_type,
+                                              null_aware)
+    prepared = jax.jit(prepare_build([0], semi=True))(build)
+    assert [int(x) for x in stats] == [
+        int(prepared[i]) for i in (8, 9, 4, 5)]
+    table = jax.jit(build_dense_table(size, semi=True))(
+        prepared[1], prepared[3], prepared[8])
+    for lookup, prep in (("search", prepared),
+                         ("dense", prepared + (table,))):
+        want, want_total = jax.jit(hash_join(
+            [0], [0], join_type, prepared=True, lookup=lookup,
+            null_aware=null_aware))(probe, prep)
+        assert got.to_pylist() == want.to_pylist(), lookup
+        assert int(got_total) == int(want_total) == int(got.num_rows)
+    if case == "empty_build":
+        assert int(stats[0]) > int(stats[1]) and int(stats[2]) == 0
+
+
+def test_the_set_table_refuses_keys_of_two_dictionaries():
+    """Codes of different pools are not comparable: the set probe fails
+    as loudly as hash_join does, from the build's key column cut to no
+    lane."""
+    probe, _ = _varchar_pages(["a", "b"], ["c"])
+    _, build = _varchar_pages(["x"], ["a", "b"])
+    with pytest.raises(NotImplementedError, match="distinct dictionaries"):
+        _set_join(probe, build, JoinType.SEMI, True)
+    with pytest.raises(NotImplementedError, match="distinct dictionaries"):
+        hash_join([0], [0], JoinType.SEMI)(probe, build)
+
+
+@pytest.mark.parametrize("join_type", [JoinType.INNER, JoinType.LEFT,
+                                       JoinType.FULL])
+def test_the_set_table_serves_no_join_that_emits_build_rows(join_type):
+    from trino_tpu.ops.join import set_semi_join
+    with pytest.raises(ValueError, match="SEMI, ANTI and MARK"):
+        set_semi_join([0], join_type)
+    with pytest.raises(ValueError, match="one key column"):
+        set_semi_join([0, 1], JoinType.SEMI)

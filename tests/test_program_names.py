@@ -33,7 +33,8 @@ KNOWN_TAGS = {
              "dense-table", "dense-table-rows", "dfbounds", "dfrange",
              "dfrange-mask", "probe-compact", "spill-prep", "spill-probe",
              "spill-probe-dense", "semijoin-prep",
-             "semijoin-dense-table", "join-composite", "uprobe-composite",
+             "semijoin-dense-table", "semijoin-stats",
+             "semijoin-set-table", "join-composite", "uprobe-composite",
              "join-prep-composite", "join-outer", "join-prep-outer",
              "join-outer-composite", "join-prep-outer-composite",
              "join-full-outer", "dense-table-outer"],
@@ -418,6 +419,56 @@ def test_semi_and_mark_joins_carry_scopes_of_their_own(join_type,
         assert jit_cache.NAME_GRAMMAR.match(scope), scope
 
 
+@pytest.mark.parametrize("join_type, program, probe_scope", [
+    ("semi", "semijoin", "join__semi_probe"),
+    ("anti", "semijoin", "join__semi_probe"),
+    ("mark", "markjoin", "join__mark_probe")])
+def test_the_set_tables_programs_read_as_the_semi_joins_own(
+        join_type, program, probe_scope):
+    """The set build's two programs and the set probe (PR 46) under the
+    names the executor gives them: `join__semijoin_stats` and
+    `join__semijoin_set_table` hold nothing but `join__semi_build`, the
+    probe is `join__semijoin` / `join__markjoin` with its lookup under
+    `join__semi_probe` / `join__mark_probe` — every name begins
+    `join__semi` or `join__mark`, so `semijoin_device_ms_per_q` (a prefix
+    match) keeps reading the work — and no radix pass is in any of them."""
+    from trino_tpu.ops.join import (build_set_table, semi_build_stats,
+                                    set_semi_join)
+    probe = Page.from_numpy([jnp.arange(64) % 7, jnp.arange(64)],
+                            [T.BIGINT, T.BIGINT])
+    build = Page.from_numpy([jnp.arange(16) % 5], [T.BIGINT])
+    stats_key = ("semijoin-stats", (0,))
+    table_key = ("semijoin-set-table", (0,), 8)
+    assert jit_cache.program_name(stats_key) == "join__semijoin_stats"
+    assert jit_cache.program_name(table_key) == "join__semijoin_set_table"
+    stats_op = jax.jit(jit_cache.named(semi_build_stats([0]), stats_key))
+    table_op = jax.jit(jit_cache.named(build_set_table([0], 8), table_key))
+    kmin, _kmax, n_rows, has_null = stats_op(build)
+    table, key_cols = table_op(build, kmin)
+    probe_op = jax.jit(jit_cache.named(
+        set_semi_join([0], join_type), (program, (0,), (0,))))
+    texts = {
+        "join__semijoin_stats": stats_op.lower(build),
+        "join__semijoin_set_table": table_op.lower(build, kmin),
+        f"join__{program}": probe_op.lower(
+            probe, (table, kmin, n_rows, has_null, key_cols))}
+    for name, lowered in texts.items():
+        text = lowered.as_text(debug_info=True)
+        assert f"jit({name})/" in text
+        scopes = set(re.findall(r"(?<=/)[a-z_]+__[a-z0-9_]+(?=/)", text))
+        assert scopes, name
+        for scope in scopes:
+            assert jit_cache.NAME_GRAMMAR.match(scope), scope
+            assert scope.startswith(("join__semi", "join__mark",
+                                     "join__compact")), (name, scope)
+        if name == f"join__{program}":
+            assert probe_scope in scopes
+            assert "join__semi_build" not in scopes
+        else:
+            assert scopes == {"join__semi_build"}
+        assert "join__radix" not in text and "stablehlo.sort" not in text
+
+
 @pytest.mark.parametrize("join_type", ["inner", "left", "semi"])
 def test_a_join_on_two_columns_carries_names_of_its_own(join_type):
     """A key of more than one column (Q9's (partkey, suppkey), PR 42) is
@@ -675,20 +726,22 @@ def test_q18_counts_its_inner_groups_and_the_orders_it_probes(
     assert stats["aggregate_groups_out"] == 15000 + kept
 
 
-@pytest.mark.parametrize("sql, row_table, position_table", [
+@pytest.mark.parametrize("sql, row_table, set_table", [
     pytest.param(chip_smoke.Q3, 2, 0, id="q3"),
     pytest.param(_Q4, 0, 1, id="q4"),
     pytest.param(_Q18, 2, 1, id="q18")])
 def test_joins_count_their_lookups_and_the_lanes_they_ran_over(
-        monkeypatch, sql, row_table, position_table):
+        monkeypatch, sql, row_table, set_table):
     """One `_prepare_probe` decision a join, counted by what the table
     holds (PR 38). q3: both builds are unique, INNER and dense — two
-    tables of build rows. Q4's `EXISTS` is a semi join: a table of
-    positions. Q18 at `tiny`: the customer join and the outer join with
-    lineitem (unique, INNER: the ~50 orders the HAVING kept span the
-    order keys, inside the row table's slot cap) read row tables, the
-    `IN` a position table; nothing is searched. `probe_lookup_lanes` is
-    the capacities of the buffers those lookups ran over."""
+    tables of build rows. Q4's `EXISTS` is a semi join on one column: a
+    set table, scattered from lineitem's lanes as they arrive (PR 46).
+    Q18 at `tiny`: the customer join and the outer join with lineitem
+    (unique, INNER: the ~50 orders the HAVING kept span the order keys,
+    inside the row table's slot cap) read row tables, the `IN` a set
+    table (at SF10 its hundred keys span 15 M and it is searched);
+    nothing reads a position table. `probe_lookup_lanes` is the
+    capacities of the buffers those lookups ran over."""
     from trino_tpu.exec.local_planner import LocalExecutionPlanner
     lanes = []
     counted = LocalExecutionPlanner._lookup_lanes
@@ -702,9 +755,12 @@ def test_joins_count_their_lookups_and_the_lanes_they_ran_over(
     tpch.execute(sql)
     stats = tpch.last_query_stats
     assert (stats["probe_lookups_row_table"],
+            stats["probe_lookups_set_table"],
             stats["probe_lookups_position_table"],
-            stats["probe_lookups_search"]) == (row_table, position_table, 0)
-    assert len(lanes) >= row_table + position_table
+            stats["probe_lookups_search"]) == (row_table, set_table, 0, 0)
+    assert (stats["semi_build_lanes_set"] > 0) == bool(set_table)
+    assert stats["semi_build_lanes_sorted"] == 0
+    assert len(lanes) >= row_table + set_table
     assert stats["probe_lookup_lanes"] == sum(lanes) > 0
 
 

@@ -226,6 +226,29 @@ def test_q18s_lanes_arrive_in_key_order_and_are_not_sorted(served, i):
         assert stats["group_by_lanes_sorted"] == 0
 
 
+@pytest.mark.parametrize("i", range(2 * CYCLES))
+def test_the_semi_joins_build_set_tables_and_sort_no_lane(served, i):
+    """Q4's EXISTS asks whether an order has a late line, not where it
+    sorts: its build — lineitem's lanes as the scan left them — goes
+    into a set table by one scatter (PR 46), and nothing of it is
+    sorted. At `tiny` Q18's IN (the few dozen orders its HAVING kept,
+    spanning under 2^20 keys) gets one too, as it got a position table
+    before; at SF10 those keys span 15 M and the router reads `search`
+    (tests/test_join_shapes.py: `semi-past-the-fill-rule`)."""
+    stats = served[i]["stats"]
+    assert stats["probe_lookups_set_table"] == 1
+    assert stats["probe_lookups_position_table"] == 0
+    assert stats["probe_lookups_search"] == 0
+    assert stats["semi_build_lanes_sorted"] == 0
+    lanes = stats["semi_build_lanes_set"]
+    assert lanes > 0 and lanes & (lanes - 1) == 0
+    if served[i]["shape"] == "q4":
+        # the late lines' page keeps the scan page's lanes
+        assert lanes >= stats["semi_join_build_rows"] > 30000
+    else:
+        assert lanes >= stats["semi_join_build_rows"]
+
+
 def test_q18_over_a_shuffled_lineitem_answers_the_same_and_sorts(served):
     """The same rows in another order (a copy of lineitem in the memory
     connector, ordered by price): the order test fails on the scan's

@@ -601,6 +601,88 @@ def test_q4_semi_join_probe_of_a_quarters_orders(one_chip):
     assert _device_bytes(compiled) < DEVICE_BUDGET
 
 
+Q4_BUILD = (T.BIGINT, T.DATE, T.DATE)   # l_orderkey and the two dates
+
+
+def _spec(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def test_q4_set_build_orders_no_lane(one_chip):
+    """The EXISTS' build since PR 46: a pass of reductions over the
+    collected page's 60 M lanes (the keys' bounds, the rows, whether a
+    key is NULL), then ONE scatter of a constant from the keys as they
+    arrive into the 2^24 slots the 15 M order keys span — no radix pass
+    over 64-bit keys, no permutation, no gather, and no temporary as long as the page but the
+    key's own halves."""
+    from trino_tpu.ops.join import build_set_table, semi_build_stats
+    build = _page(one_chip, LINEITEM_LANES, Q4_BUILD)
+    stats = _compile(semi_build_stats([0]), build, limit_s=120)
+    text = stats.as_text()
+    assert "join__semi_build" in text
+    for op in (" sort(", " gather(", " scatter("):
+        assert op not in text, op
+    # the key column's two 32-bit halves (the TPU has no 64-bit lanes)
+    # and nothing else as long as the page
+    halves = 2 * 4 * LINEITEM_LANES + (1 << 20)
+    assert stats.memory_analysis().temp_size_in_bytes < halves
+    table = _compile(build_set_table([0], GROUP_LANES), build,
+                     _spec(one_chip, (), jnp.uint64), limit_s=120)
+    text = table.as_text()
+    assert "join__semi_build" in text
+    assert "join__radix" not in text and " gather(" not in text
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert len(scatters) == 1, scatters
+    assert scatters[0].split(" scatter(")[0].split(" = ")[1] \
+        .startswith(f"s32[{GROUP_LANES}]"), scatters[0]
+    # the compiler lowers a scatter over unsorted indices to ONE sort of
+    # the int32 slot indices with their updates and a scatter in slot
+    # order (whatever `unique_indices` says; the position table's scatter
+    # had the same one): that sort is the scatter's own, the only one
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert len(sorts) == 1, sorts
+    assert "join__semi_build/scatter" in sorts[0]
+    assert sorts[0].split(" sort(")[0].split(" = ")[1].startswith(
+        f"(s32[{LINEITEM_LANES}]"), sorts[0]
+    assert "s64[" not in sorts[0] and "u64[" not in sorts[0]
+    # the slot index is formed where it is scattered: the sorted build
+    # held keys, dead flags, permutation and run lengths of 60 M lanes
+    assert table.memory_analysis().temp_size_in_bytes < halves
+    assert _device_bytes(table) < DEVICE_BUDGET
+
+
+@pytest.mark.parametrize("join_type, scope", [
+    ("semi", "join__semi_probe"), ("anti", "join__semi_probe"),
+    ("mark", "join__mark_probe")])
+def test_q4_set_probe_is_one_gather(one_chip, join_type, scope):
+    """The quarter's orders against the set table: exactly ONE gather a
+    probe lane, an int32 slot for each of the 2^20 lanes, under the
+    join's own scope — no second gather for a run length, no sort, no
+    scatter; then `Page.filter`'s shift rounds (a MARK join moves
+    nothing)."""
+    from trino_tpu.ops.join import (build_set_table, semi_build_stats,
+                                    set_semi_join)
+    build = _page(one_chip, LINEITEM_LANES, Q4_BUILD)
+    kmin, _kmax, n_rows, has_null = jax.eval_shape(
+        semi_build_stats([0]), build)
+    table, key_cols = jax.eval_shape(
+        build_set_table([0], GROUP_LANES), build, kmin)
+    assert table.shape == (GROUP_LANES,)
+    lanes = 1 << 20
+    probe = _page(one_chip, lanes, (T.BIGINT, T.DATE, T.VARCHAR))
+    compiled = _compile(
+        set_semi_join([0], join_type, null_aware=join_type != "semi"),
+        probe, (table, kmin, n_rows, has_null, key_cols), limit_s=120)
+    text = compiled.as_text()
+    gathers = [line for line in text.splitlines() if " gather(" in line]
+    assert len(gathers) == 1, gathers
+    assert scope in gathers[0]
+    assert gathers[0].split(" gather(")[0].split(" = ")[1] \
+        .startswith(f"s32[{lanes}]"), gathers[0]
+    assert " sort(" not in text and " scatter(" not in text
+    assert _device_bytes(compiled) < DEVICE_BUDGET
+
+
 # ---- Q9 at SF10 (PR 42): six lineitem columns through part's row table,
 # their compaction, and the join on (partkey, suppkey) -------------------
 

@@ -42,7 +42,7 @@ from trino_tpu.metadata import Metadata, Session
 from trino_tpu.ops import (AggSpec, JoinType, SortKey, Step, hash_aggregate,
                            hash_join, order_by, prepare_build, top_n,
                            top_n_masked)
-from trino_tpu.ops.join import unique_inner_probe
+from trino_tpu.ops.join import set_semi_join, unique_inner_probe
 from trino_tpu.page import (Column, Page, SplitColumn, concat_pages,
                             count_host_staging, defer_compaction,
                             host_staged_bytes, in_chunks, op_scope)
@@ -3023,25 +3023,68 @@ class LocalExecutionPlanner:
         duplicates, LEFT/FULL, SEMI/ANTI/MARK) reads run_len at the
         key's sorted POSITION and keeps the position table. Counted on
         the query's collector as `probe_lookups_row_table` /
-        `_position_table` / `_search`. `outer` (a LEFT join's build) only
-        names the programs.
+        `_position_table` / `_set_table` / `_search`. `outer` (a LEFT
+        join's build) only names the programs.
 
-        Returns (prepared [+ table], max_run, lookup)."""
-        from trino_tpu.ops.join import build_dense_table
+        `semi` (SEMI, ANTI, MARK) on ONE key column decides BEFORE it
+        sorts: such a join asks whether a key is there, not where, so
+        its bounds come from a pass of reductions over the page as it
+        was collected (semi_build_stats, the same one round trip), and
+        a span the position table's rule admits gets the set table —
+        one scatter from the unsorted lanes, lookup 'set', the
+        prepared tuple set_semi_join's (the build page is not in it:
+        the caller may free it) — where any other span sorts as before
+        for `search`. A composite key is mix-hashed and verifies its
+        candidates through the sort permutation: it takes the path it
+        always took. `semi_build_lanes_set` / `_sorted` sum the build
+        pages' capacities by which way they went.
+
+        Returns (prepared [+ table], max_run, lookup); a semi join
+        reads no max_run and gets None."""
+        from trino_tpu.ops.join import (build_dense_table, build_set_table,
+                                        semi_build_stats)
+        # the position table keeps the fill rule it had (at most 4 slots
+        # a build lane): no chip run has timed it
+        fill_limit = min(max(4 * build_page.capacity, 1 << 20),
+                         self._DENSE_MAX_SLOTS)
+        if semi and len(build_keys) == 1:
+            stats_op = cached_kernel(
+                ("semijoin-stats", tuple(build_keys)),
+                lambda: semi_build_stats(build_keys))
+            kmin_dev, kmax_dev, n_rows, has_null = stats_op(build_page)
+            kmin, kmax = (int(x) for x in host_read(
+                [kmin_dev, kmax_dev], "build_key_stats"))
+            span = kmax - kmin + 1 if kmax >= kmin else 0
+            if not 0 < span <= fill_limit:
+                self._adaptive_event("semi_build_lanes_sorted",
+                                     build_page.capacity)
+                self._count_lookup("search")
+                return self._prepare_build(build_keys, build_page,
+                                           semi), None, "search"
+            size = _next_pow2(span)
+            table_op = cached_kernel(
+                ("semijoin-set-table", tuple(build_keys), size),
+                lambda: build_set_table(build_keys, size))
+            table, key_cols = table_op(build_page, kmin_dev)
+            self._adaptive_event("semi_build_lanes_set",
+                                 build_page.capacity)
+            self._count_lookup("set_table")
+            return (table, kmin_dev, n_rows, has_null, key_cols), None, \
+                "set"
         prepared = self._prepare_build(build_keys, build_page, semi, outer)
         max_run, kmin, kmax = (int(x) for x in host_read(
             [prepared[7], prepared[8], prepared[9]], "build_key_stats"))
+        if semi:
+            self._adaptive_event("semi_build_lanes_sorted",
+                                 build_page.capacity)
         rows = inner and max_run <= 1
         span = kmax - kmin + 1 if kmax >= kmin else 0
         # the row table is worth its slots at any fill: one gather runs
         # at 115-137 M lanes/s into a table of 2^24 slots holding 1.5 M
         # keys or a hundred, the search at 15-20 M, and the table's own
         # scatter is 1-21 ms (chip, PERF.md PR 38) — so only the slot cap
-        # bounds it. The position table keeps the fill rule it had
-        # (at most 4 slots a build lane): no chip run has timed it
-        limit = self._DENSE_MAX_SLOTS if rows else \
-            min(max(4 * build_page.capacity, 1 << 20),
-                self._DENSE_MAX_SLOTS)
+        # bounds it
+        limit = self._DENSE_MAX_SLOTS if rows else fill_limit
         if not 0 < span <= limit:
             self._count_lookup("search")
             return prepared, max_run, "search"
@@ -3231,9 +3274,8 @@ class LocalExecutionPlanner:
 
         def semi_op(cap: int, mode: str = "search"):
             def build():
-                op = hash_join(probe_keys, build_keys, jt,
-                               output_capacity=cap, prepared=True,
-                               lookup=mode, null_aware=semi.null_aware)
+                op = _semi_probe(probe_keys, build_keys, jt, cap, mode,
+                                 semi.null_aware)
                 fn = None if rest_lowered is None \
                     else compile_filter(rest_lowered)
 
@@ -3258,6 +3300,7 @@ class LocalExecutionPlanner:
             return lambda p, b: kernel(p, b, rest_params)
 
         def gen():
+            nonlocal build_page
             bp = build_page
             if bp is None:
                 if jt == JoinType.SEMI:
@@ -3267,6 +3310,11 @@ class LocalExecutionPlanner:
                 self._count_rows("semi_join_build_rows", bp.num_rows)
                 prepared, _max_run, mode = self._prepare_probe(
                     build_keys, bp, semi=True)
+                if mode == "set":
+                    # the set table is all the probe reads: the build
+                    # page's columns go before the first probe page comes
+                    self._free_collected(build_page)
+                    bp = build_page = None
                 yield from _run_with_overflow(
                     self._lookup_lanes(self._counted(
                         self._coalesce_stream(probe_stream),
@@ -3294,10 +3342,8 @@ class LocalExecutionPlanner:
             return cached_kernel(
                 ("markjoin", tuple(probe_keys), tuple(build_keys), cap,
                  node.null_aware, mode),
-                lambda: hash_join(probe_keys, build_keys, JoinType.MARK,
-                                  output_capacity=cap, prepared=True,
-                                  lookup=mode,
-                                  null_aware=node.null_aware))
+                lambda: _semi_probe(probe_keys, build_keys, JoinType.MARK,
+                                    cap, mode, node.null_aware))
 
         def no_match(page: Page) -> Page:
             mark = Column(jnp.zeros(page.capacity, dtype=jnp.bool_), None,
@@ -3305,6 +3351,7 @@ class LocalExecutionPlanner:
             return Page(tuple(page.columns) + (mark,), page.num_rows)
 
         def gen():
+            nonlocal build_page
             bp = build_page
             if bp is None:
                 for page in probe_stream.iter_pages():
@@ -3314,6 +3361,9 @@ class LocalExecutionPlanner:
                 self._count_rows("semi_join_build_rows", bp.num_rows)
                 prepared, _max_run, mode = self._prepare_probe(
                     build_keys, bp, semi=True)
+                if mode == "set":
+                    self._free_collected(build_page)
+                    bp = build_page = None
                 yield from _run_with_overflow(
                     self._lookup_lanes(self._counted(
                         self._coalesce_stream(probe_stream),
@@ -3656,6 +3706,18 @@ def _count_overflow_rerun() -> None:
     observer = get_observer()
     if hasattr(observer, "probe_overflow_reruns"):
         observer.probe_overflow_reruns += 1
+
+
+def _semi_probe(probe_keys, build_keys, join_type: str, cap: int,
+                mode: str, null_aware: bool):
+    """The probe of a SEMI, ANTI or MARK join for the lookup
+    `_prepare_probe(semi=True)` decided: one gather against the set
+    table ('set'), else hash_join over the sorted build."""
+    if mode == "set":
+        return set_semi_join(probe_keys, join_type, null_aware)
+    return hash_join(probe_keys, build_keys, join_type,
+                     output_capacity=cap, prepared=True, lookup=mode,
+                     null_aware=null_aware)
 
 
 def _run_with_overflow(probe_stream: PageStream, build_page: Page,

@@ -257,12 +257,22 @@ class QueryStatsCollector:
         # build): `row_table` — a unique INNER build under the span limit,
         # one gather a probe lane against a table of build rows;
         # `position_table` — any other build under it, one gather against
-        # a table of sorted positions, then run_len there; `search` — a
-        # searchsorted a buffer. `probe_lookup_lanes` sums the capacities
-        # of the probe buffers those lookups ran over
+        # a table of sorted positions, then run_len there; `set_table` —
+        # a single-key SEMI, ANTI or MARK build under it, one gather
+        # against a membership table scattered from the unsorted keys, no
+        # sort at all; `search` — a searchsorted a buffer.
+        # `probe_lookup_lanes` sums the capacities of the probe buffers
+        # those lookups ran over
         self.probe_lookups_row_table = 0
         self.probe_lookups_position_table = 0
+        self.probe_lookups_set_table = 0
         self.probe_lookups_search = 0
+        # capacities of the build pages of the SEMI, ANTI and MARK joins
+        # (shapes, no sync): those whose lanes went into the set table as
+        # they arrived, and those that were sorted (`search`, a composite
+        # key)
+        self.semi_build_lanes_set = 0
+        self.semi_build_lanes_sorted = 0
         self.probe_lookup_lanes = 0
         # ... and those of them that went through `search` (a key of
         # several columns is mix-hashed to 64 bits, so its span fits no
@@ -592,8 +602,8 @@ class QueryStatsCollector:
         self.probe_compaction_lanes_gathered += int(lanes_gathered)
 
     def count_probe_lookup(self, table: str) -> None:
-        """One join's lookup decision: `row_table`, `position_table` or
-        `search`."""
+        """One join's lookup decision: `row_table`, `position_table`,
+        `set_table` or `search`."""
         name = "probe_lookups_" + table
         setattr(self, name, getattr(self, name) + 1)
 
@@ -764,7 +774,10 @@ class QueryStatsCollector:
             "probe_lookups_row_table": self.probe_lookups_row_table,
             "probe_lookups_position_table":
                 self.probe_lookups_position_table,
+            "probe_lookups_set_table": self.probe_lookups_set_table,
             "probe_lookups_search": self.probe_lookups_search,
+            "semi_build_lanes_set": self.semi_build_lanes_set,
+            "semi_build_lanes_sorted": self.semi_build_lanes_sorted,
             "probe_lookup_lanes": self.probe_lookup_lanes,
             "probe_lookup_lanes_search": self.probe_lookup_lanes_search,
             "like_tables_built": self.like_tables_built,

@@ -104,6 +104,47 @@ def _mark_page(probe: Page, matched: jnp.ndarray, pnull: jnp.ndarray,
     return Page(tuple(probe.columns) + (mark,), probe.num_rows)
 
 
+def _semi_verdict(probe: Page, join_type: str, null_aware: bool,
+                  matched: jnp.ndarray, pnull: jnp.ndarray,
+                  p_dead: jnp.ndarray, n_build_rows: jnp.ndarray,
+                  build_has_null: jnp.ndarray) -> Tuple[Page, jnp.ndarray]:
+    """A SEMI, ANTI or MARK join's answer from `matched` — which probe
+    lanes found their key on the build side — however that was found
+    (a sorted build's run counts in hash_join, a slot of the set table
+    in set_semi_join): the one place the NULL rules live (`null_aware`,
+    see hash_join). Returns (output_page, its rows)."""
+    if join_type == JoinType.MARK:
+        if null_aware:
+            out = _mark_page(probe, matched, pnull, n_build_rows,
+                             build_has_null)
+        else:
+            mark = Column(matched & ~pnull, None, T.BOOLEAN, None)
+            out = Page(tuple(probe.columns) + (mark,), probe.num_rows)
+        return out, probe.num_rows.astype(jnp.int64)
+    if join_type == JoinType.SEMI:
+        keep = matched & ~p_dead
+    elif null_aware:
+        # NOT IN: non-null probe keeps iff unmatched AND build has no
+        # NULLs; NULL probe keeps only against an empty build
+        keep = probe.row_mask() & jnp.where(
+            pnull, n_build_rows == 0, ~matched & ~build_has_null)
+    else:
+        # NOT EXISTS: unmatched live rows keep (NULL keys never match)
+        keep = probe.row_mask() & ~matched
+    out = probe.filter(keep)
+    return out, out.num_rows.astype(jnp.int64)
+
+
+def _live_key_bounds(bkey: jnp.ndarray, live_key: jnp.ndarray):
+    """(kmin, kmax) of the live non-NULL build keys in u64 space — kmin >
+    kmax where there is none: what the executor's router reads
+    (exec/local_planner._prepare_probe)."""
+    kmin = jnp.min(jnp.where(live_key, bkey,
+                             jnp.uint64(0xFFFFFFFFFFFFFFFF)))
+    kmax = jnp.max(jnp.where(live_key, bkey, jnp.uint64(0)))
+    return kmin, kmax
+
+
 def prepare_build(build_keys: Sequence[int], semi: bool = False):
     """Build-phase kernel: sort the build side ONCE into a LookupSource-like
     pytree consumed by every probe-page call (reference:
@@ -156,9 +197,7 @@ def prepare_build(build_keys: Sequence[int], semi: bool = False):
             # of a sort-engine searchsorted pass and three gathers: the
             # table holds the build row itself for a unique INNER build,
             # the sorted position (for run_len) for every other consumer
-            live_key = ~b_dead
-            kmin = jnp.min(jnp.where(live_key, bkey, u64max))
-            kmax = jnp.max(jnp.where(live_key, bkey, jnp.uint64(0)))
+            kmin, kmax = _live_key_bounds(bkey, ~b_dead)
         return (build, bkey_s, bperm, n_live_build, n_build_rows,
                 build_has_null, run_len, max_run_live, kmin, kmax)
     return prep
@@ -236,6 +275,84 @@ _PROBE_SCOPE = {JoinType.SEMI: "join__semi_probe",
                 JoinType.MARK: "join__mark_probe"}
 
 
+def semi_build_stats(build_keys: Sequence[int]):
+    """What a semi, anti or mark join must know of its build side before
+    a lane is ordered: op(build_page) -> (kmin, kmax, n_build_rows,
+    build_has_null) — the live non-NULL keys' bounds in u64 space (as
+    prepare_build forms them: kmin > kmax where there is none), the live
+    rows, and whether a live key is NULL. One pass of reductions over the
+    page as it was collected, no sort: the executor routes on the span
+    (exec/local_planner._prepare_probe), the NULL rules read the rest."""
+    build_keys = tuple(build_keys)
+
+    def op(build: Page):
+        with op_scope("join__semi_build"):
+            bkey, bnull = _key_u64(build, build_keys)
+            live_b = build.row_mask()
+            kmin, kmax = _live_key_bounds(bkey, live_b & ~bnull)
+            n_build_rows = jnp.sum(live_b).astype(jnp.int32)
+            build_has_null = jnp.any(bnull & live_b)
+        return kmin, kmax, n_build_rows, build_has_null
+    return op
+
+
+def build_set_table(build_keys: Sequence[int], size: int):
+    """Membership table of a single-column build, straight from the keys
+    as they arrive: op(build_page, kmin) -> (table, key_cols) where
+    table[key - kmin] is 0 where a live non-NULL key falls and the
+    sentinel elsewhere. A semi, anti or mark join asks whether a key is
+    there, not where — no sorted position, no permutation, no run length
+    — so ONE scatter of a constant over the unsorted lanes is the whole
+    build; duplicates write the same value. Dead lanes, NULL keys and
+    keys outside the span route to the dropped slot `size`, as
+    build_dense_table routes them. `key_cols` are the key columns cut to
+    no lane: their dictionaries are all the probe still asks of the
+    build page, whose columns the executor then frees."""
+    build_keys = tuple(build_keys)
+
+    def op(build: Page, kmin):
+        with op_scope("join__semi_build"):
+            bkey, bnull = _key_u64(build, build_keys)
+            raw = bkey - kmin
+            oob = ~build.row_mask() | bnull | (raw >= jnp.uint64(size))
+            slot = jnp.where(oob, jnp.uint64(size), raw).astype(jnp.int32)
+            table = jnp.full(size, _DENSE_SENTINEL, jnp.int32) \
+                .at[slot].set(np.int32(0), mode="drop")
+        key_cols = tuple(
+            Column(c.values[:0], None, c.type, c.dictionary)
+            for c in (build.column(bk) for bk in build_keys))
+        return table, key_cols
+    return op
+
+
+def set_semi_join(probe_keys: Sequence[int], join_type: str,
+                  null_aware: bool = True
+                  ) -> Callable[[Page, tuple], Tuple[Page, jnp.ndarray]]:
+    """A SEMI, ANTI or MARK join on ONE key column against the set table:
+    op(probe_page, (table, kmin, n_build_rows, build_has_null, key_cols))
+    -> (output_page, its rows), what hash_join(prepared=True) returns for
+    the same join over a sorted build. One gather a probe lane — slot
+    identity is key equality, to_u64 being injective — then the verdict
+    hash_join's single-key exit runs (_semi_verdict). The output never
+    exceeds the probe page, so nothing overflows."""
+    probe_keys = tuple(probe_keys)
+    if len(probe_keys) != 1 or join_type not in _PROBE_SCOPE:
+        raise ValueError("the set table serves SEMI, ANTI and MARK joins "
+                         "on one key column")
+
+    def op(probe: Page, prepared) -> Tuple[Page, jnp.ndarray]:
+        table, kmin, n_build_rows, build_has_null, key_cols = prepared
+        _check_key_dictionaries(probe, probe_keys, key_cols)
+        with op_scope(_PROBE_SCOPE[join_type]):
+            pkey, pnull = _key_u64(probe, probe_keys)
+            p_dead = ~probe.row_mask() | pnull
+            matched = (_dense_lo(table, kmin, pkey) != _DENSE_SENTINEL) \
+                & ~p_dead
+        return _semi_verdict(probe, join_type, null_aware, matched, pnull,
+                             p_dead, n_build_rows, build_has_null)
+    return op
+
+
 def _outer_fill(outer: bool):
     """The scope of what makes a LEFT or FULL join outer — an unmatched
     probe row's one emitted slot, the slots that are null-extensions, the
@@ -243,6 +360,21 @@ def _outer_fill(outer: bool):
     INNER join's ops stay where they were."""
     return op_scope("join__outer_fill") if outer \
         else contextlib.nullcontext()
+
+
+def _check_key_dictionaries(probe: Page, probe_keys: Sequence[int],
+                            build_key_cols: Sequence[Column]) -> None:
+    """Dictionary-coded keys join by code, so both sides must share one
+    pool: content-fingerprint inequality (page.py round 10), not object
+    identity — pools with byte-identical values share one code mapping,
+    so joining across them is exact."""
+    for pk, bc in zip(probe_keys, build_key_cols):
+        pd = probe.column(pk).dictionary
+        bd = bc.dictionary
+        if pd is not None and bd is not None and pd != bd:
+            raise NotImplementedError(
+                "string join keys across distinct dictionaries; "
+                "re-encode to a shared dictionary first")
 
 
 def _check_lookup(lookup: str) -> None:
@@ -316,16 +448,8 @@ def hash_join(
         n_probe = probe.capacity
         n_probe_cols = probe.num_columns
         cap = output_capacity or n_probe
-        for pk, bk in zip(probe_keys, build_keys):
-            pd = probe.column(pk).dictionary
-            bd = build.column(bk).dictionary
-            # content-fingerprint inequality (page.py round 10), not
-            # object identity: pools with byte-identical values share one
-            # code mapping, so joining across them is exact
-            if pd is not None and bd is not None and pd != bd:
-                raise NotImplementedError(
-                    "string join keys across distinct dictionaries; "
-                    "re-encode to a shared dictionary first")
+        _check_key_dictionaries(
+            probe, probe_keys, [build.column(bk) for bk in build_keys])
 
         with op_scope(_PROBE_SCOPE.get(join_type, "join__probe_lookup")):
             pkey, pnull = _key_u64(probe, probe_keys)
@@ -357,34 +481,15 @@ def hash_join(
             hi = jnp.minimum(hi, n_live_build)
             counts = jnp.where(p_dead, 0, hi - lo).astype(jnp.int64)
 
-        def anti_keep(matched: jnp.ndarray) -> jnp.ndarray:
-            live = probe.row_mask()
-            if null_aware:
-                # NOT IN: non-null probe keeps iff unmatched AND build has
-                # no NULLs; NULL probe keeps only against an empty build
-                return live & jnp.where(
-                    pnull, n_build_rows == 0, ~matched & ~build_has_null)
-            # NOT EXISTS: unmatched live rows keep (NULL keys never match)
-            return live & ~matched
-
-        def mark_page(matched: jnp.ndarray) -> Page:
-            if null_aware:
-                return _mark_page(probe, matched, pnull, n_build_rows,
-                                  build_has_null)
-            value = matched & ~pnull
-            mark = Column(value, None, T.BOOLEAN, None)
-            return Page(tuple(probe.columns) + (mark,), probe.num_rows)
+        def verdict(matched: jnp.ndarray) -> Tuple[Page, jnp.ndarray]:
+            return _semi_verdict(probe, join_type, null_aware, matched,
+                                 pnull, p_dead, n_build_rows,
+                                 build_has_null)
 
         if join_type in (JoinType.SEMI, JoinType.ANTI, JoinType.MARK) \
                 and not (composite and verify_composite):
             # single-column keys: to_u64 is injective, hash match == key match
-            if join_type == JoinType.MARK:
-                return mark_page(counts > 0), probe.num_rows.astype(jnp.int64)
-            if join_type == JoinType.SEMI:
-                out = probe.filter((counts > 0) & ~p_dead)
-            else:
-                out = probe.filter(anti_keep(counts > 0))
-            return out, out.num_rows.astype(jnp.int64)
+            return verdict(counts > 0)
 
         with op_scope("join__probe_expand"):
             emit = counts
@@ -426,15 +531,7 @@ def hash_join(
                     keep = keep & (pv == bv)
                 verified = jnp.zeros(n_probe, dtype=jnp.bool_) \
                     .at[prow_c].max(keep, mode="drop")
-            if join_type == JoinType.MARK:
-                rows = probe.num_rows.astype(jnp.int64)
-                return mark_page(verified), \
-                    jnp.where(total <= cap, rows, total)
-            if join_type == JoinType.SEMI:
-                out = probe.filter(verified & ~p_dead)
-            else:
-                out = probe.filter(anti_keep(verified))
-            rows = out.num_rows.astype(jnp.int64)
+            out, rows = verdict(verified)
             return out, jnp.where(total <= cap, rows, total)
 
         real_match = slot_live & matched      # slot is a real hash candidate
@@ -534,9 +631,7 @@ def prepare_build_spilled(build_keys: Sequence[int]):
         idx = jnp.arange(build.capacity, dtype=jnp.int32)
         dup = (bkey_s[1:] == bkey_s[:-1]) & (idx[1:] < n_live)
         is_unique = ~jnp.any(dup)
-        live_key = ~b_dead
-        kmin = jnp.min(jnp.where(live_key, bkey, u64max))
-        kmax = jnp.max(jnp.where(live_key, bkey, jnp.uint64(0)))
+        kmin, kmax = _live_key_bounds(bkey, ~b_dead)
         return (bkey_s, bperm, n_live, n_build_rows, build_has_null,
                 is_unique, kmin, kmax)
     return prep
@@ -723,16 +818,8 @@ def unique_inner_probe(
         (build, bkey_s, bperm, n_live_build, n_build_rows,
          build_has_null, run_len, _max_run, kmin, _kmax) = prepared[:10]
         n_build = build.capacity
-        for pk, bk in zip(probe_keys, build_keys):
-            pd = probe.column(pk).dictionary
-            bd = build.column(bk).dictionary
-            # content-fingerprint inequality (page.py round 10), not
-            # object identity: pools with byte-identical values share one
-            # code mapping, so joining across them is exact
-            if pd is not None and bd is not None and pd != bd:
-                raise NotImplementedError(
-                    "string join keys across distinct dictionaries; "
-                    "re-encode to a shared dictionary first")
+        _check_key_dictionaries(
+            probe, probe_keys, [build.column(bk) for bk in build_keys])
         with op_scope("join__probe_lookup"):
             pkey, pnull = _key_u64(probe, probe_keys)
             p_dead = ~probe.row_mask() | pnull
